@@ -96,12 +96,61 @@ def device_sort_table(table: DeviceTable, orders: Sequence[SortOrder]) -> Device
     return DeviceTable(cols, mask, table.num_rows, table.names)
 
 
+#: rows one top-n tournament sort spans. For v5e, XLA compiles the
+#: 8-operand lexsort of TPC-H Q3's top-n in 1 s at this width inside a
+#: ``lax.map`` body, in 101 s at 2^14 rows, 532 s at 2^16 and (a batched
+#: sort along one axis of a 2-D array) 129 s at (64, 1024) — ROADMAP A1.
+_TOPN_CHUNK = 2048
+
+
+def _chunk_winners(table: DeviceTable, orders: Sequence[SortOrder],
+                   k: int) -> DeviceTable:
+    """The first ``k`` rows in sort order of every ``_TOPN_CHUNK``-row
+    chunk, as one table of capacity chunks*k. Exact for top-n with n <= k:
+    a row leaves only when k rows of its own chunk sort before it, and
+    those precede it globally too. The sort is stable and chunks stay in
+    input order, so fully tied rows keep their input order exactly as
+    under the full sort. Chunks are sorted one after another by ONE
+    compiled 1-D sort (``lax.map``)."""
+    chunks = table.capacity // _TOPN_CHUNK
+    keys = tuple(x.reshape(chunks, _TOPN_CHUNK)
+                 for x in _order_keys(table, orders))
+    order = jax.lax.map(lambda ks: jnp.lexsort(ks)[:k].astype(jnp.int32),
+                        keys)
+    base = jnp.arange(chunks, dtype=jnp.int32) * _TOPN_CHUNK
+    idx = (order + base[:, None]).reshape(-1)
+    # the row mask travels with the rows: only real rows stay exposed
+    cols = tuple(c.gather(idx, keep_all_valid=True) for c in table.columns)
+    mask = jnp.take(table.row_mask, idx)
+    return DeviceTable(cols, mask, jnp.sum(mask, dtype=jnp.int32),
+                       table.names)
+
+
+def _topn_reduce(table: DeviceTable, orders: Sequence[SortOrder], n: int,
+                 cap: int) -> DeviceTable:
+    """Cut a wide batch down to chunk winners until one chunk-wide sort
+    finishes the top-n; the result never drops below the ``cap``-row state
+    capacity. Batches the chunk width does not divide, and n above half a
+    chunk, keep the full sort."""
+    kn = 1 << max(n - 1, 0).bit_length()
+    while table.capacity > _TOPN_CHUNK \
+            and table.capacity % _TOPN_CHUNK == 0:
+        chunks = table.capacity // _TOPN_CHUNK
+        k = max(kn, -(-cap // chunks))
+        if k > _TOPN_CHUNK // 2:
+            break
+        table = _chunk_winners(table, orders, k)
+    return table
+
+
 class TpuTakeOrderedExec(TpuExec):
     """Device top-n (reference: GpuTakeOrderedAndProjectExec, limit.scala).
 
     Folds batches through a running top-n: sort batch, truncate to n,
     concat with state, sort, truncate — state stays at a bucketed n-row
-    capacity so the kernel shapes are stable across batches."""
+    capacity so the kernel shapes are stable across batches. A batch wider
+    than ``_TOPN_CHUNK`` is first reduced to its chunks' winners
+    (``_topn_reduce``), so no sort in the program is wider than a chunk."""
 
     EXTRA_METRICS = (M.SORT_TIME,)
 
@@ -127,7 +176,8 @@ class TpuTakeOrderedExec(TpuExec):
 
         def make():
             def fn(table: DeviceTable) -> DeviceTable:
-                s = device_sort_table(table, orders)
+                s = device_sort_table(
+                    _topn_reduce(table, orders, n, cap), orders)
                 iota = jnp.arange(s.capacity, dtype=jnp.int32)
                 keep = jnp.minimum(s.num_rows, jnp.int32(n))
                 mask = iota < keep

@@ -52,10 +52,7 @@ def donation_active(conf) -> bool:
         return False
     if conf.get(DONATION_FORCE):
         return True
-    try:
-        return jax.default_backend() != "cpu"
-    except Exception:  # backend init failure: planning must not die here  # srtpu: degrade-ok(plan-time capability probe, no device work in flight)
-        return False
+    return jax.default_backend() != "cpu"
 
 
 class TpuWholeStageExec(TpuExec):

@@ -694,15 +694,25 @@ class SpillableDeviceTable:
         self.catalog.close_buffer(self.buffer_id)
 
 
+#: what the CPU backend's "device" is taken to hold: XLA:CPU has no
+#: memory_stats(), and the tests size their pools against this default
+CPU_BACKEND_DEVICE_BYTES = 8 * 1024 * 1024 * 1024
+
+
 def _device_memory_bytes() -> int:
-    try:
-        d = jax.devices()[0]
-        ms = d.memory_stats()
-        if ms and "bytes_limit" in ms:
-            return int(ms["bytes_limit"])
-    except Exception:
-        pass
-    return 8 * 1024 * 1024 * 1024  # assume 8 GiB HBM when unknown
+    """HBM the pool may plan with, as the device reports it. An accelerator
+    that reports none is an error: a guessed size either wastes half the
+    chip or plans past its end."""
+    d = jax.devices()[0]
+    ms = d.memory_stats()
+    if ms and "bytes_limit" in ms:
+        return int(ms["bytes_limit"])
+    if d.platform == "cpu":
+        return CPU_BACKEND_DEVICE_BYTES
+    raise RuntimeError(
+        f"{d.platform} device {d.device_kind!r} reports no bytes_limit "
+        f"(memory_stats() = {ms!r}); set spark.rapids.tpu.memory.pool.size "
+        f"explicitly")
 
 
 _GLOBAL: Optional[BufferCatalog] = None

@@ -16,14 +16,16 @@ the same way:
 
 Same design as the reference: a provider class per version range, a loader
 that probes installed versions once and composes the active shim set, and
-call sites that go through ``get_shims()`` instead of the raw APIs.
+call sites that go through ``get_shims()`` instead of the raw APIs. One
+installation is supported (pandas 2, numpy 2, jax 0.9), so the current-API
+provider is the only one shipped; ``register_shim_provider`` is where a
+version quirk goes when one appears.
 """
 from __future__ import annotations
 
 from typing import Callable, List, Tuple, Type
 
-__all__ = ["ShimVersions", "HostLibShims", "LegacyPandasShims",
-           "LegacyJaxShims", "get_shims", "detect_versions",
+__all__ = ["ShimVersions", "HostLibShims", "get_shims", "detect_versions",
            "register_shim_provider"]
 
 
@@ -99,31 +101,9 @@ class HostLibShims:
         return tree_util.tree_map(fn, *trees)
 
 
-class LegacyPandasShims(HostLibShims):
-    """pandas < 1.5: pre-``use_na_sentinel`` keyword."""
-
-    shim_name = "pandas-legacy"
-
-    def factorize(self, values, sort: bool = False):
-        import pandas as pd
-        return pd.factorize(values, na_sentinel=None, sort=sort)
-
-
-class LegacyJaxShims(HostLibShims):
-    """jax < 0.4.26: ``jax.tree_map`` still canonical."""
-
-    shim_name = "jax-legacy"
-
-    def tree_map(self, fn, *trees):
-        import jax
-        return jax.tree_map(fn, *trees)
-
-
 # (predicate, provider) — FIRST match wins, mirroring the reference's
 # per-version shim resolution; extend with register_shim_provider.
 _PROVIDERS: List[Tuple[Callable[[ShimVersions], bool], Type[HostLibShims]]] = [
-    (lambda v: v.pandas < (1, 5), LegacyPandasShims),
-    (lambda v: v.jax < (0, 4, 26), LegacyJaxShims),
     (lambda v: True, HostLibShims),
 ]
 

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..parallel.shard_compat import shard_map
 
 from ..columnar.device import (DeviceColumn, DeviceTable,
                                stable_counting_order)
@@ -34,8 +33,8 @@ from ..utils import movement
 from . import telemetry
 from .manager import device_partition_ids
 
-__all__ = ["ici_all_to_all_exchange", "shard_table", "unshard_table",
-           "clear_exchange_programs"]
+__all__ = ["ici_all_to_all_exchange", "exchange_program", "shard_table",
+           "unshard_table", "clear_exchange_programs"]
 
 # movement-observatory site identity (utils/movement.py SITES)
 _MOVE_UNSHARD = "spark_rapids_tpu/shuffle/ici.py::unshard_table"
@@ -101,21 +100,12 @@ def _program_key(table: DeviceTable, key_names: List[str], mesh: Mesh,
             (table.row_mask.shape, str(table.row_mask.dtype)))
 
 
-def ici_all_to_all_exchange(table: DeviceTable, key_names: List[str],
-                            mesh: Mesh, axis: str = "dp",
-                            quota: int | None = None,
-                            telemetry_sid: int | None = None
-                            ) -> DeviceTable:
-    """Hash-exchange a row-sharded table so rows with equal keys land on the
-    same shard, as one jitted shard_map program (collectives over ICI).
-
-    ``quota`` is the per-(source, destination) slot count; it MUST be >= the
-    max rows any shard sends to any destination (callers size it from a count
-    pass; undersizing would drop rows). Defaults to local capacity (always
-    safe). Returns a row-sharded table with per-shard capacity n * quota
-    (padding masked off)."""
+def exchange_program(columns, names, key_names: List[str], mesh: Mesh,
+                     axis: str = "dp", quota: int | None = None):
+    """The jitted shard_map all-to-all program for tables shaped like
+    ``columns`` (arrays or ShapeDtypeStructs — only the pytree structure
+    is read), taking ``(columns, row_mask)`` row-sharded over ``axis``."""
     n = mesh.shape[axis]
-    names = table.names
 
     # the column tuple is a pytree whose leaves are the per-column planes
     # (data/validity/lengths/elem_validity + struct children, recursively)
@@ -152,13 +142,35 @@ def ici_all_to_all_exchange(table: DeviceTable, key_names: List[str],
         out_cols = jax.tree_util.tree_map(xform, columns)
         return out_cols, out_mask
 
+    col_specs = jax.tree_util.tree_map(lambda _: P(axis), columns)
+    # check_vma off: the exchange's output specs are data-dependent in
+    # ways the static replication checker rejects
+    return jax.jit(jax.shard_map(local, mesh=mesh,
+                                 in_specs=(col_specs, P(axis)),
+                                 out_specs=(col_specs, P(axis)),
+                                 check_vma=False))
+
+
+def ici_all_to_all_exchange(table: DeviceTable, key_names: List[str],
+                            mesh: Mesh, axis: str = "dp",
+                            quota: int | None = None,
+                            telemetry_sid: int | None = None
+                            ) -> DeviceTable:
+    """Hash-exchange a row-sharded table so rows with equal keys land on the
+    same shard, as one jitted shard_map program (collectives over ICI).
+
+    ``quota`` is the per-(source, destination) slot count; it MUST be >= the
+    max rows any shard sends to any destination (callers size it from a count
+    pass; undersizing would drop rows). Defaults to local capacity (always
+    safe). Returns a row-sharded table with per-shard capacity n * quota
+    (padding masked off)."""
+    n = mesh.shape[axis]
+    names = table.names
     key = _program_key(table, key_names, mesh, axis, quota)
     prog = _PROGRAMS.get(key)
     if prog is None:
-        col_specs = jax.tree_util.tree_map(lambda _: P(axis), table.columns)
-        fn = jax.jit(shard_map(local, mesh=mesh,
-                               in_specs=(col_specs, P(axis)),
-                               out_specs=(col_specs, P(axis)), check=False))
+        fn = exchange_program(table.columns, names, key_names, mesh, axis,
+                              quota)
         # one-time lower + XLA compile, timed as its own observatory
         # phase: folding it into ``dispatch`` would read cold caches as
         # shuffle wall and trip the sentinel's shuffle-wall gate

@@ -291,16 +291,18 @@ _TINY_ROWS = {"lineitem": 3000, "orders": 750, "customer": 150, "part": 120,
               "supplier": 25, "partsupp": 480}
 
 
-def gen_all(sf: float, tiny: bool = False) -> "dict[str, pa.Table]":
-    """All 8 tables; ``tiny=True`` caps row counts for unit tests."""
+def gen_all(sf: float, tiny: bool = False, seed: int = 0
+            ) -> "dict[str, pa.Table]":
+    """All 8 tables; ``tiny=True`` caps row counts for unit tests. Table i
+    draws from ``seed + i`` (seed 0 = each generator's own default)."""
     out = {}
-    for name, g in TABLE_GENERATORS.items():
+    for i, (name, g) in enumerate(TABLE_GENERATORS.items()):
         if name in ("nation", "region"):
             out[name] = g(sf)
         elif tiny:
-            out[name] = g(sf, rows=_TINY_ROWS[name])
+            out[name] = g(sf, seed=seed + i, rows=_TINY_ROWS[name])
         else:
-            out[name] = g(sf)
+            out[name] = g(sf, seed=seed + i)
     return out
 
 

@@ -98,6 +98,12 @@ def word_count(mat):
 # ---------------------------------------------------------------------------
 # device tier: explicit Pallas kernel
 # ---------------------------------------------------------------------------
+_LANES = 128
+#: rows of one (rows, 128) float32 block: 512 KiB per operand, so the four
+#: double-buffered operands take 4 MiB of a v5e core's 16 MiB scoped VMEM
+_BLOCK_ROWS = 1024
+
+
 def _axpy_kernel(a_ref, x_ref, y_ref, o_ref):
     o_ref[...] = a_ref[...] * x_ref[...] + y_ref[...]
 
@@ -106,17 +112,34 @@ def _pallas_axpy_device(a, x, y):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
-    a = jnp.asarray(a, dtype=jnp.float32)
-    x = jnp.asarray(x, dtype=jnp.float32)
-    y = jnp.asarray(y, dtype=jnp.float32)
-    # pallas runs compiled on TPU; interpret mode keeps the same kernel
-    # runnable on the CPU test backend
-    interpret = jax.default_backend() != "tpu"
-    return pl.pallas_call(
+    n = a.shape[0]
+    # a column is viewed as (rows, 128) lanes, rows padded to the float32
+    # tile's 8 sublanes, and walked by a 1-D grid of VMEM-sized blocks: a
+    # whole 2^23-row SF1 column in one block is refused by the compiler
+    # (RESOURCE_EXHAUSTED in vmem from 2^22 rows up)
+    pad = (-n) % (8 * _LANES)
+
+    def tiled(v):
+        v = jnp.pad(jnp.asarray(v, dtype=jnp.float32), (0, pad))
+        return v.reshape(-1, _LANES)
+
+    a2, x2, y2 = tiled(a), tiled(x), tiled(y)
+    rows = a2.shape[0]
+    block = (min(rows, _BLOCK_ROWS), _LANES)
+    # int32 on purpose: the package runs with x64 on, and Mosaic cannot
+    # legalize the int64 a bare Python 0 would become in the index map
+    spec = pl.BlockSpec(block, lambda i: (i, jnp.int32(0)))
+    out = pl.pallas_call(
         _axpy_kernel,
-        out_shape=jax.ShapeDtypeStruct(a.shape, jnp.float32),
-        interpret=interpret,
-    )(a, x, y)
+        out_shape=jax.ShapeDtypeStruct(a2.shape, jnp.float32),
+        grid=(pl.cdiv(rows, block[0]),),
+        in_specs=[spec, spec, spec],
+        out_specs=spec,
+        # compiled wherever there is an accelerator; the interpreter only
+        # keeps the kernel runnable on the CPU test backend
+        interpret=jax.default_backend() == "cpu",
+    )(a2, x2, y2)
+    return out.reshape(-1)[:n]
 
 
 def _pallas_axpy_host(a, x, y):

@@ -11,9 +11,11 @@ compile-once-run-many discipline is ours to enforce.
 The cache is THREE tiers (ROADMAP item 2 — compile dominates bench wall):
 
 1. the in-process table above (``_CACHE``),
-2. XLA's own persistent compilation cache (``jax_compilation_cache_dir``,
-   wired under ``spark.rapids.tpu.compile.cacheDir`` and keyed by a
-   machine fingerprint + jax version so foreign executables never load),
+2. XLA's own persistent compilation cache. Where the environment places
+   it (``JAX_COMPILATION_CACHE_DIR``) it lives exactly there and the
+   engine never touches ``jax_compilation_cache_dir``; otherwise it is
+   wired under ``spark.rapids.tpu.compile.cacheDir``, scoped by a machine
+   fingerprint + jax version so foreign XLA:CPU executables never load,
 3. the engine's OWN manifest persisted alongside it: per plan signature,
    cumulative hit counts plus a serialized ``jax.export`` of the traced
    program at its first-call shapes. A fresh process replays the hottest
@@ -111,20 +113,32 @@ def configure_introspection(conf) -> None:
 # ---------------------------------------------------------------------------
 COMPILE_CACHE_ENABLED = register_conf(
     "spark.rapids.tpu.compile.enabled",
-    "Master switch for the persistent compilation tier: when true AND "
-    "spark.rapids.tpu.compile.cacheDir is set, XLA executables persist "
-    "across process restarts (jax_compilation_cache_dir) and the engine's "
-    "plan-signature manifest + program exports are saved on session close.",
+    "Master switch for the persistent compilation tier: when true AND a "
+    "cache directory is known (the JAX_COMPILATION_CACHE_DIR environment "
+    "variable, else spark.rapids.tpu.compile.cacheDir), XLA executables "
+    "persist across process restarts and the engine's plan-signature "
+    "manifest + program exports are saved on session close.",
     True)
 
 COMPILE_CACHE_DIR = register_conf(
     "spark.rapids.tpu.compile.cacheDir",
-    "Base directory of the persistent compilation tier; '' (default) "
-    "disables it. The engine scopes everything under a "
-    "<machine-fingerprint>-jax<version> subdirectory, so a shared "
+    "Base directory of the persistent compilation tier when the "
+    "JAX_COMPILATION_CACHE_DIR environment variable is unset; '' "
+    "(default) then disables the tier. The engine scopes everything under "
+    "a <machine-fingerprint>-jax<version> subdirectory, so a shared "
     "filesystem can hold caches for a fleet and no host ever loads "
-    "executables compiled for different CPU features or a different jax.",
+    "executables compiled for different CPU features or a different jax. "
+    "Where the environment variable is set it wins: XLA's cache stays "
+    "exactly there, untouched by the engine, and the engine's manifest "
+    "and exports go under its 'srtpu' subdirectory.",
     "")
+
+#: JAX reads this itself at import. A cache placed from outside has to be
+#: found again from another machine, so nothing machine-specific may enter
+#: the path and the engine must not re-point jax_compilation_cache_dir.
+_JAX_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+#: the engine's own files (manifest, exports, quarantine) under that dir
+_ENGINE_SUBDIR = "srtpu"
 
 WARM_POOL_ENABLED = register_conf(
     "spark.rapids.tpu.compile.warmPool.enabled",
@@ -156,7 +170,8 @@ _EXPORT_MAX_BYTES = 32 * 1024 * 1024
 # (most recent wins, like the tracer/pipeline chokepoints); _EXPORTABLE
 # retains (builder, aval-skeleton) per signature compiled THIS process so
 # session close can export the traced programs. All under _LOCK.
-_PERSIST: Dict = {"dir": None, "base": {}, "warm_enabled": True,
+_PERSIST: Dict = {"dir": None, "base": {}, "wired_xla": False,
+                  "warm_enabled": True,
                   "warm_max": int(WARM_POOL_MAX_SIGNATURES.default),
                   "warm_seconds": float(WARM_POOL_MAX_SECONDS.default)}
 _EXPORTABLE: Dict[str, Tuple[Callable, tuple]] = {}
@@ -661,49 +676,43 @@ def _load_manifest(path: str) -> Tuple[Dict[str, Dict], int]:
 
 def configure_compile_cache(conf) -> Optional[str]:
     """Apply spark.rapids.tpu.compile.* (called from TpuSession.__init__,
-    most recent session wins). Wires jax's persistent compilation cache,
-    loads the engine manifest, and starts the warm pool. Returns the tier
-    directory, or None when the tier is off."""
+    most recent session wins). Finds the tier directory (module docstring,
+    tier 2), loads the engine manifest, and starts the warm pool. Returns
+    the directory of the engine's own files, or None when the tier is
+    off."""
     stop_warm_pool()
     enabled = bool(conf.get(COMPILE_CACHE_ENABLED))
+    external = os.environ.get(_JAX_CACHE_ENV, "").strip()
     base = str(conf.get(COMPILE_CACHE_DIR) or "").strip()
-    if not enabled or not base:
-        with _LOCK:
-            was_active = _PERSIST["dir"] is not None
-            _PERSIST["dir"] = None
-            _PERSIST["base"] = {}
-        if was_active:
-            # un-wire the XLA disk cache we set earlier: the most recent
-            # session owns the chokepoint, and its tier is off
-            try:
-                jax.config.update("jax_compilation_cache_dir", None)
-            except Exception:  # pragma: no cover
-                pass
+    if enabled and external:
+        tier, xla_dir = os.path.join(os.path.abspath(external),
+                                     _ENGINE_SUBDIR), None
+    elif enabled and base:
+        tier = os.path.join(os.path.abspath(base),
+                            f"{machine_fingerprint()}-jax{jax.__version__}")
+        xla_dir = os.path.join(tier, "xla")
+    else:
+        _persist_off()
         return None
-    tier = os.path.join(os.path.abspath(base),
-                        f"{machine_fingerprint()}-jax{jax.__version__}")
     try:
         os.makedirs(os.path.join(tier, "exports"), exist_ok=True)
-        os.makedirs(os.path.join(tier, "xla"), exist_ok=True)
+        if xla_dir is not None:
+            os.makedirs(xla_dir, exist_ok=True)
     except OSError as e:
         import warnings
         warnings.warn(f"persistent compile cache disabled: cannot create "
                       f"{tier!r} ({e})", RuntimeWarning)
-        with _LOCK:
-            _PERSIST["dir"] = None
-            _PERSIST["base"] = {}
+        _persist_off()
         return None
-    try:
-        # tier 2: XLA executables survive restarts. min_compile_time 0 —
-        # the user opted into a cache dir, so persist everything
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.join(tier, "xla"))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except Exception as e:  # pragma: no cover - depends on jax build
-        print(f"# jax compilation cache not wired: {e}", file=sys.stderr)
+    # tier 2: XLA executables survive restarts. min_compile_time 0 — a
+    # cache dir was asked for, so persist everything
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    if xla_dir is not None:
+        jax.config.update("jax_compilation_cache_dir", xla_dir)
     entries, dropped = _load_manifest(_manifest_path(tier))
     with _LOCK:
         _PERSIST["dir"] = tier
+        _PERSIST["wired_xla"] = xla_dir is not None
         _PERSIST["base"] = entries
         _PERSIST["warm_enabled"] = bool(conf.get(WARM_POOL_ENABLED))
         _PERSIST["warm_max"] = int(conf.get(WARM_POOL_MAX_SIGNATURES))
@@ -714,6 +723,19 @@ def configure_compile_cache(conf) -> Optional[str]:
     if warm and entries:
         _start_warm_pool()
     return tier
+
+
+def _persist_off() -> None:
+    """Tier off for the most recent session: it owns the chokepoint, so
+    un-wire the XLA disk cache an earlier session wired — never one the
+    environment placed."""
+    with _LOCK:
+        unwire = _PERSIST["wired_xla"]
+        _PERSIST["dir"] = None
+        _PERSIST["wired_xla"] = False
+        _PERSIST["base"] = {}
+    if unwire:
+        jax.config.update("jax_compilation_cache_dir", None)
 
 
 def _warm_items_locked() -> List[Tuple[str, str, str]]:

@@ -143,11 +143,11 @@ def test_mesh_checker_rules(tmp_path):
                 node.dispatch(p)
 
         def spmd(mesh, cols, run):
-            from spark_rapids_tpu.parallel.shard_compat import shard_map
+            import jax
             for i in range(mesh.shape["dp"]):
                 prime(i)   # spec plumbing around the collective: exempt
-            return shard_map(run, mesh=mesh, in_specs=None,
-                             out_specs=None)(cols)
+            return jax.shard_map(run, mesh=mesh, in_specs=None,
+                                 out_specs=None)(cols)
 
         def alloc(node):
             return [[] for _ in range(node.num_partitions)]

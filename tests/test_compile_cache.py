@@ -144,8 +144,10 @@ print("RESULT " + json.dumps(out))
 """
 
 
-def _run_subprocess(cache_dir: str, phase: str) -> dict:
+def _run_subprocess(cache_dir: str, phase: str, **extra_env) -> dict:
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    env.update(extra_env)
     r = subprocess.run(
         [sys.executable, "-c", _SCRIPT.format(repo=REPO), cache_dir, phase],
         capture_output=True, text=True, timeout=480, env=env, cwd=REPO)
@@ -182,6 +184,21 @@ def test_persistent_tier_zero_compiles_across_processes(tmp_path):
         merged = json.load(f)
     assert sum(e["hits"] for e in merged["entries"].values()) \
         > sum(e["hits"] for e in manifest["entries"].values())
+
+
+def test_external_cache_dir_holds_every_entry(tmp_path):
+    """A fresh process with JAX_COMPILATION_CACHE_DIR set: XLA's entries
+    land directly in that directory, the engine's under its 'srtpu'
+    subdirectory, and nothing is written where the conf points."""
+    ext, conf_dir = tmp_path / "placed", tmp_path / "conf"
+    out = _run_subprocess(str(conf_dir), "cold",
+                          JAX_COMPILATION_CACHE_DIR=str(ext))
+    assert out["stats"]["compiles"] > 0
+    names = os.listdir(ext)
+    assert [n for n in names if n != "srtpu"], names    # XLA executables
+    assert (ext / "srtpu" / "manifest.json").exists()
+    assert os.listdir(ext / "srtpu" / "exports")
+    assert not conf_dir.exists()
 
 
 def test_warm_pool_precompiles_then_hits(tmp_path, tier_reset):
@@ -260,6 +277,65 @@ def test_persist_merges_deltas_not_raw_totals(tmp_path, tier_reset):
     cached_jit("test|delta|v1", builder)(x)   # in-process hit
     sess2.close()
     assert (entry()["compiles"], entry()["hits"]) == (1, 1)
+
+
+def test_external_cache_dir_is_left_to_jax(tmp_path, tier_reset,
+                                           monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR places the cache from outside: the engine
+    never re-points jax_compilation_cache_dir (not when a conf dir is also
+    given, not when a later session has the tier off), adds no fingerprint
+    level, and keeps its own files in the fixed 'srtpu' subdirectory."""
+    import jax as _jax
+    from spark_rapids_tpu.utils.compile_cache import (cached_jit,
+                                                      configure_compile_cache,
+                                                      persist_compile_cache,
+                                                      persistent_cache_dir)
+    ext = tmp_path / "placed"
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(ext))
+    # what jax's import would have read from the environment
+    before = _jax.config.jax_compilation_cache_dir
+    _jax.config.update("jax_compilation_cache_dir", str(ext))
+    try:
+        conf = RapidsConf(
+            {"spark.rapids.tpu.compile.cacheDir": str(tmp_path / "conf")})
+        assert configure_compile_cache(conf) == str(ext / "srtpu")
+        assert _jax.config.jax_compilation_cache_dir == str(ext)
+        cached_jit("test|external|v1", lambda: (lambda x: x * 3.0))(
+            jnp.ones(8))
+        persist_compile_cache()
+        assert "srtpu" in os.listdir(ext)
+        assert not [n for n in os.listdir(ext)
+                    if n.endswith(f"-jax{_jax.__version__}")]
+        assert (ext / "srtpu" / "manifest.json").exists()
+        assert not (tmp_path / "conf").exists()
+        # tier off in the next session: still not the engine's to un-wire
+        assert configure_compile_cache(RapidsConf(
+            {"spark.rapids.tpu.compile.enabled": False})) is None
+        assert persistent_cache_dir() is None
+        assert _jax.config.jax_compilation_cache_dir == str(ext)
+    finally:
+        _jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_default_cache_path_is_identical_across_calls(tmp_path, tier_reset,
+                                                     monkeypatch):
+    """Unset environment: the tier directory is a pure function of the
+    conf dir, this machine and the jax version — no pid, time or tempfile
+    — so a second call (or process) finds the first one's cache."""
+    import jax as _jax
+    from spark_rapids_tpu.utils.compile_cache import (configure_compile_cache,
+                                                      machine_fingerprint)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    conf = RapidsConf({"spark.rapids.tpu.compile.cacheDir": str(tmp_path)})
+    first = configure_compile_cache(conf)
+    xla_first = _jax.config.jax_compilation_cache_dir
+    _reset_tier()
+    assert _jax.config.jax_compilation_cache_dir is None
+    assert configure_compile_cache(conf) == first
+    assert _jax.config.jax_compilation_cache_dir == xla_first
+    assert first == os.path.join(
+        str(tmp_path), f"{machine_fingerprint()}-jax{_jax.__version__}")
+    assert xla_first == os.path.join(first, "xla")
 
 
 def test_corrupted_manifest_is_dropped_not_fatal(tmp_path, tier_reset):

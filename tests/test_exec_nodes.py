@@ -88,6 +88,26 @@ def test_take_ordered_device(session, rng):
                          ignore_order=False)
 
 
+def test_take_ordered_wide_batch_tournament(session, rng):
+    # one batch wider than a top-n chunk (40k rows -> 64k capacity) takes
+    # the chunk-winners reduction; heavy ties on the leading key and nulls
+    # must come out exactly as the host engine's full sort orders them
+    from spark_rapids_tpu.exec.sort import _TOPN_CHUNK
+    n = 5 * _TOPN_CHUNK - 960
+    k = rng.integers(0, 6, n).astype(np.float64)
+    k[rng.random(n) < 0.01] = np.nan
+    t = pa.table({"k": pa.array(k, from_pandas=False),
+                  "d": pa.array(rng.integers(0, 50, n), mask=rng.random(n) < 0.02),
+                  "i": np.arange(n)})
+    df = session.create_dataframe(t)
+    for q in (df.sort(col("k").desc(), col("d").asc(), col("i").asc()).limit(37),
+              df.filter(col("i") % lit(7) == lit(3))
+                .sort(col("d").desc(), col("k").asc(), col("i").desc()).limit(5)):
+        assert _has_node(session._physical(q.logical, True),
+                         "TpuTakeOrderedExec")
+        assert_tpu_cpu_equal(q, ignore_order=False)
+
+
 def test_take_ordered_n_larger_than_data(session, rng):
     t = data_gen(rng, 30, {"v": "float64"})
     df = session.create_dataframe(t, num_partitions=2)

@@ -91,6 +91,18 @@ def test_manager_recompute_hook():
 
 
 @pytest.mark.slow
+def test_worker_never_loads_the_tpu_runtime(monkeypatch):
+    """The driver process alone owns the chip: a spawned worker pins itself
+    to the CPU backend before touching a device, whatever JAX_PLATFORMS
+    the environment hands it, and never maps libtpu."""
+    from spark_rapids_tpu.parallel.runtime import (ProcessCluster,
+                                                   worker_backend_task)
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    with ProcessCluster(1) as cluster:
+        assert cluster.run_on(0, worker_backend_task) == {
+            "backend": "cpu", "libtpu_loaded": False}
+
+
 def test_process_cluster_shuffle_and_recovery():
     from spark_rapids_tpu.parallel.runtime import (
         ProcessCluster, shuffle_read_recompute_task, shuffle_read_task,

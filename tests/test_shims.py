@@ -3,8 +3,7 @@ resolution)."""
 import numpy as np
 
 import spark_rapids_tpu.shims as shims
-from spark_rapids_tpu.shims import (HostLibShims, LegacyJaxShims,
-                                    LegacyPandasShims, ShimVersions,
+from spark_rapids_tpu.shims import (HostLibShims, ShimVersions,
                                     detect_versions, get_shims,
                                     select_provider)
 
@@ -23,11 +22,10 @@ def test_detect_and_active_shims():
 
 
 def test_provider_selection_by_version():
-    assert select_provider(_v()) is HostLibShims
-    assert select_provider(_v(pandas=(1, 4))) is LegacyPandasShims
-    assert select_provider(_v(jax=(0, 4, 20))) is LegacyJaxShims
-    # first match wins: old pandas AND old jax -> pandas shim (list order)
-    assert select_provider(_v(pandas=(1, 3), jax=(0, 3))) is LegacyPandasShims
+    # one installation is supported: no legacy provider is shipped, so
+    # every version resolves to the current-API provider
+    for versions in (_v(), _v(pandas=(1, 4)), _v(jax=(0, 4, 20))):
+        assert select_provider(versions) is HostLibShims
 
 
 def test_shim_methods_functional():

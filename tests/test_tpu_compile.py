@@ -1,0 +1,214 @@
+"""Programs of the main path, compiled for a TPU v5e that is described, not
+attached (on-chip-measurement guide, section 2, rehearsal 3).
+
+The chip's compiler is installed beside the CPU backend the tests run on, so
+what it refuses — a Pallas block that does not fit VMEM, an int64 where
+Mosaic wants an int32, a collective it cannot partition — fails here at no
+chip time. Nothing runs, so these say nothing about results or speed.
+
+Engine programs are compiled at 2^13 rows (compile seconds grow with the
+shape; only the Pallas kernel needs its real 2^23-row column to show the VMEM
+limit). The only sort compiled here is the top-n's chunk sort: full-width
+sorts take minutes on this compiler (ROADMAP A1).
+
+The topology is described inside a module-scoped fixture, so every xdist
+worker collects the same tests and only the worker that runs this file loads
+libtpu. Keep every such test in THIS file.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pyarrow as pa
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+ROWS = 1 << 13
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to JAX's persistent cache
+    # but cannot be read back without the chip; keep these silent
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def sess():
+    from spark_rapids_tpu.session import TpuSession
+    s = TpuSession({"spark.rapids.tpu.batchRowsMinBucket": ROWS,
+                    # the static plan: the nodes below are looked up in it
+                    "spark.rapids.tpu.aqe.enabled": False})
+    yield s
+    s.close()
+
+
+def _shapes(tree, sharding):
+    """The pytree with every array leaf replaced by its shape on the chip."""
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+        if hasattr(x, "shape") and hasattr(x, "dtype") else x, tree)
+
+
+def _compile(fn, args, sharding, **jit_kw):
+    compiled = jax.jit(fn, **jit_kw).lower(*_shapes(args, sharding)).compile()
+    assert compiled.memory_analysis() is not None
+    return compiled
+
+
+def _find(plan, cls):
+    if isinstance(plan, cls):
+        return plan
+    for c in list(plan.children) + list(getattr(plan, "chain", ())):
+        hit = _find(c, cls)
+        if hit is not None:
+            return hit
+    return None
+
+
+def _sort_widths(jaxpr):
+    """Rows every sort in a jaxpr spans, loop and call bodies included."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "sort":
+            out.append(eqn.invars[0].aval.shape[eqn.params["dimension"]])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out.extend(_sort_widths(sub))
+    return out
+
+
+def _table(rng, n):
+    return pa.table({"k": np.arange(n, dtype=np.int64) * 4,
+                     "g": rng.integers(0, 50, n),
+                     "s": rng.choice(np.array(["A", "N", "R"]), n),
+                     "v": rng.uniform(0, 10, n)})
+
+
+def test_q6_whole_stage_step(topo, one_chip):
+    """The fused Q6 filter+project+partial-aggregate of the driver entry."""
+    from __graft_entry__ import entry
+    fn, args = entry()
+    _compile(fn, args, one_chip)
+    # the donating entry point is what a TPU session dispatches
+    # (exec/wholestage.py donation_active); the CPU backend never builds it
+    _compile(fn, args, one_chip, donate_argnums=(0,))
+
+
+def test_grouped_hash_aggregate(topo, one_chip, sess, rng):
+    """String- and int-keyed hash group-by: the fused partial stage and the
+    final-mode merge program."""
+    from spark_rapids_tpu.exec.aggregate import TpuHashAggregateExec
+    from spark_rapids_tpu.exec.wholestage import TpuWholeStageExec
+    from spark_rapids_tpu.expr.functions import avg, col, count_star, sum
+    df = sess.create_dataframe(_table(rng, ROWS - 7))
+    q = df.group_by("s", "g").agg(sum(col("v")).alias("t"),
+                                  avg(col("v")).alias("m"),
+                                  count_star().alias("n"))
+    plan = sess._physical(q.logical, device=True)
+    final = _find(plan, TpuHashAggregateExec)
+    assert final is not None and final.mode == "final", plan.tree_string()
+    stage = _find(plan, TpuWholeStageExec)
+    assert stage is not None, plan.tree_string()
+    batch = next(stage.source.execute_columnar(0))
+    partial = stage.batch_fn()
+    _compile(partial, (batch,), one_chip)
+    _compile(final.batch_fn(), (partial(batch),), one_chip)
+
+
+def test_pk_hash_join(topo, one_chip, sess, rng):
+    """FK->PK join on the sort-free slot table: build prep and fused probe
+    (exec/joins.py pk_hash_join_fn)."""
+    from spark_rapids_tpu.exec.joins import (TpuShuffledHashJoinExec,
+                                             _key_view)
+    from spark_rapids_tpu.expr.functions import col
+    dim = sess.create_dataframe(_table(rng, ROWS // 2)).select(
+        col("k").alias("pk"), col("v").alias("w"))
+    fact = sess.create_dataframe(_table(rng, ROWS - 7)).select(
+        (col("g") * 4).alias("fk"), col("v"))
+    q = fact.join(dim, condition=col("fk") == col("pk"))
+    plan = sess._physical(q.logical, device=True)
+    node = _find(plan, TpuShuffledHashJoinExec)
+    assert node is not None, plan.tree_string()
+    build = next(node.right.execute_columnar(0))
+    probe = next(node.left.execute_columnar(0))
+    prep = node._kernels.build_prep_hash_fn()
+    build_keys = _key_view(build, node.right_keys)
+    _compile(prep, (build_keys,), one_chip)
+    slot_row, bv, _unique = prep(build_keys)
+    clone, _ = node._canon()
+    _compile(clone._kernels.pk_hash_join_fn("inner"),
+             (build.canonical(), probe.canonical(),
+              _key_view(probe, node.left_keys), slot_row, bv), one_chip)
+
+
+def test_top_n_chunk_sort(topo, one_chip, rng):
+    """Q3-shaped top-n (float64 desc, int asc) over a batch 16 chunks wide,
+    at the default 1024-row state capacity: the chunk-winners reduction
+    keeps every sort at or under _TOPN_CHUNK rows. The full-width lexsort
+    of the same keys compiled for 101 s at 2^14 rows and 532 s at 2^16
+    (PR 22 rehearsal), so a regression shows as a timeout."""
+    from spark_rapids_tpu.columnar import dtypes as dt
+    from spark_rapids_tpu.exec.sort import _TOPN_CHUNK, TpuTakeOrderedExec
+    from spark_rapids_tpu.expr.functions import col
+    from spark_rapids_tpu.session import TpuSession
+    sess = TpuSession({"spark.rapids.tpu.aqe.enabled": False})
+    n = 16 * _TOPN_CHUNK
+    df = sess.create_dataframe(_table(rng, n - 7)).select(
+        col("k"), col("v"), col("g").cast(dt.INT).alias("d"))
+    q = df.sort(col("v").desc(), col("d").asc()).limit(10)
+    node = _find(sess._physical(q.logical, device=True), TpuTakeOrderedExec)
+    assert node is not None
+    batch = next(node.child.execute_columnar(0))
+    assert batch.capacity == n
+    widths = _sort_widths(jax.make_jaxpr(node._topn_fn("|test"))(batch).jaxpr)
+    assert widths and max(widths) <= _TOPN_CHUNK, widths
+    _compile(node._topn_fn("|test"), (batch,), one_chip)
+
+
+def test_pallas_axpy_full_column(topo, one_chip, monkeypatch):
+    """The gridded Pallas kernel at a 2^23-row SF1 lineitem bucket: the
+    ungridded kernel ran out of VMEM from 2^22 rows up."""
+    from spark_rapids_tpu.udf import examples
+    # the kernel asks the default backend whether to interpret; here that
+    # is the CPU, and the chip's lowering is what is being compiled
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    x = jax.ShapeDtypeStruct((1 << 23,), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(examples._pallas_axpy_device).lower(x, x, x).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_ici_exchange_on_four_chips(topo, sess, rng):
+    """The shard_map all-to-all of the ICI exchange, partitioned over the
+    2x2 host's four chips."""
+    from spark_rapids_tpu.columnar.device import DeviceTable
+    from spark_rapids_tpu.columnar.host import HostTable
+    from spark_rapids_tpu.shuffle.ici import exchange_program
+    mesh = Mesh(np.array(topo.devices).reshape(-1), ("dp",))
+    assert mesh.size == 4
+    table = DeviceTable.from_host(
+        HostTable.from_arrow(_table(rng, 4 * ROWS - 7)), ROWS)
+    fn = exchange_program(table.columns, table.names, ["k"], mesh, "dp",
+                          quota=ROWS // 2)
+    rows = NamedSharding(mesh, P("dp"))
+    compiled = fn.lower(*_shapes((table.columns, table.row_mask),
+                                 rows)).compile()
+    assert "all-to-all" in compiled.as_text()
+    per_device = compiled.memory_analysis()
+    assert per_device.argument_size_in_bytes \
+        < sum(x.nbytes for x in jax.tree_util.tree_leaves(table)) // 2
