@@ -37,6 +37,8 @@ import numpy as np
 
 from . import dtypes as dt
 from ..utils import movement
+from ..utils.compile_cache import named_program
+from ..utils.tracing import get_tracer
 from .host import HostColumn, HostTable
 
 __all__ = ["BucketPolicy", "DeferredScalar", "DeviceColumn", "DeviceTable",
@@ -108,7 +110,8 @@ def resolve_scalars(*values) -> Tuple:
         return ()
     if _ASYNC_ENABLED:
         t0 = movement.clock()
-        got = jax.device_get(list(values))  # srtpu: sync-ok(the deliberate batched-scalar funnel: one transfer per decision boundary)
+        with get_tracer().span("sync", "download", scalars=len(values)):
+            got = jax.device_get(list(values))  # srtpu: sync-ok(the deliberate batched-scalar funnel: one transfer per decision boundary)
         movement.note_d2h(_MOVE_RESOLVE, 4 * len(values), t0)
     else:
         # one ledger entry per transfer: the sync-forcing mode really
@@ -117,7 +120,8 @@ def resolve_scalars(*values) -> Tuple:
         got = []
         for v in values:
             t0 = movement.clock()
-            got.append(jax.device_get(v))  # srtpu: sync-ok(sync-forcing debug mode: per-scalar blocking transfers localize stalls)
+            with get_tracer().span("sync", "download", scalars=1):
+                got.append(jax.device_get(v))  # srtpu: sync-ok(sync-forcing debug mode: per-scalar blocking transfers localize stalls)
             movement.note_d2h(_MOVE_RESOLVE, 4, t0)
     return tuple(v.item() if hasattr(v, "item") else v for v in got)  # srtpu: sync-ok(item on numpy scalars the device_get above already fetched — no extra transfer)
 
@@ -251,7 +255,7 @@ def _compact_impl(table: "DeviceTable") -> "DeviceTable":
     return DeviceTable(cols, mask, table.num_rows, table.names)
 
 
-_compact_jitted = jax.jit(_compact_impl)
+_compact_jitted = named_program(_compact_impl, "compact")
 
 
 # ---------------------------------------------------------------------------
@@ -530,10 +534,11 @@ class DeviceTable:
         """Download and compact to exactly num_rows host rows."""
         _note_host_sync()
         t0 = movement.clock()
-        mask = np.asarray(self.row_mask)  # srtpu: sync-ok(result materialization: the deliberate D2H funnel)
-        n = int(np.asarray(self.num_rows))  # srtpu: sync-ok(result materialization: the deliberate D2H funnel)
-        # row_mask may be non-prefix (post-filter); boolean-index on host
-        cols = [_download_column(c, mask, n) for c in self.columns]
+        with get_tracer().span("d2h", "download", bytes=self.nbytes()):
+            mask = np.asarray(self.row_mask)  # srtpu: sync-ok(result materialization: the deliberate D2H funnel)
+            n = int(np.asarray(self.num_rows))  # srtpu: sync-ok(result materialization: the deliberate D2H funnel)
+            # row_mask may be non-prefix (post-filter); boolean-index on host
+            cols = [_download_column(c, mask, n) for c in self.columns]
         ht = HostTable(list(self.names), cols)
         movement.note_d2h(_MOVE_TO_HOST, self.nbytes, t0, table=ht)
         return ht
@@ -615,13 +620,15 @@ def to_host_batched(tables: Sequence[DeviceTable]) -> List[HostTable]:
     _note_host_sync()
     t0 = movement.clock()
     nbytes = sum(t.nbytes() for t in tables)
-    host_np = jax.device_get(tables)  # srtpu: sync-ok(the deliberate bulk-download funnel: one transfer for the whole drain)
-    out: List[HostTable] = []
-    for t in host_np:
-        mask = np.asarray(t.row_mask)  # srtpu: sync-ok(already numpy after the bulk device_get above — no further transfer)
-        n = int(np.asarray(t.num_rows))  # srtpu: sync-ok(already numpy after the bulk device_get above — no further transfer)
-        cols = [_download_column(c, mask, n) for c in t.columns]
-        out.append(HostTable(list(t.names), cols))
+    with get_tracer().span("d2h", "download", bytes=nbytes,
+                           batches=len(tables)):
+        host_np = jax.device_get(tables)  # srtpu: sync-ok(the deliberate bulk-download funnel: one transfer for the whole drain)
+        out: List[HostTable] = []
+        for t in host_np:
+            mask = np.asarray(t.row_mask)  # srtpu: sync-ok(already numpy after the bulk device_get above — no further transfer)
+            n = int(np.asarray(t.num_rows))  # srtpu: sync-ok(already numpy after the bulk device_get above — no further transfer)
+            cols = [_download_column(c, mask, n) for c in t.columns]
+            out.append(HostTable(list(t.names), cols))
     movement.note_d2h(_MOVE_BULK, nbytes, t0, table=out[0])
     # propagate the lineage tag to every table of the drain so a re-upload
     # of ANY of them flags a round trip, not just the first
@@ -993,7 +1000,8 @@ def _concat_columns(parts: List[DeviceColumn], tail: int) -> DeviceColumn:
                         all(p.all_valid for p in parts))
 
 
-_concat_jitted = jax.jit(_concat_impl, static_argnums=(1,))
+_concat_jitted = named_program(_concat_impl, "concat",
+                               static_argnums=(1,))
 
 
 def slice_rows(table: DeviceTable, start, length: int) -> DeviceTable:
@@ -1041,7 +1049,8 @@ def _slice_rows_impl(table: DeviceTable, start, length: int) -> DeviceTable:
                        table.names)
 
 
-_slice_rows_jitted = jax.jit(_slice_rows_impl, static_argnums=(2,))
+_slice_rows_jitted = named_program(_slice_rows_impl, "slice_rows",
+                                   static_argnums=(2,))
 
 
 def shrink_to_fit(table: DeviceTable, min_bucket: Optional[int] = None,
@@ -1058,7 +1067,8 @@ def shrink_to_fit(table: DeviceTable, min_bucket: Optional[int] = None,
         n = num_rows
     else:
         t0 = movement.clock()
-        n = int(table.num_rows)  # srtpu: sync-ok(capacity choice needs the host count; callers with one pass it in)
+        with get_tracer().span("sync", "download", scalars=1):
+            n = int(table.num_rows)  # srtpu: sync-ok(capacity choice needs the host count; callers with one pass it in)
         movement.note_d2h(_MOVE_SHRINK, 4, t0)
     cap = bucket_rows(max(n, 1), min_bucket)
     if cap >= table.capacity:

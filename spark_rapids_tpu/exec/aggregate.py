@@ -292,10 +292,11 @@ def _hash_group_ids(table: "DeviceTable", key_names: List[str]):
         bit_fields.extend(smalls)
     words = value_words + _pack_meta_words(bit_fields)
 
-    h = jnp.zeros(cap, dtype=jnp.uint32)
-    for i, w in enumerate(words):
-        h = h ^ _fmix_device(_word_bits_u32(w) ^ jnp.uint32(i + 1))
-        h = h * jnp.uint32(5) + jnp.uint32(0xE6546B64)
+    with jax.named_scope("groupby_key_hash"):
+        h = jnp.zeros(cap, dtype=jnp.uint32)
+        for i, w in enumerate(words):
+            h = h ^ _fmix_device(_word_bits_u32(w) ^ jnp.uint32(i + 1))
+            h = h * jnp.uint32(5) + jnp.uint32(0xE6546B64)
 
     iota = jnp.arange(cap, dtype=jnp.int32)
 
@@ -320,12 +321,16 @@ def _hash_group_ids(table: "DeviceTable", key_names: List[str]):
         unresolved = jnp.logical_and(unresolved, jnp.logical_not(eq))
         return r + 1, winner, unresolved
 
-    _, winner, _ = jax.lax.while_loop(
-        cond, body, (jnp.int32(0), iota, active))
-    is_rep = jnp.logical_and(active, winner == iota)
-    rep_rank = jnp.cumsum(is_rep.astype(jnp.int32)) - 1
-    gid = jnp.clip(jnp.take(rep_rank, winner), 0, cap - 1)
-    num_groups = jnp.sum(is_rep.astype(jnp.int32))
+    # the scopes tie the HLO's %while / gather ops to this code in a
+    # device profile (free at run time)
+    with jax.named_scope("groupby_bucket_resolve"):
+        _, winner, _ = jax.lax.while_loop(
+            cond, body, (jnp.int32(0), iota, active))
+    with jax.named_scope("groupby_group_ids"):
+        is_rep = jnp.logical_and(active, winner == iota)
+        rep_rank = jnp.cumsum(is_rep.astype(jnp.int32)) - 1
+        gid = jnp.clip(jnp.take(rep_rank, winner), 0, cap - 1)
+        num_groups = jnp.sum(is_rep.astype(jnp.int32))
     boundary = is_rep
     return iota, active, gid, boundary, num_groups
 
@@ -377,7 +382,8 @@ def _sorted_group_ids(table: "DeviceTable", key_names: List[str]):
     # lexsort: LAST entry is most significant -> meta[0] (active bit) is
     # primary, remaining meta words next, value words after
     sort_keys = list(reversed(value_words)) + list(reversed(meta))
-    order = jnp.lexsort(tuple(sort_keys))
+    with jax.named_scope("groupby_lexsort"):
+        order = jnp.lexsort(tuple(sort_keys))
     active_s = jnp.take(active, order)
     same = jnp.ones(cap, dtype=bool)
     for wd in value_words + meta:
@@ -665,20 +671,23 @@ class TpuHashAggregateExec(TpuExec):
                 group_ids(table, key_names)
             key_cols = [table.column(k) for k in key_names]
             pos = jnp.arange(cap, dtype=jnp.int64)
-            # ---- representative sorted-row per group for key output
-            rep_src = jnp.where(active_s, pos, jnp.full_like(pos, _BIG))
-            rep = jnp.clip(jax.ops.segment_min(rep_src, gid, num_segments=cap),
-                           0, cap - 1).astype(jnp.int32)
             out_cols: List[DeviceColumn] = []
             iota = jnp.arange(cap, dtype=jnp.int32)
             group_mask = iota < num_groups
-            for kc in key_cols:
-                # representative-row gather; DeviceColumn.gather recurses
-                # into struct children and the element-validity plane
-                g = kc.gather(order, keep_all_valid=True) \
-                    .gather(rep, keep_all_valid=True)
-                out_cols.append(g.with_validity(
-                    jnp.logical_and(g.validity, group_mask)))
+            with jax.named_scope("groupby_key_gather"):
+                # ---- representative sorted-row per group for key output
+                rep_src = jnp.where(active_s, pos, jnp.full_like(pos, _BIG))
+                rep = jnp.clip(
+                    jax.ops.segment_min(rep_src, gid, num_segments=cap),
+                    0, cap - 1).astype(jnp.int32)
+                for kc in key_cols:
+                    # representative-row gather; DeviceColumn.gather
+                    # recurses into struct children and the
+                    # element-validity plane
+                    g = kc.gather(order, keep_all_valid=True) \
+                        .gather(rep, keep_all_valid=True)
+                    out_cols.append(g.with_validity(
+                        jnp.logical_and(g.validity, group_mask)))
             # ---- state reductions
             for in_col, op, out_col, out_dt in cols_ops:
                 col = table.column(in_col)
@@ -780,7 +789,7 @@ class TpuHashAggregateExec(TpuExec):
     def _collect_width(self, table: DeviceTable, key: str) -> int:
         from ..columnar.device import bucket_width
         from ..utils.compile_cache import cached_jit
-        sizes = cached_jit(key + "|sizes", self._sizes_fn)
+        sizes = cached_jit(key + "|sizes", self._sizes_fn, name="agg_sizes")
         return bucket_width(max(int(sizes(table)), 1), min_width=4)
 
     def _canon_fn(self) -> Callable[[DeviceTable], DeviceTable]:
@@ -789,8 +798,11 @@ class TpuHashAggregateExec(TpuExec):
         from ..utils.compile_cache import cached_jit
         canon, ckey = self._canon_exec()
         out_names = tuple(self.schema.names)
+        grouped = bool(canon.key_names)
         if not self._has_collect():
-            base = cached_jit(ckey, canon.batch_fn)
+            base = cached_jit(
+                ckey, canon.batch_fn,
+                name="agg_grouped" if grouped else "agg_ungrouped")
 
             def fn(batch: DeviceTable) -> DeviceTable:
                 return base(batch.canonical()).with_names(out_names)
@@ -799,8 +811,9 @@ class TpuHashAggregateExec(TpuExec):
         def fn(batch: DeviceTable) -> DeviceTable:
             bc = batch.canonical()  # per-batch static width, cached per bucket
             w = canon._collect_width(bc, ckey)
-            out = cached_jit(ckey + f"|W{w}",
-                             lambda: canon.batch_fn(list_width=w))(bc)
+            out = cached_jit(
+                ckey + f"|W{w}", lambda: canon.batch_fn(list_width=w),
+                name="agg_grouped" if grouped else "agg_ungrouped")(bc)
             return out.with_names(out_names)
         return fn
 

@@ -9,7 +9,6 @@ from __future__ import annotations
 
 from typing import Callable, Iterator, List, Optional, Sequence
 
-import jax
 import jax.numpy as jnp
 
 from ..columnar import dtypes as dt
@@ -19,6 +18,7 @@ from ..expr.base import EvalContext, Expression
 from ..plan.physical import PhysicalPlan
 from ..plan.schema import Field, Schema
 from ..utils import metrics as M
+from ..utils.compile_cache import named_program
 from .base import TpuExec
 
 __all__ = ["TpuProjectExec", "TpuFilterExec", "TpuRangeExec", "TpuUnionExec",
@@ -108,7 +108,8 @@ class TpuProjectExec(TpuExec):
             return
         from ..memory.retry import split_device_rows, with_retry_split
         from .fallback import with_host_fallback
-        fn = cached_jit(self.plan_signature(), self.batch_fn)
+        fn = cached_jit(self.plan_signature(), self.batch_fn,
+                        name="op_project")
         # degradation boundary: ladder inside (spill → retry → split),
         # host fallback outside — a terminal device failure re-runs the
         # batch through the host projection instead of failing the query
@@ -195,7 +196,8 @@ class TpuFilterExec(TpuExec):
             return
         from ..memory.retry import split_device_rows, with_retry_split
         from .fallback import with_host_fallback
-        fn = cached_jit(self.plan_signature(), self.batch_fn)
+        fn = cached_jit(self.plan_signature(), self.batch_fn,
+                        name="op_filter")
         # degradation boundary (see TpuProjectExec): ladder inside,
         # host fallback outside
         run = with_host_fallback(
@@ -247,7 +249,8 @@ class TpuSampleExec(TpuExec):
                 return table.filter_mask(c.values)
             return fn
         from ..memory.retry import with_retry
-        fn = cached_jit(self.plan_signature() + f"|p{pidx}", make)
+        fn = cached_jit(self.plan_signature() + f"|p{pidx}", make,
+                        name="op_sample")
         # device-resident row offset: the accumulation rides async
         # dispatch, so sampling never blocks the host between batches
         offset = jnp.zeros((), dtype=jnp.int64)
@@ -323,7 +326,8 @@ class TpuExpandExec(TpuExec):
                 yield out
             return
         from ..memory.retry import with_retry
-        fn = cached_jit(self.plan_signature(), self.batch_fn)
+        fn = cached_jit(self.plan_signature(), self.batch_fn,
+                        name="op_expand")
         for batch in self.child_device_batches(pidx):
             with self.metrics.timed(M.OP_TIME):
                 # spill-only retry: expand interleaves P projections per
@@ -398,6 +402,17 @@ class TpuUnionExec(TpuExec):
         raise IndexError(pidx)
 
 
+def _limit_take_impl(table: DeviceTable, k) -> DeviceTable:
+    t = table.compact()
+    iota = jnp.arange(t.capacity, dtype=jnp.int32)
+    nr = jnp.minimum(t.num_rows, k).astype(jnp.int32)
+    mask = iota < nr
+    return DeviceTable(t.columns, mask, nr, t.names)
+
+
+_limit_take = named_program(_limit_take_impl, "op_limit")
+
+
 class TpuLocalLimitExec(TpuExec):
     """Per-partition limit: compacts then masks the first n rows."""
 
@@ -411,20 +426,13 @@ class TpuLocalLimitExec(TpuExec):
     def execute_columnar(self, pidx: int) -> Iterator[DeviceTable]:
         remaining = self.n
 
-        @jax.jit
-        def take(table: DeviceTable, k) -> DeviceTable:
-            t = table.compact()
-            iota = jnp.arange(t.capacity, dtype=jnp.int32)
-            nr = jnp.minimum(t.num_rows, k).astype(jnp.int32)
-            mask = iota < nr
-            return DeviceTable(t.columns, mask, nr, t.names)
-
         from ..columnar.device import resolve_scalars
         for batch in self.child_device_batches(pidx):
             if remaining <= 0:
                 return
             with self.metrics.timed(M.OP_TIME):
-                out = take(batch, jnp.asarray(remaining, jnp.int32))
+                out = _limit_take(batch,
+                                  jnp.asarray(remaining, jnp.int32))
             # early-exit decision: one batched-funnel transfer per batch
             (emitted,) = resolve_scalars(out.num_rows)
             emitted = int(emitted)

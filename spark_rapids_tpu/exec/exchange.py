@@ -33,6 +33,7 @@ from ..plan.physical import HashPartitioning, PhysicalPlan
 from ..shuffle import telemetry as shuffle_telemetry
 from ..utils import metrics as M
 from ..utils import movement
+from ..utils.compile_cache import named_jit
 from .base import TpuExec
 
 __all__ = ["TpuShuffleExchangeExec", "TpuLocalExchangeExec", "SHUFFLE_MODE",
@@ -294,8 +295,9 @@ class TpuShuffleExchangeExec(TpuExec):
             try:
                 # count pass: partition ids only (4 bytes/row) -> quota
                 keys = self.partitioning.key_names
-                pid = jax.jit(lambda t: jnp.where(
-                    t.row_mask, device_partition_ids(t, keys, n), n))(table)
+                pid = named_jit(lambda t: jnp.where(
+                    t.row_mask, device_partition_ids(t, keys, n), n),
+                    "exchange_pid")(table)
                 t0 = movement.clock()
                 pid_host = np.asarray(jax.device_get(pid))  # srtpu: sync-ok(the deliberate partition-count funnel: one transfer sizes every shard buffer for the chunk)
                 movement.note_d2h(_MOVE_CHUNK, pid_host.nbytes, t0)

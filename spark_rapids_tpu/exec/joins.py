@@ -839,7 +839,8 @@ class TpuShuffledHashJoinExec(TpuExec):
         clone, ckey = self._canon()
         lkey = (ckey + "|leftover|"
                 + ",".join(repr(f.dtype) for f in self.left.schema.fields))
-        fn = cached_jit(lkey, clone._kernels.leftover_fn)
+        fn = cached_jit(lkey, clone._kernels.leftover_fn,
+                        name="join_leftover")
         out_names = tuple(self.schema.names)
 
         def run(build: DeviceTable, seen) -> DeviceTable:
@@ -870,7 +871,8 @@ class TpuShuffledHashJoinExec(TpuExec):
         lkeys, rkeys = self.left_keys, self.right_keys
         if self._direct_key_ok():
             cnt = cached_jit(f"JoinC|probeD|t{int(track)}",
-                             lambda: self._kernels.probe_count_fn(track))
+                             lambda: self._kernels.probe_count_fn(track),
+                             name="join_probe_count")
 
             def run(build: DeviceTable, probe: DeviceTable):
                 b_order, sv, nvalid, _uniq = self._get_prep(build)
@@ -879,8 +881,9 @@ class TpuShuffledHashJoinExec(TpuExec):
                 return b_order, starts, counts, (matched if track else None)
             return run
         fn = cached_jit(f"JoinC|counts|k{len(lkeys)}",
-                        self._kernels.counts_fn)
-        matched_fn = cached_jit("JoinC|matched", self._kernels.matched_fn) \
+                        self._kernels.counts_fn, name="join_counts")
+        matched_fn = cached_jit("JoinC|matched", self._kernels.matched_fn,
+                                name="join_matched") \
             if track else None
 
         def run(build: DeviceTable, probe: DeviceTable):
@@ -893,7 +896,8 @@ class TpuShuffledHashJoinExec(TpuExec):
     def _get_prep_hash(self, build: DeviceTable):
         """Per-build-table HASH prep (slot table + key array + uniqueness),
         cached like the sorted prep; no lax.sort in the prep program."""
-        prep = cached_jit("JoinC|prepH", self._kernels.build_prep_hash_fn)
+        prep = cached_jit("JoinC|prepH", self._kernels.build_prep_hash_fn,
+                          name="join_prep_hash")
         lock = self.__dict__.setdefault("_prep_lock",
                                         __import__("threading").Lock())
         with lock:
@@ -939,7 +943,8 @@ class TpuShuffledHashJoinExec(TpuExec):
         entry, replaced on build change, race-safe (each thread uses the
         tuple it computed or read, never a second dict lookup). ``unique``
         is host-synced once per build (it gates the PK fast path)."""
-        prep = cached_jit("JoinC|prepD", self._kernels.build_prep_fn)
+        prep = cached_jit("JoinC|prepD", self._kernels.build_prep_fn,
+                          name="join_prep_dense")
         lock = self.__dict__.setdefault("_prep_lock",
                                         __import__("threading").Lock())
         with lock:
@@ -1010,7 +1015,8 @@ class TpuShuffledHashJoinExec(TpuExec):
                             fused = cached_jit(
                                 ckey + f"|pkh|{self.how}",
                                 lambda: clone._kernels
-                                .pk_hash_join_fn(self.how))
+                                .pk_hash_join_fn(self.how),
+                                name="join_pk_hash")
                             out = fused(build.canonical(),
                                         probe.canonical(),
                                         _key_view(probe, self.left_keys),
@@ -1024,7 +1030,8 @@ class TpuShuffledHashJoinExec(TpuExec):
                             fused = cached_jit(
                                 ckey + f"|pk|{self.how}",
                                 lambda: clone._kernels
-                                .pk_join_fn(self.how))
+                                .pk_join_fn(self.how),
+                                name="join_pk")
                             out = fused(build.canonical(),
                                         probe.canonical(),
                                         _key_view(probe, self.left_keys),
@@ -1050,7 +1057,8 @@ class TpuShuffledHashJoinExec(TpuExec):
                     anti = self.how == "left_anti"
                     fn = cached_jit(
                         f"JoinC|semi|{anti}",
-                        lambda: self._kernels.semi_mask_fn(anti))
+                        lambda: self._kernels.semi_mask_fn(anti),
+                        name="join_semi")
                     yield fn(probe.canonical(), counts) \
                         .with_names(probe.names)
                     continue
@@ -1085,7 +1093,8 @@ class TpuShuffledHashJoinExec(TpuExec):
             clone, ckey = self._canon()
             expand = cached_jit(
                 ckey + f"|expand{out_cap}|{eff}",
-                lambda: clone._kernels.expand_fn(out_cap, eff))
+                lambda: clone._kernels.expand_fn(out_cap, eff),
+                name="join_expand")
             yield expand(build.canonical(), probe.canonical(), b_order,
                          starts, counts).with_names(out_names)
             return
@@ -1093,15 +1102,18 @@ class TpuShuffledHashJoinExec(TpuExec):
             clone, ckey = self._canon()
             expand = cached_jit(
                 ckey + f"|expand{out_cap}|inner",
-                lambda: clone._kernels.expand_fn(out_cap, "inner"))
+                lambda: clone._kernels.expand_fn(out_cap, "inner"),
+                name="join_expand")
             out = expand(build.canonical(), probe.canonical(), b_order,
                          starts, counts).with_names(out_names)
             cond_fn = cached_jit(self.plan_signature() + "|cond",
-                                 lambda: _condition_filter_fn(self.condition))
+                                 lambda: _condition_filter_fn(self.condition),
+                                 name="join_cond")
             yield cond_fn(out)
             return
         fn = cached_jit(self.plan_signature() + f"|condexpand{out_cap}",
-                        lambda: self._kernels.expand_cond_fn(out_cap, how))
+                        lambda: self._kernels.expand_cond_fn(out_cap, how),
+                        name="join_expand_cond")
         res = fn(build, probe, b_order, starts, counts)
         if how in ("left_semi", "left_anti"):
             yield res
@@ -1473,14 +1485,15 @@ class TpuBroadcastNestedLoopJoinExec(TpuExec):
         # outer/semi decisions need the OR across slices, so single-slice
         # keeps the fast path and multi-slice accumulates per window
         fn = cached_jit(self.plan_signature() + f"|cross{ws}x{bws}",
-                        lambda: self.cross_fn(ws, self.how))
+                        lambda: self.cross_fn(ws, self.how),
+                        name="join_cross")
         # multi-slice variant: pairs only ("right" also threads the seen
         # update for right/full; stream-side fixup happens after all slices)
         pairs_how = "right" if track else (
             "cross" if self.how == "cross" else "inner")
         pairs_fn = cached_jit(
             self.plan_signature() + f"|crosspairs{pairs_how}{ws}x{bws}",
-            lambda: self.cross_fn(ws, pairs_how))
+            lambda: self.cross_fn(ws, pairs_how), name="join_cross_pairs")
         seen_slices = [jnp.zeros(min(bws, build_cap), dtype=bool)
                        for _ in range(n_bslices)] if track else None
         parts = range(self.left.num_partitions) if track else [pidx]
@@ -1498,7 +1511,7 @@ class TpuBroadcastNestedLoopJoinExec(TpuExec):
                         seen_slices, semi_like)
         if track:
             leftover = cached_jit(self.plan_signature() + "|bnlj_leftover",
-                                  self.leftover_fn)
+                                  self.leftover_fn, name="join_leftover")
             for bi in range(n_bslices):
                 with handle as build:
                     bslice = slice_rows(build, bi * bws, min(bws, build_cap))

@@ -53,6 +53,7 @@ from ..conf import register_conf
 from ..shuffle import telemetry as shuffle_telemetry
 from ..utils import faults
 from ..utils import metrics as M
+from ..utils.compile_cache import named_jit
 from .base import TpuExec
 from .exchange import TpuShuffleExchangeExec, _split_sharded
 from .wholestage import TpuWholeStageExec, _fusible, _with_children
@@ -289,22 +290,24 @@ class TpuMeshStageExec(TpuExec):
             return prog
         names = chunk.names
         axis = self.axis
-        fns = [node.batch_fn() for node in self.chain]
+        fns = [(type(node).__name__, node.batch_fn())
+               for node in self.chain]
 
         def local(columns, mask):
             table = DeviceTable(columns, mask,
                                 jnp.sum(mask, dtype=jnp.int32), names)
-            for f in fns:
-                table = f(table)
+            for scope, f in fns:
+                with jax.named_scope(scope):
+                    table = f(table)
             return table.columns, table.row_mask
 
         col_specs = jax.tree_util.tree_map(lambda _: P(axis), chunk.columns)
         # check_vma off: the output specs are data-dependent in ways the
         # static replication checker rejects
-        fn = jax.jit(jax.shard_map(local, mesh=self.mesh,
-                                   in_specs=(col_specs, P(axis)),
-                                   out_specs=(P(axis), P(axis)),
-                                   check_vma=False))
+        fn = named_jit(jax.shard_map(local, mesh=self.mesh,
+                                     in_specs=(col_specs, P(axis)),
+                                     out_specs=(P(axis), P(axis)),
+                                     check_vma=False), "mesh_stage")
         t0 = shuffle_telemetry.clock()
         prog = fn.lower(chunk.columns, chunk.row_mask).compile()
         shuffle_telemetry.note_transfer(

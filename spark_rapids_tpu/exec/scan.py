@@ -15,6 +15,7 @@ from typing import Iterator, List, Optional
 from ..columnar.device import DeviceTable, resolve_min_bucket
 from ..plan.physical import PhysicalPlan
 from ..utils import metrics as M
+from ..utils.tracing import get_tracer
 from .base import TpuExec
 
 __all__ = ["TpuParquetScanExec", "TpuCsvScanExec", "TpuJsonScanExec"]
@@ -46,8 +47,11 @@ class TpuParquetScanExec(TpuExec):
         nthreads = self.source.conf.get(MULTITHREAD_READ_NUM_THREADS)
 
         def read_bytes(p):
-            with open(p, "rb") as f:
-                return f.read()
+            with get_tracer().span("scan.read", "scan") as span:
+                with open(p, "rb") as f:
+                    raw = f.read()
+                span.note(bytes=len(raw))
+            return raw
 
         # bounded file read-ahead overlapping IO with device decode
         # (reference: MultiFileCloudParquetPartitionReader's read pool)
@@ -107,8 +111,11 @@ class TpuCsvScanExec(TpuExec):
         nthreads = self.source.conf.get(MULTITHREAD_READ_NUM_THREADS)
 
         def read_bytes(p):
-            with open(p, "rb") as f:
-                return f.read()
+            with get_tracer().span("scan.read", "scan") as span:
+                with open(p, "rb") as f:
+                    raw = f.read()
+                span.note(bytes=len(raw))
+            return raw
 
         for path, raw in prefetched(files, read_bytes, max(2, nthreads)):
             yield from self._decode_file(path, raw)
@@ -151,10 +158,11 @@ class TpuCsvScanExec(TpuExec):
         yield from self._decode_line_batches(
             raw, starts, lengths, fields, col_indices, key_prefix,
             lambda: (lambda m, ln: decode_lines(m, ln, fields, sep,
-                                                col_indices)))
+                                                col_indices)),
+            program="csv_decode")
 
     def _decode_line_batches(self, raw, starts, lengths, fields,
-                             col_indices, key_prefix, builder
+                             col_indices, key_prefix, builder, program
                              ) -> Iterator[DeviceTable]:
         """Shared line-batch loop for the text decoders: bucket lines into
         a byte matrix, run the cached jitted decoder, assemble the
@@ -182,7 +190,9 @@ class TpuCsvScanExec(TpuExec):
                 mat = lines_to_matrix(raw, s, l, cap, width)
                 lens = _np.zeros(cap, dtype=_np.int32)
                 lens[:n] = l
-                fn = cached_jit(f"{key_prefix}|{cap}x{width}", builder)
+                # srtpu: jitname-ok(csv_decode or json_decode: the two text scans pass theirs as program=)
+                fn = cached_jit(f"{key_prefix}|{cap}x{width}", builder,
+                                name=program)
                 decoded = fn(jnp.asarray(mat), jnp.asarray(lens))
                 iota = _np.arange(cap, dtype=_np.int32)
                 row_mask = jnp.asarray(iota < n)
@@ -248,4 +258,5 @@ class TpuJsonScanExec(TpuCsvScanExec):
         yield from self._decode_line_batches(
             raw, starts, lengths, fields, col_indices, key_prefix,
             lambda: (lambda m, ln: decode_json_lines(m, ln, fields,
-                                                     col_indices)))
+                                                     col_indices)),
+            program="json_decode")

@@ -191,7 +191,8 @@ class TpuTakeOrderedExec(TpuExec):
                 out_mask = mask[:cap] if s.capacity > cap else mask
                 return DeviceTable(cols, out_mask, keep, s.names)
             return fn
-        return cached_jit(self.plan_signature() + cap_key, make)
+        return cached_jit(self.plan_signature() + cap_key, make,
+                          name="sort_topn")
 
     def execute_columnar(self, pidx: int) -> Iterator[DeviceTable]:
         from ..memory.retry import (split_device_rows, with_retry,
@@ -249,7 +250,8 @@ class TpuSortExec(TpuExec):
         from ..utils.compile_cache import cached_jit
         orders = self.orders
         return cached_jit(self.plan_signature() + cap_key,
-                          lambda: (lambda t: device_sort_table(t, orders)))
+                          lambda: (lambda t: device_sort_table(t, orders)),
+                          name="sort")
 
     def _sort_combine(self, outs):
         """Split-and-retry combiner: half-sorts are only locally ordered,

@@ -197,12 +197,13 @@ class HostToDeviceExec(TpuExec):
             return concat_device_tables(outs, min_bucket)
 
         t0 = movement.clock()
-        with get_tracer().span("h2d_upload", "upload",
-                               rows=int(batch.num_rows)):  # srtpu: sync-ok(HostTable.num_rows is a host int on the upload side)
+        with get_tracer().span("h2d", "upload",
+                               rows=int(batch.num_rows)) as span:  # srtpu: sync-ok(HostTable.num_rows is a host int on the upload side)
             dtb = with_retry_split(upload, batch, splitter=split_host_rows,
                                    combiner=combine, scope="h2d-upload",
                                    context=f"rows={int(batch.num_rows)}",  # srtpu: sync-ok(HostTable.num_rows is a host int on the upload side)
                                    fault_point="alloc.upload")
+            span.note(bytes=dtb.nbytes())
         movement.note_h2d(_MOVE_UPLOAD, dtb.nbytes, t0, origin=batch)
         return dtb
 
@@ -310,10 +311,8 @@ class DeviceToHostExec(PhysicalPlan):
         from ..columnar.device import to_host_batched
         if not batches:
             return []
-        with self.metrics.timed(M.DOWNLOAD_TIME), \
-                get_tracer().span("d2h_download", "download",
-                                  batches=len(batches)):
-            hts = to_host_batched(batches)
+        with self.metrics.timed(M.DOWNLOAD_TIME):
+            hts = to_host_batched(batches)     # the "d2h" span is inside
         for batch, ht in zip(batches, hts):
             self.metrics.add(M.DOWNLOAD_BYTES, batch.nbytes())
             self.metrics.add(M.NUM_OUTPUT_BATCHES, 1)
@@ -335,10 +334,8 @@ class DeviceToHostExec(PhysicalPlan):
             lambda: self.child.execute_columnar(pidx),
             stage=f"compute:{stage_name(self.child)}", registry=self.metrics)
         for batch in child:
-            with self.metrics.timed(M.DOWNLOAD_TIME), \
-                    get_tracer().span("d2h_download", "download",
-                                      rows=int(batch.num_rows)):  # srtpu: sync-ok(sync-forcing debug mode: trace-span rows at the per-batch download boundary)
-                ht = batch.to_host()
+            with self.metrics.timed(M.DOWNLOAD_TIME):
+                ht = batch.to_host()           # the "d2h" span is inside
             self.metrics.add(M.DOWNLOAD_BYTES, batch.nbytes())
             self.metrics.add(M.NUM_OUTPUT_BATCHES, 1)
             self.metrics.add(M.NUM_OUTPUT_ROWS, ht.num_rows)

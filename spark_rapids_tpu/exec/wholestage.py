@@ -55,6 +55,21 @@ def donation_active(conf) -> bool:
     return jax.default_backend() != "cpu"
 
 
+def _scoped_chain(chain: List[TpuExec]):
+    """The composed chain function, each operator's ``batch_fn`` under a
+    ``jax.named_scope`` of its node name: the ops of the fused program
+    (``jit_srt_stage``) then say in a device profile which operator they
+    came from. Free at run time."""
+    fns = [(type(n).__name__, n.batch_fn()) for n in chain]
+
+    def run(table: DeviceTable) -> DeviceTable:
+        for scope, f in fns:
+            with jax.named_scope(scope):
+                table = f(table)
+        return table
+    return run
+
+
 class TpuWholeStageExec(TpuExec):
     """Wraps a linear chain of fusible TpuExecs [bottom, ..., top]."""
 
@@ -92,13 +107,7 @@ class TpuWholeStageExec(TpuExec):
     def batch_fn(self):
         """Composed chain function — lets an outer fusible parent absorb
         this whole-stage into its own chain (see __init__ flattening)."""
-        fns = [n.batch_fn() for n in self.chain]
-
-        def run(table: DeviceTable) -> DeviceTable:
-            for f in fns:
-                table = f(table)
-            return table
-        return run
+        return _scoped_chain(self.chain)
 
     def host_batch_fn(self):
         """Composed host-engine chain, or None when any member lacks a
@@ -122,17 +131,11 @@ class TpuWholeStageExec(TpuExec):
         chain = self.chain
 
         def build():
-            fns = [n.batch_fn() for n in chain]
-
-            def run(table: DeviceTable) -> DeviceTable:
-                for f in fns:
-                    table = f(table)
-                return table
-            return run
+            return _scoped_chain(chain)
 
         sig = self.plan_signature()
-        fused = cached_jit(sig, build)
-        donating = cached_jit(sig + "|donate", build,
+        fused = cached_jit(sig, build, name="stage")
+        donating = cached_jit(sig + "|donate", build, name="stage",
                               donate_argnums=(0,)) \
             if self.donate_inputs else None
         # stage boundary: the source (typically the upload transition)

@@ -178,7 +178,7 @@ class TpuWindowExec(TpuExec):
         if not batches:
             return
         table = concat_device_tables(batches) if len(batches) > 1 else batches[0]
-        fn = cached_jit(self.plan_signature(), self._kernel)
+        fn = cached_jit(self.plan_signature(), self._kernel, name="window")
         with self.metrics.timed(M.OP_TIME):
             out = fn(table)
         self.account_batch()

@@ -29,6 +29,7 @@ from ..conf import MULTITHREAD_READ_NUM_THREADS, PARQUET_READER_TYPE, RapidsConf
 from ..columnar.host import HostTable
 from ..plan.logical import DataSource
 from ..plan.schema import Field, Schema
+from ..utils.tracing import get_tracer
 from .memory import InMemorySource  # noqa: F401 (re-export convenience)
 
 __all__ = ["ParquetSource"]
@@ -50,6 +51,14 @@ def _expand_paths(paths) -> List[str]:
     if not out:
         raise FileNotFoundError(f"no parquet files for {paths}")
     return out
+
+
+def _host_table(t: pa.Table) -> HostTable:
+    """Arrow -> host columns for one batch of the host scan: the host
+    path's share of ``scan.parse``."""
+    with get_tracer().span("scan.parse", "scan", step="from_arrow",
+                           rows=t.num_rows):
+        return HostTable.from_arrow(t)
 
 
 class ParquetSource(DataSource):
@@ -101,10 +110,16 @@ class ParquetSource(DataSource):
 
     # -- strategies ----------------------------------------------------------
     def _read_file(self, path: str, columns) -> pa.Table:
-        if self.filter_expr is not None:
-            ds = pads.dataset(path, format="parquet")
-            return ds.to_table(columns=columns, filter=self.filter_expr)
-        return pq.read_table(path, columns=columns, use_threads=True)
+        """One file's rows as an arrow table — the funnel of all three
+        reader strategies, and the host scan's ``scan.read`` span."""
+        with get_tracer().span("scan.read", "scan") as span:
+            if self.filter_expr is not None:
+                ds = pads.dataset(path, format="parquet")
+                t = ds.to_table(columns=columns, filter=self.filter_expr)
+            else:
+                t = pq.read_table(path, columns=columns, use_threads=True)
+            span.note(bytes=t.nbytes, rows=t.num_rows)
+        return t
 
     def _read_file_batches(self, path: str, columns) -> Iterator[HostTable]:
         from .file_block import set_input_file
@@ -112,10 +127,10 @@ class ParquetSource(DataSource):
         set_input_file(path, 0, os.path.getsize(path))
         pos = 0
         while pos < t.num_rows:
-            yield HostTable.from_arrow(t.slice(pos, self.batch_rows))
+            yield _host_table(t.slice(pos, self.batch_rows))
             pos += self.batch_rows
         if t.num_rows == 0:
-            yield HostTable.from_arrow(t)
+            yield _host_table(t)
 
     def _read_coalescing(self, files: Sequence[str], columns
                          ) -> Iterator[HostTable]:
@@ -141,11 +156,11 @@ class ParquetSource(DataSource):
     def _slice_out(self, t: pa.Table, allow_empty: bool = False
                    ) -> Iterator[HostTable]:
         if t.num_rows == 0 and allow_empty:
-            yield HostTable.from_arrow(t)
+            yield _host_table(t)
             return
         pos = 0
         while pos < t.num_rows:
-            yield HostTable.from_arrow(t.slice(pos, self.batch_rows))
+            yield _host_table(t.slice(pos, self.batch_rows))
             pos += self.batch_rows
 
     def _read_multithreaded(self, files: Sequence[str], columns
@@ -155,7 +170,8 @@ class ParquetSource(DataSource):
                                    thread_name_prefix="srtpu-pq-read") \
                 as pool:
             from .file_block import set_input_file
-            futures = [pool.submit(self._read_file, f, columns) for f in files]
+            read = get_tracer().bind_query(self._read_file)
+            futures = [pool.submit(read, f, columns) for f in files]
             for f, fut in zip(files, futures):  # file order kept, reads overlap
                 t = fut.result()
                 set_input_file(f, 0, os.path.getsize(f))

@@ -407,10 +407,12 @@ def _expand_hybrid_device(out_start, is_rle, rle_value, bit_base, widths,
     on device (searchsorted for run id + LSB-first bit-field extraction for
     bit-packed runs). ``widths`` is PER RUN — successive pages of one chunk
     may bit-pack at different widths as the dictionary grows."""
+    import jax
     import jax.numpy as jnp
     i = iota.astype(jnp.int64)
-    run = jnp.clip(jnp.searchsorted(out_start, i, side="right") - 1,
-                   0, out_start.shape[0] - 1)
+    with jax.named_scope("pq_run_searchsorted"):
+        run = jnp.clip(jnp.searchsorted(out_start, i, side="right") - 1,
+                       0, out_start.shape[0] - 1)
     within = i - out_start[run]
     w = widths[run]
     bit = bit_base[run] + within * w
@@ -437,19 +439,25 @@ def _mixed_kernel_builder(npdt_str: str):
     def fn(v_start, v_rle, v_val, v_bit, v_width, v_packed,
            d_start, d_rle, d_val, d_bit, d_width, d_packed, dvals,
            plain, n_dict, n, iota_cap, iota_nv):
+        import jax
         import jax.numpy as jnp
-        validity = _expand_hybrid_device(
-            v_start, v_rle, v_val, v_bit, v_width, v_packed, iota_cap) > 0
-        validity = jnp.logical_and(validity, iota_cap < n)
-        pos = (jnp.cumsum(validity.astype(jnp.int32)) - 1).astype(jnp.int64)
-        idx = _expand_hybrid_device(d_start, d_rle, d_val, d_bit, d_width,
-                                    d_packed, iota_nv)
-        dense_dict = dvals[jnp.clip(idx, 0, dvals.shape[0] - 1)]
-        from_dict = pos < n_dict
-        v_dict = dense_dict[jnp.clip(pos, 0, dense_dict.shape[0] - 1)]
-        v_plain = plain[jnp.clip(pos - n_dict, 0, plain.shape[0] - 1)]
-        vals = jnp.where(from_dict, v_dict, v_plain)
-        vals = jnp.where(validity, vals, jnp.zeros((), vals.dtype))
+        with jax.named_scope("pq_def_levels"):
+            validity = _expand_hybrid_device(
+                v_start, v_rle, v_val, v_bit, v_width, v_packed,
+                iota_cap) > 0
+            validity = jnp.logical_and(validity, iota_cap < n)
+            pos = (jnp.cumsum(validity.astype(jnp.int32)) - 1) \
+                .astype(jnp.int64)
+        with jax.named_scope("pq_dict_indices"):
+            idx = _expand_hybrid_device(d_start, d_rle, d_val, d_bit,
+                                        d_width, d_packed, iota_nv)
+        with jax.named_scope("pq_value_gather"):
+            dense_dict = dvals[jnp.clip(idx, 0, dvals.shape[0] - 1)]
+            from_dict = pos < n_dict
+            v_dict = dense_dict[jnp.clip(pos, 0, dense_dict.shape[0] - 1)]
+            v_plain = plain[jnp.clip(pos - n_dict, 0, plain.shape[0] - 1)]
+            vals = jnp.where(from_dict, v_dict, v_plain)
+            vals = jnp.where(validity, vals, jnp.zeros((), vals.dtype))
         return vals.astype(jnp.dtype(npdt_str)), validity
     return lambda: fn
 
@@ -463,25 +471,31 @@ def _ba_kernel_builder():
            d_start, d_rle, d_val, d_bit, d_width, d_packed,
            dict_mat, dict_lens, plain_mat, plain_lens,
            n_dict, n, iota_cap, iota_nv):
+        import jax
         import jax.numpy as jnp
-        validity = _expand_hybrid_device(
-            v_start, v_rle, v_val, v_bit, v_width, v_packed, iota_cap) > 0
-        validity = jnp.logical_and(validity, iota_cap < n)
-        pos = (jnp.cumsum(validity.astype(jnp.int32)) - 1).astype(jnp.int64)
-        idx = _expand_hybrid_device(d_start, d_rle, d_val, d_bit, d_width,
-                                    d_packed, iota_nv)
-        from_dict = pos < n_dict
-        didx = idx[jnp.clip(pos, 0, idx.shape[0] - 1)]
-        row_dict = dict_mat[jnp.clip(didx, 0, dict_mat.shape[0] - 1)]
-        len_dict = dict_lens[jnp.clip(didx, 0, dict_lens.shape[0] - 1)]
-        ppos = jnp.clip(pos - n_dict, 0, plain_mat.shape[0] - 1)
-        row_plain = plain_mat[ppos]
-        len_plain = plain_lens[ppos]
-        data = jnp.where(from_dict[:, None], row_dict, row_plain)
-        lengths = jnp.where(from_dict, len_dict, len_plain)
-        ok = validity[:, None]
-        data = jnp.where(ok, data, jnp.zeros((), jnp.uint8))
-        lengths = jnp.where(validity, lengths, 0).astype(jnp.int32)
+        with jax.named_scope("pq_def_levels"):
+            validity = _expand_hybrid_device(
+                v_start, v_rle, v_val, v_bit, v_width, v_packed,
+                iota_cap) > 0
+            validity = jnp.logical_and(validity, iota_cap < n)
+            pos = (jnp.cumsum(validity.astype(jnp.int32)) - 1) \
+                .astype(jnp.int64)
+        with jax.named_scope("pq_dict_indices"):
+            idx = _expand_hybrid_device(d_start, d_rle, d_val, d_bit,
+                                        d_width, d_packed, iota_nv)
+        with jax.named_scope("pq_value_gather"):
+            from_dict = pos < n_dict
+            didx = idx[jnp.clip(pos, 0, idx.shape[0] - 1)]
+            row_dict = dict_mat[jnp.clip(didx, 0, dict_mat.shape[0] - 1)]
+            len_dict = dict_lens[jnp.clip(didx, 0, dict_lens.shape[0] - 1)]
+            ppos = jnp.clip(pos - n_dict, 0, plain_mat.shape[0] - 1)
+            row_plain = plain_mat[ppos]
+            len_plain = plain_lens[ppos]
+            data = jnp.where(from_dict[:, None], row_dict, row_plain)
+            lengths = jnp.where(from_dict, len_dict, len_plain)
+            ok = validity[:, None]
+            data = jnp.where(ok, data, jnp.zeros((), jnp.uint8))
+            lengths = jnp.where(validity, lengths, 0).astype(jnp.int32)
         return data, lengths, validity
     return lambda: fn
 
@@ -490,82 +504,105 @@ def _empty_run_tables() -> Tuple[np.ndarray, ...]:
     return _RunTable().arrays()
 
 
-def _decode_column_device(ch: _Chunk, out_dtype: dt.DataType, cap: int):
-    """-> DeviceColumn with row capacity ``cap`` (device kernels; compiled
-    callables shared via the global compile cache, shapes pow2-bucketed)."""
-    import numpy as _np
-
-    from ..columnar.device import DeviceColumn, bucket_width
-    from ..utils.compile_cache import cached_jit
-
-    n = ch.num_rows
+def _run_table_inputs(ch: _Chunk, cap: int):
+    """The run tables and iotas both decode kernels start with, and the
+    count of dictionary-encoded values."""
     v_tables = ch.defs.arrays()
-    iota_cap = _np.arange(cap, dtype=_np.int64)
+    iota_cap = np.arange(cap, dtype=np.int64)
     d_tables = ch.idx.arrays() if ch.uses_dict else _empty_run_tables()
     n_dict = ch.idx.total if ch.uses_dict else 0
-    nvcap = _pow2(max(1, n_dict))
-    iota_nv = _np.arange(nvcap, dtype=_np.int64)
+    iota_nv = np.arange(_pow2(max(1, n_dict)), dtype=np.int64)
+    return v_tables, d_tables, n_dict, iota_cap, iota_nv
 
-    if isinstance(out_dtype, (dt.StringType, dt.BinaryType)):
-        max_len = 1
-        if ch.ba_dict is not None and len(ch.ba_dict[1]):
-            max_len = max(max_len, int(ch.ba_dict[1].max()))
-        for _, lens, _b in ch.ba_plain:
-            if len(lens):
-                max_len = max(max_len, int(lens.max()))
-        width = bucket_width(max_len)
-        if ch.uses_dict:
-            if ch.ba_dict is None:
-                raise UnsupportedChunk("dict-encoded pages, no dict page")
-            dm, dlens = _ba_matrix([ch.ba_dict], width)
-            pad_to = _pow2(dm.shape[0])
-            dm = _np.pad(dm, ((0, pad_to - dm.shape[0]), (0, 0)))
-            dlens = _np.pad(dlens, (0, pad_to - len(dlens)))
-        else:
-            dm = _np.zeros((1, width), _np.uint8)
-            dlens = _np.zeros(1, _np.int32)
-        if ch.ba_plain:
-            pm, plens = _ba_matrix(ch.ba_plain, width)
-            pad_to = _pow2(pm.shape[0])
-            pm = _np.pad(pm, ((0, pad_to - pm.shape[0]), (0, 0)))
-            plens = _np.pad(plens, (0, pad_to - len(plens)))
-        else:
-            pm = _np.zeros((1, width), _np.uint8)
-            plens = _np.zeros(1, _np.int32)
-        fn = cached_jit("pq_ba", _ba_kernel_builder())
-        data, lengths, validity = fn(
-            *v_tables, *d_tables, dm, dlens.astype(_np.int32),
-            pm, plens.astype(_np.int32), _np.int64(n_dict), _np.int64(n),
-            iota_cap, iota_nv)
-        return DeviceColumn(data, validity, out_dtype, lengths)
 
-    npdt = out_dtype.np_dtype()
-    npdt_str = _np.dtype(npdt).str
+def _pad_rows_pow2(mat: np.ndarray, lens: np.ndarray):
+    pad_to = _pow2(mat.shape[0])
+    return (np.pad(mat, ((0, pad_to - mat.shape[0]), (0, 0))),
+            np.pad(lens, (0, pad_to - len(lens))).astype(np.int32))
+
+
+def _bytes_inputs(ch: _Chunk, cap: int) -> tuple:
+    """Host arrays the BYTE_ARRAY kernel takes, shapes pow2-bucketed."""
+    from ..columnar.device import bucket_width
+    v_tables, d_tables, n_dict, iota_cap, iota_nv = _run_table_inputs(ch, cap)
+    max_len = 1
+    if ch.ba_dict is not None and len(ch.ba_dict[1]):
+        max_len = max(max_len, int(ch.ba_dict[1].max()))
+    for _, lens, _b in ch.ba_plain:
+        if len(lens):
+            max_len = max(max_len, int(lens.max()))
+    width = bucket_width(max_len)
+    if ch.uses_dict:
+        if ch.ba_dict is None:
+            raise UnsupportedChunk("dict-encoded pages, no dict page")
+        dm, dlens = _pad_rows_pow2(*_ba_matrix([ch.ba_dict], width))
+    else:
+        dm, dlens = np.zeros((1, width), np.uint8), np.zeros(1, np.int32)
+    if ch.ba_plain:
+        pm, plens = _pad_rows_pow2(*_ba_matrix(ch.ba_plain, width))
+    else:
+        pm, plens = np.zeros((1, width), np.uint8), np.zeros(1, np.int32)
+    return (*v_tables, *d_tables, dm, dlens, pm, plens,
+            np.int64(n_dict), np.int64(ch.num_rows), iota_cap, iota_nv)
+
+
+def _fixed_inputs(ch: _Chunk, npdt, cap: int) -> tuple:
+    """Host arrays the fixed-width kernel takes, shapes pow2-bucketed."""
+    v_tables, d_tables, n_dict, iota_cap, iota_nv = _run_table_inputs(ch, cap)
     if ch.bool_plain and not ch.uses_dict:
         parts = [_plain_values(b, "BOOLEAN", c) for b, c in ch.bool_plain]
-        plain = _np.concatenate(parts) if parts else _np.zeros(0, _np.bool_)
+        plain = np.concatenate(parts) if parts else np.zeros(0, np.bool_)
     elif ch.plain_parts:
         blob = b"".join(ch.plain_parts)
-        d_ = _np.dtype(npdt)
+        d_ = np.dtype(npdt)
         if d_.kind == "f":
             phys = "FLOAT" if d_.itemsize == 4 else "DOUBLE"
         else:  # ints + date32/timestamp storage types
             phys = "INT32" if d_.itemsize == 4 else "INT64"
-        count = len(blob) // _np.dtype(_NP_BY_PHYS[phys]).itemsize
+        count = len(blob) // np.dtype(_NP_BY_PHYS[phys]).itemsize
         plain = _plain_values(blob, phys, count)
     else:
-        plain = _np.zeros(0, npdt)
-    plain = _np.asarray(plain, npdt)
-    plain = _np.pad(plain, (0, _pow2(max(1, len(plain))) - len(plain)))
+        plain = np.zeros(0, npdt)
+    plain = np.asarray(plain, npdt)
+    plain = np.pad(plain, (0, _pow2(max(1, len(plain))) - len(plain)))
     if ch.uses_dict:
-        dict_vals = _np.asarray(ch.dictionary, npdt)
+        dict_vals = np.asarray(ch.dictionary, npdt)
     else:
-        dict_vals = _np.zeros(1, npdt)
-    dv = _np.pad(dict_vals,
-                 (0, _pow2(max(1, len(dict_vals))) - len(dict_vals)))
-    fn = cached_jit(f"pq_mix|{npdt_str}", _mixed_kernel_builder(npdt_str))
-    data, validity = fn(*v_tables, *d_tables, dv, plain,
-                        _np.int64(n_dict), _np.int64(n), iota_cap, iota_nv)
+        dict_vals = np.zeros(1, npdt)
+    dv = np.pad(dict_vals,
+                (0, _pow2(max(1, len(dict_vals))) - len(dict_vals)))
+    return (*v_tables, *d_tables, dv, plain,
+            np.int64(n_dict), np.int64(ch.num_rows), iota_cap, iota_nv)
+
+
+def _decode_column_device(ch: _Chunk, out_dtype: dt.DataType, cap: int):
+    """-> DeviceColumn with row capacity ``cap`` (device kernels; compiled
+    callables shared via the global compile cache, shapes pow2-bucketed).
+    Three phases, each a span: the host builds the kernel's inputs
+    (``scan.parse``), uploads them (``h2d``), and dispatches the decode."""
+    import jax
+
+    from ..columnar.device import DeviceColumn
+    from ..utils.compile_cache import cached_jit
+    from ..utils.tracing import get_tracer
+
+    tracer = get_tracer()
+    is_bytes = isinstance(out_dtype, (dt.StringType, dt.BinaryType))
+    npdt = None if is_bytes else out_dtype.np_dtype()
+    with tracer.span("scan.parse", "scan", step="kernel_inputs"):
+        args = _bytes_inputs(ch, cap) if is_bytes \
+            else _fixed_inputs(ch, npdt, cap)
+    with tracer.span("h2d", "upload",
+                     bytes=sum(int(a.nbytes) for a in args)):
+        args = jax.device_put(args)
+    if is_bytes:
+        fn = cached_jit("pq_ba", _ba_kernel_builder(), name="pq_decode_bytes")
+        data, lengths, validity = fn(*args)
+        return DeviceColumn(data, validity, out_dtype, lengths)
+    npdt_str = np.dtype(npdt).str
+    fn = cached_jit(f"pq_mix|{npdt_str}", _mixed_kernel_builder(npdt_str),
+                    name="pq_decode_fixed")
+    data, validity = fn(*args)
     return DeviceColumn(data, validity, out_dtype, None)
 
 
@@ -575,6 +612,7 @@ def decode_row_group(raw: bytes, pf_metadata, rg: int, arrow_schema,
     pyarrow host decode + upload for unsupported chunks. Returns
     (DeviceTable, n_device_decoded_columns)."""
     from ..columnar.device import DeviceTable, bucket_rows
+    from ..utils.tracing import get_tracer
     rg_meta = pf_metadata.row_group(rg)
     n = rg_meta.num_rows
     cap = bucket_rows(max(n, 1), min_bucket)
@@ -591,7 +629,8 @@ def decode_row_group(raw: bytes, pf_metadata, rg: int, arrow_schema,
             fallback.append(name)
             continue
         try:
-            ch = _parse_chunk(raw, col_meta, field.nullable)
+            with get_tracer().span("scan.parse", "scan", step="pages"):
+                ch = _parse_chunk(raw, col_meta, field.nullable)
             if ch.num_rows != n:
                 raise UnsupportedChunk("row count mismatch")
             cols[name] = _decode_column_device(

@@ -44,6 +44,9 @@ def prefetched(items: Iterable[T], fn: Callable[[T], R],
     if not items:
         return
     window = max(1, window)
+    # the read-ahead threads book their spans to the query that asked
+    from ..utils.tracing import get_tracer
+    fn = get_tracer().bind_query(fn)
     with cf.ThreadPoolExecutor(max_workers=window,
                                thread_name_prefix="srtpu-io-prefetch") \
             as pool:

@@ -79,7 +79,7 @@ class TpuSemaphore:
         with self._lock:
             self._waiters[tid] = (thread.name, time.monotonic())
         try:
-            with get_tracer().span("semaphore_wait", "semaphore", task=tid):
+            with get_tracer().span("wait.semaphore", "semaphore", task=tid):
                 self._sem.acquire()
         finally:
             with self._lock:

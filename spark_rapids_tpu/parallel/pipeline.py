@@ -359,6 +359,9 @@ def prefetched(make_iter: Callable[[], Iterator], *, stage: str,
             except queue.Full:
                 continue
 
+    tracer = get_tracer()
+
+    @tracer.bind_query      # the producer's spans belong to the consumer's query
     def produce():
         _WORKER_TLS.exempt = True  # runs under the owning task's admission
         try:
@@ -400,8 +403,6 @@ def prefetched(make_iter: Callable[[], Iterator], *, stage: str,
             _WORKERS.pop(dead, None)
     t.start()
 
-    tracer = get_tracer()
-
     def _get():
         # cooperative deadline: the consumer must not block forever on a
         # producer that wedged after the query's deadline passed — poll
@@ -420,13 +421,11 @@ def prefetched(make_iter: Callable[[], Iterator], *, stage: str,
     try:
         while True:
             t0 = time.perf_counter()
-            item = _get()
-            wait = time.perf_counter() - t0
+            with tracer.span("wait.pipeline", "pipeline", stage=stage):
+                item = _get()
             if registry is not None:
-                registry.add(M.PIPELINE_WAIT, wait)
+                registry.add(M.PIPELINE_WAIT, time.perf_counter() - t0)
                 registry.observe(M.PREFETCH_QUEUE_DEPTH, q.qsize())
-            tracer.complete("pipeline_wait", "pipeline", t0, wait,
-                            stage=stage, depth=q.qsize())
             if item is _Done:
                 return
             if isinstance(item, _Failure):
@@ -477,9 +476,11 @@ def parallel_map(fn: Callable[[T], R], items: Sequence[T],
     if not pipeline_enabled() or workers <= 1 or len(items) <= 1:
         return [fn(x) for x in items]
     import concurrent.futures as cf
+    from ..utils.tracing import get_tracer
     with _WORKERS_LOCK:
         _STATS["tasks_run"] += len(items)
 
+    @get_tracer().bind_query    # pool threads book spans to the submitter's query
     def run_exempt(x):
         # pool threads run under the submitting task's admission (see
         # semaphore_exempt); pipelined_collect re-opts into admission.

@@ -305,7 +305,7 @@ def materialize_stage(cpu_exchange: ShuffleExchangeExec, conf: RapidsConf,
         return total
 
     if isinstance(converted, TpuLocalExchangeExec):
-        with get_tracer().span("aqe_stage_materialize", "stage",
+        with get_tracer().span("stage", "stage",
                                exchange=type(converted).__name__):
             converted._materialize()
         prows = pbytes = 0
@@ -315,7 +315,7 @@ def materialize_stage(cpu_exchange: ShuffleExchangeExec, conf: RapidsConf,
             pbytes += _scaled_device_bytes(t)
         stats = PartitionStats([prows], [pbytes])
     elif isinstance(converted, TpuShuffleExchangeExec):
-        with get_tracer().span("aqe_stage_materialize", "stage",
+        with get_tracer().span("stage", "stage",
                                exchange=type(converted).__name__):
             converted._materialize()
         rows, nbytes = [], []
@@ -330,7 +330,7 @@ def materialize_stage(cpu_exchange: ShuffleExchangeExec, conf: RapidsConf,
         stats = PartitionStats(rows, nbytes)
     else:
         assert isinstance(converted, ShuffleExchangeExec), type(converted)
-        with get_tracer().span("aqe_stage_materialize", "stage",
+        with get_tracer().span("stage", "stage",
                                exchange=type(converted).__name__):
             converted._materialize()
         rows, nbytes = [], []

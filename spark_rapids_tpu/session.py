@@ -183,6 +183,12 @@ class TpuSession:
     # -- execution -----------------------------------------------------------
     def _physical(self, logical: LogicalPlan,
                   device: Optional[bool] = None) -> PhysicalPlan:
+        from .utils.tracing import get_tracer
+        with get_tracer().span("plan", "plan"):
+            return self._plan_physical(logical, device)
+
+    def _plan_physical(self, logical: LogicalPlan,
+                       device: Optional[bool]) -> PhysicalPlan:
         # the executing session is the active one (mesh discovery); conf-
         # sensitive expressions are BOUND at plan time below so lazily
         # consumed iterators keep this session's semantics even if another
@@ -217,6 +223,15 @@ class TpuSession:
             # (reference: GpuQueryStagePrepOverrides on AdaptiveSparkPlanExec)
             return AdaptiveExec(cpu, self.conf, use_device=True)
         return apply_overrides(cpu, self.conf)
+
+    def last_query_phases(self) -> Optional[Dict]:
+        """Where the host time of this session's last ``collect()`` went:
+        its wall, and per phase span (``plan``, ``scan.read``, ``h2d``,
+        ``dispatch``, ``sync``, ``d2h``, ``result``, ...) calls, seconds
+        of self time and bytes — docs/observability.md "Tracer". None
+        before the first query."""
+        query = getattr(self, "_last_query", None)
+        return None if query is None else query.to_dict()
 
     def set_conf(self, key: str, value) -> "TpuSession":
         self.conf = self.conf.set(key, value)
@@ -587,18 +602,41 @@ class DataFrame:
 
     # -- actions -------------------------------------------------------------
     def collect(self, device: Optional[bool] = None) -> pa.Table:
-        plan = self.session._physical(self.logical, device)
-        # pipelined executor: partitions drain concurrently under
-        # TpuSemaphore admission (parallel/pipeline.py); sequential
-        # PhysicalPlan.collect when pipeline.enabled=false or 1 partition
+        from .utils.tracing import get_tracer
+        logger = self.session._event_logger()
+        # the root span of the query: every span of this collect() carries
+        # its query id, and its phase totals (plan / scan / h2d / dispatch
+        # / sync / d2h / result) land in tracer.recent_queries(). With an
+        # event log the query also gets the log's id and a TraceContext
+        # that crosses process boundaries.
+        if logger is None:
+            with get_tracer().query() as root:
+                return self._collect_in(root, device, None, None, None)
+        qid, tctx = logger.begin_query()
+        try:
+            with get_tracer().query(tctx, query_id=qid) as root:
+                return self._collect_in(root, device, logger, qid, tctx)
+        finally:
+            # after the root closed: the record's critical path reads the
+            # root span from the tracer's ring
+            logger.end_query(qid, tctx)
+
+    def _collect_in(self, root, device, logger, qid, tctx) -> pa.Table:
         from .parallel.pipeline import pipelined_collect
         from .utils.deadline import QUERY_TIMEOUT, deadline_scope
         from .utils.health import HEALTH_REPORT_DIR
+        from .utils.tracing import get_tracer
 
+        session = self.session
+        session._last_query = root.summary
+        plan = session._physical(self.logical, device)
+
+        # pipelined executor: partitions drain concurrently under
+        # TpuSemaphore admission (parallel/pipeline.py); sequential
+        # PhysicalPlan.collect when pipeline.enabled=false or 1 partition
         def run():
-            return pipelined_collect(plan, self.session.conf)
+            return pipelined_collect(plan, session.conf)
 
-        logger = self.session._event_logger()
         try:
             # query deadline (spark.rapids.tpu.query.timeoutSeconds):
             # cooperative cancellation checkpoints across the retry
@@ -606,11 +644,12 @@ class DataFrame:
             # structured QueryTimeoutError past the deadline (no-op scope
             # when the timeout is 0)
             with deadline_scope(
-                    self.session.conf.get(QUERY_TIMEOUT),
-                    report_dir=self.session.conf.get(HEALTH_REPORT_DIR)):
-                if logger is not None:
-                    return logger.run_query(plan, run).to_arrow()
-                return run().to_arrow()
+                    session.conf.get(QUERY_TIMEOUT),
+                    report_dir=session.conf.get(HEALTH_REPORT_DIR)):
+                table = run() if logger is None \
+                    else logger.log_query(plan, run, qid, tctx)
+            with get_tracer().span("result", "result"):
+                return table.to_arrow()
         finally:
             # the plan is single-use (re-planned per collect): close its
             # spill-registered outputs now instead of waiting on GC — the

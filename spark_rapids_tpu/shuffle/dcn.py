@@ -79,7 +79,7 @@ class MockDcnFabric:
                  table: DeviceTable, device) -> DeviceTable:
         if self.fault is not None:
             self.fault(src, dst, block)
-        with get_tracer().span("dcn_transfer", "shuffle", src=src, dst=dst,
+        with get_tracer().span("shuffle.dcn_transfer", "shuffle", src=src, dst=dst,
                                shuffle=block[0], map=block[1]):
             moved = jax.device_put(table, device)
         nbytes = table.nbytes()
@@ -234,7 +234,7 @@ class TcpDcnShuffleTransport:
         # TraceContext (the SRTC wire header activated it), so this span
         # parents under the remote query span in the merged timeline
         t0 = telemetry.clock()
-        with get_tracer().span("dcn_serialize", "shuffle",
+        with get_tracer().span("shuffle.dcn_serialize", "shuffle",
                                shuffle=block[0], map=block[1]):
             payload = serialize_table(table.to_host(), codec=self.codec)
         tctx = current_trace_context()
@@ -277,7 +277,7 @@ class TcpDcnShuffleTransport:
                 partition=b[2], wire_bytes=len(payload), t0=t_fetch,
                 queue_depth=len(remote))
             t_des = telemetry.clock()
-            with get_tracer().span("dcn_fetch", "shuffle",
+            with get_tracer().span("shuffle.dcn_fetch", "shuffle",
                                    shuffle=b[0], map=b[1],
                                    bytes=len(payload)):
                 host = deserialize_table(payload)

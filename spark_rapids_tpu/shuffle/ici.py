@@ -30,6 +30,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..columnar.device import (DeviceColumn, DeviceTable,
                                stable_counting_order)
 from ..utils import movement
+from ..utils.compile_cache import named_jit
 from . import telemetry
 from .manager import device_partition_ids
 
@@ -145,10 +146,10 @@ def exchange_program(columns, names, key_names: List[str], mesh: Mesh,
     col_specs = jax.tree_util.tree_map(lambda _: P(axis), columns)
     # check_vma off: the exchange's output specs are data-dependent in
     # ways the static replication checker rejects
-    return jax.jit(jax.shard_map(local, mesh=mesh,
-                                 in_specs=(col_specs, P(axis)),
-                                 out_specs=(col_specs, P(axis)),
-                                 check_vma=False))
+    return named_jit(jax.shard_map(local, mesh=mesh,
+                                   in_specs=(col_specs, P(axis)),
+                                   out_specs=(col_specs, P(axis)),
+                                   check_vma=False), "ici_all_to_all")
 
 
 def ici_all_to_all_exchange(table: DeviceTable, key_names: List[str],

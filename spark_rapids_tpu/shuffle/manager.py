@@ -306,11 +306,11 @@ class ShuffleManager:
         if action is not None and action != "delay":
             raise faults.FaultInjectedError("shuffle.publish", action)
         if self.cache_writes:
-            with get_tracer().span("shuffle_write", "shuffle", tier="cached",
+            with get_tracer().span("shuffle.write", "shuffle", tier="cached",
                                    shuffle=shuffle_id, map=map_id):
                 return self._write_partition_cached(
                     shuffle_id, map_id, batches, key_names, num_parts)
-        with get_tracer().span("shuffle_write", "shuffle", tier="transport",
+        with get_tracer().span("shuffle.write", "shuffle", tier="transport",
                                shuffle=shuffle_id, map=map_id):
             return self._write_partition_transport(
                 shuffle_id, map_id, batches, key_names, num_parts)
@@ -455,7 +455,7 @@ class ShuffleManager:
         fetched_bytes = 0
         pending = list(blocks)
         retried = set()
-        with get_tracer().span("shuffle_fetch", "shuffle", tier="transport",
+        with get_tracer().span("shuffle.fetch", "shuffle", tier="transport",
                                shuffle=shuffle_id, reduce=reduce_id,
                                maps=num_maps):
             while pending:
@@ -495,7 +495,7 @@ class ShuffleManager:
                         raise
                     retried.add(map_id)
                     faults.note_recovery("shuffle_recomputes")
-                    with get_tracer().span("shuffle_recompute", "shuffle",
+                    with get_tracer().span("shuffle.recompute", "shuffle",
                                            shuffle=shuffle_id, map=map_id):
                         recompute(map_id)
                     pending = pending[pending.index(e.block):]
@@ -527,7 +527,7 @@ class ShuffleManager:
         parts: List[DeviceTable] = []
         schema_holder: Optional[DeviceTable] = None
         fetched_bytes = 0
-        with get_tracer().span("shuffle_fetch", "shuffle", tier="cached",
+        with get_tracer().span("shuffle.fetch", "shuffle", tier="cached",
                                shuffle=shuffle_id, reduce=reduce_id,
                                maps=num_maps):
             tables: List[DeviceTable] = []
@@ -544,7 +544,7 @@ class ShuffleManager:
                         shuffle=shuffle_id, map=m, reduce=reduce_id,
                         retry=True)
                     faults.note_recovery("shuffle_recomputes")
-                    with get_tracer().span("shuffle_recompute", "shuffle",
+                    with get_tracer().span("shuffle.recompute", "shuffle",
                                            shuffle=shuffle_id, map=m):
                         recompute(m)
                     handle = self.buffer_catalog.get(key)
@@ -567,7 +567,7 @@ class ShuffleManager:
                             BlockId(shuffle_id, m, reduce_id),
                             f"spilled block corrupt: {e}")
                     faults.note_recovery("shuffle_recomputes")
-                    with get_tracer().span("shuffle_recompute", "shuffle",
+                    with get_tracer().span("shuffle.recompute", "shuffle",
                                            shuffle=shuffle_id, map=m):
                         recompute(m)
                     fresh = self.buffer_catalog.get(key)

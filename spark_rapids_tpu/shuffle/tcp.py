@@ -435,7 +435,7 @@ class TcpShuffleTransport(ShuffleTransport):
                 else:
                     return
                 with activate_trace_context(tctx), \
-                        get_tracer().span("shuffle_serve", "shuffle",
+                        get_tracer().span("shuffle.serve", "shuffle",
                                           op=op, shuffle=sid, map=mid,
                                           reduce=rid):
                     self._serve_request(conn, op, sid, mid, rid)
@@ -506,7 +506,7 @@ class TcpShuffleTransport(ShuffleTransport):
         the missing-block path on ShuffleFetchFailedException ->
         recompute while flaky networks just retry. With a TraceContext
         the traced wire variant (magic SRTC) carries it, so the server's
-        shuffle_serve span parents under it."""
+        shuffle.serve span parents under it."""
         if tctx is not None and self._trace_wire:
             head = _REQ.pack(_MAGIC_TRACED, _OP_GET_RANGE, *block) \
                 + tctx.pack()

@@ -51,6 +51,13 @@ Checkers (see the sibling modules):
                classifying, breaking split-and-retry bookkeeping and
                cooperative cancellation.
 
+- ``jitname`` — device programs compiled without a fixed name:
+               ``cached_jit`` without ``name=`` (or with a name
+               ``compile_cache.PROGRAM_NAMES`` does not list) and bare
+               ``jax.jit`` outside the two naming funnels — the XLA
+               module name is what profiles and the benchmark's
+               per-layer metrics find a program by.
+
 Workflow: findings are compared against a COMMITTED baseline
 (``tools/analyze/baseline.json``) so pre-existing debt is inventoried
 while any *new* violation fails tier-1 (tests/test_analyze.py). Sites
@@ -325,18 +332,19 @@ def load_project(paths: Sequence[str]) -> Project:
 
 def _checkers() -> Dict[str, object]:
     from . import (buckets, degrade, eventlog_schema, host_sync, jit_purity,
-                   locks, memtrack, mesh_loops, net, retry_scope,
-                   shuffle_observed, threads, trace_ctx)
+                   locks, memtrack, mesh_loops, net, program_names,
+                   retry_scope, shuffle_observed, threads, trace_ctx)
     return {"sync": host_sync, "lock": locks,
             "thread": threads, "jit": jit_purity, "bucket": buckets,
             "trace": trace_ctx, "memtrack": memtrack,
             "eventlog": eventlog_schema, "net": net, "retry": retry_scope,
             "degrade": degrade, "shuffle": shuffle_observed,
-            "mesh": mesh_loops}
+            "mesh": mesh_loops, "jitname": program_names}
 
 
 CHECKS = ("sync", "lock", "thread", "jit", "bucket", "trace", "memtrack",
-          "eventlog", "net", "retry", "degrade", "shuffle", "mesh")
+          "eventlog", "net", "retry", "degrade", "shuffle", "mesh",
+          "jitname")
 
 
 def analyze_paths(paths: Sequence[str],

@@ -161,6 +161,8 @@ class EventLogWriter:
         self.path = os.path.join(directory, f"{app_id}.jsonl")
         self._f = open(self.path, "a", encoding="utf-8")
         self._query_seq = 0
+        #: qid -> query_end record awaiting its root span's close (end_query)
+        self._pending_end: Dict[int, Dict] = {}
         # v4: the health monitor thread appends heartbeats while the query
         # thread writes node/query records — serialize whole lines
         self._lock = threading.Lock()
@@ -183,21 +185,49 @@ class EventLogWriter:
         self._query_seq += 1
         return self._query_seq
 
+    def begin_query(self):
+        """``(query id, TraceContext)`` of the next query. v5: one
+        TraceContext per query — the identity every process boundary
+        (ProcessCluster envelope, shuffle wire header) carries so worker
+        spans merge under this query's timeline. The caller opens the
+        root span with both (``get_tracer().query(tctx, query_id=qid)``)."""
+        from ..utils.tracing import mint_trace_context
+        qid = self.next_query_id()
+        return qid, mint_trace_context(query_id=qid)
+
     def run_query(self, plan, collect_fn):
-        """Instrument ``plan``, run ``collect_fn()``, persist the events."""
+        """Instrument ``plan``, run ``collect_fn()`` under a root
+        ``query`` span of its own, persist the events — for a plan driven
+        without ``DataFrame.collect`` (which opens the root itself, around
+        planning too, and calls the two halves)."""
+        from ..utils.tracing import get_tracer
+        qid, tctx = self.begin_query()
+        try:
+            with get_tracer().query(tctx, query_id=qid):
+                return self.log_query(plan, collect_fn, qid, tctx)
+        finally:
+            self.end_query(qid, tctx)
+
+    def end_query(self, qid: int, tctx) -> None:
+        """Write the ``query_end`` record ``log_query`` prepared — called
+        once the root ``query`` span has closed, because the record's
+        critical path is computed from the tracer's ring and the root
+        lands there on exit. No-op after a failed query (its record is
+        written where it failed)."""
+        record = self._pending_end.pop(qid, None)
+        if record is not None:
+            record["critical_path"] = _query_critical_path(tctx.trace_id)
+            self.write(record)
+
+    def log_query(self, plan, collect_fn, qid: int, tctx):
+        """Inside the root span: instrument ``plan``, run
+        ``collect_fn()``, persist everything but ``query_end``."""
         from ..memory.catalog import get_catalog
         from ..memory.semaphore import get_semaphore
         from ..utils.compile_cache import kernel_seq, kernels_since
         from ..utils.metrics import StatsRegistry, get_stats
-        from ..utils.tracing import (activate_trace_context, get_tracer,
-                                     mint_trace_context)
         from .profiler import instrument_plan
 
-        qid = self.next_query_id()
-        # v5: one TraceContext per query — the identity every process
-        # boundary (ProcessCluster envelope, shuffle wire header) carries
-        # so worker spans merge under this query's timeline
-        tctx = mint_trace_context(query_id=qid)
         epoch = time.perf_counter()
         stats: List = []
         from ..plan.aqe import AdaptiveExec
@@ -223,9 +253,7 @@ class EventLogWriter:
                     "plan": plan.tree_string()})
         t0 = time.perf_counter()
         try:
-            with activate_trace_context(tctx), \
-                    get_tracer().span("query", "query", query_id=qid):
-                result = collect_fn()
+            result = collect_fn()
         except Exception as e:
             # v6: the OOM that killed the query (if any) queued a
             # postmortem in the flight recorder — persist it, and the leak
@@ -297,10 +325,9 @@ class EventLogWriter:
         self._write_movement_records(qid)
         self._write_shuffle_records(qid)
         aqe_events: List[str] = list(getattr(plan, "events", []))
-        self.write({
+        self._pending_end[qid] = {
             "event": "query_end", "query_id": qid, "ts": time.time(),
             "trace_id": tctx.trace_id,
-            "critical_path": _query_critical_path(tctx.trace_id),
             "wall_s": wall, "final_plan": plan.tree_string(),
             "aqe_events": aqe_events,
             "spill_count": {str(k): v - spill_before.get(k, 0)
@@ -311,7 +338,7 @@ class EventLogWriter:
             # the attribution BENCH needs (VERDICT layer-11 gap)
             "stats": StatsRegistry.delta(registry.collect(),
                                          counters_before),
-        })
+        }
         return result
 
     def _write_memory_records(self, qid: int) -> None:

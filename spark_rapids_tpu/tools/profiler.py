@@ -354,12 +354,12 @@ def profile_query(df, device: Optional[bool] = None,
         import jax.profiler
         t0 = time.perf_counter()
         with jax.profiler.trace(xla_trace_dir), \
-                get_tracer().span("query", "query", profiled=True):
+                get_tracer().query(profiled=True):
             plan.collect()
         total = time.perf_counter() - t0
     else:
         t0 = time.perf_counter()
-        with get_tracer().span("query", "query", profiled=True):
+        with get_tracer().query(profiled=True):
             plan.collect()
         total = time.perf_counter() - t0
 

@@ -36,6 +36,14 @@ CLI::
         [-o merged.json] [--trace-id HEX]
     python -m spark_rapids_tpu.tools.trace critical-path <merged.json> \
         [--trace-id HEX]
+    python -m spark_rapids_tpu.tools.trace gaps <capture.xplane.pb> [--json]
+
+``gaps`` reads a ``jax.profiler`` capture instead of the engine's own
+ring: every ``Tracer.span`` is also a ``TraceAnnotation("srt.<name>")``, so
+under any profiler session the engine's spans lie in the xplane's host
+plane on the clock the device planes are synchronised to. The command
+books the device's idle time inside each query to the phase span the host
+was in.
 """
 from __future__ import annotations
 
@@ -45,7 +53,7 @@ from typing import Dict, Iterable, List, Optional, Tuple
 
 __all__ = ["merge_process_traces", "load_process_traces",
            "critical_path", "critical_path_from_tracer", "CriticalPath",
-           "CATEGORY_BY_CAT", "span_category"]
+           "CATEGORY_BY_CAT", "span_category", "idle_by_phase"]
 
 # ---------------------------------------------------------------------------
 # category attribution: tracer cat -> critical-path bucket
@@ -61,6 +69,9 @@ CATEGORY_BY_CAT: Dict[str, str] = {
     "pipeline": "pipeline_queue_idle",
     "download": "sync_wait",      # blocking D2H sync (ROADMAP item 1)
     "upload": "h2d_upload",
+    "plan": "planning",
+    "scan": "host_scan",
+    "dispatch": "dispatch",
     "spill": "spill",
     # spill-restore + OOM-recovery spans (memory/catalog.py) — time the
     # query lost to HBM pressure, distinct from proactive spill writes
@@ -421,6 +432,163 @@ def query_trace_ids(events: Iterable[dict]) -> List[Tuple[str, float]]:
 
 
 # ---------------------------------------------------------------------------
+# device idle time by host phase, from a jax.profiler capture
+# ---------------------------------------------------------------------------
+_HOST_PLANE = "/host:CPU"
+_DEVICE_PLANE_PREFIX = "/device:TPU:"
+_DEVICE_OPS_LINE = "XLA Ops"
+_NO_SPAN = "(no srt span open)"
+
+
+def _span_rank(name: str, structural) -> int:
+    """Which open span a gap is booked to: a phase that does work beats a
+    wait, and a wait beats the spans that only group others or wait on
+    the engine's own producer thread (``structural``: ``wait.pipeline`` on
+    the consumer says nothing while the producer is in ``scan.read``)."""
+    if name in structural:
+        return 0
+    return 1 if name.startswith("wait.") else 2
+
+
+def idle_by_phase(profile) -> Optional[Dict]:
+    """For the busiest device of a ``jax.profiler`` capture: its idle
+    nanoseconds inside ``srt.query`` spans, booked to the innermost
+    ``srt.*`` span open on any host thread during each gap (ranked by
+    ``_span_rank``, then the latest opened), and the remainder no span
+    covers. ``profile`` is a ``jax.profiler.ProfileData`` or anything
+    shaped like it (planes -> lines -> events with name, start_ns,
+    duration_ns). None when the capture has no device plane or no query
+    span. Reuses nothing of ``benchmark/``."""
+    from ..utils.tracing import ANNOTATION_PREFIX, STRUCTURAL_SPANS
+    spans: List[Tuple[float, float, str]] = []
+    busy: Dict[str, List[Tuple[float, float]]] = {}
+    for plane in profile.planes:
+        if plane.name == _HOST_PLANE:
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith(ANNOTATION_PREFIX):
+                        spans.append((e.start_ns,
+                                      e.start_ns + e.duration_ns,
+                                      e.name[len(ANNOTATION_PREFIX):]))
+        elif plane.name.startswith(_DEVICE_PLANE_PREFIX):
+            for line in plane.lines:
+                if line.name == _DEVICE_OPS_LINE:
+                    busy.setdefault(plane.name, []).extend(
+                        (e.start_ns, e.start_ns + e.duration_ns)
+                        for e in line.events)
+    queries = sorted((s, e) for s, e, n in spans if n == "query")
+    if not busy or not queries:
+        return None
+
+    def merged(ivals):
+        out: List[List[float]] = []
+        for s, e in sorted(ivals):
+            if out and s <= out[-1][1]:
+                out[-1][1] = max(out[-1][1], e)
+            else:
+                out.append([s, e])
+        return out
+
+    def inside(ivals, lo, hi):
+        return [(max(s, lo), min(e, hi)) for s, e in ivals
+                if min(e, hi) > max(s, lo)]
+
+    def busy_in_queries(ivals):
+        return sum(e - s for qs, qe in queries
+                   for s, e in inside(ivals, qs, qe))
+
+    device = max(busy, key=lambda d: busy_in_queries(merged(busy[d])))
+    ops = merged(busy[device])
+    gaps: List[Tuple[float, float]] = []
+    for qs, qe in merged(queries):
+        edge = qs
+        for s, e in inside(ops, qs, qe):
+            if s > edge:
+                gaps.append((edge, s))
+            edge = max(edge, e)
+        if qe > edge:
+            gaps.append((edge, qe))
+
+    # one sweep over every boundary: between two neighbours the set of
+    # open spans is constant, so the elementary interval has one owner
+    rank = {n: _span_rank(n, STRUCTURAL_SPANS) for _, _, n in spans}
+    points = []
+    for s, e, n in spans:
+        points.append((s, 1, (s, n)))
+        points.append((e, 0, (s, n)))
+    for s, e in gaps:
+        points.append((s, 3, None))
+        points.append((e, 2, None))
+    points.sort(key=lambda p: (p[0], p[1]))
+    open_spans: Dict[Tuple[float, str], int] = {}
+    in_gap = False
+    by_phase: Dict[str, float] = {}
+    prev = None
+    for t, kind, key in points:
+        if in_gap and prev is not None and t > prev:
+            owner = max(open_spans, default=None,
+                        key=lambda k: (rank[k[1]], k[0]))
+            name = _NO_SPAN if owner is None else owner[1]
+            by_phase[name] = by_phase.get(name, 0.0) + (t - prev)
+        prev = t
+        if kind == 1:
+            open_spans[key] = open_spans.get(key, 0) + 1
+        elif kind == 0:
+            if open_spans.get(key, 0) <= 1:
+                open_spans.pop(key, None)
+            else:
+                open_spans[key] -= 1
+        else:
+            in_gap = kind == 3
+    ns = 1e-9
+    idle = sum(e - s for s, e in gaps)
+    named = sum(v for n, v in by_phase.items()
+                if n != _NO_SPAN and rank[n] > 0)
+    return {
+        "device": device,
+        "queries": len(queries),
+        "query_s": sum(e - s for s, e in queries) * ns,
+        "busy_s": busy_in_queries(ops) * ns,
+        "idle_s": idle * ns,
+        "idle_by_phase_s": {n: v * ns for n, v in sorted(
+            by_phase.items(), key=lambda kv: -kv[1])},
+        "named_share": named / idle if idle else 0.0,
+    }
+
+
+def _cmd_gaps(argv: List[str]) -> int:
+    import argparse
+    ap = argparse.ArgumentParser(
+        prog="spark_rapids_tpu.tools.trace gaps",
+        description="Device idle time inside each query, by the engine "
+                    "span the host was in (from a jax.profiler capture).")
+    ap.add_argument("xplane", help="a .xplane.pb file of a jax.profiler "
+                                   "capture taken while queries ran")
+    ap.add_argument("--json", action="store_true",
+                    help="emit machine-readable JSON")
+    ns = ap.parse_args(argv)
+    from jax.profiler import ProfileData
+    got = idle_by_phase(ProfileData.from_file(ns.xplane))
+    if got is None:
+        print("no device plane with XLA ops, or no srt.query span, in "
+              "this capture")
+        return 1
+    if ns.json:
+        print(json.dumps(got, indent=2))
+        return 0
+    print(f"{got['device']}: {got['queries']} queries, "
+          f"{got['query_s']:.6f} s inside srt.query; device busy "
+          f"{got['busy_s']:.6f} s, idle {got['idle_s']:.6f} s")
+    print(f"idle seconds by the span the host was in "
+          f"({100 * got['named_share']:.1f}% in a named phase):")
+    for name, sec in got["idle_by_phase_s"].items():
+        share = 100 * sec / got["idle_s"] if got["idle_s"] else 0.0
+        print(f"  {name:<22} {sec:12.6f} s {share:6.2f}%"
+              f" {sec / got['queries']:12.6f} s/query")
+    return 0
+
+
+# ---------------------------------------------------------------------------
 # CLI
 # ---------------------------------------------------------------------------
 def _cmd_merge(argv: List[str]) -> int:
@@ -495,14 +663,17 @@ def main(argv: Optional[List[str]] = None) -> int:
     argv = list(sys.argv[1:] if argv is None else argv)
     if not argv or argv[0] in ("-h", "--help"):
         print("usage: python -m spark_rapids_tpu.tools.trace "
-              "{merge,critical-path} ...")
+              "{merge,critical-path,gaps} ...")
         return 0 if argv else 1
     cmd, rest = argv[0], argv[1:]
     if cmd == "merge":
         return _cmd_merge(rest)
     if cmd in ("critical-path", "critical_path"):
         return _cmd_critical_path(rest)
-    print(f"unknown subcommand: {cmd!r} (expected merge | critical-path)")
+    if cmd == "gaps":
+        return _cmd_gaps(rest)
+    print(f"unknown subcommand: {cmd!r} "
+          f"(expected merge | critical-path | gaps)")
     return 1
 
 

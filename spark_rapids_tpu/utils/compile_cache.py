@@ -48,7 +48,8 @@ import jax
 
 from ..conf import register_conf
 
-__all__ = ["cached_jit", "cache_stats", "clear_cache", "oom_retry",
+__all__ = ["cached_jit", "named_jit", "named_program", "PROGRAM_NAMES",
+           "cache_stats", "clear_cache", "oom_retry",
            "configure_introspection", "kernel_table", "kernel_seq",
            "kernels_since", "XLA_INTROSPECTION", "KERNEL_TABLE_SIZE",
            "configure_compile_cache", "persist_compile_cache",
@@ -56,6 +57,97 @@ __all__ = ["cached_jit", "cache_stats", "clear_cache", "oom_retry",
            "persistent_cache_dir", "COMPILE_CACHE_DIR",
            "COMPILE_CACHE_ENABLED", "WARM_POOL_ENABLED",
            "WARM_POOL_MAX_SIGNATURES", "WARM_POOL_MAX_SECONDS"]
+
+#: Every device program the engine compiles, by its stable name. XLA names
+#: a module after the jitted function (``jit_<__name__>``), so ``cached_jit``
+#: / ``named_jit`` rename the function to ``srt_<name>`` first: the module is
+#: ``jit_srt_<name>`` in every profile, HLO dump and compile log, whatever the
+#: inner function of the builder happens to be called. A name never holds a
+#: shape, a key or a partition number (those are the cache KEY's job).
+#: FROZEN: the module name is part of XLA's persistent-cache key, so renaming
+#: an entry costs every user one cold compile of that program. Add, never
+#: rename.
+PROGRAM_NAMES: Dict[str, str] = {
+    "pq_decode_fixed": "Parquet fixed-width column decode (io/parquet_device.py)",
+    "pq_decode_bytes": "Parquet BYTE_ARRAY column decode (io/parquet_device.py)",
+    "csv_decode": "CSV field split + typed parse (exec/scan.py)",
+    "json_decode": "JSON-lines field extract + typed parse (exec/scan.py)",
+    "stage": "whole-stage fused operator chain (exec/wholestage.py)",
+    "op_project": "TpuProjectExec run alone (exec/basic.py)",
+    "op_filter": "TpuFilterExec run alone (exec/basic.py)",
+    "op_sample": "TpuSampleExec (exec/basic.py)",
+    "op_expand": "TpuExpandExec run alone (exec/basic.py)",
+    "op_limit": "TpuLocalLimitExec: compact and keep the first n rows (exec/basic.py)",
+    "agg_grouped": "hash group-by aggregate, one batch (exec/aggregate.py)",
+    "agg_ungrouped": "aggregate without keys, one batch (exec/aggregate.py)",
+    "agg_sizes": "collect_list/set width probe (exec/aggregate.py)",
+    "compact": "DeviceTable.compact (columnar/device.py)",
+    "concat": "concat_device_tables (columnar/device.py)",
+    "slice_rows": "slice_rows (columnar/device.py)",
+    "sort": "full sort of one batch (exec/sort.py)",
+    "sort_topn": "top-n reduce + sort (exec/sort.py)",
+    "window": "window functions over one sorted partition (exec/window.py)",
+    "join_prep_hash": "hash-join build-side prep (exec/joins.py)",
+    "join_prep_dense": "sorted-join build-side prep (exec/joins.py)",
+    "join_counts": "probe match counts (exec/joins.py)",
+    "join_probe_count": "dense probe match counts (exec/joins.py)",
+    "join_matched": "build rows matched so far (exec/joins.py)",
+    "join_pk_hash": "fused primary-key hash join (exec/joins.py)",
+    "join_pk": "fused primary-key sorted join (exec/joins.py)",
+    "join_semi": "semi/anti probe mask (exec/joins.py)",
+    "join_expand": "join output expansion (exec/joins.py)",
+    "join_expand_cond": "join expansion with a condition (exec/joins.py)",
+    "join_cond": "post-join condition filter (exec/joins.py)",
+    "join_leftover": "unmatched build rows of an outer join (exec/joins.py)",
+    "join_cross": "nested-loop / cross join slice (exec/joins.py)",
+    "join_cross_pairs": "nested-loop join pairs of one slice (exec/joins.py)",
+    "exchange_pid": "partition ids of one exchange chunk (exec/exchange.py)",
+    "mesh_stage": "operator chain over a device mesh (exec/mesh.py)",
+    "ici_all_to_all": "hash exchange as one all-to-all (shuffle/ici.py)",
+    "warm_replay": "a persisted export replayed by the warm pool",
+}
+_PROGRAM_PREFIX = "srt_"
+
+
+def _named(fn: Callable, name: str) -> Callable:
+    """``fn`` under the fixed program name ``srt_<name>``: what
+    ``jax.jit`` reads the XLA module name from. A wrapper, because a
+    builder may return a bound method or a shared function whose
+    ``__name__`` is not ours to set; it exists only while tracing."""
+    if name not in PROGRAM_NAMES:
+        raise ValueError(
+            f"device program name {name!r} is not in "
+            f"compile_cache.PROGRAM_NAMES: add it there (names are frozen "
+            f"once released, so choose it for good)")
+
+    def program(*args, **kwargs):
+        return fn(*args, **kwargs)
+    program.__name__ = program.__qualname__ = _PROGRAM_PREFIX + name
+    program.__wrapped__ = fn
+    return program
+
+
+def named_jit(fn: Callable, name: str, **jit_kwargs) -> Callable:
+    """``jax.jit(fn)`` compiled as module ``jit_srt_<name>`` — for the few
+    programs jitted outside ``cached_jit`` (module-level utilities, mesh
+    programs with caches of their own)."""
+    return jax.jit(_named(fn, name), **jit_kwargs)
+
+
+def named_program(fn: Callable, name: str, **jit_kwargs) -> Callable:
+    """``named_jit`` for a program that is called directly (the
+    module-level table utilities of columnar/device.py): each call is a
+    ``dispatch`` span, as through a ``cached_jit`` entry."""
+    from .tracing import get_tracer
+    jitted = named_jit(fn, name, **jit_kwargs)
+    program = _PROGRAM_PREFIX + name
+
+    @functools.wraps(fn)
+    def dispatch(*args, **kwargs):
+        with get_tracer().span("dispatch", "dispatch", program=program):
+            return jitted(*args, **kwargs)
+    return dispatch
+
 
 _CACHE: Dict[str, Callable] = {}
 _LOCK = threading.Lock()
@@ -331,33 +423,37 @@ _EXEC_MISMATCH_MARKERS = ("but got buffer with incompatible size",
                           "buffers but compiled program expected")
 
 
-def _rebuild_on_mismatch(key: str, builder: Callable[[], Callable],
+def _rebuild_on_mismatch(builder: Callable[[], Callable],
                          fn: Callable) -> Callable:
     """jax 0.9 workaround: a jit wrapper's dispatch cache can resolve to a
     stale executable for inputs whose treedef+avals are IDENTICAL to a
     previously successful call (observed with (n, 2) two-limb decimal128
     columns — no-lengths 2-D data planes). A fresh jax.jit of the same
     builder always works, so on that specific INVALID_ARGUMENT signature
-    the entry is rebuilt once and the call retried."""
+    the jitted callable is rebuilt and the call retried. The rebuild
+    happens INSIDE this wrapper, so the cache entry (and the ``dispatch``
+    span around it) stays the one every caller already holds."""
+    current = [fn]
+
     @functools.wraps(fn)
     def wrapped(*args, **kwargs):
         try:
-            return fn(*args, **kwargs)
+            return current[0](*args, **kwargs)
         except ValueError as e:
             msg = str(e)
             if not any(m in msg for m in _EXEC_MISMATCH_MARKERS):
                 raise
-            fresh = oom_retry(jax.jit(builder()))
-            with _LOCK:
-                _CACHE[key] = _rebuild_on_mismatch(key, builder, fresh)
-            return fresh(*args, **kwargs)
+            current[0] = oom_retry(jax.jit(builder()))
+            return current[0](*args, **kwargs)
     return wrapped
 
 
 def _time_first_call(key: str, fn: Callable,
-                     builder: Optional[Callable[[], Callable]] = None
-                     ) -> Callable:
-    """Attribute a cache entry's first invocation to XLA compile time.
+                     builder: Optional[Callable[[], Callable]] = None,
+                     name: str = "") -> Callable:
+    """The wrapper every cache entry is called through: a ``dispatch``
+    span (the host side of one call into the compiled program ``name``)
+    around each call, and the first call attributed to XLA compile time.
 
     jax.jit compiles lazily on first dispatch, so the first call through a
     fresh entry is (compile + run); later calls are steady-state dispatch.
@@ -367,13 +463,16 @@ def _time_first_call(key: str, fn: Callable,
     compile stalls on the query timeline. The first call also feeds the
     kernel table: compile wall + (when introspection is on) the program's
     HLO cost/memory analysis, attributed to the executing node."""
+    from .tracing import get_tracer
     state = {"done": False}
+    program = _PROGRAM_PREFIX + name
 
     @functools.wraps(fn)
     def wrapped(*args, **kwargs):
         global _COMPILES, _COMPILE_SECONDS
         if state["done"]:
-            return fn(*args, **kwargs)
+            with get_tracer().span("dispatch", "dispatch", program=program):
+                return fn(*args, **kwargs)
         # shape/dtype skeleton BEFORE dispatch: donated input buffers may
         # be dead afterwards; the skeleton is what session close exports
         # for the persistent tier (cheap — aval metadata only)
@@ -383,9 +482,10 @@ def _time_first_call(key: str, fn: Callable,
                 skeleton = jax.tree_util.tree_map(_aval_of, (args, kwargs))
             except Exception:
                 skeleton = None
-        from .tracing import get_tracer
         t0 = time.perf_counter()
-        with get_tracer().span("xla_compile", "compile", key=key[:160]):
+        with get_tracer().span("dispatch", "dispatch", program=program), \
+                get_tracer().span("compile", "compile", program=program,
+                                  key=key[:160]):
             out = fn(*args, **kwargs)
         dt = time.perf_counter() - t0
         first = False
@@ -434,9 +534,14 @@ def _attribute(metric_name: str) -> None:
         reg.add(metric_name, 1)
 
 
-def cached_jit(key: str, builder: Callable[[], Callable],
+def cached_jit(key: str, builder: Callable[[], Callable], *, name: str,
                donate_argnums=None) -> Callable:
     """Return a jitted callable for ``key``, building it on first use.
+
+    ``name`` is the program's fixed name from ``PROGRAM_NAMES``: the
+    built function is compiled as XLA module ``jit_srt_<name>`` and every
+    call is a ``dispatch`` span carrying it. Many keys share one name
+    (every fused chain of every query is ``stage``).
 
     ``donate_argnums`` requests XLA input-buffer donation for the jitted
     entry (exec/wholestage.py input donation — callers MUST key donating
@@ -454,33 +559,46 @@ def cached_jit(key: str, builder: Callable[[], Callable],
                 _touch_locked(entry)
         else:
             _MISSES += 1
-            _kernel_entry_locked(key)["misses"] += 1
+            entry = _kernel_entry_locked(key)
+            entry["misses"] += 1
+            entry["program"] = _PROGRAM_PREFIX + name
+
+    def named_builder():
+        return _named(builder(), name)
+
     if fn is not None:
         if isinstance(fn, _WarmedEntry):
             # warm-pool entries need the builder for output-pytree
             # reconstruction and as the unexpected-shape fallback
-            fn.attach_builder(builder, donate_argnums)
+            fn.attach_builder(named_builder, donate_argnums, name)
         _attribute(M.COMPILE_CACHE_HITS)
         return fn
     _attribute(M.COMPILE_CACHE_MISSES)
-    if donate_argnums is None:
-        built = _time_first_call(key, _rebuild_on_mismatch(
-            key, builder, oom_retry(jax.jit(builder()))), builder)
-    else:
-        # donating entries get NO call-again recovery with the SAME args
-        # (the failed dispatch may have consumed the donated input); the
-        # donating ladder re-materializes from the retained host origin
-        # instead, or spills-and-raises structured when there is none
-        built = _time_first_call(key, oom_spill_noretry(
-            jax.jit(builder(), donate_argnums=donate_argnums)), builder)
+    built = _build_entry(key, named_builder, donate_argnums, name)
     with _LOCK:
         fn = _CACHE.setdefault(key, built)
     if fn is not built and isinstance(fn, _WarmedEntry):
         # the warm pool installed this key between our miss check and the
         # setdefault — the warmed entry has never seen a cached_jit() hit,
         # so it still needs the builder for out-tree/fallback dispatch
-        fn.attach_builder(builder, donate_argnums)
+        fn.attach_builder(named_builder, donate_argnums, name)
     return fn
+
+
+def _build_entry(key: str, builder: Callable[[], Callable], donate_argnums,
+                 name: str) -> Callable:
+    """A fresh cache entry over ``builder`` (already named). The OOM
+    wrapper sits directly on the jitted callable and ``_time_first_call``
+    outermost, so the ``dispatch`` span covers recovery too."""
+    if donate_argnums is None:
+        return _time_first_call(key, _rebuild_on_mismatch(
+            builder, oom_retry(jax.jit(builder()))), builder, name)
+    # donating entries get NO call-again recovery with the SAME args (the
+    # failed dispatch may have consumed the donated input); the donating
+    # ladder re-materializes from the retained host origin instead, or
+    # spills-and-raises structured when there is none
+    return _time_first_call(key, oom_spill_noretry(
+        jax.jit(builder(), donate_argnums=donate_argnums)), builder, name)
 
 
 def cache_stats() -> Dict[str, float]:
@@ -552,16 +670,19 @@ class _WarmedEntry:
         self._out_trees: Dict[str, object] = {}   # aval_sig -> out treedef
         self._builder: Optional[Callable] = None
         self._donate = None
+        self._name = "warm_replay"
         self._fallback: Optional[Callable] = None
         self._elock = threading.Lock()
 
     def add_record(self, aval_sig: str, dispatch: Callable) -> None:
         self._records[aval_sig] = dispatch
 
-    def attach_builder(self, builder: Callable, donate_argnums) -> None:
+    def attach_builder(self, builder: Callable, donate_argnums,
+                       name: str) -> None:
         if self._builder is None:
             self._builder = builder
             self._donate = donate_argnums
+            self._name = name
 
     def _fallback_fn(self) -> Callable:
         fb = self._fallback
@@ -574,16 +695,8 @@ class _WarmedEntry:
                     raise RuntimeError(
                         f"warmed compile-cache entry {self.key!r} dispatched "
                         f"before any cached_jit() call attached its builder")
-                if self._donate is None:
-                    self._fallback = _time_first_call(
-                        self.key, _rebuild_on_mismatch(
-                            self.key, builder,
-                            oom_retry(jax.jit(builder()))), builder)
-                else:
-                    self._fallback = _time_first_call(
-                        self.key, oom_spill_noretry(jax.jit(
-                            builder(), donate_argnums=self._donate)),
-                        builder)
+                self._fallback = _build_entry(self.key, builder,
+                                              self._donate, self._name)
             return self._fallback
 
     def _out_tree_for(self, aval_sig: str, args, kwargs, n_out: int):
@@ -610,8 +723,11 @@ class _WarmedEntry:
             with _LOCK:
                 _PSTATS["misses"] += 1
             return self._fallback_fn()(*args, **kwargs)
+        from .tracing import get_tracer
         try:
-            flat_out = dispatch(*leaves)
+            with get_tracer().span("dispatch", "dispatch",
+                                   program=_PROGRAM_PREFIX + self._name):
+                flat_out = dispatch(*leaves)
             tree = self._out_tree_for(aval_sig, args, kwargs, len(flat_out))
             if tree is None:
                 raise TypeError("output arity mismatch")
@@ -669,6 +785,7 @@ def _load_manifest(path: str) -> Tuple[Dict[str, Dict], int]:
                  "compiles": int(e.get("compiles", 0)),
                  "compile_s": float(e.get("compile_s", 0.0) or 0.0),
                  "node_name": e.get("node_name"),
+                 "program": e.get("program"),
                  "exports": good_exports}
         entries[sig] = entry
     return entries, dropped
@@ -738,15 +855,16 @@ def _persist_off() -> None:
         jax.config.update("jax_compilation_cache_dir", None)
 
 
-def _warm_items_locked() -> List[Tuple[str, str, str]]:
-    """(signature, export file, aval_sig) triples for the hottest
+def _warm_items_locked() -> List[Tuple[str, str, str, Optional[str]]]:
+    """(signature, export file, aval_sig, program name) for the hottest
     manifest signatures, bounded by warmPool.maxSignatures."""
     ranked = sorted(_PERSIST["base"].items(),
                     key=lambda kv: -(kv[1]["hits"] + kv[1]["compiles"]))
-    items: List[Tuple[str, str, str]] = []
+    items: List[Tuple[str, str, str, Optional[str]]] = []
     for sig, entry in ranked[:_PERSIST["warm_max"]]:
         for ex in entry["exports"]:
-            items.append((sig, ex["file"], ex["aval_sig"]))
+            items.append((sig, ex["file"], ex["aval_sig"],
+                          entry.get("program")))
     return items
 
 
@@ -781,10 +899,15 @@ def _start_warm_pool() -> None:
 
 
 def _warm_one(tier_dir: str, deadline: float, sig: str, fname: str,
-              aval_sig: str) -> None:
+              aval_sig: str, program: Optional[str] = None) -> None:
     """Replay one persisted export: deserialize, AOT-compile (an XLA
     disk-cache hit when tier 2 already holds the executable), and install
-    a dispatchable entry under the plan signature."""
+    a dispatchable entry under the plan signature. It compiles under the
+    program name the manifest recorded (``warm_replay`` for a manifest
+    written before programs had names)."""
+    name = (program or "")[len(_PROGRAM_PREFIX):]
+    if name not in PROGRAM_NAMES:
+        name = "warm_replay"
     if _WARM_STOP.is_set() or time.monotonic() > deadline:
         return
     try:
@@ -795,7 +918,7 @@ def _warm_one(tier_dir: str, deadline: float, sig: str, fname: str,
         exported = jax_export.deserialize(bytearray(data))
         sds = [jax.ShapeDtypeStruct(a.shape, a.dtype)
                for a in exported.in_avals]
-        compiled = jax.jit(exported.call).lower(*sds).compile()
+        compiled = named_jit(exported.call, name).lower(*sds).compile()
         dispatch = oom_retry(compiled)
     except Exception as e:
         with _LOCK:
@@ -904,17 +1027,19 @@ def persist_compile_cache() -> int:
             kernels[sig] = {"hits": cur[0] - prev[0],
                             "compiles": cur[1] - prev[1],
                             "compile_s": cur[2] - prev[2],
-                            "node_name": e.get("node_name")}
+                            "node_name": e.get("node_name"),
+                            "program": e.get("program")}
         exportable = dict(_EXPORTABLE)
         cap = _PERSIST["warm_max"]
     for sig, k in kernels.items():
         e = entries.setdefault(
             sig, {"hits": 0, "compiles": 0, "compile_s": 0.0,
-                  "node_name": None, "exports": []})
+                  "node_name": None, "program": None, "exports": []})
         e["hits"] += int(k["hits"])
         e["compiles"] += int(k["compiles"])
         e["compile_s"] = round(e["compile_s"] + float(k["compile_s"]), 6)
         e["node_name"] = e["node_name"] or k["node_name"]
+        e["program"] = e.get("program") or k["program"]
     # export the hottest signatures compiled this process whose captured
     # shapes are not persisted yet
     exports_dir = os.path.join(tier, "exports")

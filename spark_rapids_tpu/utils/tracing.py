@@ -13,8 +13,14 @@ Design constraints:
 - bounded: a ring buffer (``spark.rapids.tpu.trace.bufferSize`` events)
   caps memory no matter how long the session runs; overflow drops the
   OLDEST events and counts the drops.
-- near-zero cost when disabled: ``span()`` yields immediately without
-  taking the lock or reading the clock.
+- one span API, three sinks. ``Tracer.span()`` always (1) opens a
+  ``jax.profiler.TraceAnnotation("srt.<name>")`` when a profiler session
+  is capturing, so the engine's spans lie in the xplane's host plane on
+  the clock the device planes are synchronised to, and (2) books its self
+  time to the running query's phase totals (``recent_queries``); only
+  (3), the ring buffer + Chrome export, is gated by
+  ``spark.rapids.tpu.trace.enabled``. With no profiler session and the
+  ring off a span costs two clock reads and a dict update.
 
 The export format is the Chrome trace-event JSON (``ph: "X"`` complete
 events with microsecond timestamps), loadable in Perfetto / chrome://tracing
@@ -31,11 +37,14 @@ import time
 import uuid
 from collections import deque
 from contextlib import contextmanager
-from typing import Dict, List, Optional
+from typing import Callable, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from ..conf import register_conf
 
-__all__ = ["TraceEvent", "Tracer", "TraceContext", "get_tracer",
+__all__ = ["TraceEvent", "Tracer", "TraceContext", "QuerySummary",
+           "get_tracer", "ANNOTATION_PREFIX", "STRUCTURAL_SPANS",
            "set_tracer", "configure_tracer", "tracer_stats",
            "mint_trace_context", "current_trace_context",
            "activate_trace_context", "new_span_id",
@@ -45,10 +54,14 @@ __all__ = ["TraceEvent", "Tracer", "TraceContext", "get_tracer",
 
 TRACE_ENABLED = register_conf(
     "spark.rapids.tpu.trace.enabled",
-    "Record runtime spans (query/stage/task/operator plus shuffle, compile, "
-    "upload, spill and semaphore-wait events) into the process-wide tracer "
-    "(the NVTX-range analogue; reference: NvtxWithMetrics.scala). Export "
-    "with Tracer.to_chrome_trace() or spark.rapids.tpu.trace.dir.", False)
+    "Keep runtime spans (query/plan/scan/h2d/dispatch/sync/d2h, stage/task/"
+    "operator, shuffle, compile, spill and semaphore-wait events) in the "
+    "process-wide tracer's ring buffer for the Chrome-trace export (the "
+    "NVTX-range analogue; reference: NvtxWithMetrics.scala). The spans "
+    "themselves are always on: they appear in any jax.profiler capture as "
+    "srt.<name> and feed TpuSession.last_query_phases() whatever this is "
+    "set to. Export with Tracer.to_chrome_trace() or "
+    "spark.rapids.tpu.trace.dir.", False)
 
 TRACE_BUFFER_SIZE = register_conf(
     "spark.rapids.tpu.trace.bufferSize",
@@ -217,14 +230,257 @@ class TraceEvent:
                 f"depth={self.depth})")
 
 
+#: every engine span's name in a ``jax.profiler`` capture starts with this
+ANNOTATION_PREFIX = "srt."
+
+#: spans whose time is really other spans': those that only group (the
+#: query root, a partition task, an AQE stage) and the consumer's wait on
+#: the engine's own producer thread. Their self time is booked like any
+#: phase's, but they do not count as COVERED wall: a ``task`` span tiles a
+#: whole drain and ``wait.pipeline`` the whole of a producer's work, and
+#: counting them would hide exactly the time no phase span claims
+#: (``host_unattributed_share``).
+STRUCTURAL_SPANS = frozenset({"query", "task", "stage", "wait.pipeline"})
+
+#: queries whose phase totals ``Tracer.recent_queries`` remembers
+RECENT_QUERIES = 256
+#: phase-span intervals remembered per query for the covered-wall union;
+#: past it the totals keep counting and ``spans_dropped`` says how many
+#: intervals the union lacks
+QUERY_SPAN_CAP = 2048
+
+_QUERY_SEQ = itertools.count(1)
+
+
+def _union_s(intervals: List) -> float:
+    total, hi = 0.0, float("-inf")
+    for s, e in sorted(intervals):
+        if s > hi:
+            total += e - s
+            hi = e
+        elif e > hi:
+            total += e - hi
+            hi = e
+    return total
+
+
+class QuerySummary:
+    """Per-phase totals of ONE ``collect()`` — the counter half of the
+    spans, always on. Filled by every span that closes while the query
+    is the thread's current one (pool threads get it from
+    ``Tracer.bind_query``), sealed when the ``query`` span closes.
+
+    ``phases[name]`` is ``[calls, self_s, bytes]``: thread-seconds of
+    SELF time (duration minus what child spans on the same thread
+    cover), so phases sum to at most ``wall_s`` x ``threads``.
+    ``covered_s`` is the union over all threads of the non-structural
+    spans' intervals, clipped to the query span."""
+
+    __slots__ = ("query_id", "t0", "wall_s", "phases", "covered_s",
+                 "spans_dropped", "_intervals", "_threads", "_lock")
+
+    def __init__(self, query_id: int, t0: float):
+        self.query_id = query_id
+        self.t0 = t0
+        self.wall_s: Optional[float] = None     # None while the query runs
+        self.phases: Dict[str, List] = {}
+        self.covered_s = 0.0
+        self.spans_dropped = 0
+        self._intervals: List = []
+        self._threads = set()
+        self._lock = threading.Lock()
+
+    def _book(self, name: str, t0: float, t1: float, self_s: float,
+              nbytes: int) -> None:
+        with self._lock:
+            if self.wall_s is not None:
+                return      # a straggler of a query that already returned
+            p = self.phases.get(name)
+            if p is None:
+                p = self.phases[name] = [0, 0.0, 0]
+            p[0] += 1
+            p[1] += self_s
+            p[2] += nbytes
+            self._threads.add(threading.get_ident())
+            if name not in STRUCTURAL_SPANS:
+                if len(self._intervals) < QUERY_SPAN_CAP:
+                    self._intervals.append((t0, t1))
+                else:
+                    self.spans_dropped += 1
+
+    def _seal(self, t1: float) -> None:
+        with self._lock:
+            self.wall_s = t1 - self.t0
+            self.covered_s = _union_s(
+                [(max(s, self.t0), min(e, t1)) for s, e in self._intervals
+                 if min(e, t1) > max(s, self.t0)])
+            self._intervals = []
+
+    def to_dict(self) -> Dict:
+        with self._lock:
+            return {
+                "query_id": self.query_id, "wall_s": self.wall_s,
+                "covered_s": self.covered_s,
+                "threads": len(self._threads),
+                "spans_dropped": self.spans_dropped,
+                "phases": {n: {"calls": p[0], "self_s": p[1], "bytes": p[2]}
+                           for n, p in self.phases.items()}}
+
+
+def _small_args(args: Dict) -> Dict:
+    """What of a span's args rides on its TraceAnnotation: numbers and
+    short strings (an xplane event's stats are not the place for a plan
+    signature)."""
+    return {k: v for k, v in args.items()
+            if isinstance(v, (bool, int, float))
+            or (isinstance(v, str) and len(v) <= 64)}
+
+
+class _Span:
+    """One open span (``Tracer.span`` / ``Tracer.query``): a small class
+    with ``__enter__``/``__exit__``, not a generator — a disabled
+    ``@contextmanager`` cost 1.2 us a span, this costs the two clock
+    reads. ``note(bytes=...)`` adds args known only once the work ran."""
+
+    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_child_s",
+                 "_parent", "_query", "_ann", "_ring", "_ctx", "_span_id",
+                 "_root", "_pushed")
+
+    def __init__(self, tracer: "Tracer", name: str, cat: str, args: Dict,
+                 root: bool = False, ctx: Optional[TraceContext] = None):
+        self._tracer = tracer
+        self.name = name
+        self.cat = cat
+        self.args = args
+        self._root = root
+        self._ctx = ctx
+        self._child_s = 0.0
+        self._ann = None
+        self._span_id = None
+
+    def note(self, **args) -> None:
+        self.args.update(args)
+
+    def __enter__(self) -> "_Span":
+        tracer = self._tracer
+        tls = tracer._tls
+        stack = getattr(tls, "stack", None)
+        if stack is None:
+            stack = tls.stack = []
+        self._parent = stack[-1] if stack else None
+        query = getattr(tls, "query", None)
+        if self._root and query is not None:
+            self._root = False      # a collect() inside a collect(): nested
+        if self._root:
+            query = QuerySummary(next(_QUERY_SEQ), 0.0)
+            tls.query = query
+            self.args.setdefault("query_id", query.query_id)
+        self._query = query
+        self._ring = tracer.enabled
+        # a query root activates the TraceContext it was given; with the
+        # ring on, every span under a context gets a span id and
+        # re-parents the context for its block (the cross-process DAG)
+        given = self._ctx
+        ctx = given if given is not None or not self._ring \
+            else current_trace_context()
+        self._ctx = ctx
+        self._pushed = 0
+        if ctx is not None:
+            cstack = getattr(_CTX_TLS, "stack", None)
+            if cstack is None:
+                cstack = _CTX_TLS.stack = []
+            if given is not None:
+                cstack.append(ctx)
+                self._pushed += 1
+            if self._ring:
+                self._span_id = new_span_id()
+                cstack.append(ctx.child(self._span_id))
+                self._pushed += 1
+        if TraceAnnotation.is_enabled():
+            small = _small_args(self.args)
+            if query is not None:
+                small["query_id"] = query.query_id
+            self._ann = TraceAnnotation(ANNOTATION_PREFIX + self.name,
+                                        **small)
+            self._ann.__enter__()
+        stack.append(self)
+        self._t0 = time.perf_counter()
+        if self._root:
+            query.t0 = self._t0
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        t1 = time.perf_counter()
+        tracer = self._tracer
+        tls = tracer._tls
+        stack = tracer._stack()
+        if stack and stack[-1] is self:
+            stack.pop()
+        elif self in stack:     # closed out of order (a generator's span)
+            stack.remove(self)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        dur = t1 - self._t0
+        if self._parent is not None:
+            self._parent._child_s += dur
+        query = self._query
+        if query is not None:
+            query._book(self.name, self._t0, t1,
+                        max(0.0, dur - self._child_s),
+                        int(self.args.get("bytes", 0) or 0))
+            if self._root:
+                query._seal(t1)
+                tls.query = None
+                tracer._remember(query)
+        if self._pushed:
+            del _CTX_TLS.stack[-self._pushed:]
+        if self._ring:
+            args = self.args
+            if self._parent is not None:
+                args = dict(args, parent=self._parent.name)
+            if query is not None and self._ctx is None \
+                    and "query_id" not in args:
+                args = dict(args, query_id=query.query_id)
+            tracer._record(TraceEvent(
+                self.name, self.cat, "X", (self._t0 - tracer.epoch) * 1e6,
+                dur * 1e6, threading.get_ident(), len(stack),
+                tracer._ctx_args(args, self._ctx, self._span_id)))
+        return False
+
+    @property
+    def summary(self) -> Optional[QuerySummary]:
+        return self._query
+
+
+class _BoundQuery:
+    """``with`` scope that makes a query the current one of a pool
+    thread for the length of one task."""
+
+    __slots__ = ("_tls", "_query", "_prev")
+
+    def __init__(self, tls, query: Optional[QuerySummary]):
+        self._tls = tls
+        self._query = query
+
+    def __enter__(self):
+        self._prev = getattr(self._tls, "query", None)
+        self._tls.query = self._query
+        return self._query
+
+    def __exit__(self, *exc) -> bool:
+        self._tls.query = self._prev
+        return False
+
+
 class Tracer:
-    """Thread-safe bounded span recorder."""
+    """Thread-safe span recorder: the one span API of the engine."""
 
     def __init__(self, capacity: int = 65536, enabled: bool = False,
                  process_name: Optional[str] = None):
         self.enabled = enabled
         self.capacity = capacity
         self._events: deque = deque(maxlen=capacity)
+        self._recent: deque = deque(maxlen=RECENT_QUERIES)
         self._lock = threading.Lock()
         self._tls = threading.local()
         # epoch (perf_counter domain) and its wall-clock anchor are taken
@@ -237,7 +493,7 @@ class Tracer:
         self._drop_warned = False
 
     # -- recording ------------------------------------------------------------
-    def _stack(self) -> List[str]:
+    def _stack(self) -> List[_Span]:
         st = getattr(self._tls, "stack", None)
         if st is None:
             st = self._tls.stack = []
@@ -281,47 +537,64 @@ class Tracer:
             out["query_id"] = out.get("query_id", ctx.query_id)
         return out
 
-    @contextmanager
-    def span(self, name: str, cat: str = "misc", **args):
-        """Record a complete event around the with-block. Nesting depth is
-        tracked per thread so exported traces preserve the span hierarchy.
-        Under an active TraceContext the span gets its own span id and
-        re-parents the context for the block, so nested spans (this thread
-        or a remote process the block talks to) chain under it."""
-        if not self.enabled:
-            yield
-            return
-        stack = self._stack()
-        depth = len(stack)
-        stack.append(name)
-        ctx = current_trace_context()
-        span_id = new_span_id() if ctx is not None else None
-        t0 = time.perf_counter()
-        try:
-            if ctx is not None:
-                with activate_trace_context(ctx.child(span_id)):
-                    yield
-            else:
-                yield
-        finally:
-            t1 = time.perf_counter()
-            stack.pop()
-            self._record(TraceEvent(
-                name, cat, "X", (t0 - self.epoch) * 1e6, (t1 - t0) * 1e6,
-                threading.get_ident(), depth,
-                self._ctx_args(args, ctx, span_id)))
+    def span(self, name: str, cat: str = "misc", **args) -> _Span:
+        """A span around the with-block, into all three sinks (module
+        docstring). Nesting is tracked per thread: a span's self time is
+        its duration minus its children's. Under an active TraceContext
+        (ring on) the span gets its own span id and re-parents the context
+        for the block, so nested spans (this thread or a remote process
+        the block talks to) chain under it."""
+        return _Span(self, name, cat, args)
 
     def complete(self, name: str, cat: str, start_s: float, dur_s: float,
                  **args) -> None:
-        """Record a complete event with caller-measured times
-        (``time.perf_counter()`` domain) — for code that already owns its
-        own timers, e.g. the per-batch operator instrumentation."""
+        """Record a ring-buffer event with caller-measured times
+        (``time.perf_counter()`` domain) — for the per-batch operator
+        instrumentation (tools/profiler.py), which owns its timers."""
         if not self.enabled:
             return
         self._record(TraceEvent(
             name, cat, "X", (start_s - self.epoch) * 1e6, dur_s * 1e6,
             threading.get_ident(), len(self._stack()),
             self._ctx_args(args)))
+
+    def query(self, ctx: Optional[TraceContext] = None, **args) -> _Span:
+        """The root span ``query`` of one ``collect()``: mints the query
+        id every span of the query carries, starts its phase totals, and
+        files them under ``recent_queries`` when the block ends. ``ctx``
+        (the event log's TraceContext) is activated for the block. Inside
+        another query on the same thread it is a plain nested span."""
+        return _Span(self, "query", "query", args, root=True, ctx=ctx)
+
+    # -- per-query phase totals ---------------------------------------------
+    def current_query(self) -> Optional[QuerySummary]:
+        """The query whose spans THIS thread is recording, if any."""
+        return getattr(self._tls, "query", None)
+
+    def bind_query(self, fn: Callable) -> Callable:
+        """``fn`` bound to the calling thread's current query, for
+        handing to a pool or a worker thread: its spans are then booked to
+        that query — carried in the task, never through a process global,
+        so concurrent queries do not mix."""
+        query = self.current_query()
+        if query is None:
+            return fn
+
+        def bound(*args, **kwargs):
+            with _BoundQuery(self._tls, query):
+                return fn(*args, **kwargs)
+        return bound
+
+    def _remember(self, query: QuerySummary) -> None:
+        with self._lock:
+            self._recent.append(query)
+
+    def recent_queries(self, n: int = RECENT_QUERIES) -> List[Dict]:
+        """Phase totals of the newest ``n`` finished queries, oldest
+        first (at most the last 256)."""
+        with self._lock:
+            recent = list(self._recent)
+        return [q.to_dict() for q in recent[-n:]] if n > 0 else []
 
     def instant(self, name: str, cat: str = "misc", **args) -> None:
         if not self.enabled:

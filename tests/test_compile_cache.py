@@ -219,7 +219,7 @@ def test_warm_pool_precompiles_then_hits(tmp_path, tier_reset):
     x = jnp.arange(64, dtype=jnp.float32)
     sess1 = TpuSession(
         {"spark.rapids.tpu.compile.cacheDir": str(tmp_path)})
-    fn = cached_jit("test|warmpool|v1", builder)
+    fn = cached_jit("test|warmpool|v1", builder, name="stage")
     expect = float(fn(x))
     assert cache_stats()["compiles"] == 1
     sess1.close()           # exports + manifest land on disk
@@ -231,7 +231,7 @@ def test_warm_pool_precompiles_then_hits(tmp_path, tier_reset):
     stats = cache_stats()
     assert stats["persist_warmed_entries"] == 1, stats
     assert stats["persist_warm_compiles"] == 1
-    fn2 = cached_jit("test|warmpool|v1", builder)
+    fn2 = cached_jit("test|warmpool|v1", builder, name="stage")
     assert float(fn2(x)) == expect
     stats = cache_stats()
     assert stats["compiles"] == 0, stats
@@ -260,7 +260,7 @@ def test_persist_merges_deltas_not_raw_totals(tmp_path, tier_reset):
 
     x = jnp.ones(16)
     sess = TpuSession({"spark.rapids.tpu.compile.cacheDir": str(tmp_path)})
-    cached_jit("test|delta|v1", builder)(x)
+    cached_jit("test|delta|v1", builder, name="stage")(x)
     sess.close()
 
     def entry():
@@ -274,7 +274,7 @@ def test_persist_merges_deltas_not_raw_totals(tmp_path, tier_reset):
     # a second session in the SAME process adds only its own delta
     sess2 = TpuSession({"spark.rapids.tpu.compile.cacheDir": str(tmp_path)})
     warm_pool_wait(60)
-    cached_jit("test|delta|v1", builder)(x)   # in-process hit
+    cached_jit("test|delta|v1", builder, name="stage")(x)   # in-process hit
     sess2.close()
     assert (entry()["compiles"], entry()["hits"]) == (1, 1)
 
@@ -300,7 +300,8 @@ def test_external_cache_dir_is_left_to_jax(tmp_path, tier_reset,
             {"spark.rapids.tpu.compile.cacheDir": str(tmp_path / "conf")})
         assert configure_compile_cache(conf) == str(ext / "srtpu")
         assert _jax.config.jax_compilation_cache_dir == str(ext)
-        cached_jit("test|external|v1", lambda: (lambda x: x * 3.0))(
+        cached_jit("test|external|v1", lambda: (lambda x: x * 3.0),
+                   name="stage")(
             jnp.ones(8))
         persist_compile_cache()
         assert "srtpu" in os.listdir(ext)
@@ -400,7 +401,7 @@ def test_no_leaked_warm_pool_threads(tmp_path, tier_reset):
         return lambda x: x + 1.0
 
     sess = TpuSession({"spark.rapids.tpu.compile.cacheDir": str(tmp_path)})
-    cached_jit("test|leak|v1", builder)(jnp.ones(8))
+    cached_jit("test|leak|v1", builder, name="stage")(jnp.ones(8))
     sess.close()
     clear_cache()
     sess2 = TpuSession({"spark.rapids.tpu.compile.cacheDir": str(tmp_path)})

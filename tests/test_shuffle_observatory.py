@@ -115,7 +115,14 @@ def test_tcp_stitches_sender_and_receiver_halves(observatory):
         with activate_trace_context(ctx):
             got = dict(b.fetch([BlockId(3, 1, 2)]))
         assert BlockId(3, 1, 2) in got
+        # the server thread notes its half AFTER the last sendall: give it
+        # a moment before reading the pairs
+        import time
+        deadline = time.monotonic() + 10
         stitched = observatory.stitched()
+        while not stitched and time.monotonic() < deadline:
+            time.sleep(0.01)
+            stitched = observatory.stitched()
         assert stitched, "no sender/receiver pair stitched"
         (pair,) = [s for s in stitched if s["shuffle_id"] == 3]
         assert pair["trace_id"] == "0123456789abcdef"
