@@ -9,11 +9,19 @@ design, mapped onto XLA's static-shape world:
   (io/parquet_thrift.py), page decompression, and a one-pass scan of the
   RLE/bit-packed hybrid streams into *run tables* (a few entries per run,
   NOT per value — the classic GPU decoder split).
-- DEVICE does the per-value work, one fused jit per column chunk:
-  run-table expansion (searchsorted over run starts), bit-field extraction
-  of dictionary indices from the packed blob, dictionary gather, and
-  null-scatter of the dense non-null values into row slots via a validity
-  cumsum.
+- DEVICE does the per-value work, one fused jit per column chunk, with no
+  search and no loop: each run's attributes are scattered as DELTAS at the
+  run's first position and one int32 prefix sum spreads them over the
+  rows; bit-packed fields unpack densely (eight values of width w are w
+  bytes, so the host hands the blob over byte-column-major and every field
+  is static shifts of whole vectors); what is left is one gather a stream
+  to close the short last group of each page, the dictionary gather, and
+  for chunks with nulls the validity prefix sum and the gather spreading the
+  dense non-null stream over the row slots.
+- A chunk whose definition levels are all set (the parser knows before it
+  uploads anything) takes a program of its own in which validity is
+  ``iota < n`` and the definition-level stream is not traced at all
+  (span ``decode.dense``; chunks with nulls: ``decode.general``).
 
 Supported (everything else falls back per COLUMN to pyarrow + upload):
 flat columns (no repetition), physical BOOLEAN/INT32/INT64/FLOAT/DOUBLE/
@@ -104,18 +112,35 @@ def _decompress(buf: bytes, codec: str, uncompressed_size: int) -> bytes:
                          codec=codec.lower()).to_pybytes()
 
 
+#: Run-table lengths the decode programs are compiled for. With no search
+#: over the table its length sets no loop count, only the size of a
+#: scatter, so two sizes cover most chunks (a page is a few runs; a
+#: million rows of a bit-packed dictionary column a few thousand) and
+#: longer tables take the next power of two.
+_RUN_BUCKETS = (256, 4096)
+
+
+def _bucket_runs(n_runs: int) -> int:
+    for b in _RUN_BUCKETS:
+        if n_runs <= b:
+            return b
+    return _pow2(n_runs)
+
+
 class _RunTable:
     """Accumulated RLE/bit-packed runs across a chunk's pages."""
 
     def __init__(self):
         self.out_start: List[int] = []
         self.count: List[int] = []
-        self.is_rle: List[bool] = []
         self.rle_value: List[int] = []
-        self.bit_base: List[int] = []   # absolute first-bit into self.packed
         self.width: List[int] = []      # PER-RUN bit width (pages with a
-        # growing dictionary are written at increasing widths!)
-        self.packed = bytearray()
+        # growing dictionary are written at increasing widths!); 0 = RLE run
+        self.field_base: List[int] = []  # first field of a bit-packed run
+        # in its width's blob, counted in values of that width
+        self.packed: Dict[int, bytearray] = {}  # width -> its runs' bytes;
+        # every run is whole groups of 8 values = w bytes, so field j of
+        # the blob sits at bit j x w
         self.total = 0
 
     def parse_hybrid(self, buf: bytes, pos: int, end: int, width: int,
@@ -142,16 +167,23 @@ class _RunTable:
                 groups = header >> 1
                 nvals = min(groups * 8, max_count - produced)
                 nbytes = groups * width  # groups*8 values * width/8 bits
-                self.out_start.append(self.total)
-                self.count.append(nvals)
-                self.is_rle.append(False)
-                self.rle_value.append(0)
-                self.bit_base.append(len(self.packed) * 8)
-                self.width.append(width)
-                self.packed.extend(buf[pos:pos + nbytes])
+                if nvals > 0:
+                    # keep only the groups that hold values, whole (a
+                    # truncated last group is zero-filled): the blob stays
+                    # a multiple of w bytes
+                    keep = (nvals + 7) // 8 * width
+                    blob = self.packed.setdefault(width, bytearray())
+                    self.out_start.append(self.total)
+                    self.count.append(nvals)
+                    self.rle_value.append(0)
+                    self.width.append(width)
+                    self.field_base.append(len(blob) // width * 8)
+                    got = buf[pos:pos + keep]
+                    blob.extend(got)
+                    blob.extend(bytes(keep - len(got)))
+                    self.total += nvals
+                    produced += nvals
                 pos += nbytes
-                self.total += nvals
-                produced += nvals
             else:           # RLE run
                 run = min(header >> 1, max_count - produced)
                 v = int.from_bytes(buf[pos:pos + vbytes], "little")
@@ -164,27 +196,65 @@ class _RunTable:
             return
         self.out_start.append(self.total)
         self.count.append(run)
-        self.is_rle.append(True)
         self.rle_value.append(v)
-        self.bit_base.append(0)
         self.width.append(0)
+        self.field_base.append(0)
         self.total += run
 
-    def arrays(self) -> Tuple[np.ndarray, ...]:
-        # pow2-pad entry count and packed blob so XLA sees a bounded shape
-        # set across chunks (padding runs have out_start == total -> the
-        # searchsorted expansion never selects them)
-        n = _pow2(max(1, len(self.out_start)))
-        pad = n - len(self.out_start)
-        out_start = np.asarray(self.out_start + [self.total] * pad, np.int64)
-        packed = np.frombuffer(bytes(self.packed) or b"\0", np.uint8)
-        packed = np.pad(packed, (0, _pow2(len(packed)) - len(packed)))
-        return (out_start,
-                np.asarray(self.is_rle + [True] * pad, np.bool_),
-                np.asarray(self.rle_value + [0] * pad, np.int64),
-                np.asarray(self.bit_base + [0] * pad, np.int64),
-                np.asarray(self.width + [0] * pad, np.int64),
-                packed)
+    def widths(self) -> Tuple[int, ...]:
+        """The bit widths of the chunk's bit-packed runs, sorted: part of
+        the decode program's key (the unpack is static per width)."""
+        return tuple(sorted(self.packed))
+
+    def device_inputs(self, cap: int) -> Tuple[np.ndarray, ...]:
+        """What ``_expand_runs`` takes, all int32 / uint8:
+
+        - ``starts`` (R,): each run's first output position, strictly
+          increasing; padding entries lie past ``cap`` (the scatter drops
+          them) and stay unique and sorted;
+        - ``deltas`` (k, R): per attribute the CHANGE from the run before,
+          so a prefix sum over the scattered deltas leaves every position
+          holding its own run's attribute. Row 0: ``field_base -
+          out_start`` (position + it = the field to read); row 1: an RLE
+          run's value + 1, 0 for a bit-packed run; row 2, only with
+          several widths: where the run's width starts in the unpacked
+          field table;
+        - ``packed_t`` (sum of widths, G): each width's blob as w byte
+          columns of its G groups (byte c of every group contiguous).
+
+        R is bucketed coarsely and G = cap/8 + R follows from it (a run
+        wastes less than one group), so neither adds a shape of its own."""
+        n_runs = len(self.out_start)
+        if self.total > cap:
+            raise UnsupportedChunk("more values than rows")
+        rb = _bucket_runs(n_runs)
+        groups = cap // 8 + rb
+        widths = self.widths()
+        if len(widths) * 8 * groups + cap + rb >= 2 ** 31:
+            raise UnsupportedChunk("field offsets past int32")
+        starts = np.arange(cap, cap + rb, dtype=np.int32)
+        starts[:n_runs] = self.out_start
+        width = np.asarray(self.width, np.int64)
+        packed_run = width > 0
+        attrs = np.zeros((3 if len(widths) > 1 else 2, n_runs), np.int64)
+        attrs[0] = np.where(packed_run, np.asarray(self.field_base, np.int64)
+                            - starts[:n_runs], 0)
+        attrs[1] = np.where(packed_run, 0,
+                            np.asarray(self.rle_value, np.int64) + 1)
+        if len(widths) > 1:
+            attrs[2] = np.where(
+                packed_run, np.searchsorted(widths, width) * (8 * groups), 0)
+        deltas = np.zeros((len(attrs), rb), np.int32)
+        deltas[:, :n_runs] = np.diff(attrs, axis=1, prepend=0)
+        packed_t = np.zeros((sum(widths), groups), np.uint8)
+        row = 0
+        for w in widths:
+            blob = np.frombuffer(self.packed[w], np.uint8).reshape(-1, w)
+            if len(blob) > groups:
+                raise UnsupportedChunk("more bit-packed groups than rows")
+            packed_t[row:row + w, :len(blob)] = blob.T
+            row += w
+        return starts, deltas, packed_t
 
 
 class _Chunk:
@@ -207,10 +277,24 @@ class _Chunk:
         self.ba_dict: Optional[Tuple[np.ndarray, np.ndarray, np.ndarray]] = None
         self.ba_plain: List[Tuple[np.ndarray, np.ndarray, np.ndarray]] = []
         self.num_rows = 0
+        self.n_defined = 0           # rows whose definition level is set
         self.nullable = False
         self.bool_plain: List[Tuple[bytes, int]] = []  # packed bits, count
         self.uses_dict = False
         self.uses_plain = False
+
+    @property
+    def all_defined(self) -> bool:
+        """Every definition level is set: the chunk has no null."""
+        return self.n_defined == self.num_rows
+
+    @property
+    def segments(self) -> str:
+        """Which value segments the chunk has: "dict", "plain" (also a
+        chunk with no page at all) or "mixed" (dictionary, then PLAIN)."""
+        if self.uses_dict:
+            return "mixed" if self.uses_plain else "dict"
+        return "plain"
 
 
 def _parse_byte_array_stream(buf: bytes, n: int
@@ -350,6 +434,7 @@ def _parse_chunk(raw: bytes, col_meta, nullable: bool) -> _Chunk:
         else:
             raise UnsupportedChunk(f"encoding {hdr.encoding}")
         ch.num_rows += nvals
+        ch.n_defined += n_nonnull
     if ch.uses_dict and ch.bool_plain:
         raise UnsupportedChunk("mixed dict+plain boolean pages")
     return ch
@@ -364,13 +449,13 @@ def _count_defined(rt: _RunTable, from_entry_total: int) -> int:
     for i in range(len(rt.out_start)):
         if rt.out_start[i] < from_entry_total:
             continue
-        if rt.is_rle[i]:
+        if rt.width[i] == 0:
             total += rt.count[i] * (1 if rt.rle_value[i] else 0)
         else:
             # bit-packed def levels at width 1: count set bits in the run
-            base = rt.bit_base[i] // 8
+            base = rt.field_base[i] // 8
             nbits = rt.count[i]
-            blob = bytes(rt.packed[base:base + (nbits + 7) // 8])
+            blob = bytes(rt.packed[1][base:base + (nbits + 7) // 8])
             bits = np.unpackbits(np.frombuffer(blob, np.uint8),
                                  bitorder="little")[:nbits]
             total += int(bits.sum())
@@ -401,130 +486,209 @@ def _pow2(n: int) -> int:
     return c
 
 
-def _expand_hybrid_device(out_start, is_rle, rle_value, bit_base, widths,
-                          packed, iota):
-    """values[i] for each output position in ``iota``: expand the run table
-    on device (searchsorted for run id + LSB-first bit-field extraction for
-    bit-packed runs). ``widths`` is PER RUN — successive pages of one chunk
-    may bit-pack at different widths as the dictionary grows."""
+#: Block length of ``_prefix_sum``.
+_SCAN_BLOCK = 1024
+
+
+def _prefix_sum(x):
+    """Inclusive prefix sum along the last axis: log-step shifted adds
+    inside blocks of 1,024, the block totals scanned the same way and
+    added back. ``jnp.cumsum`` over 2^20 elements computes the same no
+    faster on the chip (0.8-1.3 ms against 0.6-0.8) and takes the TPU's
+    compiler 17-31 s a program where this takes under a second (PERF.md,
+    PR 27): it was most of every decode program's compile time."""
+    import jax.numpy as jnp
+    n = x.shape[-1]
+    lead = [(0, 0)] * (x.ndim - 1)
+    if n > _SCAN_BLOCK:
+        blocks = jnp.pad(x, lead + [(0, -n % _SCAN_BLOCK)]).reshape(
+            x.shape[:-1] + (-1, _SCAN_BLOCK))
+        inner = _prefix_sum(blocks)
+        totals = inner[..., -1]
+        before = _prefix_sum(totals) - totals
+        return (inner + before[..., None]).reshape(
+            x.shape[:-1] + (-1,))[..., :n]
+    step = 1
+    while step < n:
+        x = x + jnp.pad(x, lead + [(step, 0)])[..., :n]
+        step *= 2
+    return x
+
+
+def _unpack_fields(packed_t, widths: Tuple[int, ...]):
+    """Every bit field of the blobs, densely: for each width w the w byte
+    columns of G groups give the 8 fields of every group by static shifts
+    of whole vectors. -> (len(widths) * 8 * G,) int32, width-major, then
+    field-in-group-major: field f of width number q is at
+    ``q * 8G + (f & 7) * G + (f >> 3)``."""
+    import jax.numpy as jnp
+    fields = []
+    row = 0
+    for w in widths:
+        cols = packed_t[row:row + w].astype(jnp.uint32)
+        row += w
+        for k in range(8):
+            c0, shift = (k * w) >> 3, (k * w) & 7
+            # width <= 24 enforced at parse time: shift + w <= 31 bits
+            word = cols[c0]
+            for b in range(1, (shift + w + 7) >> 3):
+                word = word | (cols[c0 + b] << (8 * b))
+            fields.append((word >> shift) & jnp.uint32((1 << w) - 1))
+    return jnp.concatenate(fields).astype(jnp.int32)
+
+
+def _expand_runs(starts, deltas, packed_t, widths: Tuple[int, ...],
+                 cap: int):
+    """values[i] for output positions 0..cap of one RLE/bit-packed hybrid
+    stream (``_RunTable.device_inputs``), in int32 throughout. The run
+    table is expanded without a search: its per-run deltas are scattered
+    at the run starts (a few thousand elements) and ONE prefix sum leaves
+    each position with its run's attributes. A bit-packed value is then
+    one gather from the densely unpacked fields — position + offset, a
+    piecewise shift of the identity that skips each page's short last
+    group. Positions past the stream's total hold garbage; callers mask."""
     import jax
     import jax.numpy as jnp
-    i = iota.astype(jnp.int64)
-    with jax.named_scope("pq_run_searchsorted"):
-        run = jnp.clip(jnp.searchsorted(out_start, i, side="right") - 1,
-                       0, out_start.shape[0] - 1)
-    within = i - out_start[run]
-    w = widths[run]
-    bit = bit_base[run] + within * w
-    byte0 = bit >> 3
-    shift = (bit & 7).astype(jnp.uint32)
-    nb = packed.shape[0]
-    g = lambda k: packed[jnp.clip(byte0 + k, 0, nb - 1)].astype(jnp.uint32)
-    dword = g(0) | (g(1) << 8) | (g(2) << 16) | (g(3) << 24)
-    # width <= 24 enforced at parse time, so 4 gathered bytes always cover
-    mask = (jnp.uint32(1) << w.astype(jnp.uint32)) - jnp.uint32(1)
-    bp_val = (dword >> shift) & mask
-    return jnp.where(is_rle[run], rle_value[run].astype(jnp.int64),
-                     bp_val.astype(jnp.int64))
+    with jax.named_scope("pq_run_prefix_sum"):
+        scattered = jnp.zeros((deltas.shape[0], cap), jnp.int32) \
+            .at[:, starts].add(deltas, mode="drop", indices_are_sorted=True,
+                               unique_indices=True)
+        attrs = _prefix_sum(scattered)
+    rle = attrs[1] - 1          # an RLE run's value, -1 in bit-packed runs
+    if not widths:
+        return rle
+    with jax.named_scope("pq_bit_unpack"):
+        groups = packed_t.shape[1]
+        field = jax.lax.iota(jnp.int32, cap) + attrs[0]
+        at = (field & 7) * groups + (field >> 3)
+        if len(widths) > 1:
+            at = at + attrs[2]
+        unpacked = jnp.take(_unpack_fields(packed_t, widths), at,
+                            mode="clip")
+    return jnp.where(rle >= 0, rle, unpacked)
 
 
-def _mixed_kernel_builder(npdt_str: str):
+def _validity_and_pos(defs, def_widths: Optional[Tuple[int, ...]], n,
+                      cap: int):
+    """(validity, pos): row r is non-null and reads entry ``pos[r]`` of the
+    chunk's dense non-null stream. ``def_widths`` None = the parser found
+    every definition level set: validity is ``iota < n``, pos the identity
+    (returned as None), and no definition-level op is traced."""
+    import jax
+    import jax.numpy as jnp
+    in_rows = jax.lax.iota(jnp.int32, cap) < n
+    if def_widths is None:
+        return in_rows, None
+    with jax.named_scope("pq_def_levels"):
+        validity = jnp.logical_and(
+            _expand_runs(*defs, def_widths, cap) > 0, in_rows)
+        return validity, _prefix_sum(validity.astype(jnp.int32)) - 1
+
+
+def _after_dict(plain, n_dict, cap: int):
+    """``plain`` (cap rows, entry j = the j-th PLAIN value) moved down by
+    ``n_dict`` rows, so that it lines up with the dense stream it follows
+    the dictionary-encoded values in: a slice, not a gather."""
+    import jax
+    import jax.numpy as jnp
+    return jax.lax.dynamic_slice_in_dim(
+        jnp.concatenate([jnp.zeros_like(plain), plain]), cap - n_dict, cap)
+
+
+def _fixed_kernel_builder(npdt_str: str, cap: int, segments: str,
+                          def_widths: Optional[Tuple[int, ...]],
+                          idx_widths: Tuple[int, ...]):
     """Fixed-width decode: dense stream = dict segment ++ plain segment.
 
-    Row r's dense position ``pos[r]`` reads from the dictionary gather
-    while pos < n_dict (the count of dictionary-encoded non-null values)
-    and from the host-parsed plain array after — one kernel covers
-    dict-only (plain is a 1-slot dummy), plain-only (n_dict = 0), and the
-    pyarrow dictionary-overflow mixed chunk."""
-    def fn(v_start, v_rle, v_val, v_bit, v_width, v_packed,
-           d_start, d_rle, d_val, d_bit, d_width, d_packed, dvals,
-           plain, n_dict, n, iota_cap, iota_nv):
+    ``segments`` says which of the two the chunk has ("dict", "plain",
+    "mixed" — the pyarrow dictionary-overflow chunk, whose first
+    ``n_dict`` non-null values are dictionary-encoded and the rest PLAIN);
+    an absent segment's inputs are ``()`` and nothing of it is traced."""
+    def fn(defs, idx, dvals, plain, n_dict, n):
         import jax
         import jax.numpy as jnp
-        with jax.named_scope("pq_def_levels"):
-            validity = _expand_hybrid_device(
-                v_start, v_rle, v_val, v_bit, v_width, v_packed,
-                iota_cap) > 0
-            validity = jnp.logical_and(validity, iota_cap < n)
-            pos = (jnp.cumsum(validity.astype(jnp.int32)) - 1) \
-                .astype(jnp.int64)
-        with jax.named_scope("pq_dict_indices"):
-            idx = _expand_hybrid_device(d_start, d_rle, d_val, d_bit,
-                                        d_width, d_packed, iota_nv)
+        validity, pos = _validity_and_pos(defs, def_widths, n, cap)
+        if segments != "plain":
+            with jax.named_scope("pq_dict_indices"):
+                indices = _expand_runs(*idx, idx_widths, cap)
         with jax.named_scope("pq_value_gather"):
-            dense_dict = dvals[jnp.clip(idx, 0, dvals.shape[0] - 1)]
-            from_dict = pos < n_dict
-            v_dict = dense_dict[jnp.clip(pos, 0, dense_dict.shape[0] - 1)]
-            v_plain = plain[jnp.clip(pos - n_dict, 0, plain.shape[0] - 1)]
-            vals = jnp.where(from_dict, v_dict, v_plain)
+            if segments == "plain":
+                stream = plain
+            else:
+                stream = jnp.take(dvals, indices, mode="clip")
+                if segments == "mixed":
+                    stream = jnp.where(
+                        jax.lax.iota(jnp.int32, cap) < n_dict, stream,
+                        _after_dict(plain, n_dict, cap))
+            vals = stream if pos is None \
+                else jnp.take(stream, pos, mode="clip")
             vals = jnp.where(validity, vals, jnp.zeros((), vals.dtype))
         return vals.astype(jnp.dtype(npdt_str)), validity
     return lambda: fn
 
 
-def _ba_kernel_builder():
+def _bytes_kernel_builder(cap: int, segments: str,
+                          def_widths: Optional[Tuple[int, ...]],
+                          idx_widths: Tuple[int, ...]):
     """BYTE_ARRAY decode into the bucketed (rows, width) byte-matrix +
     lengths layout — dictionary rows gather as whole matrix rows (an
     MXU-friendly 2D gather), plain rows come from the host-assembled
-    matrix, segment choice as in _mixed_kernel_builder."""
-    def fn(v_start, v_rle, v_val, v_bit, v_width, v_packed,
-           d_start, d_rle, d_val, d_bit, d_width, d_packed,
-           dict_mat, dict_lens, plain_mat, plain_lens,
-           n_dict, n, iota_cap, iota_nv):
+    matrix, segments as in _fixed_kernel_builder."""
+    def fn(defs, idx, dict_mat, dict_lens, plain_mat, plain_lens, n_dict, n):
         import jax
         import jax.numpy as jnp
-        with jax.named_scope("pq_def_levels"):
-            validity = _expand_hybrid_device(
-                v_start, v_rle, v_val, v_bit, v_width, v_packed,
-                iota_cap) > 0
-            validity = jnp.logical_and(validity, iota_cap < n)
-            pos = (jnp.cumsum(validity.astype(jnp.int32)) - 1) \
-                .astype(jnp.int64)
-        with jax.named_scope("pq_dict_indices"):
-            idx = _expand_hybrid_device(d_start, d_rle, d_val, d_bit,
-                                        d_width, d_packed, iota_nv)
+        validity, pos = _validity_and_pos(defs, def_widths, n, cap)
+        if segments != "plain":
+            with jax.named_scope("pq_dict_indices"):
+                indices = _expand_runs(*idx, idx_widths, cap)
         with jax.named_scope("pq_value_gather"):
-            from_dict = pos < n_dict
-            didx = idx[jnp.clip(pos, 0, idx.shape[0] - 1)]
-            row_dict = dict_mat[jnp.clip(didx, 0, dict_mat.shape[0] - 1)]
-            len_dict = dict_lens[jnp.clip(didx, 0, dict_lens.shape[0] - 1)]
-            ppos = jnp.clip(pos - n_dict, 0, plain_mat.shape[0] - 1)
-            row_plain = plain_mat[ppos]
-            len_plain = plain_lens[ppos]
-            data = jnp.where(from_dict[:, None], row_dict, row_plain)
-            lengths = jnp.where(from_dict, len_dict, len_plain)
-            ok = validity[:, None]
-            data = jnp.where(ok, data, jnp.zeros((), jnp.uint8))
+            if segments != "plain":
+                if pos is not None:
+                    indices = jnp.take(indices, pos, mode="clip")
+                data = jnp.take(dict_mat, indices, axis=0, mode="clip")
+                lengths = jnp.take(dict_lens, indices, mode="clip")
+            if segments != "dict":
+                if pos is None:
+                    row_plain = _after_dict(plain_mat, n_dict, cap)
+                    len_plain = _after_dict(plain_lens, n_dict, cap)
+                else:
+                    row_plain = jnp.take(plain_mat, pos - n_dict, axis=0,
+                                         mode="clip")
+                    len_plain = jnp.take(plain_lens, pos - n_dict,
+                                         mode="clip")
+                if segments == "plain":
+                    data, lengths = row_plain, len_plain
+                else:
+                    dense_pos = jax.lax.iota(jnp.int32, cap) if pos is None \
+                        else pos
+                    from_dict = dense_pos < n_dict
+                    data = jnp.where(from_dict[:, None], data, row_plain)
+                    lengths = jnp.where(from_dict, lengths, len_plain)
+            data = jnp.where(validity[:, None], data,
+                             jnp.zeros((), jnp.uint8))
             lengths = jnp.where(validity, lengths, 0).astype(jnp.int32)
         return data, lengths, validity
     return lambda: fn
 
 
-def _empty_run_tables() -> Tuple[np.ndarray, ...]:
-    return _RunTable().arrays()
+def _pad_rows(a: np.ndarray, rows: int) -> np.ndarray:
+    if a.shape[0] > rows:
+        raise UnsupportedChunk("more values than rows")
+    return np.pad(a, ((0, rows - a.shape[0]),) + ((0, 0),) * (a.ndim - 1))
 
 
-def _run_table_inputs(ch: _Chunk, cap: int):
-    """The run tables and iotas both decode kernels start with, and the
-    count of dictionary-encoded values."""
-    v_tables = ch.defs.arrays()
-    iota_cap = np.arange(cap, dtype=np.int64)
-    d_tables = ch.idx.arrays() if ch.uses_dict else _empty_run_tables()
-    n_dict = ch.idx.total if ch.uses_dict else 0
-    iota_nv = np.arange(_pow2(max(1, n_dict)), dtype=np.int64)
-    return v_tables, d_tables, n_dict, iota_cap, iota_nv
-
-
-def _pad_rows_pow2(mat: np.ndarray, lens: np.ndarray):
-    pad_to = _pow2(mat.shape[0])
-    return (np.pad(mat, ((0, pad_to - mat.shape[0]), (0, 0))),
-            np.pad(lens, (0, pad_to - len(lens))).astype(np.int32))
+def _stream_inputs(ch: _Chunk, cap: int):
+    """The definition-level and dictionary-index streams as the kernels
+    take them: ``()`` for a stream its program does not trace."""
+    defs = () if ch.all_defined else ch.defs.device_inputs(cap)
+    idx = ch.idx.device_inputs(cap) if ch.uses_dict else ()
+    return defs, idx, np.int32(ch.idx.total), np.int32(ch.num_rows)
 
 
 def _bytes_inputs(ch: _Chunk, cap: int) -> tuple:
-    """Host arrays the BYTE_ARRAY kernel takes, shapes pow2-bucketed."""
+    """Host arrays the BYTE_ARRAY kernel takes."""
     from ..columnar.device import bucket_width
-    v_tables, d_tables, n_dict, iota_cap, iota_nv = _run_table_inputs(ch, cap)
+    defs, idx, n_dict, n = _stream_inputs(ch, cap)
     max_len = 1
     if ch.ba_dict is not None and len(ch.ba_dict[1]):
         max_len = max(max_len, int(ch.ba_dict[1].max()))
@@ -532,54 +696,51 @@ def _bytes_inputs(ch: _Chunk, cap: int) -> tuple:
         if len(lens):
             max_len = max(max_len, int(lens.max()))
     width = bucket_width(max_len)
+    dict_part = plain_part = ((), ())
     if ch.uses_dict:
         if ch.ba_dict is None:
             raise UnsupportedChunk("dict-encoded pages, no dict page")
-        dm, dlens = _pad_rows_pow2(*_ba_matrix([ch.ba_dict], width))
-    else:
-        dm, dlens = np.zeros((1, width), np.uint8), np.zeros(1, np.int32)
-    if ch.ba_plain:
-        pm, plens = _pad_rows_pow2(*_ba_matrix(ch.ba_plain, width))
-    else:
-        pm, plens = np.zeros((1, width), np.uint8), np.zeros(1, np.int32)
-    return (*v_tables, *d_tables, dm, dlens, pm, plens,
-            np.int64(n_dict), np.int64(ch.num_rows), iota_cap, iota_nv)
+        dm, dlens = _ba_matrix([ch.ba_dict], width)
+        rows = _pow2(dm.shape[0])
+        dict_part = (_pad_rows(dm, rows), _pad_rows(dlens, rows))
+    if ch.segments != "dict":
+        pm, plens = _ba_matrix(ch.ba_plain, width)
+        plain_part = (_pad_rows(pm, cap), _pad_rows(plens, cap))
+    return (defs, idx, *dict_part, *plain_part, n_dict, n)
 
 
 def _fixed_inputs(ch: _Chunk, npdt, cap: int) -> tuple:
-    """Host arrays the fixed-width kernel takes, shapes pow2-bucketed."""
-    v_tables, d_tables, n_dict, iota_cap, iota_nv = _run_table_inputs(ch, cap)
-    if ch.bool_plain and not ch.uses_dict:
-        parts = [_plain_values(b, "BOOLEAN", c) for b, c in ch.bool_plain]
-        plain = np.concatenate(parts) if parts else np.zeros(0, np.bool_)
-    elif ch.plain_parts:
-        blob = b"".join(ch.plain_parts)
-        d_ = np.dtype(npdt)
-        if d_.kind == "f":
-            phys = "FLOAT" if d_.itemsize == 4 else "DOUBLE"
-        else:  # ints + date32/timestamp storage types
-            phys = "INT32" if d_.itemsize == 4 else "INT64"
-        count = len(blob) // np.dtype(_NP_BY_PHYS[phys]).itemsize
-        plain = _plain_values(blob, phys, count)
-    else:
-        plain = np.zeros(0, npdt)
-    plain = np.asarray(plain, npdt)
-    plain = np.pad(plain, (0, _pow2(max(1, len(plain))) - len(plain)))
+    """Host arrays the fixed-width kernel takes."""
+    defs, idx, n_dict, n = _stream_inputs(ch, cap)
+    dv = plain = ()
     if ch.uses_dict:
-        dict_vals = np.asarray(ch.dictionary, npdt)
-    else:
-        dict_vals = np.zeros(1, npdt)
-    dv = np.pad(dict_vals,
-                (0, _pow2(max(1, len(dict_vals))) - len(dict_vals)))
-    return (*v_tables, *d_tables, dv, plain,
-            np.int64(n_dict), np.int64(ch.num_rows), iota_cap, iota_nv)
+        dv = np.asarray(ch.dictionary, npdt)
+        dv = _pad_rows(dv, _pow2(max(1, len(dv))))
+    if ch.segments != "dict":
+        if ch.bool_plain:
+            plain = np.concatenate(
+                [_plain_values(b, "BOOLEAN", c) for b, c in ch.bool_plain])
+        else:
+            blob = b"".join(ch.plain_parts)
+            d_ = np.dtype(npdt)
+            if d_.kind == "f":
+                phys = "FLOAT" if d_.itemsize == 4 else "DOUBLE"
+            else:  # ints + date32/timestamp storage types
+                phys = "INT32" if d_.itemsize == 4 else "INT64"
+            count = len(blob) // np.dtype(_NP_BY_PHYS[phys]).itemsize
+            plain = _plain_values(blob, phys, count)
+        plain = _pad_rows(np.asarray(plain, npdt), cap)
+    return (defs, idx, dv, plain, n_dict, n)
 
 
 def _decode_column_device(ch: _Chunk, out_dtype: dt.DataType, cap: int):
     """-> DeviceColumn with row capacity ``cap`` (device kernels; compiled
-    callables shared via the global compile cache, shapes pow2-bucketed).
-    Three phases, each a span: the host builds the kernel's inputs
-    (``scan.parse``), uploads them (``h2d``), and dispatches the decode."""
+    callables shared via the global compile cache). The program is chosen
+    by what the parser saw in the chunk — all rows defined or not, which
+    value segments it has, the bit widths of each stream — never by a
+    conf. Three phases, each a span: the host builds the kernel's inputs
+    (``scan.parse``), uploads them (``h2d``), and dispatches the decode
+    (``decode.dense`` for an all-defined chunk, else ``decode.general``)."""
     import jax
 
     from ..columnar.device import DeviceColumn
@@ -593,17 +754,32 @@ def _decode_column_device(ch: _Chunk, out_dtype: dt.DataType, cap: int):
         args = _bytes_inputs(ch, cap) if is_bytes \
             else _fixed_inputs(ch, npdt, cap)
     with tracer.span("h2d", "upload",
-                     bytes=sum(int(a.nbytes) for a in args)):
+                     bytes=sum(int(a.nbytes)
+                               for a in jax.tree_util.tree_leaves(args))):
         args = jax.device_put(args)
-    if is_bytes:
-        fn = cached_jit("pq_ba", _ba_kernel_builder(), name="pq_decode_bytes")
-        data, lengths, validity = fn(*args)
-        return DeviceColumn(data, validity, out_dtype, lengths)
-    npdt_str = np.dtype(npdt).str
-    fn = cached_jit(f"pq_mix|{npdt_str}", _mixed_kernel_builder(npdt_str),
-                    name="pq_decode_fixed")
-    data, validity = fn(*args)
-    return DeviceColumn(data, validity, out_dtype, None)
+    segments = ch.segments
+    def_widths = None if ch.all_defined else ch.defs.widths()
+    idx_widths = ch.idx.widths() if ch.uses_dict else ()
+    shape_key = f"{cap}|{segments}|{def_widths}|{idx_widths}"
+    with tracer.span("decode.dense" if ch.all_defined else "decode.general",
+                     "decode",
+                     runs=len(ch.defs.out_start) + len(ch.idx.out_start),
+                     widths=",".join(map(str, idx_widths))):
+        if is_bytes:
+            fn = cached_jit(
+                f"pq_ba|{shape_key}",
+                _bytes_kernel_builder(cap, segments, def_widths, idx_widths),
+                name="pq_decode_bytes")
+            data, lengths, validity = fn(*args)
+            return DeviceColumn(data, validity, out_dtype, lengths)
+        npdt_str = np.dtype(npdt).str
+        fn = cached_jit(
+            f"pq_mix|{npdt_str}|{shape_key}",
+            _fixed_kernel_builder(npdt_str, cap, segments, def_widths,
+                                  idx_widths),
+            name="pq_decode_fixed")
+        data, validity = fn(*args)
+        return DeviceColumn(data, validity, out_dtype, None)
 
 
 def decode_row_group(raw: bytes, pf_metadata, rg: int, arrow_schema,

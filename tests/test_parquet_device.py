@@ -282,3 +282,297 @@ def test_per_type_device_decode_gates(sess, tmp_path):
     assert nd2 == 1  # only the int column stayed on device
     assert dt2.to_host().to_arrow().column("s").to_pylist() == \
         t.column("s").to_pylist()
+
+
+# ---------------------------------------------------------------------------
+# The decode programs expand run tables by a scatter of run-start deltas and
+# one prefix sum, and unpack bit fields densely: every case below is checked
+# bit for bit, the streams against a plain numpy expander of the SAME run
+# table and against the values that were encoded, the files against the host
+# reader.
+# ---------------------------------------------------------------------------
+def _varint(v):
+    out = bytearray()
+    while True:
+        if v < 0x80:
+            out.append(v)
+            return bytes(out)
+        out.append((v & 0x7F) | 0x80)
+        v >>= 7
+
+
+def _hybrid(parts, width):
+    """Encode one RLE/bit-packed hybrid stream (parquet format spec):
+    ``("rle", value, count)`` or ``("bp", values)``, a short last group of
+    a bit-packed run zero-padded to 8 values."""
+    out = bytearray()
+    for part in parts:
+        if part[0] == "rle":
+            _, value, count = part
+            out += _varint(count << 1)
+            out += int(value).to_bytes((width + 7) // 8, "little")
+        else:
+            vals = np.asarray(part[1], np.int64)
+            vals = np.pad(vals, (0, -len(vals) % 8))
+            out += _varint((len(vals) // 8) << 1 | 1)
+            bits = ((vals[:, None] >> np.arange(width)) & 1).astype(np.uint8)
+            out += np.packbits(bits.reshape(-1), bitorder="little").tobytes()
+    return bytes(out)
+
+
+def _pages_to_run_table(pages):
+    """pages: [(width, parts)] -> (_RunTable, the values encoded)."""
+    from spark_rapids_tpu.io.parquet_device import _RunTable
+    rt = _RunTable()
+    truth = []
+    for width, parts in pages:
+        vals = np.concatenate(
+            [np.full(p[2], p[1], np.int64) if p[0] == "rle"
+             else np.asarray(p[1], np.int64) for p in parts])
+        buf = _hybrid(parts, width)
+        rt.parse_hybrid(buf, 0, len(buf), width, len(vals))
+        truth.append(vals)
+    return rt, np.concatenate(truth)
+
+
+def _expand_numpy(rt):
+    """The plain expander: run by run, bit by bit, from the run table."""
+    out = np.zeros(rt.total, np.int64)
+    for s, c, w, v, fb in zip(rt.out_start, rt.count, rt.width,
+                              rt.rle_value, rt.field_base):
+        if w == 0:
+            out[s:s + c] = v
+            continue
+        bits = np.unpackbits(np.frombuffer(bytes(rt.packed[w]), np.uint8),
+                             bitorder="little")
+        fields = bits[fb * w:(fb + c) * w].reshape(c, w).astype(np.int64)
+        out[s:s + c] = (fields << np.arange(w)).sum(axis=1)
+    return out
+
+
+def _expand_device(rt):
+    import jax
+    from spark_rapids_tpu.io import parquet_device as pd
+    cap = max(8, pd._pow2(rt.total))
+    widths = rt.widths()
+    got = jax.jit(lambda *a: pd._expand_runs(*a, widths, cap))(
+        *rt.device_inputs(cap))
+    assert str(got.dtype) == "int32"
+    return np.asarray(got)[:rt.total].astype(np.int64)
+
+
+def _width_pages(width, seed=3, pages=5):
+    """Pages of one width, each a bit-packed run, an RLE run, and a second
+    bit-packed run whose last group is short (only a page's last run may
+    be: a reader cannot tell padding from values anywhere else)."""
+    rng = np.random.default_rng(seed + width)
+    hi = 1 << width
+    out = []
+    for p in range(pages):
+        out.append((width, [
+            ("bp", rng.integers(0, hi, 8 * (3 + p))),
+            ("rle", int(rng.integers(0, hi)), 9 + 7 * p),
+            ("bp", rng.integers(0, hi, 11 + p))]))
+    return out
+
+
+def _stream_cases():
+    rng = np.random.default_rng(17)
+    cases = {
+        "rle-only": [(3, [("rle", 3, 1000), ("rle", 0, 17)]),
+                     (3, [("rle", 5, 9)])],
+        "bit-packed-only": [(6, [("bp", rng.integers(0, 64, 4096))])],
+        "interleaved": [
+            (2, [("bp", rng.integers(0, 4, 8 * (1 + p % 3))),
+                 ("rle", p % 4, 8 + p),
+                 ("bp", rng.integers(0, 4, 13 + p))]) for p in range(20)],
+        "width-0": [(0, [("rle", 0, 777)])],
+        "pages-grow-15-to-18": [
+            (w, [("rle", (1 << w) - 1, 10),
+                 ("bp", rng.integers(0, 1 << w, 1000 + w))])
+            for w in (15, 16, 17, 18)],
+        "short-last-group-every-page": [
+            (6, [("bp", rng.integers(0, 64, 13))]) for _ in range(300)],
+        "n-not-multiple-of-8": [(12, [("bp", rng.integers(0, 4096, 1003))])],
+        "one-row-bit-packed": [(6, [("bp", [41])])],
+        "one-row-rle": [(6, [("rle", 41, 1)])],
+    }
+    for w in (1, 2, 6, 12, 24):
+        cases[f"width-{w}"] = _width_pages(w)
+    return cases
+
+
+def _file_cases():
+    def table(null_share, n=5000, seed=23):
+        rng = np.random.default_rng(seed)
+        mask = rng.random(n) < null_share if 0 < null_share < 1 \
+            else np.full(n, bool(null_share))
+        return pa.table({
+            "lowcard": pa.array(rng.integers(0, 40, n), type=pa.int64(),
+                                mask=mask),
+            "f": pa.array(np.round(rng.normal(size=n), 1), mask=mask),
+            "i": pa.array(rng.integers(-2**40, 2**40, n), type=pa.int64(),
+                          mask=mask),
+            "d": pa.array(rng.integers(0, 2500, n).astype(np.int32),
+                          mask=mask).cast(pa.date32()),
+            "b": pa.array(rng.integers(0, 2, n).astype(bool), mask=mask),
+            "s": pa.array([f"s{i % 7}" * (1 + i % 3) for i in range(n)],
+                          type=pa.string(), mask=mask),
+        })
+    overflow = dict(dictionary_pagesize_limit=4096, data_page_size=2048)
+    return {
+        "nulls-0pct": (table(0.0), {}),
+        "nulls-0.6pct": (table(0.006), {}),
+        "nulls-50pct": (table(0.5), {}),
+        "nulls-100pct": (table(1.0), {}),
+        "dict-to-plain-overflow": (table(0.0), overflow),
+        "dict-to-plain-overflow-nulls": (table(0.1), overflow),
+        # v2 writes BOOLEAN values RLE-encoded: host decode, by column
+        "data-page-v2": (table(0.0).drop_columns(["b"]),
+                         dict(data_page_version="2.0")),
+        "data-page-v2-nulls": (table(0.2).drop_columns(["b"]),
+                               dict(data_page_version="2.0")),
+        "strings-large-dictionary": (pa.table({"s": pa.array(
+            [f"key-{i % 3000:05d}" for i in range(20000)])}), {}),
+        "many-small-pages": (table(0.006, n=20000),
+                             dict(data_page_size=512)),
+    }
+
+
+_STREAM_CASES = _stream_cases()
+_FILE_CASES = _file_cases()
+
+
+@pytest.mark.parametrize(
+    "case", [f"stream:{k}" for k in _STREAM_CASES]
+    + [f"file:{k}" for k in _FILE_CASES])
+def test_decode_bit_identical(case):
+    import io as _io
+    from spark_rapids_tpu.io import parquet_device as pd
+    kind, name = case.split(":", 1)
+    if kind == "stream":
+        rt, truth = _pages_to_run_table(_STREAM_CASES[name])
+        assert np.array_equal(_expand_numpy(rt), truth)
+        assert np.array_equal(_expand_device(rt), truth)
+        return
+    t, kw = _FILE_CASES[name]
+    buf = _io.BytesIO()
+    pq.write_table(t, buf, row_group_size=t.num_rows, compression="snappy",
+                   **kw)
+    raw = buf.getvalue()
+    pf = pq.ParquetFile(_io.BytesIO(raw))
+    names = list(t.column_names)
+    rg = pf.metadata.row_group(0)
+    for ci, cname in enumerate(names):
+        ch = pd._parse_chunk(raw, rg.column(ci),
+                             pf.schema_arrow.field(cname).nullable)
+        col = t.column(cname)
+        assert ch.n_defined == len(col) - col.null_count
+        for rt in (ch.defs, ch.idx):
+            if rt.total:
+                assert np.array_equal(_expand_device(rt), _expand_numpy(rt))
+    dt_, ndev = pd.decode_row_group(raw, pf.metadata, 0, pf.schema_arrow,
+                                    names, 64)
+    assert ndev == len(names), f"{ndev}/{len(names)} columns on device"
+    got = dt_.to_host().to_arrow()
+    host = pf.read_row_group(0)
+    for c in names:
+        assert got.column(c).to_pylist() == host.column(c).to_pylist(), c
+
+
+def _lowered_decode(decoder, segments, def_widths, idx_widths,
+                    cap=1 << 20):
+    """StableHLO text (with scopes) of one decode variant at the shapes
+    the benchmark's SF 1 chunks have: 2^20 rows, 53 definition-level runs
+    (bucket 256), 265-3,593 index runs (bucket 4,096)."""
+    import jax
+    import jax.numpy as jnp
+    from spark_rapids_tpu.io import parquet_device as pd
+    S = jax.ShapeDtypeStruct
+
+    def stream(widths, rb):
+        if widths is None:
+            return ()
+        return (S((rb,), jnp.int32),
+                S((3 if len(widths) > 1 else 2, rb), jnp.int32),
+                S((sum(widths), cap // 8 + rb), jnp.uint8))
+    defs = stream(def_widths, 256)
+    idx = stream(idx_widths if segments != "plain" else None, 4096)
+    n = S((), jnp.int32)
+    has_dict, has_plain = segments != "plain", segments != "dict"
+    if decoder == "fixed":
+        fn = pd._fixed_kernel_builder("<f8", cap, segments, def_widths,
+                                      idx_widths)()
+        args = (defs, idx, S((4096,), jnp.float64) if has_dict else (),
+                S((cap,), jnp.float64) if has_plain else (), n, n)
+    else:
+        fn = pd._bytes_kernel_builder(cap, segments, def_widths,
+                                      idx_widths)()
+        args = (defs, idx,
+                S((4, 8), jnp.uint8) if has_dict else (),
+                S((4,), jnp.int32) if has_dict else (),
+                S((cap, 8), jnp.uint8) if has_plain else (),
+                S((cap,), jnp.int32) if has_plain else (), n, n)
+    return jax.jit(fn).lower(*args).as_text(debug_info=True)
+
+
+# The gathers a decode program may hold, settled in PR 27. All-defined
+# chunk: the short-last-group shift of the index fields + the dictionary
+# gather (fixed: 2; strings gather rows and lengths: 3); a PLAIN chunk
+# none. Chunk with nulls: + the definition-level fields and the spread of
+# the dense stream over the rows (fixed: 4); strings spread the indices,
+# then gather rows and lengths from the dictionary and, where the chunk
+# overflowed its dictionary, from the PLAIN matrix as well (5 / 7).
+@pytest.mark.parametrize("decoder,segments,def_widths,idx_widths,gathers", [
+    ("fixed", "dict", None, (6,), 2),       # l_quantity
+    ("fixed", "dict", None, (4,), 2),       # l_discount, l_tax
+    ("fixed", "dict", None, (12,), 2),      # l_shipdate
+    ("fixed", "mixed", None, (15, 16, 17, 18), 2),   # l_extendedprice
+    ("fixed", "plain", None, (), 0),
+    ("bytes", "dict", None, (2,), 3),       # l_returnflag
+    ("bytes", "dict", None, (1,), 3),       # l_linestatus
+    ("bytes", "mixed", None, (10, 11), 3),
+    ("fixed", "dict", (), (6,), 3),         # nulls in long RLE runs only
+    ("fixed", "dict", (1,), (6,), 4),
+    ("fixed", "mixed", (1,), (15, 16, 17, 18), 4),
+    ("fixed", "plain", (1,), (), 2),
+    ("bytes", "dict", (1,), (2,), 5),
+    ("bytes", "mixed", (1,), (10, 11), 7),
+])
+def test_decode_programs_hold_no_loop_and_few_gathers(
+        decoder, segments, def_widths, idx_widths, gathers):
+    import re
+    text = _lowered_decode(decoder, segments, def_widths, idx_widths)
+    assert "stablehlo.while" not in text
+    assert "stablehlo.sort" not in text
+    assert len(re.findall(r'= "?stablehlo\.gather', text)) <= gathers
+    assert not re.search(r"tensor<(\d+x)*\d{3,}(x\d+)*xi64>", text), \
+        "an int64 vector in the decode"
+    if segments != "plain":
+        assert "pq_run_prefix_sum" in text and "pq_bit_unpack" in text
+    # the all-defined variant traces nothing of the definition levels
+    assert ("pq_def_levels" in text) == (def_widths is not None)
+
+
+@pytest.mark.parametrize("null_share,span", [(0.0, "decode.dense"),
+                                             (0.1, "decode.general")])
+def test_decode_path_is_booked_by_span(sess, tmp_path, null_share, span):
+    """A chunk without nulls takes the all-defined program and books
+    ``decode.dense``; one with nulls books ``decode.general`` — the phase
+    totals count chunks by path (what the benchmark's
+    ``decode_general_chunks_per_query`` reads)."""
+    from spark_rapids_tpu.utils.tracing import get_tracer
+    n = 3000
+    rng = np.random.default_rng(2)
+    mask = rng.random(n) < null_share
+    t = pa.table({"k": pa.array(rng.integers(0, 9, n), type=pa.int64(),
+                                mask=mask),
+                  "s": pa.array([f"v{i % 5}" for i in range(n)], mask=mask)})
+    p = str(tmp_path / "t.parquet")
+    pq.write_table(t, p, row_group_size=1000)
+    out = sess.read_parquet(p).collect(device=True)
+    assert out.column("k").to_pylist() == t.column("k").to_pylist()
+    phases = get_tracer().recent_queries(1)[-1]["phases"]
+    other = ({"decode.dense", "decode.general"} - {span}).pop()
+    assert phases[span]["calls"] == 2 * 3      # columns x row groups
+    assert other not in phases

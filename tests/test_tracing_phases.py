@@ -212,6 +212,7 @@ def test_with_the_ring_off_no_trace_event_is_built(sess, lineitem_dir,
                                                    monkeypatch):
     tracer = get_tracer()
     assert not tracer.enabled
+    tracer.clear()      # what an earlier test file left in this process
     built = []
 
     class Counting(tracing.TraceEvent):
