@@ -33,7 +33,8 @@ from ..plan.physical import HashPartitioning, PhysicalPlan
 from ..shuffle import telemetry as shuffle_telemetry
 from ..utils import metrics as M
 from ..utils import movement
-from ..utils.compile_cache import named_jit
+from ..utils.compile_cache import cached_jit
+from ..utils.tracing import get_tracer
 from .base import TpuExec
 
 __all__ = ["TpuShuffleExchangeExec", "TpuLocalExchangeExec", "SHUFFLE_MODE",
@@ -67,6 +68,18 @@ EXCHANGE_CHUNK_ROWS = register_conf(
     "can spill (reference: the streaming per-batch exchange, "
     "GpuShuffleExchangeExecBase.scala:146).", 1 << 19,
     checker=lambda v: None if int(v) > 0 else "must be positive")
+
+
+def _pid_program(key_names: List[str], n: int):
+    """The count pass's program: the partition id of every row of a chunk,
+    ``n`` for a masked-off row. One cache entry per (keys, partitions), so
+    the chunks of every exchange of that shape share one trace."""
+    def build():
+        from ..shuffle.manager import device_partition_ids
+        return lambda t: jnp.where(
+            t.row_mask, device_partition_ids(t, key_names, n), n)
+    return cached_jit(f"exchange_pid|{'|'.join(key_names)}|{n}", build,
+                      name="exchange_pid")
 
 
 def pad_table_capacity(table: DeviceTable, capacity: int) -> DeviceTable:
@@ -271,10 +284,10 @@ class TpuShuffleExchangeExec(TpuExec):
         production accounts its own opTime upstream."""
         from ..memory.catalog import SpillPriorities, get_catalog
         from ..shuffle.ici import ici_all_to_all_exchange, shard_table
-        from ..shuffle.manager import device_partition_ids
 
         n = self.num_partitions
         catalog = get_catalog()
+        tracer = get_tracer()
         with self.metrics.timed(M.OP_TIME):
             table = concat_device_tables(batches, self.min_bucket)
             chunk_nbytes = table.nbytes()
@@ -295,20 +308,25 @@ class TpuShuffleExchangeExec(TpuExec):
             try:
                 # count pass: partition ids only (4 bytes/row) -> quota
                 keys = self.partitioning.key_names
-                pid = named_jit(lambda t: jnp.where(
-                    t.row_mask, device_partition_ids(t, keys, n), n),
-                    "exchange_pid")(table)
-                t0 = movement.clock()
-                pid_host = np.asarray(jax.device_get(pid))  # srtpu: sync-ok(the deliberate partition-count funnel: one transfer sizes every shard buffer for the chunk)
-                movement.note_d2h(_MOVE_CHUNK, pid_host.nbytes, t0)
-                src = np.arange(table.capacity) // per_shard
-                active = pid_host < n
-                counts = np.zeros((n, n), dtype=np.int64)
-                np.add.at(counts, (src[active], pid_host[active]), 1)
-                max_cnt = int(counts.max()) if active.any() else 1
-                quota = min(per_shard, bucket_rows(max_cnt, self.min_bucket))
+                with tracer.span("exchange.count", "exchange") as count:
+                    pid = _pid_program(keys, n)(table)
+                    count.note(bytes=pid.nbytes)
+                    t0 = movement.clock()
+                    with tracer.span("d2h", "download", bytes=pid.nbytes):
+                        pid_host = np.asarray(jax.device_get(pid))  # srtpu: sync-ok(the deliberate partition-count funnel: one transfer sizes every shard buffer for the chunk)
+                    movement.note_d2h(_MOVE_CHUNK, pid_host.nbytes, t0)
+                    src = np.arange(table.capacity) // per_shard
+                    active = pid_host < n
+                    counts = np.zeros((n, n), dtype=np.int64)
+                    np.add.at(counts, (src[active], pid_host[active]), 1)
+                    max_cnt = int(counts.max()) if active.any() else 1
+                    quota = min(per_shard,
+                                bucket_rows(max_cnt, self.min_bucket))
 
-                sharded = shard_table(table, self.mesh, self.axis)
+                # where the rows leave the device that produced them
+                with tracer.span("exchange.shard", "exchange",
+                                 bytes=chunk_nbytes):
+                    sharded = shard_table(table, self.mesh, self.axis)
                 del table, batches
                 exchanged = ici_all_to_all_exchange(
                     sharded, keys, self.mesh, self.axis, quota=quota,
@@ -334,8 +352,9 @@ class TpuShuffleExchangeExec(TpuExec):
                     # per-destination row counts sync, for skew + quota
                     # telemetry parity with the split path
                     t0 = movement.clock()
-                    shard_rows = jax.device_get(  # srtpu: sync-ok(batched count sync, 4B per shard once per chunk)
-                        shard_row_counts(exchanged, n))
+                    with tracer.span("sync", "download", scalars=n):
+                        shard_rows = jax.device_get(  # srtpu: sync-ok(batched count sync, 4B per shard once per chunk)
+                            shard_row_counts(exchanged, n))
                     movement.note_d2h(_MOVE_CHUNK, 4 * len(shard_rows), t0)
                     self._sharded_chunks.append(exchanged)
                     self._sharded_chunk_rows.append(
@@ -365,19 +384,22 @@ class TpuShuffleExchangeExec(TpuExec):
         from ..memory.catalog import SpillPriorities, get_catalog
         catalog = get_catalog()
         n = self.num_partitions
-        parts = _split_sharded(exchanged, n)
-        # ONE bulk D2H of n 4-byte scalars replaces a blocking round
-        # trip per shard plus one more for the row total
-        t0 = movement.clock()
-        shard_rows = jax.device_get(  # srtpu: sync-ok(batched count sync, 4B per shard once per chunk)
-            [t.num_rows for t in parts])
-        movement.note_d2h(_MOVE_CHUNK, 4 * len(shard_rows), t0)
-        for i, (t, cnt) in enumerate(zip(parts, shard_rows)):
-            if not int(cnt):
-                continue
-            h = catalog.register(t, SpillPriorities.OUTPUT_FOR_SHUFFLE)
-            self._own_spill_handle(h)
-            shards[i].append(h)
+        tracer = get_tracer()
+        with tracer.span("exchange.split", "exchange"):
+            parts = _split_sharded(exchanged, n)
+            # ONE bulk D2H of n 4-byte scalars replaces a blocking round
+            # trip per shard plus one more for the row total
+            t0 = movement.clock()
+            with tracer.span("sync", "download", scalars=n):
+                shard_rows = jax.device_get(  # srtpu: sync-ok(batched count sync, 4B per shard once per chunk)
+                    [t.num_rows for t in parts])
+            movement.note_d2h(_MOVE_CHUNK, 4 * len(shard_rows), t0)
+            for i, (t, cnt) in enumerate(zip(parts, shard_rows)):
+                if not int(cnt):
+                    continue
+                h = catalog.register(t, SpillPriorities.OUTPUT_FOR_SHUFFLE)
+                self._own_spill_handle(h)
+                shards[i].append(h)
         return [int(c) for c in shard_rows]
 
     def shuffle_skew(self) -> Optional[dict]:
@@ -393,6 +415,12 @@ class TpuShuffleExchangeExec(TpuExec):
 class TpuLocalExchangeExec(TpuExec):
     """Single-chip device-resident exchange: the whole input coalesces into
     ONE spill-registered output partition, never leaving the device.
+
+    Under a mesh the same operator is the single-partition gather
+    (``gather_device`` set): the child's partitions live one on each device
+    of the mesh, and every non-empty batch is copied chip to chip onto
+    ``gather_device`` before it is registered, so the consumer's concat sees
+    one device.
 
     With one addressable chip there is no locality to exploit and no
     transport to ride: hash, range and single partitioning contracts are
@@ -410,12 +438,13 @@ class TpuLocalExchangeExec(TpuExec):
     EXTRA_METRICS = (M.SHUFFLE_BYTES,)
 
     def __init__(self, child: PhysicalPlan, partitioning,
-                 min_bucket: Optional[int] = None):
+                 min_bucket: Optional[int] = None, gather_device=None):
         super().__init__()
         self.child = child
         self.children = (child,)
         self.partitioning = partitioning
         self.min_bucket = resolve_min_bucket(min_bucket)
+        self.gather_device = gather_device
         self.schema = child.schema
         self.telemetry_sid = next(_EXCHANGE_IDS)
         self._handles: Optional[List] = None
@@ -429,6 +458,8 @@ class TpuLocalExchangeExec(TpuExec):
         return 1
 
     def node_desc(self) -> str:
+        if self.gather_device is not None:
+            return f"gather n=1 -> device {self.gather_device.id}"
         return "local n=1"
 
     def _materialize(self) -> None:
@@ -474,6 +505,11 @@ class TpuLocalExchangeExec(TpuExec):
                     # capacity would inflate every downstream kernel
                     shrunk = shrink_to_fit(b, self.min_bucket, num_rows=n)
                     nbytes = shrunk.nbytes()
+                    if self.gather_device is not None:
+                        with get_tracer().span("exchange.gather", "exchange",
+                                               bytes=nbytes):
+                            shrunk = jax.device_put(shrunk,
+                                                    self.gather_device)
                     self.metrics.add(M.SHUFFLE_BYTES, nbytes)
                     # mirrors the shuffleBytes metric add exactly so the
                     # shuffle_summary tier bytes reconcile with it

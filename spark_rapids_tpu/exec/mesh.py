@@ -54,6 +54,7 @@ from ..shuffle import telemetry as shuffle_telemetry
 from ..utils import faults
 from ..utils import metrics as M
 from ..utils.compile_cache import named_jit
+from ..utils.tracing import get_tracer
 from .base import TpuExec
 from .exchange import TpuShuffleExchangeExec, _split_sharded
 from .wholestage import TpuWholeStageExec, _fusible, _with_children
@@ -77,6 +78,7 @@ MESH_STAGE_ENABLED = register_conf(
 # the one-time XLA compile is timed as its own observatory phase.
 _PROGRAMS: "OrderedDict[tuple, object]" = OrderedDict()
 _PROGRAMS_MAX = 64
+_PROGRAM = "srt_mesh_stage"   # what its dispatch and compile spans carry
 
 
 def clear_mesh_programs() -> None:
@@ -265,7 +267,8 @@ class TpuMeshStageExec(TpuExec):
         prog = self._program(chunk)
         with self.metrics.timed(M.OP_TIME):
             t0 = shuffle_telemetry.clock()
-            out_cols, out_mask = prog(chunk.columns, chunk.row_mask)
+            with get_tracer().span("dispatch", "dispatch", program=_PROGRAM):
+                out_cols, out_mask = prog(chunk.columns, chunk.row_mask)
             shuffle_telemetry.note_transfer(
                 "ici", "mesh_stage", shuffle_id=self.exchange.telemetry_sid,
                 t0=t0, queue_depth=n, wire_bytes=lambda: chunk.nbytes())
@@ -309,7 +312,8 @@ class TpuMeshStageExec(TpuExec):
                                      out_specs=(P(axis), P(axis)),
                                      check_vma=False), "mesh_stage")
         t0 = shuffle_telemetry.clock()
-        prog = fn.lower(chunk.columns, chunk.row_mask).compile()
+        with get_tracer().span("compile", "compile", program=_PROGRAM):
+            prog = fn.lower(chunk.columns, chunk.row_mask).compile()
         shuffle_telemetry.note_transfer(
             "ici", "compile", shuffle_id=self.exchange.telemetry_sid,
             t0=t0, queue_depth=self.num_partitions)

@@ -24,7 +24,7 @@ from .meta import (EXEC_RULES, EXPR_RULES, register_exec_rule,
                    register_expr_rule, wrap_plan)
 from .physical import (CpuFilterExec, CpuHashAggregateExec, CpuLocalLimitExec,
                        CpuProjectExec, CpuRangeExec, CpuSortExec, CpuUnionExec,
-                       PhysicalPlan)
+                       PhysicalPlan, SinglePartitioning)
 
 __all__ = ["apply_overrides", "explain_plan"]
 
@@ -851,10 +851,14 @@ def _register_exec_rules():
             # local tier: any partitioning is satisfied by one device-
             # resident partition — no key-type constraints
             return
+        if isinstance(p.partitioning, SinglePartitioning):
+            # the gather to one partition stays on the device: a chip-to-
+            # chip copy onto the mesh's first device (_convert_exchange)
+            return
         if not isinstance(p.partitioning, HashPartitioning):
             meta.cannot_run(
                 f"{type(p.partitioning).__name__} stays on the host tier "
-                "(only hash partitioning exchanges over ICI)")
+                "(only hash and single partitioning exchange on the mesh)")
             return
         _pkey = _device_all.with_structs(_device_all)
         for k in p.partitioning.key_names:
@@ -875,6 +879,10 @@ def _convert_exchange(p, ch, conf, mesh):
     if mode == "local" or mesh is None:
         return TpuLocalExchangeExec(ch[0], p.partitioning,
                                     conf.min_bucket_rows)
+    if isinstance(p.partitioning, SinglePartitioning):
+        return TpuLocalExchangeExec(ch[0], p.partitioning,
+                                    conf.min_bucket_rows,
+                                    gather_device=mesh.devices.flat[0])
     return TpuShuffleExchangeExec(ch[0], p.partitioning, mesh,
                                   conf.min_bucket_rows,
                                   chunk_rows=conf.get(EXCHANGE_CHUNK_ROWS))

@@ -31,6 +31,7 @@ from ..columnar.device import (DeviceColumn, DeviceTable,
                                stable_counting_order)
 from ..utils import movement
 from ..utils.compile_cache import named_jit
+from ..utils.tracing import get_tracer
 from . import telemetry
 from .manager import device_partition_ids
 
@@ -152,6 +153,19 @@ def exchange_program(columns, names, key_names: List[str], mesh: Mesh,
                                    check_vma=False), "ici_all_to_all")
 
 
+_PROGRAM = "srt_ici_all_to_all"
+
+
+def _crossing_bytes(table: DeviceTable, n: int, quota: int | None) -> int:
+    """Bytes the all-to-all hands over: every shard sends ``quota`` slots of
+    every plane (and of the mask) to each of the ``n`` shards, padding
+    included; the 1/n of them addressed to the sending shard stay on it."""
+    cap = table.capacity // n
+    q = cap if quota is None else min(quota, cap)
+    leaves = jax.tree_util.tree_leaves((table.columns, table.row_mask))
+    return sum(l.nbytes // l.shape[0] for l in leaves) * n * n * q
+
+
 def ici_all_to_all_exchange(table: DeviceTable, key_names: List[str],
                             mesh: Mesh, axis: str = "dp",
                             quota: int | None = None,
@@ -167,6 +181,7 @@ def ici_all_to_all_exchange(table: DeviceTable, key_names: List[str],
     (padding masked off)."""
     n = mesh.shape[axis]
     names = table.names
+    tracer = get_tracer()
     key = _program_key(table, key_names, mesh, axis, quota)
     prog = _PROGRAMS.get(key)
     if prog is None:
@@ -176,7 +191,8 @@ def ici_all_to_all_exchange(table: DeviceTable, key_names: List[str],
         # phase: folding it into ``dispatch`` would read cold caches as
         # shuffle wall and trip the sentinel's shuffle-wall gate
         t0 = telemetry.clock()
-        prog = fn.lower(table.columns, table.row_mask).compile()
+        with tracer.span("compile", "compile", program=_PROGRAM):
+            prog = fn.lower(table.columns, table.row_mask).compile()
         telemetry.note_transfer("ici", "compile", shuffle_id=telemetry_sid,
                                 t0=t0, queue_depth=n)
         _PROGRAMS[key] = prog
@@ -189,7 +205,9 @@ def ici_all_to_all_exchange(table: DeviceTable, key_names: List[str],
     # input actually crossing ICI links (vs the pre-padding logical bytes
     # the exchange exec notes at enqueue)
     t0 = telemetry.clock()
-    out_cols, mask = prog(table.columns, table.row_mask)
+    with tracer.span("dispatch", "dispatch", program=_PROGRAM,
+                     bytes=_crossing_bytes(table, n, quota)):
+        out_cols, mask = prog(table.columns, table.row_mask)
     telemetry.note_transfer("ici", "dispatch", shuffle_id=telemetry_sid,
                             t0=t0, queue_depth=n,
                             wire_bytes=lambda: table.nbytes())

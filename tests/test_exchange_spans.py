@@ -1,0 +1,217 @@
+"""The exchange path as the tracer sees it: spans ``exchange.count`` /
+``.shard`` / ``.split`` / ``.gather`` with their bytes, the exchange's
+downloads under ``sync`` / ``d2h``, a ``dispatch`` span for each of the three
+mesh programs (the all-to-all's carries the bytes handed over), the
+partition-id program traced once per shape, and the single-partition gather
+kept on the device under a mesh."""
+import jax
+import numpy as np
+import pyarrow as pa
+import pytest
+
+from spark_rapids_tpu.expr.functions import col, sum as fsum
+from spark_rapids_tpu.session import TpuSession
+from spark_rapids_tpu.utils.tracing import get_tracer
+
+N = 4   # of conftest's 8 virtual devices
+
+
+def session(mesh=True, **extra):
+    from spark_rapids_tpu.parallel.mesh import data_parallel_mesh
+    if len(jax.devices()) < N:
+        pytest.skip(f"needs {N} virtual devices")
+    sess = TpuSession({
+        "spark.rapids.tpu.batchRowsMinBucket": 8,
+        "spark.rapids.tpu.shuffle.partitions": N,
+        "spark.rapids.sql.test.enabled": True,
+        # the static lowering: with AQE every exchange is a stage of its
+        # own and no mesh stage is planned (tests/benchmark has that plan)
+        "spark.rapids.tpu.aqe.enabled": False,
+        **extra})
+    if mesh:
+        sess.attach_mesh(data_parallel_mesh(N))
+    return sess
+
+
+def table(seed=0, rows=600):
+    rng = np.random.default_rng(seed)
+    return pa.table({"k": rng.integers(0, 40, rows),
+                     "v": rng.uniform(0, 10, rows)})
+
+
+def group_by(sess, t):
+    df = sess.create_dataframe(t, num_partitions=3)
+    return df.group_by("k").agg(fsum(col("v")).alias("s"))
+
+
+def count_chunks(monkeypatch):
+    from spark_rapids_tpu.exec.exchange import TpuShuffleExchangeExec
+    chunks = []
+    real = TpuShuffleExchangeExec._exchange_chunk
+
+    def counted(self, batches, shards):
+        chunks.append(self)
+        return real(self, batches, shards)
+    monkeypatch.setattr(TpuShuffleExchangeExec, "_exchange_chunk", counted)
+    return chunks
+
+
+@pytest.mark.parametrize("mesh_stage", [True, False],
+                         ids=["kept-sharded", "split"])
+def test_an_exchanged_chunk_books_its_spans_and_its_downloads(
+        monkeypatch, mesh_stage):
+    sess = session(**{
+        "spark.rapids.tpu.mesh.stageExecution.enabled": mesh_stage})
+    chunks = count_chunks(monkeypatch)
+    t = table()
+    got = group_by(sess, t).collect().to_pandas()
+    want = t.to_pandas().groupby("k", as_index=False).agg(s=("v", "sum"))
+    got = got.sort_values("k").reset_index(drop=True)
+    assert got.k.tolist() == want.k.tolist()
+    assert np.allclose(got.s, want.s, rtol=1e-12)
+    n = len(chunks)
+    assert n >= 1
+    phases = sess.last_query_phases()["phases"]
+    for name in ("exchange.count", "exchange.shard"):
+        assert phases[name]["calls"] == n, (name, phases[name])
+        assert phases[name]["bytes"] > 0, name
+    # the all-to-all's dispatch is the one dispatch that carries bytes: the
+    # padded slots handed over, n x quota a shard
+    assert phases["dispatch"]["bytes"] > 0
+    # kept sharded for a mesh stage, a chunk is not split
+    assert phases.get("exchange.split", {"calls": 0})["calls"] \
+        == (0 if mesh_stage else n)
+    # the partition-id download of every chunk is a d2h of 4 bytes a row,
+    # its row-count sync a sync: what host_syncs_per_query counts
+    assert phases["exchange.count"]["bytes"] % 4 == 0
+    assert phases["d2h"]["bytes"] >= phases["exchange.count"]["bytes"]
+    assert phases["d2h"]["calls"] >= n + 1      # + the answer's download
+    assert phases["sync"]["calls"] >= n
+    shuffle_bytes = sum(x.metrics.snapshot()["shuffleBytes"]
+                        for x in set(chunks))
+    assert phases["exchange.shard"]["bytes"] == shuffle_bytes
+    sess.close()
+
+
+def test_each_mesh_program_is_a_dispatch_span_under_its_name():
+    sess = session()
+    tracer = get_tracer()
+    was = tracer.enabled
+    tracer.enabled = True
+    tracer.clear()
+    try:
+        group_by(sess, table(1)).collect()
+        programs = [e.args.get("program") for e in tracer.events()
+                    if e.name == "dispatch"]
+    finally:
+        tracer.enabled = was
+        tracer.clear()
+        sess.close()
+    assert {"srt_exchange_pid", "srt_ici_all_to_all",
+            "srt_mesh_stage"} <= set(programs), sorted(set(programs))
+    calls = sess.last_query_phases()["phases"]["dispatch"]["calls"]
+    assert calls == len(programs)
+
+
+def test_two_exchanges_of_one_shape_trace_the_partition_id_program_once(
+        monkeypatch):
+    """The count pass used to wrap a fresh lambda in a fresh jax.jit for
+    every chunk: a re-trace and a compile request (served from XLA's cache,
+    but asked) per chunk of every query."""
+    from spark_rapids_tpu.shuffle import manager
+    from spark_rapids_tpu.utils.compile_cache import clear_cache
+    clear_cache()
+    jax.clear_caches()
+    traces = []
+    real = manager.device_partition_ids
+
+    def traced(t, keys, n):
+        traces.append((tuple(keys), n, t.capacity))
+        return real(t, keys, n)
+    # shuffle/ici.py holds its own reference: only the count pass's program
+    # looks the function up when it is built
+    monkeypatch.setattr(manager, "device_partition_ids", traced)
+    sess = session()
+    chunks = count_chunks(monkeypatch)
+    t = table(2)
+    # a cold and a warm query: the chunk shapes a query of this table has
+    # (the entry's first call is lowered once more for its cost analysis)
+    for _ in range(2):
+        group_by(sess, t).collect()
+    assert 1 <= len(traces) <= 3, traces
+    warm = len(traces)
+    for _ in range(3):
+        group_by(sess, t).collect()
+    sess.close()
+    assert len(chunks) == 5 and len(set(chunks)) == 5   # five exchanges
+    assert len(traces) == warm, traces
+
+
+def top_n(sess, t):
+    df = sess.create_dataframe(t, num_partitions=3)
+    return (df.group_by("k").agg(fsum(col("v")).alias("s"))
+              .sort(col("s").desc()).limit(5))
+
+
+def nodes(plan):
+    return [ln.split("[")[0].split()[0]
+            for ln in plan.tree_string().splitlines() if ln.strip()]
+
+
+@pytest.mark.parametrize("aqe", [False, True], ids=["static", "aqe"])
+def test_the_single_partition_gather_stays_on_the_device_under_a_mesh(
+        monkeypatch, aqe):
+    from spark_rapids_tpu.exec.exchange import TpuLocalExchangeExec
+    t = table(5)
+    alone = session(mesh=False)
+    want = top_n(alone, t).collect().to_pandas()
+    alone.close()
+    registered = []     # the devices of every batch the gather registers
+    own = TpuLocalExchangeExec._own_spill_handle
+    monkeypatch.setattr(
+        TpuLocalExchangeExec, "_own_spill_handle",
+        lambda self, h: (registered.append(h.get().row_mask.devices()),
+                         own(self, h))[1])
+
+    sess = session(**{"spark.rapids.tpu.aqe.enabled": aqe})
+    executed = []
+    physical = sess._physical
+    sess._physical = lambda *a, **k: (executed.append(physical(*a, **k)),
+                                      executed[-1])[1]
+    got = top_n(sess, t).collect().to_pandas()
+    plan = executed[-1]
+    if hasattr(plan, "final_plan"):
+        plan = plan.final_plan()
+    names = nodes(plan)
+    assert "ShuffleExchangeExec" not in names, plan.tree_string()
+    assert "TpuLocalExchangeExec" in names and "TpuShuffleExchangeExec" in names
+
+    def find(node):
+        if isinstance(node, TpuLocalExchangeExec):
+            return node
+        kids = list(node.children) + [getattr(node, a) for a in
+                                      ("inner", "stage") if hasattr(node, a)]
+        return next((f for f in map(find, kids) if f is not None), None)
+    gather = find(plan)
+    first = sess.shuffle_mesh().devices.flat[0]
+    assert gather.gather_device == first
+    assert registered and set().union(*registered) == {first}
+    phases = sess.last_query_phases()["phases"]
+    assert phases["exchange.gather"]["calls"] == len(registered)
+    assert phases["exchange.gather"]["bytes"] \
+        == gather.metrics.snapshot()["shuffleBytes"]
+    sess.close()
+    assert got.k.tolist() == want.k.tolist()
+    assert np.allclose(got.s, want.s, rtol=1e-12)
+
+
+def test_range_partitioning_still_stays_on_the_host_tier_under_a_mesh():
+    sess = session(**{"spark.rapids.sql.test.enabled": False})
+    df = sess.create_dataframe(table(6), num_partitions=3)
+    q = df.sort(col("v").asc())
+    plan = sess._physical(q.logical, device=True)
+    names = nodes(plan)
+    sess.close()
+    if "ShuffleExchangeExec" not in names:
+        pytest.skip("the planner sorted without a range exchange")
+    assert "TpuLocalExchangeExec" not in names
