@@ -204,19 +204,47 @@ def canonical_names(n: int) -> Tuple[str, ...]:
     return tuple(f"c{i}" for i in range(n))
 
 
+#: Block length of ``prefix_sum``.
+_SCAN_BLOCK = 1024
+
+
+def prefix_sum(x: jax.Array) -> jax.Array:
+    """Inclusive prefix sum along the last axis: log-step shifted adds
+    inside blocks of 1,024, the block totals scanned the same way and
+    added back. ``jnp.cumsum`` over 2^20 elements computes the same no
+    faster on the chip (0.8-1.3 ms against 0.6-0.8) and takes the TPU's
+    compiler 17-31 s a program where this takes under a second (PERF.md,
+    PR 27): keep ``jnp.cumsum`` off row-capacity vectors."""
+    n = x.shape[-1]
+    lead = [(0, 0)] * (x.ndim - 1)
+    if n > _SCAN_BLOCK:
+        blocks = jnp.pad(x, lead + [(0, -n % _SCAN_BLOCK)]).reshape(
+            x.shape[:-1] + (-1, _SCAN_BLOCK))
+        inner = prefix_sum(blocks)
+        totals = inner[..., -1]
+        before = prefix_sum(totals) - totals
+        return (inner + before[..., None]).reshape(
+            x.shape[:-1] + (-1,))[..., :n]
+    step = 1
+    while step < n:
+        x = x + jnp.pad(x, lead + [(step, 0)])[..., :n]
+        step *= 2
+    return x
+
+
 def stable_partition_order(mask: jax.Array) -> jax.Array:
     """Sort-free stable-partition permutation: gather indices that put
     mask=True rows first, preserving relative order in both segments —
-    identical to ``argsort(!mask, stable=True)`` but built from two
-    cumsums + one scatter (O(n) work, and no lax.sort in the program —
-    sorts are the pathological op for some TPU toolchains)."""
+    identical to ``argsort(!mask, stable=True)`` but built from one
+    prefix sum + one scatter (O(n) work, and no lax.sort in the program —
+    sorts are the pathological op for some TPU toolchains). A dropped
+    row's rank among the dropped is its index minus the kept before it."""
     n = mask.shape[0]
     m32 = mask.astype(jnp.int32)
-    kept_rank = jnp.cumsum(m32) - m32
-    n_keep = jnp.sum(m32)
-    drop_rank = jnp.cumsum(1 - m32) - (1 - m32)
-    dest = jnp.where(mask, kept_rank, n_keep + drop_rank)
+    kept_before = prefix_sum(m32) - m32
     iota = jnp.arange(n, dtype=jnp.int32)
+    n_keep = jnp.sum(m32)
+    dest = jnp.where(mask, kept_before, n_keep + iota - kept_before)
     return jnp.zeros(n, dtype=jnp.int32).at[dest].set(iota)
 
 
@@ -241,12 +269,14 @@ def stable_counting_order(keys: jax.Array, num_vals: int) -> jax.Array:
     return jnp.zeros(n, dtype=jnp.int32).at[dest].set(iota)
 
 
-def _compact_impl(table: "DeviceTable") -> "DeviceTable":
-    order = stable_partition_order(table.row_mask)
+def _gather_rows(table: "DeviceTable", order: jax.Array) -> "DeviceTable":
+    """The rows ``order`` names, in that order, as a table of
+    ``len(order)`` rows whose live rows are the first ``num_rows``: what
+    both compaction programs do once they hold their gather indices."""
     # permutation + re-mask below: only real rows stay exposed
     cols = tuple(c.gather(order, keep_all_valid=True)
                  for c in table.columns)
-    iota = jnp.arange(table.capacity, dtype=jnp.int32)
+    iota = jnp.arange(order.shape[0], dtype=jnp.int32)
     mask = iota < table.num_rows
     # masked-off tail keeps stale data; null it for hygiene
     cols = tuple(c.with_validity(jnp.logical_and(c.validity, mask),
@@ -255,7 +285,31 @@ def _compact_impl(table: "DeviceTable") -> "DeviceTable":
     return DeviceTable(cols, mask, table.num_rows, table.names)
 
 
+def _compact_impl(table: "DeviceTable") -> "DeviceTable":
+    return _gather_rows(table, stable_partition_order(table.row_mask))
+
+
+def _compact_shrink_impl(table: "DeviceTable", out_cap: int
+                         ) -> "DeviceTable":
+    """``compact()`` cut to ``out_cap`` rows, built without touching more
+    than ``out_cap`` rows of any column: kept rows scatter their own index
+    to their rank among the kept, dropped rows (and kept ones past
+    ``out_cap``) to a slot that ``mode="drop"`` discards, and every array
+    is gathered at those ``out_cap`` indices."""
+    mask = table.row_mask
+    with jax.named_scope("shrink_index"):
+        m32 = mask.astype(jnp.int32)
+        dest = jnp.where(mask, prefix_sum(m32) - m32, out_cap)
+        iota = jnp.arange(mask.shape[0], dtype=jnp.int32)
+        order = jnp.zeros(out_cap, dtype=jnp.int32).at[dest].set(
+            iota, mode="drop")
+    with jax.named_scope("shrink_gather"):
+        return _gather_rows(table, order)
+
+
 _compact_jitted = named_program(_compact_impl, "compact")
+_compact_shrink_jitted = named_program(_compact_shrink_impl, "compact_shrink",
+                                       static_argnums=(1,))
 
 
 # ---------------------------------------------------------------------------
@@ -1055,41 +1109,32 @@ _slice_rows_jitted = named_program(_slice_rows_impl, "slice_rows",
 
 def shrink_to_fit(table: DeviceTable, min_bucket: Optional[int] = None,
                   num_rows: Optional[int] = None) -> DeviceTable:
-    """Compact and shrink capacity to the bucket of the active row count.
+    """Compact and shrink capacity to the bucket of the active row count,
+    in one program that builds only the rows it keeps (``compact_shrink``).
 
     Syncs the row count to host (one int) — used between pipeline steps to
     stop capacities from growing across incremental merges. Callers that
-    already hold the host count pass ``num_rows`` to skip the sync."""
+    already hold the host count pass ``num_rows`` to skip the sync. Span
+    ``shrink`` (``rows_in``, ``rows_out``) when the program runs,
+    ``shrink.skip`` when the table already fits its bucket."""
     min_bucket = resolve_min_bucket(min_bucket)
-    if table.capacity <= min_bucket:
-        return table  # cannot shrink below one bucket: skip the device sync
-    if num_rows is not None:
-        n = num_rows
-    else:
-        t0 = movement.clock()
-        with get_tracer().span("sync", "download", scalars=1):
-            n = int(table.num_rows)  # srtpu: sync-ok(capacity choice needs the host count; callers with one pass it in)
-        movement.note_d2h(_MOVE_SHRINK, 4, t0)
-    cap = bucket_rows(max(n, 1), min_bucket)
-    if cap >= table.capacity:
+    tracer = get_tracer()
+    # cannot shrink below one bucket: skip the device sync too
+    if table.capacity > min_bucket:
+        if num_rows is not None:
+            n = num_rows
+        else:
+            t0 = movement.clock()
+            with tracer.span("sync", "download", scalars=1):
+                n = int(table.num_rows)  # srtpu: sync-ok(capacity choice needs the host count; callers with one pass it in)
+            movement.note_d2h(_MOVE_SHRINK, 4, t0)
+        cap = bucket_rows(max(n, 1), min_bucket)
+        if cap < table.capacity:
+            with tracer.span("shrink", "shrink", rows_in=table.capacity,
+                             rows_out=cap):
+                return _compact_shrink_jitted(table, cap)
+    with tracer.span("shrink.skip", "shrink", rows_in=table.capacity):
         return table
-    compacted = table.compact()
-
-    def cut(a):
-        return a[:cap]
-
-    def cut_col(c: DeviceColumn) -> DeviceColumn:
-        return DeviceColumn(cut(c.data), cut(c.validity), c.dtype,
-                            None if c.lengths is None else cut(c.lengths),
-                            None if c.elem_validity is None
-                            else cut(c.elem_validity),
-                            None if c.children is None
-                            else tuple(cut_col(k) for k in c.children),
-                            c.all_valid)
-
-    cols = tuple(cut_col(c) for c in compacted.columns)
-    return DeviceTable(cols, cut(compacted.row_mask),
-                       compacted.num_rows, compacted.names)
 
 
 def append_column(table: DeviceTable, name: str, col: DeviceColumn

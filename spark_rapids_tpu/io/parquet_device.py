@@ -486,35 +486,6 @@ def _pow2(n: int) -> int:
     return c
 
 
-#: Block length of ``_prefix_sum``.
-_SCAN_BLOCK = 1024
-
-
-def _prefix_sum(x):
-    """Inclusive prefix sum along the last axis: log-step shifted adds
-    inside blocks of 1,024, the block totals scanned the same way and
-    added back. ``jnp.cumsum`` over 2^20 elements computes the same no
-    faster on the chip (0.8-1.3 ms against 0.6-0.8) and takes the TPU's
-    compiler 17-31 s a program where this takes under a second (PERF.md,
-    PR 27): it was most of every decode program's compile time."""
-    import jax.numpy as jnp
-    n = x.shape[-1]
-    lead = [(0, 0)] * (x.ndim - 1)
-    if n > _SCAN_BLOCK:
-        blocks = jnp.pad(x, lead + [(0, -n % _SCAN_BLOCK)]).reshape(
-            x.shape[:-1] + (-1, _SCAN_BLOCK))
-        inner = _prefix_sum(blocks)
-        totals = inner[..., -1]
-        before = _prefix_sum(totals) - totals
-        return (inner + before[..., None]).reshape(
-            x.shape[:-1] + (-1,))[..., :n]
-    step = 1
-    while step < n:
-        x = x + jnp.pad(x, lead + [(step, 0)])[..., :n]
-        step *= 2
-    return x
-
-
 def _unpack_fields(packed_t, widths: Tuple[int, ...]):
     """Every bit field of the blobs, densely: for each width w the w byte
     columns of G groups give the 8 fields of every group by static shifts
@@ -549,11 +520,12 @@ def _expand_runs(starts, deltas, packed_t, widths: Tuple[int, ...],
     group. Positions past the stream's total hold garbage; callers mask."""
     import jax
     import jax.numpy as jnp
+    from ..columnar.device import prefix_sum
     with jax.named_scope("pq_run_prefix_sum"):
         scattered = jnp.zeros((deltas.shape[0], cap), jnp.int32) \
             .at[:, starts].add(deltas, mode="drop", indices_are_sorted=True,
                                unique_indices=True)
-        attrs = _prefix_sum(scattered)
+        attrs = prefix_sum(scattered)
     rle = attrs[1] - 1          # an RLE run's value, -1 in bit-packed runs
     if not widths:
         return rle
@@ -576,13 +548,14 @@ def _validity_and_pos(defs, def_widths: Optional[Tuple[int, ...]], n,
     (returned as None), and no definition-level op is traced."""
     import jax
     import jax.numpy as jnp
+    from ..columnar.device import prefix_sum
     in_rows = jax.lax.iota(jnp.int32, cap) < n
     if def_widths is None:
         return in_rows, None
     with jax.named_scope("pq_def_levels"):
         validity = jnp.logical_and(
             _expand_runs(*defs, def_widths, cap) > 0, in_rows)
-        return validity, _prefix_sum(validity.astype(jnp.int32)) - 1
+        return validity, prefix_sum(validity.astype(jnp.int32)) - 1
 
 
 def _after_dict(plain, n_dict, cap: int):

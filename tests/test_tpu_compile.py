@@ -181,6 +181,24 @@ def test_top_n_chunk_sort(topo, one_chip, rng):
     _compile(node._topn_fn("|test"), (batch,), one_chip)
 
 
+@pytest.mark.parametrize("out_cap", [1 << 10, 1 << 17])
+def test_compact_shrink_at_a_full_batch(topo, one_chip, rng, out_cap):
+    """``shrink_to_fit``'s program at the real 2^20-row batch, into Q1's
+    1,024-row bucket and into a join output's 2^17: seconds to compile
+    (the ``jnp.cumsum`` form of the permutation took this compiler 16-19 s),
+    and no loop in what it compiled."""
+    from spark_rapids_tpu.columnar.device import (DeviceTable,
+                                                  _compact_shrink_impl)
+    from spark_rapids_tpu.columnar.host import HostTable
+    small = DeviceTable.from_host(HostTable.from_arrow(_table(rng, 8)))
+    full = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct((1 << 20,) + x.shape[1:], x.dtype)
+        if getattr(x, "ndim", 0) else x, small)
+    compiled = _compile(_compact_shrink_impl, (full, out_cap), one_chip,
+                        static_argnums=(1,))
+    assert "while" not in compiled.as_text()
+
+
 def test_pallas_axpy_full_column(topo, one_chip, monkeypatch):
     """The gridded Pallas kernel at a 2^23-row SF1 lineitem bucket: the
     ungridded kernel ran out of VMEM from 2^22 rows up."""
