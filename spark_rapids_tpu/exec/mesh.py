@@ -3,9 +3,8 @@
 The ICI exchange (shuffle/ici.py) re-homes rows with a single
 ``jax.lax.all_to_all``, but the per-partition consumer contract then
 breaks its output into n per-device partitions that downstream operators
-drain as n SEQUENTIAL single-device programs — on an 8-device mesh
-~80-90% of MULTICHIP wall is serialized compute, not shuffle
-(MULTICHIP_r06.json: shuffle_wall_frac 0.11-0.21). This module closes
+drain as n SEQUENTIAL single-device programs: serialized compute, not
+shuffle, is then most of a multi-device query's wall. This module closes
 that gap, the TPU analogue of the reference's "partitioned operators run
 on all executors at once" property (SURVEY §2.7, the point of the UCX
 tier): ``TpuMeshStageExec`` takes the exchange's output STILL sharded
@@ -34,13 +33,13 @@ quarantine store (exec/fallback.py) so the next session plans around it.
 
 Telemetry: the mesh dispatch notes a ``mesh_stage`` phase and the
 one-time XLA build a ``compile`` phase on the ici tier, so
-shuffle_summary's tier breakdown reconciles post-exchange compute that
-rides the collective program cache.
+shuffle_summary's tier breakdown reconciles post-exchange compute. The
+executable is kept by ``compile_cache.aot_program``, beside the
+all-to-all's.
 """
 from __future__ import annotations
 
 import threading
-from collections import OrderedDict
 from typing import Iterator, List, Optional
 
 import jax
@@ -53,14 +52,13 @@ from ..conf import register_conf
 from ..shuffle import telemetry as shuffle_telemetry
 from ..utils import faults
 from ..utils import metrics as M
-from ..utils.compile_cache import named_jit
+from ..utils.compile_cache import aot_program, named_jit
 from ..utils.tracing import get_tracer
 from .base import TpuExec
 from .exchange import TpuShuffleExchangeExec, _split_sharded
 from .wholestage import TpuWholeStageExec, _fusible, _with_children
 
-__all__ = ["TpuMeshStageExec", "plan_mesh_stages", "MESH_STAGE_ENABLED",
-           "clear_mesh_programs"]
+__all__ = ["TpuMeshStageExec", "plan_mesh_stages", "MESH_STAGE_ENABLED"]
 
 MESH_STAGE_ENABLED = register_conf(
     "spark.rapids.tpu.mesh.stageExecution.enabled",
@@ -72,19 +70,7 @@ MESH_STAGE_ENABLED = register_conf(
     "exchange runs on the ICI tier (session has a mesh); non-mesh plans "
     "and non-fusible consumers keep the per-partition path.", True)
 
-# Mesh-stage programs are AOT-compiled (lower + compile) and cached by
-# semantic key — same design as the exchange program cache
-# (shuffle/ici.py): repeated same-shape stages reuse the executable, and
-# the one-time XLA compile is timed as its own observatory phase.
-_PROGRAMS: "OrderedDict[tuple, object]" = OrderedDict()
-_PROGRAMS_MAX = 64
 _PROGRAM = "srt_mesh_stage"   # what its dispatch and compile spans carry
-
-
-def clear_mesh_programs() -> None:
-    """Drop cached mesh-stage executables (test hygiene: compiled-program
-    caches accumulate per shape family, tests/conftest.py)."""
-    _PROGRAMS.clear()
 
 
 def _is_final_agg(node) -> bool:
@@ -287,10 +273,19 @@ class TpuMeshStageExec(TpuExec):
                str(treedef),
                tuple((l.shape, str(l.dtype)) for l in leaves),
                (chunk.row_mask.shape, str(chunk.row_mask.dtype)))
-        prog = _PROGRAMS.get(key)
-        if prog is not None:
-            _PROGRAMS.move_to_end(key)
-            return prog
+        t0 = shuffle_telemetry.clock()
+        prog, compiled = aot_program(
+            key, lambda: self._build(chunk),
+            (chunk.columns, chunk.row_mask), name="mesh_stage")
+        if compiled:
+            shuffle_telemetry.note_transfer(
+                "ici", "compile", shuffle_id=self.exchange.telemetry_sid,
+                t0=t0, queue_depth=self.num_partitions)
+        return prog
+
+    def _build(self, chunk: DeviceTable):
+        """The chain as one ``shard_map`` function over the mesh, for
+        tables shaped like ``chunk``."""
         names = chunk.names
         axis = self.axis
         fns = [(type(node).__name__, node.batch_fn())
@@ -307,20 +302,10 @@ class TpuMeshStageExec(TpuExec):
         col_specs = jax.tree_util.tree_map(lambda _: P(axis), chunk.columns)
         # check_vma off: the output specs are data-dependent in ways the
         # static replication checker rejects
-        fn = named_jit(jax.shard_map(local, mesh=self.mesh,
-                                     in_specs=(col_specs, P(axis)),
-                                     out_specs=(P(axis), P(axis)),
-                                     check_vma=False), "mesh_stage")
-        t0 = shuffle_telemetry.clock()
-        with get_tracer().span("compile", "compile", program=_PROGRAM):
-            prog = fn.lower(chunk.columns, chunk.row_mask).compile()
-        shuffle_telemetry.note_transfer(
-            "ici", "compile", shuffle_id=self.exchange.telemetry_sid,
-            t0=t0, queue_depth=self.num_partitions)
-        _PROGRAMS[key] = prog
-        while len(_PROGRAMS) > _PROGRAMS_MAX:
-            _PROGRAMS.popitem(last=False)
-        return prog
+        return named_jit(jax.shard_map(local, mesh=self.mesh,
+                                       in_specs=(col_specs, P(axis)),
+                                       out_specs=(P(axis), P(axis)),
+                                       check_vma=False), "mesh_stage")
 
 
 def plan_mesh_stages(plan, conf=None):

@@ -135,7 +135,7 @@ def configure_oom_retry(conf) -> None:
 # ---------------------------------------------------------------------------
 #: Runtime/allocator substrings that mark an exception as device OOM.
 #: "cannot fit" is the strict-pool MemoryError from BufferCatalog.register
-#: — without it a pinned-HBM-limit run (BENCH_OOM) could never retry.
+#: — without it a pinned-HBM-limit run could never retry.
 #: "Failed to allocate" covers the XLA allocator variants surfaced under
 #: an INTERNAL status ("INTERNAL: Failed to allocate 123B ...") — those
 #: are memory pressure, not engine bugs, and must walk the ladder before
@@ -652,8 +652,8 @@ def _concat_combine(outs: Sequence[Any]):
 # jit chokepoint wrappers (utils/compile_cache.py)
 # ---------------------------------------------------------------------------
 def wrap_jit(fn: Callable, context: Optional[str] = None) -> Callable:
-    """Spill-and-retry OOM recovery around a jitted callable (replaces
-    compile_cache.oom_retry; reference: DeviceMemoryEventHandler.scala:33).
+    """Spill-and-retry OOM recovery around a jitted callable (reference:
+    DeviceMemoryEventHandler.scala:33).
     Splitting stays at the operator layer — this wrapper raises a
     retryable :class:`DeviceOomError` on exhaustion, which an enclosing
     with_retry_split scope escalates to split-and-retry."""

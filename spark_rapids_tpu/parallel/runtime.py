@@ -19,6 +19,7 @@ program.
 """
 from __future__ import annotations
 
+import collections
 import itertools
 import threading
 from concurrent.futures import ThreadPoolExecutor
@@ -340,7 +341,14 @@ class ProcessCluster:
         from ..utils.tracing import TRACE_CLOCK_PROBES, TRACE_DISTRIBUTED
         self._mp = mp.get_context("spawn")
         self._addr_q = self._mp.Queue()
-        self._result_q = self._mp.Queue()
+        # one result queue a worker, never one for all: a queue's write
+        # lock is shared by its writers and dies with a holder. A worker
+        # terminated right after the driver read its answer still holds it
+        # four times in ten on a loaded box (the feeder thread releases it
+        # after the bytes are out), and every other worker's heartbeats
+        # and answers would then block behind it for good
+        self._result_qs = [self._mp.Queue() for _ in range(n_executors)]
+        self._results: collections.deque = collections.deque()
         self._task_qs = [self._mp.Queue() for _ in range(n_executors)]
         self._conf_values = dict(conf or {})
         rconf = RapidsConf(self._conf_values)
@@ -385,7 +393,23 @@ class ProcessCluster:
         return self._mp.Process(
             target=_worker_main,
             args=(worker, self._conf_values, self._addr_q,
-                  self._task_qs[worker], self._result_q), daemon=True)
+                  self._task_qs[worker], self._result_qs[worker]),
+            daemon=True)
+
+    def _next_result(self, timeout_s: float):
+        """The next record any live worker put on its result queue;
+        ``queue.Empty`` when none comes within ``timeout_s``. The queue of
+        an excluded worker is never read again."""
+        import queue as _queue
+        from multiprocessing.connection import wait
+        if not self._results:
+            readers = {q._reader: q for i, q in enumerate(self._result_qs)
+                       if i not in self._excluded}
+            for r in wait(list(readers), timeout_s):
+                self._results.append(readers[r].get())
+        if not self._results:
+            raise _queue.Empty
+        return self._results.popleft()
 
     def live_workers(self) -> List[int]:
         return [i for i, p in enumerate(self.procs)
@@ -438,7 +462,7 @@ class ProcessCluster:
             self.detector.heartbeat(w)
         while tid not in self._done:
             try:
-                got_tid, status, value = self._result_q.get(timeout=0.2)
+                got_tid, status, value = self._next_result(0.2)
             except _queue.Empty:
                 self._check_workers()
                 if time.monotonic() >= deadline:
@@ -548,12 +572,14 @@ class ProcessCluster:
 
     def _respawn_worker(self, worker: int):
         """Replace a dead worker with a fresh process on the same slot:
-        fresh task queue (the old one may hold stale envelopes), new
+        fresh task and result queues (the old ones may hold stale
+        envelopes, or a write lock the dead process took with it), new
         transport address announced to every surviving peer, clock offset
         re-estimated."""
         self._respawns[worker] = self._respawns.get(worker, 0) + 1
         old_q = self._task_qs[worker]
         self._task_qs[worker] = self._mp.Queue()
+        self._result_qs[worker] = self._mp.Queue()
         p = self._spawn_process(worker)
         self.procs[worker] = p
         p.start()

@@ -54,8 +54,8 @@ class TpuSession:
         # repeated queries land on repeatable XLA shapes
         from .columnar.device import configure_buckets
         configure_buckets(self.conf)
-        # persistent compilation tier (spark.rapids.tpu.compile.*): XLA
-        # disk cache + plan-signature manifest + warm-pool precompiler
+        # persistent compilation tier (spark.rapids.tpu.compile.*): where
+        # XLA's disk cache lives
         from .utils.compile_cache import configure_compile_cache
         configure_compile_cache(self.conf)
         # apply spark.rapids.tpu.pipeline.* to the pipelined executor
@@ -255,8 +255,7 @@ class TpuSession:
 
     def health_status(self) -> Dict:
         """The live /status snapshot as a dict (works whether or not the
-        monitor thread / HTTP server are running — bench.py captures one
-        per phase into the bench JSON)."""
+        monitor thread / HTTP server are running)."""
         health = getattr(self, "_health", None)
         if health is not None:
             return health.monitor.snapshot()
@@ -271,15 +270,8 @@ class TpuSession:
         if health is not None:
             health.close()
             self._health = None
-        # stop the warm-pool precompiler, then flush the persistent
-        # compile tier (manifest + program exports) while builders for
-        # this session's programs are still retained
-        from .utils.compile_cache import (persist_compile_cache,
-                                          stop_warm_pool)
-        stop_warm_pool()
-        persist_compile_cache()
-        # flush the operator-quarantine store next to the compile-cache
-        # manifest so the NEXT session plans known-bad operators on host
+        # flush the operator-quarantine store beside the compile cache so
+        # the NEXT session plans known-bad operators on host
         from .exec.fallback import persist_quarantine
         persist_quarantine()
         # cancel + join any straggling pipeline prefetch workers (queries

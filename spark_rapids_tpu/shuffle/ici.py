@@ -18,7 +18,6 @@ the CPU test mesh (tests/conftest.py).
 """
 from __future__ import annotations
 
-from collections import OrderedDict
 from typing import List
 
 import jax
@@ -30,13 +29,13 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..columnar.device import (DeviceColumn, DeviceTable,
                                stable_counting_order)
 from ..utils import movement
-from ..utils.compile_cache import named_jit
+from ..utils.compile_cache import aot_program, named_jit
 from ..utils.tracing import get_tracer
 from . import telemetry
 from .manager import device_partition_ids
 
 __all__ = ["ici_all_to_all_exchange", "exchange_program", "shard_table",
-           "unshard_table", "clear_exchange_programs"]
+           "unshard_table"]
 
 # movement-observatory site identity (utils/movement.py SITES)
 _MOVE_UNSHARD = "spark_rapids_tpu/shuffle/ici.py::unshard_table"
@@ -73,24 +72,6 @@ def unshard_table(table: DeviceTable) -> DeviceTable:
     cols = jax.tree_util.tree_map(jnp.asarray, host_cols)
     mask = jnp.asarray(host_mask)
     return DeviceTable(cols, mask, jnp.sum(mask, dtype=jnp.int32), table.names)
-
-
-# Exchange programs are AOT-compiled (lower + compile) and cached by
-# their semantic key so repeated same-shape exchanges reuse the
-# executable instead of re-tracing a fresh ``jax.jit`` closure per call,
-# and so the one-time XLA compile can be timed SEPARATELY from the
-# collective dispatch (the ``compile`` vs ``dispatch`` phase split in the
-# shuffle observatory — a cold cache must not read as shuffle wall).
-# Bounded LRU: shapes are bucketed upstream (quota bucketing,
-# exec/exchange.py), so a handful of entries covers a whole run.
-_PROGRAMS: "OrderedDict[tuple, object]" = OrderedDict()
-_PROGRAMS_MAX = 64
-
-
-def clear_exchange_programs() -> None:
-    """Drop cached exchange executables (test hygiene: compiled-program
-    caches accumulate per shape family, tests/conftest.py)."""
-    _PROGRAMS.clear()
 
 
 def _program_key(table: DeviceTable, key_names: List[str], mesh: Mesh,
@@ -182,24 +163,18 @@ def ici_all_to_all_exchange(table: DeviceTable, key_names: List[str],
     n = mesh.shape[axis]
     names = table.names
     tracer = get_tracer()
-    key = _program_key(table, key_names, mesh, axis, quota)
-    prog = _PROGRAMS.get(key)
-    if prog is None:
-        fn = exchange_program(table.columns, names, key_names, mesh, axis,
-                              quota)
-        # one-time lower + XLA compile, timed as its own observatory
-        # phase: folding it into ``dispatch`` would read cold caches as
-        # shuffle wall and trip the sentinel's shuffle-wall gate
-        t0 = telemetry.clock()
-        with tracer.span("compile", "compile", program=_PROGRAM):
-            prog = fn.lower(table.columns, table.row_mask).compile()
+    # the one-time lower + XLA compile is its own observatory phase:
+    # folded into ``dispatch`` a cold cache would read as shuffle wall and
+    # trip the sentinel's shuffle-wall gate
+    t0 = telemetry.clock()
+    prog, compiled = aot_program(
+        _program_key(table, key_names, mesh, axis, quota),
+        lambda: exchange_program(table.columns, names, key_names, mesh,
+                                 axis, quota),
+        (table.columns, table.row_mask), name="ici_all_to_all")
+    if compiled:
         telemetry.note_transfer("ici", "compile", shuffle_id=telemetry_sid,
                                 t0=t0, queue_depth=n)
-        _PROGRAMS[key] = prog
-        while len(_PROGRAMS) > _PROGRAMS_MAX:
-            _PROGRAMS.popitem(last=False)
-    else:
-        _PROGRAMS.move_to_end(key)
     # collective dispatch wall: dispatch of the all-to-all over n devices
     # (compile is its own phase above); wire bytes are the padded sharded
     # input actually crossing ICI links (vs the pre-padding logical bytes

@@ -4,7 +4,7 @@ ROADMAP item 3 (shuffle and scale-out) had zero measurement: none of
 the four shuffle tiers — ICI collectives (shuffle/ici.py), the cached
 device-resident tier, host-TCP transport (shuffle/tcp.py) and DCN
 (shuffle/dcn.py) — recorded per-transfer phase walls, wire bytes or
-queue/backpressure state, so a MULTICHIP timeout was an opaque rc=124.
+queue/backpressure state, so a multi-device timeout was an opaque rc=124.
 Theseus (PAPERS.md) argues data movement is *the* bottleneck of a
 distributed columnar engine and Thallus specifies exactly the
 per-transfer protocol telemetry this module records: every transfer at
@@ -113,7 +113,7 @@ class ShuffleObservatory:
     """Process-wide ledger of shuffle/collective transfers.
 
     Raw events land in a bounded ring (forensics: the exact transfer
-    sequence, dumped into MULTICHIP timeout diagnostics); exact
+    sequence, for timeout diagnostics); exact
     aggregation is kept per (query, tier) and per (query, shuffle,
     tier), with per-(shuffle, partition, tier) walls for straggler
     attribution. All state is lock-guarded — hooks fire from pipeline

@@ -409,8 +409,8 @@ class Report:
 
     def summary(self) -> Dict:
         """Per-check, per-severity counts + the top files by hot sync
-        debt — the shape bench.py copies into the bench JSON and
-        tools/diagnose.py cross-references against trace spans."""
+        debt — the shape tools/diagnose.py cross-references against
+        trace spans."""
         checks: Dict[str, Dict[str, int]] = {}
         for f in self.findings:
             c = checks.setdefault(f.check,
@@ -526,8 +526,8 @@ def compare_to_baseline(report: Report,
 
 def baseline_summary(path: Optional[str] = None) -> Dict:
     """The committed baseline's summary block (plus initial inventory) —
-    what bench.py records so sync-site count becomes a tracked
-    trajectory metric. Never raises: {} when absent/corrupt."""
+    so sync-site count can be tracked as a trajectory metric. Never
+    raises: {} when absent/corrupt."""
     try:
         data = load_baseline(path)
     except (OSError, ValueError):

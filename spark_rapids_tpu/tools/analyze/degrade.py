@@ -51,7 +51,7 @@ SWALLOW_SEVERITIES = ("hot", "warm")
 #: (mirrors retry_scope._RETRY_API — the two checkers must agree on
 #: what "inside the ladder" means)
 _RETRY_API = ("with_retry", "with_retry_split", "wrap_jit",
-              "wrap_jit_donating", "oom_retry", "oom_spill_noretry")
+              "wrap_jit_donating")
 
 #: referencing any of these marks the scope chain as fallback-covered
 _DEGRADE_API = ("with_host_fallback", "quarantine_on_failure",

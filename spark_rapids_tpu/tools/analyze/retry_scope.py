@@ -20,8 +20,7 @@ unprotected surface statically:
 
 A scope counts as covered when it, or any enclosing function scope,
 references ``with_retry``/``with_retry_split``/``wrap_jit``/
-``wrap_jit_donating`` (or the compile_cache shims ``oom_retry``/
-``oom_spill_noretry``): closures dispatched by a sibling
+``wrap_jit_donating``: closures dispatched by a sibling
 ``with_retry_split`` call are defined in the covered enclosing scope, so
 the chain test follows the value flow the AST can see. Sites that are
 deliberately spill-only (merge kernels whose inputs cannot split,
@@ -46,7 +45,7 @@ REPORTED_SEVERITIES = ("hot",)
 
 #: referencing any of these marks the scope chain as retry-covered
 _RETRY_API = ("with_retry", "with_retry_split", "wrap_jit",
-              "wrap_jit_donating", "oom_retry", "oom_spill_noretry")
+              "wrap_jit_donating")
 
 
 class _RetryVisitor(ScopedVisitor):

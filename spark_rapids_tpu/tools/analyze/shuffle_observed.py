@@ -5,7 +5,7 @@ transfer cost, retries and stragglers are attributable from one place —
 but only for transfers that actually note it. A new chokepoint added to
 the shuffle package without a ``telemetry.note_transfer`` nearby is a
 blind spot: its bytes vanish from the event log's ``shuffle_summary``,
-the sentinel's shuffle-wall gate, and the MULTICHIP tier breakdown,
+the sentinel's shuffle-wall gate, and the per-tier breakdown,
 and the first anyone learns of it is a straggler nobody can attribute.
 
 Rule:

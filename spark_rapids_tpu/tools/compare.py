@@ -4,11 +4,12 @@ Reference: the plugin tools' CompareApplications
 (tools/.../profiling/CompareApplications.scala) lines up several Spark
 event logs and reports matching SQL IDs / stage durations side by side so
 a regression can be localized to an operator, not just a query. Same job
-here, over our own JSONL event logs (tools/eventlog.py) or two ``bench.py``
-result JSONs:
+here, over our own JSONL event logs (tools/eventlog.py) or two bench
+result JSONs (the per-query files of the pre-chip bench, deleted in PR 30:
+nothing writes them any more, ROADMAP C2c):
 
 - queries align by query id (the workloads are assumed to be the same
-  script run twice — exactly the BENCH_rNN trajectory use case);
+  script run twice);
 - operators align by (name, occurrence-index) within a query, which is
   stable across runs of the same plan even when node ids shift;
 - per-operator wall/rows deltas plus per-query counter deltas (compile
@@ -568,8 +569,8 @@ def compare_event_logs(path_a: str, path_b: str, threshold: float = 0.2,
 
 
 def _bench_memory(entry: Dict) -> Optional[Dict]:
-    """Per-query memory numbers from a bench JSON entry (bench.py writes
-    peak_hbm_bytes + spill_bytes when BENCH_MEMPROF is on)."""
+    """Per-query memory numbers from a bench JSON entry (peak_hbm_bytes +
+    spill_bytes, written when the memory profiler was on)."""
     if "peak_hbm_bytes" not in entry:
         return None
     return {"peak_bytes": int(entry.get("peak_hbm_bytes") or 0),
@@ -577,9 +578,9 @@ def _bench_memory(entry: Dict) -> Optional[Dict]:
 
 
 def _bench_movement(entry: Dict) -> Optional[Dict]:
-    """Per-query transfer numbers from a bench JSON entry (bench.py
-    writes d2h_bytes/h2d_bytes/round_trips when the movement ledger is
-    on)."""
+    """Per-query transfer numbers from a bench JSON entry
+    (d2h_bytes/h2d_bytes/round_trips, written when the movement ledger
+    was on)."""
     if "d2h_bytes" not in entry:
         return None
     return {"d2h_bytes": int(entry.get("d2h_bytes") or 0),
@@ -588,9 +589,9 @@ def _bench_movement(entry: Dict) -> Optional[Dict]:
 
 
 def _bench_shuffle(entry: Dict) -> Optional[Dict]:
-    """Per-query shuffle numbers from a bench JSON entry (bench.py
-    writes shuffle_wall_s/shuffle_wall_frac/wire_bytes when shuffle
-    telemetry is on)."""
+    """Per-query shuffle numbers from a bench JSON entry
+    (shuffle_wall_s/shuffle_wall_frac/wire_bytes, written when shuffle
+    telemetry was on)."""
     if "shuffle_wall_s" not in entry:
         return None
     return {"shuffle_wall_s": float(entry.get("shuffle_wall_s") or 0.0),
@@ -599,7 +600,7 @@ def _bench_shuffle(entry: Dict) -> Optional[Dict]:
 
 def compare_bench_results(path_a: str, path_b: str, threshold: float = 0.2,
                           min_seconds: float = 0.001) -> CompareReport:
-    """Compare two ``bench.py`` per-query result JSONs (the
+    """Compare two per-query bench result JSONs (the
     BENCH_partial.json shape, with smoke/tpch sections): device seconds as
     single-op queries so the same report/flagging machinery applies."""
     with open(path_a, encoding="utf-8") as f:

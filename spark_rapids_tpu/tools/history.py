@@ -117,7 +117,7 @@ SHUFFLE_WALL_KEY = "shuffle_telemetry_wall_s"
 SHUFFLE_WALL_FLAG_MIN_S = 0.05
 
 #: absolute growth floor for the aggregate total-wall gate (2 s): the
-#: MULTICHIP trajectory gate sums per-query walls across the run, so a
+#: trajectory gate sums per-query walls across the run, so a
 #: fleet-wide slowdown spread thinly over every query (each one under
 #: the per-query threshold) still flags, while compile-cache jitter on
 #: a single tiny query doesn't
@@ -381,7 +381,7 @@ def run_sentinel(store: HistoryStore,
     # recovery overhead on purpose — exempt it from every gate instead
     # of flagging the slowdown as a regression. Uninjected recovery
     # (fault records absent) still gates: that slowdown is real.
-    # v9: same exemption for queries the BENCH_OOM phase ran under a
+    # v9: same exemption for queries run on purpose under a
     # shrunken HBM pool — their oom_retry records (spills, retries,
     # splits) are deliberate pressure, not a regression.
     # v10: ditto for queries that recovered via host fallback — the
@@ -408,7 +408,7 @@ def run_sentinel(store: HistoryStore,
     shuffle_flags = [f for f in _count_gate(report, SHUFFLE_WALL_KEY,
                                             SHUFFLE_WALL_FLAG_MIN_S)
                      if f["query_id"] not in chaos_ok]
-    # v13: aggregate total-wall gate (the MULTICHIP trajectory number) —
+    # v13: aggregate total-wall gate (the multi-device trajectory number) —
     # per-query wall gates can miss a fleet-wide slowdown spread thinly
     # across the run; sum walls over the query ids present in BOTH runs
     # (chaos-exempt ones excluded, like every other gate) and flag

@@ -8,24 +8,20 @@ canonical plan signature so repeated queries hit steady-state dispatch
 (~0.1ms). The reference relies on cuDF's precompiled kernels; on TPU the
 compile-once-run-many discipline is ours to enforce.
 
-The cache is THREE tiers (ROADMAP item 2 — compile dominates bench wall):
+The cache is TWO tiers (docs/compilation.md):
 
-1. the in-process table above (``_CACHE``),
+1. the in-process tables: ``_CACHE`` (jitted callables by plan signature)
+   and ``_AOT`` (the mesh programs' AOT executables, ``aot_program``),
 2. XLA's own persistent compilation cache. Where the environment places
    it (``JAX_COMPILATION_CACHE_DIR``) it lives exactly there and the
    engine never touches ``jax_compilation_cache_dir``; otherwise it is
    wired under ``spark.rapids.tpu.compile.cacheDir``, scoped by a machine
-   fingerprint + jax version so foreign XLA:CPU executables never load,
-3. the engine's OWN manifest persisted alongside it: per plan signature,
-   cumulative hit counts plus a serialized ``jax.export`` of the traced
-   program at its first-call shapes. A fresh process replays the hottest
-   exports on background threads at session start (the warm pool,
-   ``spark.rapids.tpu.compile.warmPool.*``) and installs ready-to-dispatch
-   executables into ``_CACHE`` — the second run of a query in a NEW
-   process then executes with zero XLA compiles (``cache_stats()``).
+   fingerprint + jax version so foreign XLA:CPU executables never load.
 
-Every load path is corruption-tolerant: a bad manifest, entry, or export
-file is dropped (and counted), never fatal.
+There is no third: an engine-side replay of exported programs lost to
+tier 2 on the chip (PR 22: its executables changed every downstream HLO,
+so a second process hit XLA's cache 48 of 120 times against 112 of 112
+without it).
 
 Every jitted device computation flows through here, which makes it the
 TPU-native stand-in for RMM's allocation-failure callback (reference:
@@ -37,26 +33,23 @@ from __future__ import annotations
 
 import functools
 import hashlib
-import json
 import os
-import sys
 import threading
 import time
+from collections import OrderedDict
 from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 
 from ..conf import register_conf
 
-__all__ = ["cached_jit", "named_jit", "named_program", "PROGRAM_NAMES",
-           "cache_stats", "clear_cache", "oom_retry",
+__all__ = ["cached_jit", "named_jit", "named_program", "aot_program",
+           "PROGRAM_NAMES", "cache_stats", "clear_cache",
            "configure_introspection", "kernel_table", "kernel_seq",
            "kernels_since", "XLA_INTROSPECTION", "KERNEL_TABLE_SIZE",
-           "configure_compile_cache", "persist_compile_cache",
-           "machine_fingerprint", "warm_pool_wait", "stop_warm_pool",
+           "configure_compile_cache", "machine_fingerprint",
            "persistent_cache_dir", "COMPILE_CACHE_DIR",
-           "COMPILE_CACHE_ENABLED", "WARM_POOL_ENABLED",
-           "WARM_POOL_MAX_SIGNATURES", "WARM_POOL_MAX_SECONDS"]
+           "COMPILE_CACHE_ENABLED"]
 
 #: Every device program the engine compiles, by its stable name. XLA names
 #: a module after the jitted function (``jit_<__name__>``), so ``cached_jit``
@@ -105,7 +98,6 @@ PROGRAM_NAMES: Dict[str, str] = {
     "exchange_pid": "partition ids of one exchange chunk (exec/exchange.py)",
     "mesh_stage": "operator chain over a device mesh (exec/mesh.py)",
     "ici_all_to_all": "hash exchange as one all-to-all (shuffle/ici.py)",
-    "warm_replay": "a persisted export replayed by the warm pool",
 }
 _PROGRAM_PREFIX = "srt_"
 
@@ -130,8 +122,8 @@ def _named(fn: Callable, name: str) -> Callable:
 
 def named_jit(fn: Callable, name: str, **jit_kwargs) -> Callable:
     """``jax.jit(fn)`` compiled as module ``jit_srt_<name>`` — for the few
-    programs jitted outside ``cached_jit`` (module-level utilities, mesh
-    programs with caches of their own)."""
+    programs jitted outside ``cached_jit`` (module-level utilities, the
+    mesh programs kept by ``aot_program``)."""
     return jax.jit(_named(fn, name), **jit_kwargs)
 
 
@@ -209,8 +201,7 @@ COMPILE_CACHE_ENABLED = register_conf(
     "Master switch for the persistent compilation tier: when true AND a "
     "cache directory is known (the JAX_COMPILATION_CACHE_DIR environment "
     "variable, else spark.rapids.tpu.compile.cacheDir), XLA executables "
-    "persist across process restarts and the engine's plan-signature "
-    "manifest + program exports are saved on session close.",
+    "persist across process restarts.",
     True)
 
 COMPILE_CACHE_DIR = register_conf(
@@ -222,63 +213,26 @@ COMPILE_CACHE_DIR = register_conf(
     "filesystem can hold caches for a fleet and no host ever loads "
     "executables compiled for different CPU features or a different jax. "
     "Where the environment variable is set it wins: XLA's cache stays "
-    "exactly there, untouched by the engine, and the engine's manifest "
-    "and exports go under its 'srtpu' subdirectory.",
+    "exactly there, untouched by the engine, and the engine's own file "
+    "(quarantine.json) goes under its 'srtpu' subdirectory.",
     "")
 
 #: JAX reads this itself at import. A cache placed from outside has to be
 #: found again from another machine, so nothing machine-specific may enter
 #: the path and the engine must not re-point jax_compilation_cache_dir.
 _JAX_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
-#: the engine's own files (manifest, exports, quarantine) under that dir
+#: the engine's own file (exec/fallback.py's quarantine.json) under that dir
 _ENGINE_SUBDIR = "srtpu"
 
-WARM_POOL_ENABLED = register_conf(
-    "spark.rapids.tpu.compile.warmPool.enabled",
-    "Precompile the hottest persisted plan signatures on background "
-    "threads at session start (under the pipeline task pool), so even the "
-    "FIRST run of a repeated workload in a fresh process hits steady-state "
-    "dispatch. Requires compile.cacheDir.", True)
-
-WARM_POOL_MAX_SIGNATURES = register_conf(
-    "spark.rapids.tpu.compile.warmPool.maxSignatures",
-    "How many persisted plan signatures the warm pool precompiles, "
-    "hottest (by cumulative cross-process hits) first. Also caps how many "
-    "program exports are written per session close.", 32,
-    checker=lambda v: None if int(v) > 0 else "must be positive")
-
-WARM_POOL_MAX_SECONDS = register_conf(
-    "spark.rapids.tpu.compile.warmPool.maxSeconds",
-    "Wall-clock budget for warm-pool precompilation; signatures not "
-    "reached by the deadline stay cold (they compile on first dispatch as "
-    "usual).", 30.0, conf_type=float,
-    checker=lambda v: None if float(v) > 0 else "must be positive")
-
-#: refuse to persist a single program export larger than this — a giant
-#: export means a builder closed over baked-in data, which the in-process
-#: cache contract already forbids; never let one entry bloat the tier
-_EXPORT_MAX_BYTES = 32 * 1024 * 1024
-
-# persistent-tier process state. _PERSIST is reconfigured per session
-# (most recent wins, like the tracer/pipeline chokepoints); _EXPORTABLE
-# retains (builder, aval-skeleton) per signature compiled THIS process so
-# session close can export the traced programs. All under _LOCK.
-_PERSIST: Dict = {"dir": None, "base": {}, "wired_xla": False,
-                  "warm_enabled": True,
-                  "warm_max": int(WARM_POOL_MAX_SIGNATURES.default),
-                  "warm_seconds": float(WARM_POOL_MAX_SECONDS.default)}
-_EXPORTABLE: Dict[str, Tuple[Callable, tuple]] = {}
-_PSTATS = {"manifest_entries": 0, "warmed_entries": 0, "hits": 0,
-           "misses": 0, "warm_compiles": 0, "warm_errors": 0,
-           "exports_written": 0, "dropped_entries": 0}
-_WARM_STOP = threading.Event()
-_WARM_THREAD: Optional[threading.Thread] = None
+# persistent-tier process state, reconfigured per session (most recent
+# wins, like the tracer/pipeline chokepoints). Under _LOCK.
+_PERSIST: Dict = {"dir": None, "wired_xla": False}
 
 
 def machine_fingerprint() -> str:
     """Stable id for 'programs compiled here run here' (XLA:CPU bakes host
     CPU features into generated code; a foreign cache recompiles or
-    SIGILLs — bench.py learned this across rounds)."""
+    SIGILLs)."""
     import platform
     parts = [platform.system(), platform.machine()]
     try:
@@ -400,25 +354,6 @@ def _introspect(key: str, builder: Callable[[], Callable],
         if entry is not None:
             entry.update(entry_update)
 
-def oom_retry(fn: Callable) -> Callable:
-    """Spill-and-retry OOM recovery at the jit chokepoint. The
-    classification and the escalation ladder live in memory/retry.py
-    (wrap_jit) — this name survives as the cache's chokepoint so every
-    existing call site (and test) keeps working."""
-    from ..memory.retry import wrap_jit
-    return wrap_jit(fn)
-
-
-def oom_spill_noretry(fn: Callable) -> Callable:
-    """OOM handling for DONATING entries (donate_argnums): a failed
-    dispatch may already have invalidated the donated input buffers, so
-    re-calling with the same arguments is unsound. memory/retry.py's
-    wrap_jit_donating re-materializes the input from the host origin
-    retained by the upload site and retries; with no origin it spills
-    for SUBSEQUENT batches and raises a structured DeviceOomError."""
-    from ..memory.retry import wrap_jit_donating
-    return wrap_jit_donating(fn)
-
 
 _EXEC_MISMATCH_MARKERS = ("but got buffer with incompatible size",
                           "buffers but compiled program expected")
@@ -444,7 +379,8 @@ def _rebuild_on_mismatch(builder: Callable[[], Callable],
             msg = str(e)
             if not any(m in msg for m in _EXEC_MISMATCH_MARKERS):
                 raise
-            current[0] = oom_retry(jax.jit(builder()))
+            from ..memory.retry import wrap_jit
+            current[0] = wrap_jit(jax.jit(builder()))
             return current[0](*args, **kwargs)
     return wrapped
 
@@ -474,15 +410,6 @@ def _time_first_call(key: str, fn: Callable,
         if state["done"]:
             with get_tracer().span("dispatch", "dispatch", program=program):
                 return fn(*args, **kwargs)
-        # shape/dtype skeleton BEFORE dispatch: donated input buffers may
-        # be dead afterwards; the skeleton is what session close exports
-        # for the persistent tier (cheap — aval metadata only)
-        skeleton = None
-        if builder is not None and _PERSIST["dir"] is not None:
-            try:
-                skeleton = jax.tree_util.tree_map(_aval_of, (args, kwargs))
-            except Exception:
-                skeleton = None
         t0 = time.perf_counter()
         with get_tracer().span("dispatch", "dispatch", program=program), \
                 get_tracer().span("compile", "compile", program=program,
@@ -498,12 +425,6 @@ def _time_first_call(key: str, fn: Callable,
                 first = True
                 _COMPILES += 1
                 _COMPILE_SECONDS += dt
-                if skeleton is not None:
-                    if len(_EXPORTABLE) >= 512 and key not in _EXPORTABLE:
-                        # bound builder-closure retention: beyond any
-                        # plausible warm set, drop the oldest capture
-                        _EXPORTABLE.pop(next(iter(_EXPORTABLE)))
-                    _EXPORTABLE[key] = (builder, skeleton)
                 entry = _KERNELS.get(key)
                 if entry is not None:
                     entry["compiles"] += 1
@@ -568,22 +489,12 @@ def cached_jit(key: str, builder: Callable[[], Callable], *, name: str,
         return _named(builder(), name)
 
     if fn is not None:
-        if isinstance(fn, _WarmedEntry):
-            # warm-pool entries need the builder for output-pytree
-            # reconstruction and as the unexpected-shape fallback
-            fn.attach_builder(named_builder, donate_argnums, name)
         _attribute(M.COMPILE_CACHE_HITS)
         return fn
     _attribute(M.COMPILE_CACHE_MISSES)
     built = _build_entry(key, named_builder, donate_argnums, name)
     with _LOCK:
-        fn = _CACHE.setdefault(key, built)
-    if fn is not built and isinstance(fn, _WarmedEntry):
-        # the warm pool installed this key between our miss check and the
-        # setdefault — the warmed entry has never seen a cached_jit() hit,
-        # so it still needs the builder for out-tree/fallback dispatch
-        fn.attach_builder(named_builder, donate_argnums, name)
-    return fn
+        return _CACHE.setdefault(key, built)
 
 
 def _build_entry(key: str, builder: Callable[[], Callable], donate_argnums,
@@ -591,15 +502,51 @@ def _build_entry(key: str, builder: Callable[[], Callable], donate_argnums,
     """A fresh cache entry over ``builder`` (already named). The OOM
     wrapper sits directly on the jitted callable and ``_time_first_call``
     outermost, so the ``dispatch`` span covers recovery too."""
+    from ..memory.retry import wrap_jit, wrap_jit_donating
     if donate_argnums is None:
         return _time_first_call(key, _rebuild_on_mismatch(
-            builder, oom_retry(jax.jit(builder()))), builder, name)
+            builder, wrap_jit(jax.jit(builder()))), builder, name)
     # donating entries get NO call-again recovery with the SAME args (the
     # failed dispatch may have consumed the donated input); the donating
     # ladder re-materializes from the retained host origin instead, or
     # spills-and-raises structured when there is none
-    return _time_first_call(key, oom_spill_noretry(
+    return _time_first_call(key, wrap_jit_donating(
         jax.jit(builder(), donate_argnums=donate_argnums)), builder, name)
+
+
+#: AOT executables of the mesh programs (exec/mesh.py's stage,
+#: shuffle/ici.py's all-to-all), by (program name, the caller's semantic
+#: key). They are lowered and compiled ahead of the call so that the
+#: one-time XLA build is its own ``compile`` span and never reads as
+#: collective wall. Bounded LRU: shapes are bucketed upstream (quota
+#: bucketing, exec/exchange.py), so a handful of entries covers a run.
+_AOT: "OrderedDict[tuple, object]" = OrderedDict()
+_AOT_MAX = 64
+
+
+def aot_program(key: tuple, build: Callable[[], Callable], args: tuple, *,
+                name: str) -> Tuple[object, bool]:
+    """The compiled executable of program ``name`` for ``key``, and whether
+    it was compiled by this call. On a miss ``build()`` returns the
+    ``named_jit`` function, which is lowered at ``args`` and compiled under
+    a ``compile`` span; the caller wraps its own ``dispatch`` span around
+    each call of the executable (``cached_jit`` would nest the one inside
+    the other)."""
+    from .tracing import get_tracer
+    key = (name, key)
+    with _LOCK:
+        prog = _AOT.get(key)
+        if prog is not None:
+            _AOT.move_to_end(key)
+            return prog, False
+    with get_tracer().span("compile", "compile",
+                           program=_PROGRAM_PREFIX + name):
+        prog = build().lower(*args).compile()
+    with _LOCK:
+        _AOT[key] = prog
+        while len(_AOT) > _AOT_MAX:
+            _AOT.popitem(last=False)
+    return prog, True
 
 
 def cache_stats() -> Dict[str, float]:
@@ -607,198 +554,39 @@ def cache_stats() -> Dict[str, float]:
     # and a lock-free multi-field read can tear (hits from one moment,
     # compiles from another) — stats consumers diff these across queries
     with _LOCK:
-        out = {"entries": len(_CACHE), "hits": _HITS, "misses": _MISSES,
-               "compiles": _COMPILES,
-               "compile_seconds": round(_COMPILE_SECONDS, 6)}
-        out.update({f"persist_{k}": v for k, v in _PSTATS.items()})
-    return out
+        return {"entries": len(_CACHE), "hits": _HITS, "misses": _MISSES,
+                "compiles": _COMPILES,
+                "compile_seconds": round(_COMPILE_SECONDS, 6)}
 
 
 def clear_cache():
     global _HITS, _MISSES, _COMPILES, _COMPILE_SECONDS
     with _LOCK:
         _CACHE.clear()
+        _AOT.clear()
         _KERNELS.clear()
-        _EXPORTABLE.clear()
-        # flushed deltas track _KERNELS totals; clearing one without the
-        # other would produce negative deltas at the next persist
-        _PERSIST.pop("flushed", None)
-        for k in _PSTATS:
-            _PSTATS[k] = 0
         _HITS = _MISSES = 0
         _COMPILES = 0
         _COMPILE_SECONDS = 0.0
 
 
 # ---------------------------------------------------------------------------
-# persistent tier: manifest + program exports + warm pool
+# persistent tier: where XLA's cache lives
 # ---------------------------------------------------------------------------
 def persistent_cache_dir() -> Optional[str]:
-    """The active tier directory (fingerprint+jax scoped), or None."""
+    """The directory of the engine's own files beside XLA's cache
+    (exec/fallback.py keeps quarantine.json there), or None."""
     with _LOCK:
         return _PERSIST["dir"]
 
 
-def _aval_signature(treedef, leaves) -> str:
-    """Stable id of a call's input pytree: structure + leaf shape/dtype.
-    Identical across processes for identical plans over identical bucket
-    ladders — the key that matches a live dispatch to a persisted export."""
-    parts = [str(treedef)]
-    for leaf in leaves:
-        shape = getattr(leaf, "shape", None)
-        dtype = getattr(leaf, "dtype", None)
-        if shape is not None and dtype is not None:
-            parts.append(f"{dtype}{tuple(shape)}")
-        else:
-            parts.append(f"py:{type(leaf).__name__}")
-    return hashlib.sha256("|".join(parts).encode()).hexdigest()[:16]
-
-
-class _WarmedEntry:
-    """A ``_CACHE`` entry installed by the warm pool BEFORE any builder
-    exists in this process: per input-shape signature, an AOT-compiled
-    executable replayed from a persisted ``jax.export``.
-
-    Dispatch flattens the call's args, matches the aval signature, runs the
-    flat executable and unflattens through the output pytree learned from
-    ONE abstract trace of the builder (``jax.eval_shape`` — no XLA compile).
-    Any mismatch (unexpected shapes, incompatible arguments) falls back to
-    the normal build path, which counts a real compile."""
-
-    def __init__(self, key: str):
-        self.key = key
-        self._records: Dict[str, Callable] = {}   # aval_sig -> flat dispatch
-        self._out_trees: Dict[str, object] = {}   # aval_sig -> out treedef
-        self._builder: Optional[Callable] = None
-        self._donate = None
-        self._name = "warm_replay"
-        self._fallback: Optional[Callable] = None
-        self._elock = threading.Lock()
-
-    def add_record(self, aval_sig: str, dispatch: Callable) -> None:
-        self._records[aval_sig] = dispatch
-
-    def attach_builder(self, builder: Callable, donate_argnums,
-                       name: str) -> None:
-        if self._builder is None:
-            self._builder = builder
-            self._donate = donate_argnums
-            self._name = name
-
-    def _fallback_fn(self) -> Callable:
-        fb = self._fallback
-        if fb is not None:
-            return fb
-        with self._elock:
-            if self._fallback is None:
-                builder = self._builder
-                if builder is None:
-                    raise RuntimeError(
-                        f"warmed compile-cache entry {self.key!r} dispatched "
-                        f"before any cached_jit() call attached its builder")
-                self._fallback = _build_entry(self.key, builder,
-                                              self._donate, self._name)
-            return self._fallback
-
-    def _out_tree_for(self, aval_sig: str, args, kwargs, n_out: int):
-        tree = self._out_trees.get(aval_sig)
-        if tree is not None:
-            return tree
-        builder = self._builder
-        if builder is None:
-            return None
-        # one abstract trace to learn the output pytree (cheap: no XLA)
-        out_shape = jax.eval_shape(builder(), *args, **kwargs)
-        leaves, tree = jax.tree_util.tree_flatten(out_shape)
-        if len(leaves) != n_out:
-            return None
-        with self._elock:
-            self._out_trees.setdefault(aval_sig, tree)
-        return tree
-
-    def __call__(self, *args, **kwargs):
-        leaves, treedef = jax.tree_util.tree_flatten((args, kwargs))
-        aval_sig = _aval_signature(treedef, leaves)
-        dispatch = self._records.get(aval_sig)
-        if dispatch is None:
-            with _LOCK:
-                _PSTATS["misses"] += 1
-            return self._fallback_fn()(*args, **kwargs)
-        from .tracing import get_tracer
-        try:
-            with get_tracer().span("dispatch", "dispatch",
-                                   program=_PROGRAM_PREFIX + self._name):
-                flat_out = dispatch(*leaves)
-            tree = self._out_tree_for(aval_sig, args, kwargs, len(flat_out))
-            if tree is None:
-                raise TypeError("output arity mismatch")
-            out = jax.tree_util.tree_unflatten(tree, flat_out)
-        except (TypeError, ValueError) as e:
-            # incompatible-argument class of errors only: device OOM
-            # (RuntimeError) propagates through the oom_retry wrapper
-            with _LOCK:
-                self._records.pop(aval_sig, None)
-                _PSTATS["warm_errors"] += 1
-                _PSTATS["misses"] += 1
-            print(f"# warmed entry {self.key[:80]!r} fell back to a live "
-                  f"compile: {type(e).__name__}", file=sys.stderr)
-            return self._fallback_fn()(*args, **kwargs)
-        with _LOCK:
-            _PSTATS["hits"] += 1
-        return out
-
-
-def _manifest_path(tier_dir: str) -> str:
-    return os.path.join(tier_dir, "manifest.json")
-
-
-def _load_manifest(path: str) -> Tuple[Dict[str, Dict], int]:
-    """Read the persisted plan-signature manifest. Corruption-tolerant by
-    contract: a bad file or a bad entry is dropped (counted), never
-    raised — a wedged cache must not take the engine down."""
-    try:
-        with open(path, encoding="utf-8") as f:
-            data = json.load(f)
-    except FileNotFoundError:
-        return {}, 0
-    except (OSError, ValueError):
-        return {}, 1
-    raw = data.get("entries") if isinstance(data, dict) else None
-    if not isinstance(raw, dict):
-        return {}, 1
-    entries: Dict[str, Dict] = {}
-    dropped = 0
-    for sig, e in raw.items():
-        if not isinstance(e, dict) \
-                or not isinstance(e.get("hits", 0), (int, float)) \
-                or not isinstance(e.get("compiles", 0), (int, float)):
-            dropped += 1
-            continue
-        exports = e.get("exports", [])
-        if not isinstance(exports, list):
-            dropped += 1
-            continue
-        good_exports = [x for x in exports
-                        if isinstance(x, dict)
-                        and isinstance(x.get("file"), str)
-                        and isinstance(x.get("aval_sig"), str)]
-        entry = {"hits": int(e.get("hits", 0)),
-                 "compiles": int(e.get("compiles", 0)),
-                 "compile_s": float(e.get("compile_s", 0.0) or 0.0),
-                 "node_name": e.get("node_name"),
-                 "program": e.get("program"),
-                 "exports": good_exports}
-        entries[sig] = entry
-    return entries, dropped
-
-
 def configure_compile_cache(conf) -> Optional[str]:
     """Apply spark.rapids.tpu.compile.* (called from TpuSession.__init__,
-    most recent session wins). Finds the tier directory (module docstring,
-    tier 2), loads the engine manifest, and starts the warm pool. Returns
-    the directory of the engine's own files, or None when the tier is
-    off."""
-    stop_warm_pool()
+    most recent session wins): find the cache directory (module docstring,
+    tier 2) and point XLA's persistent cache at it. Returns the directory
+    of the engine's own files, or None when the tier is off. Whatever an
+    older version left there (its manifest, exports/) is neither read
+    nor deleted."""
     enabled = bool(conf.get(COMPILE_CACHE_ENABLED))
     external = os.environ.get(_JAX_CACHE_ENV, "").strip()
     base = str(conf.get(COMPILE_CACHE_DIR) or "").strip()
@@ -813,7 +601,7 @@ def configure_compile_cache(conf) -> Optional[str]:
         _persist_off()
         return None
     try:
-        os.makedirs(os.path.join(tier, "exports"), exist_ok=True)
+        os.makedirs(tier, exist_ok=True)
         if xla_dir is not None:
             os.makedirs(xla_dir, exist_ok=True)
     except OSError as e:
@@ -822,24 +610,13 @@ def configure_compile_cache(conf) -> Optional[str]:
                       f"{tier!r} ({e})", RuntimeWarning)
         _persist_off()
         return None
-    # tier 2: XLA executables survive restarts. min_compile_time 0 — a
-    # cache dir was asked for, so persist everything
+    # min_compile_time 0 — a cache dir was asked for, so persist everything
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     if xla_dir is not None:
         jax.config.update("jax_compilation_cache_dir", xla_dir)
-    entries, dropped = _load_manifest(_manifest_path(tier))
     with _LOCK:
         _PERSIST["dir"] = tier
         _PERSIST["wired_xla"] = xla_dir is not None
-        _PERSIST["base"] = entries
-        _PERSIST["warm_enabled"] = bool(conf.get(WARM_POOL_ENABLED))
-        _PERSIST["warm_max"] = int(conf.get(WARM_POOL_MAX_SIGNATURES))
-        _PERSIST["warm_seconds"] = float(conf.get(WARM_POOL_MAX_SECONDS))
-        _PSTATS["manifest_entries"] = len(entries)
-        _PSTATS["dropped_entries"] += dropped
-        warm = _PERSIST["warm_enabled"]
-    if warm and entries:
-        _start_warm_pool()
     return tier
 
 
@@ -851,253 +628,5 @@ def _persist_off() -> None:
         unwire = _PERSIST["wired_xla"]
         _PERSIST["dir"] = None
         _PERSIST["wired_xla"] = False
-        _PERSIST["base"] = {}
     if unwire:
         jax.config.update("jax_compilation_cache_dir", None)
-
-
-def _warm_items_locked() -> List[Tuple[str, str, str, Optional[str]]]:
-    """(signature, export file, aval_sig, program name) for the hottest
-    manifest signatures, bounded by warmPool.maxSignatures."""
-    ranked = sorted(_PERSIST["base"].items(),
-                    key=lambda kv: -(kv[1]["hits"] + kv[1]["compiles"]))
-    items: List[Tuple[str, str, str, Optional[str]]] = []
-    for sig, entry in ranked[:_PERSIST["warm_max"]]:
-        for ex in entry["exports"]:
-            items.append((sig, ex["file"], ex["aval_sig"],
-                          entry.get("program")))
-    return items
-
-
-def _start_warm_pool() -> None:
-    global _WARM_THREAD
-    if _WARM_THREAD is not None and _WARM_THREAD.is_alive():
-        # a previous pool outlived its stop request (mid-AOT-compile);
-        # clearing _WARM_STOP under it would un-cancel it — skip warming
-        print("# warm pool not started: previous pool still draining",
-              file=sys.stderr)
-        return
-    with _LOCK:
-        tier = _PERSIST["dir"]
-        items = _warm_items_locked()
-        deadline = time.monotonic() + _PERSIST["warm_seconds"]
-    if not items or tier is None:
-        return
-    _WARM_STOP.clear()
-
-    def main():
-        from ..parallel.pipeline import parallel_map
-        try:
-            parallel_map(lambda it: _warm_one(tier, deadline, *it), items,
-                         stage="warm-pool")
-        except Exception as e:  # never let warming break a session
-            print(f"# warm pool aborted: {type(e).__name__}: {e}",
-                  file=sys.stderr)
-
-    _WARM_THREAD = threading.Thread(target=main, daemon=True,
-                                    name="tpu-warm-pool")
-    _WARM_THREAD.start()
-
-
-def _warm_one(tier_dir: str, deadline: float, sig: str, fname: str,
-              aval_sig: str, program: Optional[str] = None) -> None:
-    """Replay one persisted export: deserialize, AOT-compile (an XLA
-    disk-cache hit when tier 2 already holds the executable), and install
-    a dispatchable entry under the plan signature. It compiles under the
-    program name the manifest recorded (``warm_replay`` for a manifest
-    written before programs had names)."""
-    name = (program or "")[len(_PROGRAM_PREFIX):]
-    if name not in PROGRAM_NAMES:
-        name = "warm_replay"
-    if _WARM_STOP.is_set() or time.monotonic() > deadline:
-        return
-    try:
-        from jax import export as jax_export
-        path = os.path.join(tier_dir, "exports", os.path.basename(fname))
-        with open(path, "rb") as f:
-            data = f.read()
-        exported = jax_export.deserialize(bytearray(data))
-        sds = [jax.ShapeDtypeStruct(a.shape, a.dtype)
-               for a in exported.in_avals]
-        compiled = named_jit(exported.call, name).lower(*sds).compile()
-        dispatch = oom_retry(compiled)
-    except Exception as e:
-        with _LOCK:
-            _PSTATS["warm_errors"] += 1
-        print(f"# warm pool skipped {sig[:80]!r}: "
-              f"{type(e).__name__}: {str(e)[:120]}", file=sys.stderr)
-        return
-    with _LOCK:
-        cur = _CACHE.get(sig)
-        if cur is None:
-            cur = _CACHE[sig] = _WarmedEntry(sig)
-            _PSTATS["warmed_entries"] += 1
-            entry = _kernel_entry_locked(sig)
-            entry["warmed"] = True
-        if isinstance(cur, _WarmedEntry):
-            cur.add_record(aval_sig, dispatch)
-            _PSTATS["warm_compiles"] += 1
-        # else: a live compile beat us to the key — keep the live entry
-
-
-def warm_pool_wait(timeout: Optional[float] = None) -> bool:
-    """Block until warm-pool precompilation settles (bench/tests call this
-    before measuring). True when the pool is idle."""
-    t = _WARM_THREAD
-    if t is None or not t.is_alive():
-        return True
-    with _LOCK:
-        budget = _PERSIST["warm_seconds"] + 10.0
-    t.join(timeout if timeout is not None else budget)
-    return not t.is_alive()
-
-
-def stop_warm_pool(timeout: float = 10.0) -> None:
-    """Cancel + join the warm pool (session close / reconfigure); part of
-    the no-leaked-threads contract."""
-    global _WARM_THREAD
-    t = _WARM_THREAD
-    if t is None:
-        return
-    _WARM_STOP.set()
-    t.join(timeout)
-    if t.is_alive():
-        # join timed out mid-AOT-compile: keep the handle so the leak is
-        # VISIBLE (warm_pool_wait / thread checks still see it) and so
-        # _start_warm_pool refuses to race a second pool against it
-        print("# warm pool still busy after stop request; it will exit "
-              "after the in-flight compile", file=sys.stderr)
-        return
-    _WARM_THREAD = None
-
-
-def _export_one(key: str, builder: Callable, skeleton, exports_dir: str
-                ) -> Optional[Dict[str, str]]:
-    """Serialize the traced program behind ``key`` at its captured input
-    shapes. The export wraps the computation in a FLAT (leaves-in,
-    leaves-out) function so no custom pytree type needs a serializer;
-    dispatch re-learns the output tree from one eval_shape."""
-    from jax import export as jax_export
-    leaves, treedef = jax.tree_util.tree_flatten(skeleton)
-
-    def flat_fn(*flat):
-        a, kw = jax.tree_util.tree_unflatten(treedef, flat)
-        out = builder()(*a, **kw)
-        return tuple(jax.tree_util.tree_flatten(out)[0])
-
-    exported = jax_export.export(jax.jit(flat_fn))(*leaves)
-    data = exported.serialize()
-    if len(data) > _EXPORT_MAX_BYTES:
-        raise ValueError(f"export too large ({len(data)} bytes) — builder "
-                         f"likely closed over concrete data")
-    aval_sig = _aval_signature(treedef, leaves)
-    fname = hashlib.sha256(
-        (key + "|" + aval_sig).encode()).hexdigest()[:24] + ".jaxexport"
-    path = os.path.join(exports_dir, fname)
-    tmp = path + ".tmp"
-    with open(tmp, "wb") as f:
-        f.write(bytes(data))
-    os.replace(tmp, path)
-    return {"file": fname, "aval_sig": aval_sig}
-
-
-def persist_compile_cache() -> int:
-    """Flush the engine manifest (+ new program exports) to the tier
-    directory — called from TpuSession.close(). Merges this process's
-    hit/compile counts into the cumulative cross-process totals, exports
-    the hottest newly-compiled programs (bounded by
-    warmPool.maxSignatures), and atomically replaces manifest.json.
-    Returns the number of exports written; never raises."""
-    with _LOCK:
-        tier = _PERSIST["dir"]
-        if tier is None:
-            return 0
-        entries: Dict[str, Dict] = {
-            sig: dict(e, exports=list(e["exports"]))
-            for sig, e in _PERSIST["base"].items()}
-        # merge DELTAS vs the last flush, not raw process totals: a
-        # process cycling several sessions (or a double close()) must not
-        # re-merge counts it already persisted
-        flushed = _PERSIST.setdefault("flushed", {})
-        kernels, totals = {}, {}
-        for sig, e in _KERNELS.items():
-            cur = (int(e.get("hits", 0)), int(e.get("compiles", 0)),
-                   float(e.get("compile_s", 0.0)))
-            prev = flushed.get(sig, (0, 0, 0.0))
-            totals[sig] = cur
-            kernels[sig] = {"hits": cur[0] - prev[0],
-                            "compiles": cur[1] - prev[1],
-                            "compile_s": cur[2] - prev[2],
-                            "node_name": e.get("node_name"),
-                            "program": e.get("program")}
-        exportable = dict(_EXPORTABLE)
-        cap = _PERSIST["warm_max"]
-    for sig, k in kernels.items():
-        e = entries.setdefault(
-            sig, {"hits": 0, "compiles": 0, "compile_s": 0.0,
-                  "node_name": None, "program": None, "exports": []})
-        e["hits"] += int(k["hits"])
-        e["compiles"] += int(k["compiles"])
-        e["compile_s"] = round(e["compile_s"] + float(k["compile_s"]), 6)
-        e["node_name"] = e["node_name"] or k["node_name"]
-        e["program"] = e.get("program") or k["program"]
-    # export the hottest signatures compiled this process whose captured
-    # shapes are not persisted yet
-    exports_dir = os.path.join(tier, "exports")
-    candidates = sorted(
-        exportable, key=lambda s: -(entries.get(s, {}).get("hits", 0)
-                                    + entries.get(s, {}).get("compiles", 0)))
-    written = 0
-    exported_keys = []       # captures persisted (or already on disk) —
-    stale_files = []         # release the builder closures afterwards
-    for sig in candidates:
-        if written >= cap:
-            break
-        builder, skeleton = exportable[sig]
-        entry = entries.setdefault(
-            sig, {"hits": 0, "compiles": 0, "compile_s": 0.0,
-                  "node_name": None, "exports": []})
-        try:
-            leaves, treedef = jax.tree_util.tree_flatten(skeleton)
-            aval_sig = _aval_signature(treedef, leaves)
-            if any(x["aval_sig"] == aval_sig for x in entry["exports"]):
-                exported_keys.append(sig)
-                continue
-            rec = _export_one(sig, builder, skeleton, exports_dir)
-        except Exception as e:
-            print(f"# compile-cache export skipped {sig[:80]!r}: "
-                  f"{type(e).__name__}: {str(e)[:120]}", file=sys.stderr)
-            continue
-        if rec is not None:
-            # newest first; bound the per-signature shape fanout, and
-            # reclaim the files of records falling off the end
-            kept = [rec] + entry["exports"][:3]
-            stale_files.extend(x["file"] for x in entry["exports"][3:])
-            entry["exports"] = kept
-            written += 1
-            exported_keys.append(sig)
-    try:
-        path = _manifest_path(tier)
-        tmp = path + ".tmp"
-        with open(tmp, "w", encoding="utf-8") as f:
-            json.dump({"version": 1, "tool": "spark-rapids-tpu",
-                       "jax": jax.__version__,
-                       "fingerprint": machine_fingerprint(),
-                       "entries": entries}, f, indent=1, sort_keys=True)
-        os.replace(tmp, path)
-    except OSError as e:
-        print(f"# compile-cache manifest not written: {e}", file=sys.stderr)
-        return written
-    for fname in stale_files:   # only after the manifest dropped them
-        try:
-            os.unlink(os.path.join(exports_dir, os.path.basename(fname)))
-        except OSError:
-            pass
-    with _LOCK:
-        _PERSIST["base"] = entries
-        _PERSIST["flushed"] = dict(flushed, **totals)
-        for sig in exported_keys:
-            _EXPORTABLE.pop(sig, None)
-        _PSTATS["manifest_entries"] = len(entries)
-        _PSTATS["exports_written"] += written
-    return written

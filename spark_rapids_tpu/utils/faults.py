@@ -8,7 +8,7 @@ boundary. This engine owns its whole runtime, so it owns its chaos
 layer too: named fault points threaded through every failure surface
 (shuffle fetch/publish, TCP/DCN socket I/O, spill-store write/read,
 worker task execution, H2D upload) that deterministic, seeded fault
-specs can trigger in tests and in the ``BENCH_CHAOS=1`` bench phase.
+specs can trigger in tests.
 
 Cost model mirrors the tracer (utils/tracing.py) and the memory
 profiler (utils/memprof.py): a module-level ``_INJECTOR`` that is

@@ -49,10 +49,6 @@ def _clear_jax_caches_per_module():
     jax.clear_caches()
     from spark_rapids_tpu.utils.compile_cache import clear_cache
     clear_cache()
-    from spark_rapids_tpu.exec.mesh import clear_mesh_programs
-    from spark_rapids_tpu.shuffle.ici import clear_exchange_programs
-    clear_mesh_programs()
-    clear_exchange_programs()
 
 
 @pytest.fixture(autouse=True, scope="module")

@@ -839,8 +839,8 @@ def test_tier1_thread_and_lock_and_jit_clean(package_report):
 
 
 def test_baseline_summary_matches_committed_file(package_report):
-    """bench.py copies baseline_summary() into the bench JSON; it must
-    agree with a live analyzer run so the trajectory metric is honest."""
+    """baseline_summary() must agree with a live analyzer run so the
+    trajectory metric is honest."""
     info = baseline_summary()
     assert info, "committed baseline missing"
     live = package_report.summary()["checks"].get("sync", {})

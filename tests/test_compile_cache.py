@@ -1,23 +1,29 @@
-"""Compile-time amortization (ISSUE 7): canonical shape-bucket ladder,
-persistent compile tier, and the warm-pool precompiler.
+"""Compile-time amortization: the canonical shape-bucket ladder and the
+two tiers of the program cache (docs/compilation.md).
 
-Covers the acceptance contract:
 - bucket-ladder unit tests (monotonic, covering, bounded waste, conf
   round-trip through a session),
-- persistent manifest + export save/load across a REAL subprocess
-  boundary, pinning the zero-compiles-on-second-run criterion,
-- corrupted-cache-dir tolerance (bad manifest, bad export file),
-- warm pool precompiles-then-hits in-process,
-- no-leaked-threads after session close.
+- the in-process AOT table of the mesh programs (``aot_program``): one
+  executable a key, bounded, emptied by ``clear_cache()``,
+- XLA's persistent cache across a REAL subprocess boundary: the second
+  process's compile requests are all served from it,
+- where the cache is placed (environment first, then the conf), and that
+  nothing but XLA's entries and ``quarantine.json`` is kept there: what an
+  older version's manifest / export tier left behind is not touched.
 """
+import builtins
 import json
 import os
 import pathlib
 import subprocess
 import sys
 import threading
+import warnings
 
+import jax
 import jax.numpy as jnp
+import numpy as np
+import pyarrow as pa
 import pytest
 
 from spark_rapids_tpu.columnar.device import (BucketPolicy, bucket_rows,
@@ -100,13 +106,123 @@ def test_bucket_conf_round_trip():
 
 
 # ---------------------------------------------------------------------------
+# the AOT table of the mesh programs
+# ---------------------------------------------------------------------------
+def _compile_spans(tracer, program):
+    return [e for e in tracer.events()
+            if e.name == "compile" and e.args.get("program") == program]
+
+
+@pytest.fixture
+def traced():
+    from spark_rapids_tpu.utils.tracing import get_tracer
+    tracer = get_tracer()
+    was = tracer.enabled
+    tracer.enabled = True
+    tracer.clear()
+    yield tracer
+    tracer.enabled = was
+    tracer.clear()
+
+
+def _aot(key, built=None):
+    """``aot_program`` of a one-line program under ``key``."""
+    from spark_rapids_tpu.utils.compile_cache import aot_program, named_jit
+
+    def build():
+        if built is not None:
+            built.append(key)
+        return named_jit(lambda x: x + 1.0, "stage")
+    return aot_program(key, build, (jnp.ones(8),), name="stage")
+
+
+def test_aot_program_keeps_one_executable_a_key(traced):
+    """The same key returns the same executable: ``build`` is not called
+    and no second ``compile`` span is booked."""
+    from spark_rapids_tpu.utils.compile_cache import clear_cache
+    clear_cache()
+    built = []
+    prog, compiled = _aot(("k", 1), built)
+    assert compiled and built == [("k", 1)]
+    assert len(_compile_spans(traced, "srt_stage")) == 1
+    again, compiled = _aot(("k", 1), built)
+    assert again is prog and not compiled and built == [("k", 1)]
+    assert len(_compile_spans(traced, "srt_stage")) == 1
+    assert float(prog(jnp.ones(8))[0]) == 2.0
+    other, compiled = _aot(("k", 2), built)
+    assert compiled and other is not prog
+
+
+def test_aot_program_is_a_bounded_lru():
+    """64 executables are kept: the 65th key evicts the one used longest
+    ago, which a hit in between moves to the young end."""
+    from spark_rapids_tpu.utils import compile_cache as cc
+    cc.clear_cache()
+    for i in range(cc._AOT_MAX):
+        _aot(("lru", i))
+    assert len(cc._AOT) == cc._AOT_MAX == 64
+    assert not _aot(("lru", 0))[1]              # a hit: 0 is young again
+    assert _aot(("lru", 64))[1]                 # the 65th key
+    assert len(cc._AOT) == 64
+    assert ("stage", ("lru", 1)) not in cc._AOT
+    assert ("stage", ("lru", 0)) in cc._AOT
+    assert _aot(("lru", 1))[1]                  # evicted: compiled again
+
+
+def test_clear_cache_empties_the_aot_table():
+    from spark_rapids_tpu.utils import compile_cache as cc
+    _aot(("clear", 0))
+    assert cc._AOT
+    cc.clear_cache()
+    assert not cc._AOT
+    assert _aot(("clear", 0))[1]
+
+
+@pytest.mark.parametrize("program", ["mesh_stage", "ici_all_to_all"])
+def test_the_mesh_programs_are_kept_in_the_one_aot_table(traced, program):
+    """A group-by over a 4-virtual-device mesh compiles its all-to-all and
+    its mesh stage into ``_AOT``; the same query again compiles neither."""
+    from spark_rapids_tpu.expr.functions import col, sum as fsum
+    from spark_rapids_tpu.parallel.mesh import data_parallel_mesh
+    from spark_rapids_tpu.session import TpuSession
+    from spark_rapids_tpu.utils import compile_cache as cc
+    if len(jax.devices()) < 4:
+        pytest.skip("needs 4 virtual devices")
+    cc.clear_cache()
+    sess = TpuSession({"spark.rapids.tpu.batchRowsMinBucket": 8,
+                       "spark.rapids.tpu.shuffle.partitions": 4,
+                       "spark.rapids.sql.test.enabled": True,
+                       # with AQE no mesh stage is planned
+                       "spark.rapids.tpu.aqe.enabled": False})
+    sess.attach_mesh(data_parallel_mesh(4))
+    rng = np.random.default_rng(0)
+    t = pa.table({"k": rng.integers(0, 40, 600),
+                  "v": rng.uniform(0, 10, 600)})
+
+    def query():
+        df = sess.create_dataframe(t, num_partitions=3)
+        return df.group_by("k").agg(fsum(col("v")).alias("s")).collect()
+
+    try:
+        first = query().to_pandas().sort_values("k")
+        kept = [k for k in cc._AOT if k[0] == program]
+        assert kept, list(cc._AOT)
+        compiled = len(_compile_spans(traced, "srt_" + program))
+        assert compiled == len(kept)
+        again = query().to_pandas().sort_values("k")
+        assert len(_compile_spans(traced, "srt_" + program)) == compiled
+        assert [k for k in cc._AOT if k[0] == program] == kept
+        assert again.s.tolist() == first.s.tolist()
+    finally:
+        sess.close()
+
+
+# ---------------------------------------------------------------------------
 # persistent tier helpers
 # ---------------------------------------------------------------------------
 def _reset_tier():
     from spark_rapids_tpu.utils.compile_cache import (clear_cache,
-                                                      configure_compile_cache,
-                                                      stop_warm_pool)
-    stop_warm_pool()
+                                                      configure_compile_cache)
     configure_compile_cache(RapidsConf())
     clear_cache()
 
@@ -118,38 +234,49 @@ def tier_reset():
     _reset_tier()
 
 
-# one tiny jitted computation exercised through cached_jit, signature-stable
+# TPC-H Q6 in a fresh process, with XLA's own compile counters
+# (jax.monitoring) around it: every compile request, and those of them the
+# persistent cache served
 _SCRIPT = r"""
 import json, os, sys
 os.environ["JAX_PLATFORMS"] = "cpu"
 sys.path.insert(0, {repo!r})
-cache_dir, phase = sys.argv[1], sys.argv[2]
+cache_dir = sys.argv[1]
+import jax
+xla = {{"requests": 0, "hits": 0}}
+def _duration(event, seconds, **_):
+    if event == "/jax/core/compile/backend_compile_duration":
+        xla["requests"] += 1
+def _event(event, **_):
+    if event == "/jax/compilation_cache/cache_hits":
+        xla["hits"] += 1
+jax.monitoring.register_event_duration_secs_listener(_duration)
+jax.monitoring.register_event_listener(_event)
 from spark_rapids_tpu.session import TpuSession
 from spark_rapids_tpu.tools import tpch
-from spark_rapids_tpu.utils.compile_cache import cache_stats, warm_pool_wait
+from spark_rapids_tpu.utils.compile_cache import cache_stats
 
 sess = TpuSession({{
     "spark.rapids.tpu.batchRowsMinBucket": 128,
     "spark.rapids.tpu.compile.cacheDir": cache_dir,
 }})
-if phase == "warm":
-    assert warm_pool_wait(120), "warm pool did not settle"
 lineitem = tpch.gen_lineitem(0.001, seed=0, rows=1500)
 df = sess.create_dataframe(lineitem, num_partitions=1).cache()
 q = tpch.q6({{"lineitem": df}})
 res = q.collect(device=True)
-out = {{"revenue": res.column("revenue")[0].as_py(), "stats": cache_stats()}}
+out = {{"revenue": res.column("revenue")[0].as_py(), "stats": cache_stats(),
+       "xla": xla}}
 sess.close()
 print("RESULT " + json.dumps(out))
 """
 
 
-def _run_subprocess(cache_dir: str, phase: str, **extra_env) -> dict:
+def _run_subprocess(cache_dir: str, **extra_env) -> dict:
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO)
     env.pop("JAX_COMPILATION_CACHE_DIR", None)
     env.update(extra_env)
     r = subprocess.run(
-        [sys.executable, "-c", _SCRIPT.format(repo=REPO), cache_dir, phase],
+        [sys.executable, "-c", _SCRIPT.format(repo=REPO), cache_dir],
         capture_output=True, text=True, timeout=480, env=env, cwd=REPO)
     assert r.returncode == 0, (r.stdout[-2000:], r.stderr[-2000:])
     line = next(ln for ln in r.stdout.splitlines()
@@ -157,126 +284,41 @@ def _run_subprocess(cache_dir: str, phase: str, **extra_env) -> dict:
     return json.loads(line[len("RESULT "):])
 
 
+def _tier_files(tier) -> list:
+    """What lies in an engine directory besides XLA's own ``xla/``."""
+    return sorted(n for n in os.listdir(tier) if n != "xla")
+
+
 def test_persistent_tier_zero_compiles_across_processes(tmp_path):
     """THE acceptance pin: a TPC-H query in a fresh process after a prior
-    warmed run executes with compiles == 0 in cache_stats()."""
+    run compiles nothing — every compile request it makes is served from
+    XLA's persistent cache — and answers the same."""
     cache_dir = str(tmp_path / "tier")
-    cold = _run_subprocess(cache_dir, "cold")
+    cold = _run_subprocess(cache_dir)
     assert cold["stats"]["compiles"] > 0
-    # the tier persisted a manifest with this process's signatures
-    import glob
-    manifests = glob.glob(os.path.join(cache_dir, "*", "manifest.json"))
-    assert len(manifests) == 1
-    with open(manifests[0]) as f:
-        manifest = json.load(f)
-    assert manifest["entries"]
-    assert any(e["exports"] for e in manifest["entries"].values())
-    exports = glob.glob(os.path.join(cache_dir, "*", "exports", "*"))
-    assert exports
+    assert cold["xla"]["requests"] > cold["xla"]["hits"], cold["xla"]
+    (tier,) = os.listdir(cache_dir)
+    assert os.listdir(os.path.join(cache_dir, tier, "xla"))
+    assert _tier_files(os.path.join(cache_dir, tier)) == []
 
-    warm = _run_subprocess(cache_dir, "warm")
-    assert warm["revenue"] == pytest.approx(cold["revenue"], rel=1e-9)
-    assert warm["stats"]["compiles"] == 0, warm["stats"]
-    assert warm["stats"]["persist_warmed_entries"] > 0
-    assert warm["stats"]["persist_hits"] > 0
-    # cumulative cross-process hit counts merged on close
-    with open(manifests[0]) as f:
-        merged = json.load(f)
-    assert sum(e["hits"] for e in merged["entries"].values()) \
-        > sum(e["hits"] for e in manifest["entries"].values())
+    warm = _run_subprocess(cache_dir)
+    assert warm["revenue"] == cold["revenue"]
+    assert warm["xla"]["requests"] > 0
+    assert warm["xla"]["hits"] == warm["xla"]["requests"], warm["xla"]
+    assert _tier_files(os.path.join(cache_dir, tier)) == []
 
 
 def test_external_cache_dir_holds_every_entry(tmp_path):
     """A fresh process with JAX_COMPILATION_CACHE_DIR set: XLA's entries
-    land directly in that directory, the engine's under its 'srtpu'
-    subdirectory, and nothing is written where the conf points."""
+    land directly in that directory and nothing is written where the conf
+    points."""
     ext, conf_dir = tmp_path / "placed", tmp_path / "conf"
-    out = _run_subprocess(str(conf_dir), "cold",
-                          JAX_COMPILATION_CACHE_DIR=str(ext))
+    out = _run_subprocess(str(conf_dir), JAX_COMPILATION_CACHE_DIR=str(ext))
     assert out["stats"]["compiles"] > 0
     names = os.listdir(ext)
     assert [n for n in names if n != "srtpu"], names    # XLA executables
-    assert (ext / "srtpu" / "manifest.json").exists()
-    assert os.listdir(ext / "srtpu" / "exports")
+    assert os.listdir(ext / "srtpu") == []
     assert not conf_dir.exists()
-
-
-def test_warm_pool_precompiles_then_hits(tmp_path, tier_reset):
-    """In-process round trip: session 1 compiles + persists; after a full
-    cache clear, session 2's warm pool replays the export and the same
-    signature dispatches with zero compiles."""
-    from spark_rapids_tpu.session import TpuSession
-    from spark_rapids_tpu.utils.compile_cache import (cache_stats,
-                                                      cached_jit,
-                                                      clear_cache,
-                                                      warm_pool_wait)
-
-    def builder():
-        def fn(x):
-            return (x * 2.0 + 1.0).sum()
-        return fn
-
-    x = jnp.arange(64, dtype=jnp.float32)
-    sess1 = TpuSession(
-        {"spark.rapids.tpu.compile.cacheDir": str(tmp_path)})
-    fn = cached_jit("test|warmpool|v1", builder, name="stage")
-    expect = float(fn(x))
-    assert cache_stats()["compiles"] == 1
-    sess1.close()           # exports + manifest land on disk
-    clear_cache()           # forget everything in-process
-
-    sess2 = TpuSession(
-        {"spark.rapids.tpu.compile.cacheDir": str(tmp_path)})
-    assert warm_pool_wait(60)
-    stats = cache_stats()
-    assert stats["persist_warmed_entries"] == 1, stats
-    assert stats["persist_warm_compiles"] == 1
-    fn2 = cached_jit("test|warmpool|v1", builder, name="stage")
-    assert float(fn2(x)) == expect
-    stats = cache_stats()
-    assert stats["compiles"] == 0, stats
-    assert stats["hits"] == 1
-    assert stats["persist_hits"] == 1
-    # an UNSEEN shape falls back to a live compile (counted), still correct
-    y = jnp.arange(128, dtype=jnp.float32)
-    assert float(fn2(y)) == float((y * 2.0 + 1.0).sum())
-    stats = cache_stats()
-    assert stats["compiles"] == 1
-    assert stats["persist_misses"] == 1
-    sess2.close()
-
-
-def test_persist_merges_deltas_not_raw_totals(tmp_path, tier_reset):
-    """A process cycling sessions (or a double close) must not re-merge
-    counts it already persisted into the cumulative manifest."""
-    import glob
-    from spark_rapids_tpu.session import TpuSession
-    from spark_rapids_tpu.utils.compile_cache import (cached_jit,
-                                                      persist_compile_cache,
-                                                      warm_pool_wait)
-
-    def builder():
-        return lambda x: x * 3.0
-
-    x = jnp.ones(16)
-    sess = TpuSession({"spark.rapids.tpu.compile.cacheDir": str(tmp_path)})
-    cached_jit("test|delta|v1", builder, name="stage")(x)
-    sess.close()
-
-    def entry():
-        (m,) = glob.glob(os.path.join(str(tmp_path), "*", "manifest.json"))
-        with open(m) as f:
-            return json.load(f)["entries"]["test|delta|v1"]
-
-    assert (entry()["compiles"], entry()["hits"]) == (1, 0)
-    persist_compile_cache()                   # double close: no growth
-    assert (entry()["compiles"], entry()["hits"]) == (1, 0)
-    # a second session in the SAME process adds only its own delta
-    sess2 = TpuSession({"spark.rapids.tpu.compile.cacheDir": str(tmp_path)})
-    warm_pool_wait(60)
-    cached_jit("test|delta|v1", builder, name="stage")(x)   # in-process hit
-    sess2.close()
-    assert (entry()["compiles"], entry()["hits"]) == (1, 1)
 
 
 def test_external_cache_dir_is_left_to_jax(tmp_path, tier_reset,
@@ -288,7 +330,6 @@ def test_external_cache_dir_is_left_to_jax(tmp_path, tier_reset,
     import jax as _jax
     from spark_rapids_tpu.utils.compile_cache import (cached_jit,
                                                       configure_compile_cache,
-                                                      persist_compile_cache,
                                                       persistent_cache_dir)
     ext = tmp_path / "placed"
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(ext))
@@ -303,11 +344,9 @@ def test_external_cache_dir_is_left_to_jax(tmp_path, tier_reset,
         cached_jit("test|external|v1", lambda: (lambda x: x * 3.0),
                    name="stage")(
             jnp.ones(8))
-        persist_compile_cache()
         assert "srtpu" in os.listdir(ext)
         assert not [n for n in os.listdir(ext)
                     if n.endswith(f"-jax{_jax.__version__}")]
-        assert (ext / "srtpu" / "manifest.json").exists()
         assert not (tmp_path / "conf").exists()
         # tier off in the next session: still not the engine's to un-wire
         assert configure_compile_cache(RapidsConf(
@@ -339,73 +378,88 @@ def test_default_cache_path_is_identical_across_calls(tmp_path, tier_reset,
     assert xla_first == os.path.join(first, "xla")
 
 
-def test_corrupted_manifest_is_dropped_not_fatal(tmp_path, tier_reset):
-    from spark_rapids_tpu.utils.compile_cache import (cache_stats,
-                                                      configure_compile_cache,
-                                                      machine_fingerprint,
-                                                      persistent_cache_dir)
-    import jax as _jax
-    tier = os.path.join(
-        str(tmp_path), f"{machine_fingerprint()}-jax{_jax.__version__}")
-    os.makedirs(tier, exist_ok=True)
-    with open(os.path.join(tier, "manifest.json"), "w") as f:
-        f.write("{ this is not json")
-    conf = RapidsConf({"spark.rapids.tpu.compile.cacheDir": str(tmp_path)})
-    assert configure_compile_cache(conf) == tier   # no raise
-    assert persistent_cache_dir() == tier
-    stats = cache_stats()
-    assert stats["persist_dropped_entries"] == 1
-    assert stats["persist_manifest_entries"] == 0
+# ---------------------------------------------------------------------------
+# no third tier: nothing of the manifest / export / warm-pool tier is left
+# ---------------------------------------------------------------------------
+def _q6(sess):
+    from spark_rapids_tpu.tools import tpch
+    lineitem = tpch.gen_lineitem(0.001, seed=0, rows=1500)
+    df = sess.create_dataframe(lineitem, num_partitions=1)
+    return tpch.q6({"lineitem": df}).collect(device=True)
 
 
-def test_corrupted_entries_and_exports_are_skipped(tmp_path, tier_reset):
-    """A bad manifest entry is dropped entry-wise; a manifest pointing at
-    a garbage export file makes the warm pool skip (warm_errors), never
-    raise."""
-    from spark_rapids_tpu.utils.compile_cache import (cache_stats,
-                                                      configure_compile_cache,
-                                                      machine_fingerprint,
-                                                      warm_pool_wait)
-    import jax as _jax
-    tier = os.path.join(
-        str(tmp_path), f"{machine_fingerprint()}-jax{_jax.__version__}")
-    os.makedirs(os.path.join(tier, "exports"), exist_ok=True)
-    with open(os.path.join(tier, "exports", "bad.jaxexport"), "wb") as f:
-        f.write(b"definitely not a serialized export")
-    manifest = {"version": 1, "entries": {
-        "good|sig": {"hits": 5, "compiles": 1, "compile_s": 0.1,
-                     "exports": [{"file": "bad.jaxexport",
-                                  "aval_sig": "abc"}]},
-        "bad-entry": {"hits": "NaN-ish"},
-        "also-bad": ["not", "a", "dict"],
-    }}
-    with open(os.path.join(tier, "manifest.json"), "w") as f:
-        json.dump(manifest, f)
-    conf = RapidsConf({"spark.rapids.tpu.compile.cacheDir": str(tmp_path)})
-    configure_compile_cache(conf)
-    assert warm_pool_wait(60)
-    stats = cache_stats()
-    assert stats["persist_manifest_entries"] == 1   # only the good entry
-    assert stats["persist_dropped_entries"] == 2
-    assert stats["persist_warm_errors"] == 1        # bad export skipped
-    assert stats["persist_warmed_entries"] == 0
-
-
-def test_no_leaked_warm_pool_threads(tmp_path, tier_reset):
-    """Session close reaps the warm pool: no tpu-warm-pool* /
-    warm-pool worker threads survive (no-leaked-threads contract)."""
+@pytest.mark.parametrize("config", ["tpch-sf1-1chip", "tpch-sf1-mesh4"])
+def test_the_benchmarks_session_conf_runs_and_close_writes_no_tier(
+        tmp_path, tier_reset, monkeypatch, config):
+    """The configuration files still carry
+    ``spark.rapids.tpu.compile.warmPool.enabled``, now an unregistered key:
+    a session opened with their ``session_conf`` as committed plans and
+    runs Q6, and ``close()`` leaves no manifest, no exports and no
+    warm-pool thread behind."""
+    from spark_rapids_tpu.conf import conf_entries, import_conf_modules
     from spark_rapids_tpu.session import TpuSession
-    from spark_rapids_tpu.utils.compile_cache import cached_jit, clear_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           config + ".json")) as f:
+        conf = dict(json.load(f)["session_conf"])
+    stale = "spark.rapids.tpu.compile.warmPool.enabled"
+    import_conf_modules()
+    assert stale in conf and stale not in {e.key for e in conf_entries()}
+    conf["spark.rapids.tpu.compile.cacheDir"] = str(tmp_path)
+    sess = TpuSession(conf)
+    try:
+        assert sess.conf.get(stale) is False          # kept raw, read by none
+        revenue = _q6(sess).column("revenue")[0].as_py()
+    finally:
+        sess.close()
+    assert revenue > 0
+    (tier,) = os.listdir(tmp_path)
+    assert _tier_files(tmp_path / tier) == []
+    assert not [t.name for t in threading.enumerate()
+                if "warm-pool" in t.name]
 
-    def builder():
-        return lambda x: x + 1.0
 
-    sess = TpuSession({"spark.rapids.tpu.compile.cacheDir": str(tmp_path)})
-    cached_jit("test|leak|v1", builder, name="stage")(jnp.ones(8))
-    sess.close()
-    clear_cache()
-    sess2 = TpuSession({"spark.rapids.tpu.compile.cacheDir": str(tmp_path)})
-    sess2.close()
-    leaked = [t.name for t in threading.enumerate()
-              if "warm-pool" in t.name and t.is_alive()]
-    assert not leaked, leaked
+@pytest.mark.parametrize("placed_by", ["conf", "environment"])
+def test_an_older_versions_tier_files_are_left_alone(
+        tmp_path, tier_reset, monkeypatch, capsys, placed_by):
+    """A directory that holds an older version's ``manifest.json`` and
+    ``exports/*.jaxexport`` (garbage in both) is opened, used and closed
+    without a read of, a write to, or a warning about either."""
+    from spark_rapids_tpu.session import TpuSession
+    from spark_rapids_tpu.utils.compile_cache import machine_fingerprint
+    if placed_by == "conf":
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        tier = tmp_path / f"{machine_fingerprint()}-jax{jax.__version__}"
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        tier = tmp_path / "srtpu"
+    (tier / "exports").mkdir(parents=True)
+    old = {tier / "manifest.json": b"{ this is not json",
+           tier / "exports" / "bad.jaxexport": b"not a serialized export"}
+    for path, data in old.items():
+        path.write_bytes(data)
+    stamps = {p: os.stat(p).st_mtime_ns for p in old}
+    opened = []
+    real_open = builtins.open
+
+    def recording_open(file, *args, **kwargs):
+        opened.append(str(file))
+        return real_open(file, *args, **kwargs)
+    monkeypatch.setattr(builtins, "open", recording_open)
+    with warnings.catch_warnings(record=True) as caught:
+        warnings.simplefilter("always")
+        sess = TpuSession({"spark.rapids.tpu.compile.cacheDir": str(tmp_path)})
+        try:
+            assert _q6(sess).num_rows == 1
+        finally:
+            sess.close()
+    monkeypatch.setattr(builtins, "open", real_open)
+    assert not [p for p in opened
+                if "manifest" in p or p.endswith(".jaxexport")], opened
+    for path, data in old.items():
+        assert path.read_bytes() == data
+        assert os.stat(path).st_mtime_ns == stamps[path]
+    assert sorted(os.listdir(tier / "exports")) == ["bad.jaxexport"]
+    said = capsys.readouterr()
+    for text in [said.out, said.err] + [str(w.message) for w in caught]:
+        assert "manifest" not in text and "export" not in text, text

@@ -178,7 +178,7 @@ def test_sentinel_clean_then_regressed(tmp_path):
 def test_sentinel_treats_recovered_chaos_run_as_clean(tmp_path):
     """A candidate whose queries carry schema-v8 fault records but no
     errors (an injected-chaos run that recovered to the right answer,
-    e.g. BENCH_CHAOS=1) is exempt from every gate — its recovery
+    e.g. a chaos drive) is exempt from every gate — its recovery
     overhead is paid on purpose. A query that regressed WITHOUT
     injection in the same run still flags."""
     store = HistoryStore(str(tmp_path / "store"))

@@ -243,7 +243,7 @@ def test_runtime_oom_spills_and_retries():
     """A RESOURCE_EXHAUSTED from the runtime triggers one synchronous
     spill + retry at the jit chokepoint — the query completes."""
     from spark_rapids_tpu.memory.catalog import BufferCatalog, set_catalog
-    from spark_rapids_tpu.utils.compile_cache import oom_retry
+    from spark_rapids_tpu.memory.retry import wrap_jit
     cat = BufferCatalog(device_limit=10**9, host_limit=10**9)
     set_catalog(cat)
     try:
@@ -258,7 +258,7 @@ def test_runtime_oom_spills_and_retries():
                     "allocate 123456 bytes.")
             return x + 1
 
-        out = oom_retry(flaky)(41)
+        out = wrap_jit(flaky)(41)
         assert out == 42 and calls["n"] == 2
         assert cat.oom_events == 1
         assert sum(cat.spill_count.values()) > 0, cat.spill_count
@@ -270,7 +270,7 @@ def test_runtime_oom_spills_and_retries():
 
 def test_runtime_oom_second_failure_dumps_diagnostics():
     from spark_rapids_tpu.memory.catalog import BufferCatalog, set_catalog
-    from spark_rapids_tpu.utils.compile_cache import oom_retry
+    from spark_rapids_tpu.memory.retry import wrap_jit
     cat = BufferCatalog(device_limit=10**9, host_limit=10**9)
     set_catalog(cat)
     try:
@@ -280,11 +280,11 @@ def test_runtime_oom_second_failure_dumps_diagnostics():
             raise RuntimeError("RESOURCE_EXHAUSTED: Out of memory")
 
         with pytest.raises(RuntimeError, match="catalog state"):
-            oom_retry(always_oom)(0)
+            wrap_jit(always_oom)(0)
         # non-OOM errors pass through untouched
         def boom(_):
             raise ValueError("unrelated")
         with pytest.raises(ValueError, match="unrelated"):
-            oom_retry(boom)(0)
+            wrap_jit(boom)(0)
     finally:
         set_catalog(None)

@@ -291,3 +291,26 @@ def test_process_cluster_dcn_tier_and_fetch_failure():
         cluster.kill(0)
         with pytest.raises(RuntimeError, match="ShuffleFetchFailed"):
             cluster.run_on(2, dcn_fetch_task, 8, 0, 0)
+
+
+def test_a_worker_that_dies_holding_its_result_lock_wedges_no_other():
+    """A multiprocessing.Queue's write lock is shared by its writers and is
+    not released when a holder dies. ``terminate()`` right after a worker's
+    answer was read catches its feeder thread still holding it (on a loaded
+    box four times in ten), and with one result queue for all workers no
+    heartbeat and no answer came through again: the fetch from a killed
+    publisher then failed six minutes later with "no live workers remain"
+    where ``ShuffleFetchFailed`` was due. Each worker has its own queue, so
+    the lock a dead worker holds is nobody else's."""
+    from spark_rapids_tpu.parallel.runtime import (ProcessCluster,
+                                                   dcn_address_task)
+    with ProcessCluster(2) as cluster:
+        lock = cluster._result_qs[0]._wlock
+        assert lock.acquire(timeout=10)     # as a worker killed mid-put leaves it
+        try:
+            cluster.kill(0)
+            host, port = cluster.run_on(1, dcn_address_task, timeout_s=60)
+            assert port > 0
+            assert cluster.live_workers() == [1]
+        finally:
+            lock.release()
