@@ -21,7 +21,7 @@ reference's merge passes.
 """
 from __future__ import annotations
 
-from typing import Callable, Iterator, List, Sequence, Tuple
+from typing import Callable, Iterator, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -33,9 +33,10 @@ from ..conf import register_conf
 from ..plan.physical import AggSpec, PhysicalPlan
 from ..plan.schema import Field, Schema
 from ..utils import metrics as M
+from ..utils.tracing import get_tracer
 from .base import TpuExec
 
-__all__ = ["TpuHashAggregateExec"]
+__all__ = ["TpuHashAggregateExec", "fused_grouped_aggregate"]
 
 _BIG = np.int64(2**62)
 
@@ -152,6 +153,17 @@ def _keys_equal_prev(sv: jax.Array) -> jax.Array:
     if jnp.issubdtype(sv.dtype, jnp.floating):
         eq = jnp.logical_or(eq, jnp.logical_and(jnp.isnan(sv), jnp.isnan(prev)))
     return eq.at[0].set(False) if eq.ndim == 1 else eq
+
+
+#: Most groups a batch may have for ``grouped`` to reduce it group by group
+#: with dense masked reductions instead of scatters into ``capacity``
+#: segments; the device picks the branch from the batch's own group count.
+#: The dense branch costs one streaming pass over the batch per live group,
+#: a scatter-add ~77 ms per buffer per 2^20 rows whatever the groups; fixed
+#: from the chip readings in PERF.md section 6 (PR 31), where the dense
+#: branch at its worst (this many groups, 2^20 rows, Q1's 11 buffers) takes
+#: under a quarter of the scatter branch's time.
+FEW_GROUPS = 16
 
 
 def _seg_sum(x, gid, cap):
@@ -273,13 +285,14 @@ def _hash_group_ids(table: "DeviceTable", key_names: List[str]):
     rehash unresolved rows until none remain (a lax.while_loop — compile
     cost is one body regardless of rounds; expected 2-4 rounds).
 
-    Returns the same contract as _sorted_group_ids but with the IDENTITY
-    order: every consumer (segment reductions, representative gather,
-    collect ranks) is order-agnostic, so the GROUPING contributes no
-    lax.sort to the program (collect_set/merge_sets dedup still sorts
-    elements) — the escape hatch for toolchains where sort compilation is
-    pathological (see spark.rapids.tpu.groupby.strategy), and the closest
-    analogue of the reference's cuDF HASH groupby."""
+    Returns the same contract as _sorted_group_ids but with NO order
+    (``None``): rows stay where they are, so a consumer reads the input
+    columns as they stand and gathers nothing by a permutation. Every
+    consumer (group reductions, representative gather) is order-agnostic,
+    so the GROUPING contributes no lax.sort to the program — the escape
+    hatch for toolchains where sort compilation is pathological (see
+    spark.rapids.tpu.groupby.strategy), and the closest analogue of the
+    reference's cuDF HASH groupby."""
     from ..shuffle.manager import _fmix_device
     cap = table.capacity
     active = table.row_mask
@@ -332,7 +345,7 @@ def _hash_group_ids(table: "DeviceTable", key_names: List[str]):
         gid = jnp.clip(jnp.take(rep_rank, winner), 0, cap - 1)
         num_groups = jnp.sum(is_rep.astype(jnp.int32))
     boundary = is_rep
-    return iota, active, gid, boundary, num_groups
+    return None, active, gid, boundary, num_groups
 
 
 GROUPBY_STRATEGY = register_conf(
@@ -568,6 +581,30 @@ class TpuHashAggregateExec(TpuExec):
         return any(op in _COLLECT_OPS
                    for (_, op, _, _) in self._columns_ops())
 
+    def _dense_ok(self) -> bool:
+        """Whether ``grouped`` holds the few-groups branch: static, from
+        the plan. Every op ``_reduce_segment`` reduces through ``_seg_*``
+        has the one-segment (plain reduce) form the branch runs; a collect
+        has none, nor a decimal128 state (its limb sums carry their own
+        scatter)."""
+        return not any(op in _COLLECT_OPS or dt.is_d128(out_dt)
+                       for (_, op, _, out_dt) in self._columns_ops())
+
+    def book_branch(self, num_groups: int) -> None:
+        """Span ``agg.dense`` / ``agg.scatter`` (``groups=``): the branch
+        of ``grouped`` that reduced a batch of ``num_groups`` groups.
+        Booked where the host already holds the batch's group count (the
+        row-count sync of the ``shrink_to_fit`` that follows, or the
+        count an exchange resolved): the device picked the branch from
+        the same number, so this adds no sync and no program. A batch
+        whose count the host never reads books neither."""
+        if not self.key_names:
+            return
+        dense = self._dense_ok() and num_groups <= FEW_GROUPS
+        with get_tracer().span("agg.dense" if dense else "agg.scatter",
+                               "agg", groups=num_groups):
+            pass
+
     def host_batch_fn(self):
         # host-engine partial aggregation over one downloaded batch — the
         # per-table body of CpuHashAggregateExec.execute. Only the
@@ -665,46 +702,121 @@ class TpuHashAggregateExec(TpuExec):
             if (_resolve_groupby_strategy() == "hash" and not has_collect) \
             else _sorted_group_ids
 
+        # where an op has no dense form the program holds only the
+        # scatter branch
+        dense_ok = self._dense_ok()
+
         def grouped(table: DeviceTable) -> DeviceTable:
             cap = table.capacity
             order, active_s, gid, boundary, num_groups = \
                 group_ids(table, key_names)
+
+            def in_order(a):
+                # only a sorted grouping permutes the rows
+                return a if order is None or a is None \
+                    else jnp.take(a, order, axis=0)
+
             key_cols = [table.column(k) for k in key_names]
             pos = jnp.arange(cap, dtype=jnp.int64)
-            out_cols: List[DeviceColumn] = []
-            iota = jnp.arange(cap, dtype=jnp.int32)
-            group_mask = iota < num_groups
-            with jax.named_scope("groupby_key_gather"):
-                # ---- representative sorted-row per group for key output
-                rep_src = jnp.where(active_s, pos, jnp.full_like(pos, _BIG))
-                rep = jnp.clip(
-                    jax.ops.segment_min(rep_src, gid, num_segments=cap),
-                    0, cap - 1).astype(jnp.int32)
-                for kc in key_cols:
-                    # representative-row gather; DeviceColumn.gather
-                    # recurses into struct children and the
-                    # element-validity plane
-                    g = kc.gather(order, keep_all_valid=True) \
-                        .gather(rep, keep_all_valid=True)
-                    out_cols.append(g.with_validity(
-                        jnp.logical_and(g.validity, group_mask)))
-            # ---- state reductions
+            inputs = []
             for in_col, op, out_col, out_dt in cols_ops:
                 col = table.column(in_col)
-                sv = jnp.take(col.data, order, axis=0)
                 contrib = active_s if col.all_valid else jnp.logical_and(
-                    jnp.take(col.validity, order), active_s)
+                    in_order(col.validity), active_s)
+                inputs.append((in_order(col.data), contrib,
+                               in_order(col.lengths)
+                               if op in _COLLECT_OPS else None))
+
+            def key_rows(rep):
+                """Key columns gathered at each group's representative
+                row (``rep``: positions in grouping order)."""
+                rep = jnp.clip(rep, 0, cap - 1).astype(jnp.int32)
+                if order is not None:
+                    rep = jnp.take(order, rep)
+                # DeviceColumn.gather recurses into struct children and
+                # the element-validity plane
+                return [kc.gather(rep, keep_all_valid=True)
+                        for kc in key_cols]
+
+            def scatter():
+                """Every row scattered into its group's segment: flat in
+                the group count, ``cap`` segments and ``cap`` key rows."""
+                with jax.named_scope("groupby_key_gather"):
+                    rep_src = jnp.where(active_s, pos,
+                                        jnp.full_like(pos, _BIG))
+                    keys = key_rows(_seg_min(rep_src, gid, cap))
+                states = []
+                with jax.named_scope("agg_scatter"):
+                    for (_, op, _, out_dt), (sv, contrib, slen) in zip(
+                            cols_ops, inputs):
+                        states.append(
+                            _collect_segment(op, sv, slen, contrib, gid, cap,
+                                             list_width)
+                            if op in _COLLECT_OPS else
+                            _reduce_segment(op, sv, contrib, gid, cap, pos,
+                                            out_dt))
+                return keys, states
+
+            def dense():
+                """Group by group, over the live groups only: each is the
+                one-segment (plain reduce) form of the same reductions over
+                ``gid == g``, so no row is scattered and a pass costs what
+                an ungrouped aggregate of the batch costs. Results land in
+                ``FEW_GROUPS`` slots, keys and ``first`` / ``last`` values
+                are gathered by as many indices, and all is padded to
+                ``cap`` rows: the same pytree as ``scatter``."""
+                # at least one slot, so the program traces with the
+                # branch switched off (FEW_GROUPS 0: only an empty batch)
+                n = max(1, min(FEW_GROUPS, cap))
+
+                def of_group(g):
+                    member = gid == g
+                    rep = _seg_min(
+                        jnp.where(jnp.logical_and(active_s, member), pos,
+                                  jnp.full_like(pos, _BIG)), gid, 1)
+                    return rep, [
+                        _reduce_segment(op, sv,
+                                        jnp.logical_and(contrib, member),
+                                        gid, 1, pos, out_dt)
+                        for (_, op, _, out_dt), (sv, contrib, _)
+                        in zip(cols_ops, inputs)]
+
+                def body(g, slots):
+                    return jax.tree_util.tree_map(
+                        lambda a, v: jax.lax.dynamic_update_slice_in_dim(
+                            a, v, g, 0), slots, of_group(g))
+
+                slots = jax.tree_util.tree_map(
+                    lambda v: jnp.zeros((n,) + v.shape[1:], v.dtype),
+                    jax.eval_shape(of_group, num_groups))
+                with jax.named_scope("agg_dense"):
+                    rep, states = jax.lax.fori_loop(0, num_groups, body,
+                                                    slots)
+                with jax.named_scope("groupby_key_gather"):
+                    keys = key_rows(rep)
+                return jax.tree_util.tree_map(
+                    lambda a: jnp.pad(a, [(0, cap - n)]
+                                      + [(0, 0)] * (a.ndim - 1)),
+                    (keys, states))
+
+            # the group ids, and so the order of the output rows, are the
+            # same whichever branch reduces them
+            keys, states = jax.lax.cond(
+                num_groups <= FEW_GROUPS, dense, scatter) if dense_ok \
+                else scatter()
+            iota = jnp.arange(cap, dtype=jnp.int32)
+            group_mask = iota < num_groups
+            out_cols: List[DeviceColumn] = [
+                g.with_validity(jnp.logical_and(g.validity, group_mask))
+                for g in keys]
+            for (_, op, _, out_dt), state in zip(cols_ops, states):
                 if op in _COLLECT_OPS:
-                    slen = None if col.lengths is None \
-                        else jnp.take(col.lengths, order)
-                    data, lens = _collect_segment(
-                        op, sv, slen, contrib, gid, cap, list_width)
-                    lens = jnp.where(group_mask, lens, 0)
-                    out_cols.append(
-                        DeviceColumn(data, group_mask, out_dt, lens))
+                    data, lens = state
+                    out_cols.append(DeviceColumn(
+                        data, group_mask, out_dt,
+                        jnp.where(group_mask, lens, 0)))
                     continue
-                vals, has = _reduce_segment(op, sv, contrib, gid, cap, pos,
-                                            out_dt)
+                vals, has = state
                 validity = jnp.logical_and(has, group_mask) if op != "count" \
                     else group_mask
                 out_cols.append(DeviceColumn(vals, validity, out_dt, None))
@@ -823,6 +935,7 @@ class TpuHashAggregateExec(TpuExec):
         from ..memory.retry import (split_device_rows, with_retry,
                                     with_retry_split)
         fn = self._canon_fn()
+        merged = self._merged_exec()
         merge_fn = None  # built lazily, loop-invariant
         catalog = get_catalog()
         pending = None  # SpillableDeviceTable holding the running merge state
@@ -834,7 +947,7 @@ class TpuHashAggregateExec(TpuExec):
             nonlocal merge_fn
             both = concat_device_tables(outs)
             if merge_fn is None:
-                merge_fn = self._merged_exec()._canon_fn()
+                merge_fn = merged._canon_fn()
             return merge_fn(both)
 
         # only the partial pass is splittable: its half-outputs are
@@ -874,7 +987,8 @@ class TpuHashAggregateExec(TpuExec):
                     # not scale with input capacity (out-of-core bound)
                     out = shrink_to_fit(with_retry_split(
                         fn, batch, splitter=splitter, combiner=agg_combine,
-                        scope="partial-agg", context=self.node_desc()))
+                        scope="partial-agg", context=self.node_desc()),
+                        on_count=self.book_branch)
                 if pending is None:
                     pending = catalog.register(
                         out, SpillPriorities.ACTIVE_ON_DECK)
@@ -889,15 +1003,16 @@ class TpuHashAggregateExec(TpuExec):
                     with pending as prev:
                         both = concat_device_tables([prev, out])
                     if merge_fn is None:
-                        merge_fn = self._merged_exec()._canon_fn()
+                        merge_fn = merged._canon_fn()
                     # spill-only retry: the concat'd pair is already at
                     # the group bucket — there is nothing useful to halve
-                    merged = shrink_to_fit(with_retry(
+                    state = shrink_to_fit(with_retry(
                         merge_fn, both, scope="agg-merge",
-                        context=self.node_desc()))
+                        context=self.node_desc()),
+                        on_count=merged.book_branch)
                     pending.close()
                     pending = catalog.register(
-                        merged, SpillPriorities.ACTIVE_ON_DECK)
+                        state, SpillPriorities.ACTIVE_ON_DECK)
             if pending is None:
                 if not self.key_names:
                     empty = _empty_device_table(self.child.schema, 8)
@@ -938,6 +1053,18 @@ class TpuHashAggregateExec(TpuExec):
 class _SchemaOnly:
     def __init__(self, schema: Schema):
         self.schema = schema
+
+
+def fused_grouped_aggregate(node) -> "Optional[TpuHashAggregateExec]":
+    """The grouped aggregate that ends ``node``'s fused chain, if one
+    does. Its batches leave the stage at input capacity with no host
+    count read yet, so the consumer that reads the count (the exchange)
+    books their ``agg.dense`` / ``agg.scatter``."""
+    chain = getattr(node, "chain", None)
+    top = chain[-1] if chain else None
+    if isinstance(top, TpuHashAggregateExec) and top.key_names:
+        return top
+    return None
 
 
 def _empty_device_table(schema: Schema, cap: int) -> DeviceTable:

@@ -475,6 +475,10 @@ class TpuLocalExchangeExec(TpuExec):
         from ..parallel.pipeline import parallel_map
         catalog = get_catalog()
         from ..columnar.device import resolve_scalars, shrink_to_fit
+        from .aggregate import fused_grouped_aggregate
+        # a fused partial aggregate's batches arrive unshrunk: their row
+        # count, read below, is the group count its branch was picked by
+        fused_agg = fused_grouped_aggregate(self.child)
         # node context is thread-local; drain() runs on pool workers, so
         # capture the query identity here (the materializing thread holds
         # the instrumented node scope) and attribute notes explicitly
@@ -496,6 +500,8 @@ class TpuLocalExchangeExec(TpuExec):
             ns = resolve_scalars(*[b.num_rows for b in batches])
             for b, n in zip(batches, ns):
                 n = int(n)
+                if fused_agg is not None:
+                    fused_agg.book_branch(n)
                 if not n:
                     continue
                 with self.metrics.timed(M.OP_TIME):

@@ -212,10 +212,12 @@ class TpuMeshStageExec(TpuExec):
             parts = outs[0]
             (_, in_rows) = chunks[0]
             counts = resolve_scalars(*[t.num_rows for t in parts])
+            final_agg = next(n for n in self.chain if _is_final_agg(n))
             for i, (t, cnt) in enumerate(zip(parts, counts)):
                 rows = int(cnt)
                 if rows == 0 and in_rows[i] == 0:
                     continue
+                final_agg.book_branch(rows)
                 out = shrink_to_fit(t, num_rows=rows)
                 per_part[i].append(out)
                 self.account_batch(rows)

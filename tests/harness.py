@@ -11,7 +11,8 @@ import math
 import numpy as np
 import pyarrow as pa
 
-__all__ = ["assert_tpu_cpu_equal", "assert_tables_equal", "data_gen"]
+__all__ = ["assert_tpu_cpu_equal", "assert_tables_equal", "data_gen",
+           "jaxpr_eqns"]
 
 
 def _sort_table(t: pa.Table) -> pa.Table:
@@ -26,6 +27,15 @@ def _sort_table(t: pa.Table) -> pa.Table:
         return t.sort_by(keys)
     except (pa.ArrowInvalid, pa.ArrowTypeError):
         return t
+
+
+def jaxpr_eqns(jaxpr):
+    """Every equation of a jaxpr, loop, call and branch bodies included."""
+    import jax
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from jaxpr_eqns(sub)
 
 
 def assert_tables_equal(actual: pa.Table, expected: pa.Table,

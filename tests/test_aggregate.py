@@ -8,7 +8,7 @@ from spark_rapids_tpu.expr.functions import (avg, col, count, count_star,
                                              min as fmin, stddev_pop,
                                              stddev_samp, sum as fsum,
                                              var_pop, var_samp)
-from harness import assert_tpu_cpu_equal, data_gen
+from harness import assert_tpu_cpu_equal, data_gen, jaxpr_eqns as _eqns
 
 
 @pytest.fixture
@@ -125,3 +125,198 @@ def test_groupby_strategy_differential(strategy):
     dev = sorted(map(str, q.collect(device=True).to_pylist()))
     cpu = sorted(map(str, q.collect(device=False).to_pylist()))
     assert dev == cpu
+
+
+def _branch_case(case: str, few: int):
+    """(table, query builder) of one few-groups differential case; ``few``
+    is the engine's own FEW_GROUPS, which the boundary cases straddle."""
+    import numpy as np
+    import spark_rapids_tpu.expr.functions as F
+    rng = np.random.default_rng([31, sum(map(ord, case))])
+    n = {"one_row": 1}.get(case, 700)
+    v = rng.normal(size=n).round(3) * 100
+    vmask = rng.random(n) < 0.15                 # a nullable input column
+    if case == "mixed_keys":
+        fk = rng.choice(np.array([np.nan, -0.0, 0.0, 1.5, -2.25]), n)
+        keys = {"fk": pa.array(fk, mask=rng.random(n) < 0.1),
+                "sk": pa.array(rng.choice(
+                    np.array(["ab", "ab\x00", "", "b", None], dtype=object),
+                    n).tolist(), type=pa.string())}
+    else:
+        groups = {"few_minus_1": few - 1, "few": few,
+                  "few_plus_1": few + 1}.get(case, 3)
+        keys = {"k": pa.array(rng.integers(0, groups, n).astype(np.int32))}
+        if case.startswith("few"):
+            # every group present, whatever the draw
+            keys["k"] = pa.array((np.arange(n) % groups).astype(np.int32))
+    t = pa.table({**keys,
+                  "v": pa.array(v, mask=vmask),
+                  "i": pa.array(rng.integers(-50, 50, n), mask=vmask),
+                  "nul": pa.array([None] * n, type=pa.float64())})
+
+    def query(df):
+        if case == "all_masked":
+            df = df.filter(col("i") > lit(1000))
+        return df.group_by(*keys).agg(
+            fsum(col("v")).alias("s"), fsum(col("i")).alias("si"),
+            count(col("v")).alias("c"), count_star().alias("n"),
+            fmin(col("v")).alias("mn"), fmax(col("i")).alias("mx"),
+            first(col("v")).alias("fst"), last(col("i")).alias("lst"),
+            avg(col("v")).alias("av"), var_samp(col("v")).alias("var"),
+            fsum(col("nul")).alias("snul"), fmax(col("nul")).alias("mnul"),
+            F.count(col("nul")).alias("cnul"))
+    return t, query
+
+
+@pytest.mark.parametrize("case", ["mixed_keys", "few_minus_1", "few",
+                                  "few_plus_1", "all_masked", "one_row"])
+@pytest.mark.parametrize("strategy", ["sort", "hash"])
+@pytest.mark.parametrize("branch", ["scatter", "dense", "picked"])
+def test_grouped_branches_differential(branch, strategy, case, monkeypatch):
+    """The same batches through the scatter branch of ``grouped``
+    (FEW_GROUPS patched to 0), its dense branch (patched past any batch)
+    and the branch the device picks, each against the host engine:
+    integers, keys and nulls equal, floats within the harness's rel_tol.
+    The ``agg.dense`` / ``agg.scatter`` spans say the forced branch ran."""
+    import spark_rapids_tpu.exec.aggregate as A
+    from spark_rapids_tpu.session import TpuSession
+    from spark_rapids_tpu.utils.compile_cache import clear_cache
+    from harness import assert_tables_equal
+    few = A.FEW_GROUPS
+    t, query = _branch_case(case, few)
+    if branch != "picked":
+        monkeypatch.setattr(A, "FEW_GROUPS", 0 if branch == "scatter"
+                            else 1 << 30)
+    clear_cache()        # programs are cached by plan, not by FEW_GROUPS
+    sess = TpuSession({"spark.rapids.tpu.batchRowsMinBucket": 64,
+                       "spark.rapids.tpu.groupby.strategy": strategy})
+    try:
+        q = query(sess.create_dataframe(t, num_partitions=1))
+        dev = q.collect(device=True)
+        phases = sess.last_query_phases()["phases"]
+        cpu = q.collect(device=False)
+    finally:
+        sess.close()
+        clear_cache()
+    assert_tables_equal(dev, cpu)
+    groups = dev.num_rows
+    took_dense = {"scatter": groups == 0, "dense": True,
+                  "picked": groups <= few}[branch]
+    assert ("agg.scatter" if took_dense else "agg.dense") not in phases
+    if t.num_rows > 64:      # a batch at the minimum bucket syncs no count
+        assert phases["agg.dense" if took_dense else "agg.scatter"]["calls"]
+
+
+# ---------------------------------------------------------------------------
+# what the grouped aggregate's program may hold (a jaxpr guard of the kind
+# tests/test_shrink_to_fit.py has): gathers and scatters cost by their index
+# count on the chip (PERF.md section 6, PR 29 and PR 31)
+# ---------------------------------------------------------------------------
+_GUARD_CAP = 1 << 14
+
+
+def _identity_gathers(jaxpr, iota_invars=()):
+    """Gathers whose indices are an ``iota`` (through the index
+    normalisation ``jnp.take`` wraps them in), branch bodies included."""
+    import jax
+    from jax.extend.core import Var
+    derived = set(iota_invars)
+    found = []
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        ins = [v for v in eqn.invars if isinstance(v, Var)]
+        if name == "gather":
+            if eqn.invars[1] in derived:
+                found.append(eqn)
+        elif name in ("cond", "pjit", "jit"):
+            # operands map one to one onto each body's inputs (after the
+            # cond's predicate)
+            args = eqn.invars[1:] if name == "cond" else eqn.invars
+            for body in jax.core.jaxprs_in_params(eqn.params):
+                found += _identity_gathers(
+                    body, [b for b, v in zip(body.invars, args)
+                           if isinstance(v, Var) and v in derived])
+            if name != "cond" and ins and all(v in derived for v in ins):
+                derived.update(eqn.outvars)     # e.g. jnp.take's _where
+        elif name == "iota" or (ins and all(v in derived for v in ins)
+                                and not list(jax.core.jaxprs_in_params(
+                                    eqn.params))):
+            derived.update(eqn.outvars)
+    return found
+
+
+@pytest.fixture(scope="module")
+def q1_shaped_jaxpr():
+    """``batch_fn()`` of a Q1-shaped partial aggregate (2 string keys, 11
+    buffers) traced at capacity 2^14 under the hash strategy."""
+    import jax
+    import numpy as np
+    from spark_rapids_tpu.exec.wholestage import TpuWholeStageExec
+    from spark_rapids_tpu.session import TpuSession
+    sess = TpuSession({"spark.rapids.tpu.batchRowsMinBucket": _GUARD_CAP,
+                       "spark.rapids.tpu.groupby.strategy": "hash",
+                       "spark.rapids.tpu.aqe.enabled": False})
+    try:
+        rng = np.random.default_rng(0)
+        n = _GUARD_CAP - 3
+        df = sess.create_dataframe(pa.table({
+            "rf": rng.choice(np.array(["A", "N", "R"]), n),
+            "ls": rng.choice(np.array(["F", "O"]), n),
+            "q": rng.uniform(0, 50, n), "p": rng.uniform(0, 1e5, n),
+            "d": rng.uniform(0, .1, n), "x": rng.uniform(0, .08, n)}))
+        disc = col("p") * (lit(1.0) - col("d"))
+        q = df.group_by("rf", "ls").agg(
+            fsum(col("q")).alias("a"), fsum(col("p")).alias("b"),
+            fsum(disc).alias("c"),
+            fsum(disc * (lit(1.0) + col("x"))).alias("e"),
+            avg(col("q")).alias("f"), avg(col("p")).alias("g"),
+            avg(col("d")).alias("h"), count_star().alias("i"))
+
+        def find(plan):
+            if isinstance(plan, TpuWholeStageExec):
+                return plan
+            return next(filter(None, map(find, plan.children)), None)
+
+        stage = find(sess._physical(q.logical, device=True))
+        partial = stage.chain[-1]
+        assert partial.mode == "partial" and len(partial._columns_ops()) == 11
+        batch = next(stage.source.execute_columnar(0))
+        for node in stage.chain[:-1]:
+            batch = node.batch_fn()(batch)
+        assert batch.capacity == _GUARD_CAP
+        return jax.make_jaxpr(partial.batch_fn())(batch).jaxpr
+    finally:
+        sess.close()
+
+
+def test_hash_grouping_gathers_by_no_identity_permutation(q1_shaped_jaxpr):
+    """``_hash_group_ids`` has no permutation to give, so no column is
+    gathered by one (the parent took every column by an ``iota``)."""
+    assert not _identity_gathers(q1_shaped_jaxpr)
+
+
+def test_grouped_holds_one_cond_with_a_dense_and_a_scatter_branch(
+        q1_shaped_jaxpr):
+    from spark_rapids_tpu.exec.aggregate import FEW_GROUPS
+    conds = [e for e in _eqns(q1_shaped_jaxpr) if e.primitive.name == "cond"]
+    assert len(conds) == 1
+    scatter, dense = (list(_eqns(b.jaxpr))
+                      for b in conds[0].params["branches"])
+    # the dense branch: no scatter, no gather longer than FEW_GROUPS
+    assert not [e for e in dense if e.primitive.name.startswith("scatter")]
+    gathers = [e for e in dense if e.primitive.name == "gather"]
+    assert gathers
+    for e in gathers:
+        assert e.invars[1].aval.shape[0] <= FEW_GROUPS, e
+        assert e.outvars[0].aval.shape[0] <= FEW_GROUPS, e
+    # the scatter branch: a value and a count scatter-add for each of the 7
+    # sums, a count for each of the 4 counts (ROADMAP A1(b): buffers over
+    # one input can share a count), and the representative row's scatter-min
+    names = [e.primitive.name for e in scatter]
+    assert names.count("scatter-add") <= 18, names.count("scatter-add")
+    assert [n for n in names if n.startswith("scatter")
+            and n != "scatter-add"] == ["scatter-min"]
+    # outside the branches only the bucket-resolve loop scatters
+    outside = [e.primitive.name for e in q1_shaped_jaxpr.eqns
+               if e.primitive.name.startswith("scatter")]
+    assert not outside, outside

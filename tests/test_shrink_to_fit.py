@@ -14,6 +14,8 @@ from spark_rapids_tpu.columnar.device import (DeviceColumn, DeviceTable,
                                               prefix_sum, shrink_to_fit,
                                               stable_partition_order)
 
+from harness import jaxpr_eqns as _eqns
+
 MIN_BUCKET = 1024
 
 
@@ -154,14 +156,6 @@ def test_prefix_sum_is_cumsum(n):
                                   np.cumsum(x, axis=-1))
 
 
-def _eqns(jaxpr):
-    """Every equation of a jaxpr, call and branch bodies included."""
-    for eqn in jaxpr.eqns:
-        yield eqn
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _eqns(sub)
-
-
 def _wide_table() -> DeviceTable:
     rng = np.random.default_rng(3)
     mask = rng.random(1 << 14) < 0.01
@@ -244,3 +238,38 @@ def test_a_small_query_books_its_shrinks_by_span(rows, expect, monkeypatch):
     assert all(out < cap for cap, out, _ in ran)
     assert phases.get("shrink", {"calls": 0})["calls"] == len(ran)
     assert phases.get("shrink.skip", {"calls": 0})["calls"] == len(skipped)
+
+
+@pytest.mark.parametrize("groups,span,dispatches",
+                         [(3, "agg.dense", 8), (5000, "agg.scatter", 7)],
+                         ids=["few_groups", "many_groups"])
+def test_a_grouped_query_books_the_branch_its_batches_took(groups, span,
+                                                           dispatches):
+    """Every aggregate batch of a filtered group-by over two partitions —
+    the two fused partials, whose count the exchange resolves, and the final
+    merge, whose count its ``shrink_to_fit`` syncs — books ``agg.dense``
+    (at most FEW_GROUPS groups) or ``agg.scatter``, from the count the host
+    already held: programs and blocking syncs are the parent's (PR 30: 8 / 7
+    dispatches — the 5,000-group final state skips one shrink — and 3 syncs
+    + 1 download either way)."""
+    import pyarrow as pa
+    from spark_rapids_tpu.expr.functions import col, lit, sum as fsum
+    from spark_rapids_tpu.session import TpuSession
+
+    sess = TpuSession({"spark.rapids.tpu.batchRowsMinBucket": 64})
+    try:
+        rng = np.random.default_rng(11)
+        rows = 20000
+        df = sess.create_dataframe(pa.table({
+            "k": rng.integers(0, groups, rows).astype(np.int32),
+            "v": rng.random(rows)}), num_partitions=2)
+        got = df.filter(col("v") > lit(0.5)).group_by("k") \
+            .agg(fsum(col("v")).alias("s")).collect(device=True)
+        assert got.num_rows == (3 if groups == 3 else 4275)
+        phases = sess.last_query_phases()["phases"]
+    finally:
+        sess.close()
+    other = ({"agg.dense", "agg.scatter"} - {span}).pop()
+    assert phases[span]["calls"] == 3 and other not in phases, phases
+    assert phases["dispatch"]["calls"] == dispatches
+    assert (phases["sync"]["calls"], phases["d2h"]["calls"]) == (3, 1)

@@ -127,8 +127,11 @@ def test_grouped_hash_aggregate(topo, one_chip, sess, rng):
     assert stage is not None, plan.tree_string()
     batch = next(stage.source.execute_columnar(0))
     partial = stage.batch_fn()
-    _compile(partial, (batch,), one_chip)
+    text = _compile(partial, (batch,), one_chip).as_text()
     _compile(final.batch_fn(), (partial(batch),), one_chip)
+    # the few-groups branch of `grouped`: one conditional, whose dense side
+    # loops over the live groups
+    assert text.count(" conditional(") == 1
 
 
 def test_pk_hash_join(topo, one_chip, sess, rng):
