@@ -43,6 +43,7 @@ from ..plan.physical import PhysicalPlan
 from ..plan.schema import Field, Schema
 from ..utils import metrics as M
 from ..utils.compile_cache import cached_jit
+from ..utils.tracing import get_tracer
 from .base import TpuExec
 
 # grace sub-partitioning uses its own hash seed: the upstream exchange
@@ -193,6 +194,12 @@ def _null_device_column(dtype: dt.DataType, capacity: int) -> DeviceColumn:
 
 _I64_MAX = np.int64(2**63 - 1)
 
+#: the hash prep runs full-capacity rounds while more than this share of
+#: the build's capacity is unplaced, then one compaction and rounds that
+#: long: a unique build at a load of 0.5 leaves a quarter of its rows after
+#: the first round and a sixteenth after the second
+_PREP_TAIL_SHARE = 16
+
 from ..conf import register_conf  # noqa: E402  (grouped with sibling confs)
 
 JOIN_STRATEGY = register_conf(
@@ -310,89 +317,98 @@ class _JoinKernels:
     def build_prep_hash_fn(self):
         """SORT-FREE build prep: vectorized open-addressing insertion into
         a 2x-capacity slot table (double hashing; each while_loop round
-        claims empty slots by minimum row index). Duplicate keys are
-        detected during insertion — the PK fast path only engages when the
-        build side is unique, same as the sorted prep. No lax.sort
-        anywhere (spark.rapids.tpu.join.strategy; reference analogue:
-        cuDF's hash join build)."""
+        claims empty slots by minimum row index). No lax.sort anywhere
+        (spark.rapids.tpu.join.strategy; reference analogue: cuDF's hash
+        join build).
+
+        The table holds ONE row a distinct key. Equal keys share their
+        hash and step, so they contend for the same slot in the same round:
+        when one of them claims it, the others see their own key in the
+        winner and retire (``twin``) — which is also how duplicates are
+        detected: ``unique`` is "no row ever retired as a twin". The PK fast
+        path needs a unique build; semi / anti joins only ask existence and
+        probe any build. (A duplicate that claimed a slot of its own would
+        cost a round a row of the longest chain: 22-30 rounds over
+        ``sf1.q4``'s 2^23 rows, moving with the seed.)
+
+        A round costs by the rows it is run over, not by the rows still
+        unplaced, and the last rounds place a handful. So full-capacity
+        rounds run only while more than 1/``_PREP_TAIL_SHARE`` of the
+        capacity is unplaced; the rest are compacted once and finish in
+        rounds that long. -> (slot_row, keys, unique, rounds,
+        full_rounds): the trip counts ride on span ``join.prep``."""
         def fn(build_keys: DeviceTable):
+            from ..columnar.device import prefix_sum
+            from ..shuffle.manager import _fmix_device
             bc = build_keys.columns[0]
             bmask = jnp.logical_and(bc.validity, build_keys.row_mask)
             bv = _monotone_i64(bc.data)
             cap = bv.shape[0]
             T = 2 * cap                       # pow2 (capacity is pow2)
+            tail_cap = max(cap // _PREP_TAIL_SHARE, 1)
             mask = jnp.uint32(T - 1)
             u = jax.lax.bitcast_convert_type(bv, jnp.uint64)
             lo = (u & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32)
             hi = (u >> jnp.uint64(32)).astype(jnp.uint32)
-            from ..shuffle.manager import _fmix_device
             h1 = _fmix_device(lo ^ _fmix_device(hi))
             step = (_fmix_device(h1 ^ jnp.uint32(0x9E3779B9))
                     | jnp.uint32(1))          # odd: full cycle over pow2 T
             iota = jnp.arange(cap, dtype=jnp.int32)
             big = jnp.int32(cap)
 
-            def cond(state):
-                r, slot_row, placed, dup = state
-                return jnp.logical_and(jnp.logical_not(jnp.all(placed)),
-                                       r < T)
+            def insert(rows, keys, h, s):
+                """The loop body over build rows ``rows`` (their keys,
+                hashes and steps beside them)."""
+                def body(state):
+                    r, slot_row, active, dup = state
+                    bucket = ((h + r.astype(jnp.uint32) * s) & mask) \
+                        .astype(jnp.int32)
+                    want = jnp.logical_and(active,
+                                           jnp.take(slot_row, bucket) < 0)
+                    claim = jax.ops.segment_min(jnp.where(want, rows, big),
+                                                bucket, num_segments=T)
+                    winner = jnp.take(claim, bucket)
+                    lost = jnp.logical_and(want, winner != rows)
+                    twin = jnp.logical_and(
+                        lost,
+                        jnp.take(bv, jnp.clip(winner, 0, cap - 1)) == keys)
+                    slot_row = jnp.where(
+                        jnp.logical_and(slot_row < 0, claim < big),
+                        claim, slot_row)
+                    # still to place: lost to another key, or slot taken
+                    active = jnp.logical_and(
+                        active, jnp.logical_or(
+                            jnp.logical_not(want),
+                            jnp.logical_and(lost, jnp.logical_not(twin))))
+                    return (r + 1, slot_row, active,
+                            jnp.logical_or(dup, jnp.any(twin)))
+                return body
 
-            def body(state):
-                r, slot_row, placed, dup = state
-                bucket = ((h1 + r.astype(jnp.uint32) * step) & mask) \
-                    .astype(jnp.int32)
-                occ = jnp.take(slot_row, bucket)
-                occ_safe = jnp.clip(occ, 0, cap - 1)
-                same = jnp.logical_and(occ >= 0,
-                                       jnp.take(bv, occ_safe) == bv)
-                dup = jnp.logical_or(
-                    dup, jnp.logical_and(jnp.logical_not(placed), same))
-                want = jnp.logical_and(jnp.logical_not(placed), occ < 0)
-                cand = jnp.where(want, iota, big)
-                claim = jax.ops.segment_min(cand, bucket, num_segments=T)
-                won = jnp.logical_and(want,
-                                      jnp.take(claim, bucket) == iota)
-                slot_row = jnp.where(
-                    jnp.logical_and(slot_row < 0, claim < big),
-                    claim, slot_row)
-                placed = jnp.logical_or(placed, won)
-                return r + 1, slot_row, placed, dup
+            def many_left(state):
+                r, _, active, _ = state
+                return jnp.logical_and(
+                    jnp.sum(active, dtype=jnp.int32) > tail_cap, r < T)
 
-            init = (jnp.int32(0), jnp.full(T, -1, jnp.int32),
-                    jnp.logical_not(bmask), jnp.zeros(cap, dtype=bool))
-            _, slot_row, _, _ = jax.lax.while_loop(cond, body, init)
+            def any_left(state):
+                r, _, active, _ = state
+                return jnp.logical_and(jnp.any(active), r < T)
 
-            # uniqueness via SELF-PROBE: walk each build key's chain; with
-            # duplicates the later row's walk hits the earlier row first,
-            # so found_row != self. (In-round dup insertion evades the
-            # insertion-time check: two equal keys claiming different
-            # slots in the same sweep never see each other.)
-            def pcond(state):
-                r, resolved, found_row = state
-                return jnp.logical_and(jnp.logical_not(jnp.all(resolved)),
-                                       r < T)
-
-            def pbody(state):
-                r, resolved, found_row = state
-                bucket = ((h1 + r.astype(jnp.uint32) * step) & mask) \
-                    .astype(jnp.int32)
-                row = jnp.take(slot_row, bucket)
-                empty = row < 0
-                row_safe = jnp.clip(row, 0, cap - 1)
-                eq = jnp.logical_and(jnp.logical_not(empty),
-                                     jnp.take(bv, row_safe) == bv)
-                hit = jnp.logical_and(jnp.logical_not(resolved), eq)
-                found_row = jnp.where(hit, row_safe, found_row)
-                resolved = jnp.logical_or(resolved,
-                                          jnp.logical_or(empty, eq))
-                return r + 1, resolved, found_row
-
-            pinit = (jnp.int32(0), jnp.logical_not(bmask),
-                     jnp.full(cap, -1, jnp.int32))
-            _, _, found_row = jax.lax.while_loop(pcond, pbody, pinit)
-            unique = jnp.all(jnp.logical_or(jnp.logical_not(bmask),
-                                            found_row == iota))
-            return slot_row, bv, unique
+            full_rounds, slot_row, active, dup = jax.lax.while_loop(
+                many_left, insert(iota, bv, h1, step),
+                (jnp.int32(0), jnp.full(T, -1, jnp.int32), bmask,
+                 jnp.zeros((), dtype=bool)))
+            # the rows still unplaced, at most tail_cap, by their rank
+            a32 = active.astype(jnp.int32)
+            dest = jnp.where(active, prefix_sum(a32) - a32, tail_cap)
+            rows = jnp.zeros(tail_cap, jnp.int32).at[dest].set(
+                iota, mode="drop")
+            live = jnp.arange(tail_cap, dtype=jnp.int32) < jnp.sum(a32)
+            rounds, slot_row, _, dup = jax.lax.while_loop(
+                any_left,
+                insert(rows, jnp.take(bv, rows), jnp.take(h1, rows),
+                       jnp.take(step, rows)),
+                (full_rounds, slot_row, live, dup))
+            return slot_row, bv, jnp.logical_not(dup), rounds, full_rounds
         return fn
 
     def pk_hash_join_fn(self, how: str):
@@ -778,9 +794,8 @@ class TpuShuffledHashJoinExec(TpuExec):
                            jnp.sum(emit, dtype=jnp.int32), tuple(names))
 
     # -- execution ------------------------------------------------------------
-    def _build_table(self, pidx: int) -> DeviceTable:
+    def _concat_build(self, batches: List[DeviceTable]) -> DeviceTable:
         from ..memory.retry import with_retry
-        batches = list(_device_batches(self.right, pidx))
         if not batches:
             from .aggregate import _empty_device_table
             return _empty_device_table(self.right.schema, self.min_bucket)
@@ -811,13 +826,28 @@ class TpuShuffledHashJoinExec(TpuExec):
                 self.account_batch()
                 yield out
 
+    def _open_build(self, pidx: int):
+        """-> (build table, its SpillableDeviceTable or None where the
+        table is over the batch budget and the join goes grace,
+        close_when_done). Span ``join.build`` (``rows`` = capacity,
+        ``bytes``), one a build table: the child's drain and the
+        ``srt_concat`` dispatch nest inside it."""
+        from ..memory.catalog import SpillPriorities, get_catalog
+        with get_tracer().span("join.build", "join") as span:
+            build = self._concat_build(
+                list(_device_batches(self.right, pidx)))
+            span.note(rows=build.capacity, bytes=build.nbytes())
+            if build.nbytes() > self.batch_bytes:
+                return build, None, False
+            return build, get_catalog().register(
+                build, SpillPriorities.ACTIVE_ON_DECK), True
+
     def _join_batches(self, pidx: int) -> Iterator[DeviceTable]:
-        build = self._build_table(pidx)
-        if build.nbytes() > self.batch_bytes:
+        build, handle, own = self._open_build(pidx)
+        if handle is None:
             yield from self._grace_join(build, pidx)
             return
         build_cap = build.capacity
-        handle, own = self._register_build(build)
         del build  # the catalog handle is the owner from here on
         track = self.how in ("right", "full")
         seen_box = [jnp.zeros(build_cap, dtype=bool)] if track else None
@@ -846,12 +876,6 @@ class TpuShuffledHashJoinExec(TpuExec):
         def run(build: DeviceTable, seen) -> DeviceTable:
             return fn(build.canonical(), seen).with_names(out_names)
         return run
-
-    def _register_build(self, build: DeviceTable):
-        """-> (SpillableDeviceTable, close_when_done)."""
-        from ..memory.catalog import SpillPriorities, get_catalog
-        return (get_catalog().register(build, SpillPriorities.ACTIVE_ON_DECK),
-                True)
 
     def _direct_key_ok(self) -> bool:
         """Single-key joins on identical non-nested, non-string dtypes use
@@ -895,7 +919,9 @@ class TpuShuffledHashJoinExec(TpuExec):
 
     def _get_prep_hash(self, build: DeviceTable):
         """Per-build-table HASH prep (slot table + key array + uniqueness),
-        cached like the sorted prep; no lax.sort in the prep program."""
+        cached like the sorted prep; no lax.sort in the prep program. A
+        miss books span ``join.prep`` (``rows``, ``unique``, ``rounds``,
+        ``full_rounds``), a hit nothing."""
         prep = cached_jit("JoinC|prepH", self._kernels.build_prep_hash_fn,
                           name="join_prep_hash")
         lock = self.__dict__.setdefault("_prep_lock",
@@ -903,10 +929,20 @@ class TpuShuffledHashJoinExec(TpuExec):
         with lock:
             hit = self.__dict__.get("_prep_cache_hash")
             if hit is None or hit[0] is not build.row_mask:
-                slot_row, bv, unique = prep(_key_view(build,
-                                                      self.right_keys))
-                pr = self._register_prep_hash(slot_row, bv, unique)
-                hit = (build.row_mask, pr)
+                with get_tracer().span("join.prep", "join",
+                                       rows=build.capacity) as span:
+                    slot_row, bv, unique, rounds, full_rounds = prep(
+                        _key_view(build, self.right_keys))
+                    handle = self._register_prep_hash(slot_row, bv)
+                    # uniqueness gates the PK fast path: one batched-funnel
+                    # transfer per build table (cached across probe
+                    # batches/partitions); the insertion loop's trip counts
+                    # ride in it
+                    uniq, rounds, full_rounds = resolve_scalars(
+                        unique, rounds, full_rounds)
+                    span.note(unique=bool(uniq), rounds=int(rounds),
+                              full_rounds=int(full_rounds))
+                hit = (build.row_mask, (handle, bool(uniq)))
                 old = self.__dict__.get("_prep_cache_hash")
                 if old is not None:
                     _close_quietly(old[1][0])
@@ -916,7 +952,7 @@ class TpuShuffledHashJoinExec(TpuExec):
         cap = pt.capacity // 2
         return pt.columns[0].data, pt.columns[1].data[:cap], unique
 
-    def _register_prep_hash(self, slot_row, bv, unique):
+    def _register_prep_hash(self, slot_row, bv):
         from ..columnar.device import canonical_names
         from ..memory.catalog import SpillPriorities, get_catalog
         T = slot_row.shape[0]
@@ -928,10 +964,7 @@ class TpuShuffledHashJoinExec(TpuExec):
                         canonical_names(2))
         h = get_catalog().register(t, SpillPriorities.ACTIVE_ON_DECK)
         self._own_spill_handle(h)
-        # uniqueness gates the PK fast path: one batched-funnel transfer
-        # per build table (cached across probe batches/partitions)
-        (uniq,) = resolve_scalars(unique)
-        return (h, bool(uniq))
+        return h
 
     def _get_prep(self, build: DeviceTable):
         """Per-build-table sorted-key prep: (b_order, sv, nvalid, unique).
@@ -942,7 +975,8 @@ class TpuShuffledHashJoinExec(TpuExec):
         registered spillable so memory pressure can evict them; single
         entry, replaced on build change, race-safe (each thread uses the
         tuple it computed or read, never a second dict lookup). ``unique``
-        is host-synced once per build (it gates the PK fast path)."""
+        is host-synced once per build (it gates the PK fast path). A miss
+        books span ``join.prep`` (``rows``, ``unique``)."""
         prep = cached_jit("JoinC|prepD", self._kernels.build_prep_fn,
                           name="join_prep_dense")
         lock = self.__dict__.setdefault("_prep_lock",
@@ -950,8 +984,11 @@ class TpuShuffledHashJoinExec(TpuExec):
         with lock:
             hit = self.__dict__.get("_prep_cache")
             if hit is None or hit[0] is not build.row_mask:
-                pr = self._register_prep(
-                    prep(_key_view(build, self.right_keys)))
+                with get_tracer().span("join.prep", "join",
+                                       rows=build.capacity) as span:
+                    pr = self._register_prep(
+                        prep(_key_view(build, self.right_keys)))
+                    span.note(unique=pr[2])
                 hit = (build.row_mask, pr)
                 old = self.__dict__.get("_prep_cache")
                 if old is not None:
@@ -994,96 +1031,112 @@ class TpuShuffledHashJoinExec(TpuExec):
         pk_eligible = (not has_cond and self._direct_key_ok()
                        and self.how in ("inner", "left", "left_semi",
                                         "left_anti"))
+        tracer = get_tracer()
         for probe in probe_batches:
             with self.metrics.timed(M.JOIN_TIME), build_handle as build:
                 probe = _co_locate(probe, build)
-                if pk_eligible:
-                    out_names = tuple(self.schema.names) \
-                        if self.how in ("inner", "left") \
-                        else tuple(probe.names)
-                    clone, ckey = self._canon()
-                    out = None
-                    if _resolve_join_strategy() == "hash":
-                        # sort-free tier: open-addressing slot table.
-                        # semi/anti only ask EXISTENCE, so duplicate build
-                        # keys are fine (the chain walk finds any
-                        # representative); inner/left need uniqueness for
-                        # the single-match gather
-                        slot_row, bv, unique = self._get_prep_hash(build)
-                        if unique or self.how in ("left_semi",
-                                                  "left_anti"):
-                            fused = cached_jit(
-                                ckey + f"|pkh|{self.how}",
-                                lambda: clone._kernels
-                                .pk_hash_join_fn(self.how),
-                                name="join_pk_hash")
-                            out = fused(build.canonical(),
-                                        probe.canonical(),
-                                        _key_view(probe, self.left_keys),
-                                        slot_row, bv)
-                    else:
-                        b_order, sv, nvalid, unique = self._get_prep(build)
-                        if unique:
-                            # FK->PK: counts are 0/1, output fits the
-                            # probe capacity — one fused program, no
-                            # count sync
-                            fused = cached_jit(
-                                ckey + f"|pk|{self.how}",
-                                lambda: clone._kernels
-                                .pk_join_fn(self.how),
-                                name="join_pk")
-                            out = fused(build.canonical(),
-                                        probe.canonical(),
-                                        _key_view(probe, self.left_keys),
-                                        b_order, sv, nvalid)
-                    if out is not None:
-                        out = out.with_names(out_names)
-                        if self.how in ("inner", "left_semi", "left_anti"):
-                            # selective joins keep the probe CAPACITY with
-                            # a mask; shrink (one int sync) so downstream
-                            # sorts/groupbys don't run over dead padding
-                            out = shrink_to_fit(out, self.min_bucket)
-                        yield out
-                        continue
+                pk = self._pk_program(build) if pk_eligible else None
+                if pk is not None:
+                    # FK->PK or existence: counts are 0/1, output fits the
+                    # probe capacity — one fused program, no count sync
+                    fused, prep = pk
+                    with tracer.span("join.probe.pk", "join",
+                                     rows=probe.capacity):
+                        out = fused(build.canonical(), probe.canonical(),
+                                    _key_view(probe, self.left_keys), *prep) \
+                            .with_names(self.schema.names
+                                        if self.how in ("inner", "left")
+                                        else probe.names)
+                    if self.how in ("inner", "left_semi", "left_anti"):
+                        # selective joins keep the probe CAPACITY with
+                        # a mask; shrink (one int sync) so downstream
+                        # sorts/groupbys don't run over dead padding
+                        out = shrink_to_fit(out, self.min_bucket)
+                    yield out
+                    continue
                 if seen_box is not None and hasattr(seen_box[0], "devices") \
                         and hasattr(build.row_mask, "devices") \
                         and seen_box[0].devices() != build.row_mask.devices():
                     seen_box[0] = jax.device_put(
                         seen_box[0], next(iter(build.row_mask.devices())))
-                b_order, starts, counts, matched = counts_fn(build, probe)
-                if matched is not None:
-                    seen_box[0] = jnp.logical_or(seen_box[0], matched)
                 if self.how in ("left_semi", "left_anti") and not has_cond:
-                    anti = self.how == "left_anti"
-                    fn = cached_jit(
-                        f"JoinC|semi|{anti}",
-                        lambda: self._kernels.semi_mask_fn(anti),
-                        name="join_semi")
-                    yield fn(probe.canonical(), counts) \
-                        .with_names(probe.names)
+                    with tracer.span("join.probe.semi", "join",
+                                     rows=probe.capacity):
+                        _, _, counts, _ = counts_fn(build, probe)
+                        anti = self.how == "left_anti"
+                        fn = cached_jit(
+                            f"JoinC|semi|{anti}",
+                            lambda: self._kernels.semi_mask_fn(anti),
+                            name="join_semi")
+                        out = fn(probe.canonical(), counts) \
+                            .with_names(probe.names)
+                    yield out
                     continue
-                outer_slots = self.how in ("left", "full") and not has_cond
-                # output capacity is data-dependent: one batched-funnel
-                # transfer resolves the slot total (the decision boundary)
-                (total,) = resolve_scalars(
-                    jnp.sum(jnp.where(
-                        probe.row_mask,
-                        jnp.maximum(counts, 1) if outer_slots else counts, 0)))
-                total = int(total)
-                max_out = self._max_out_rows()
-                if total > max_out:
-                    # oversized gather: emit in probe row windows (reference:
-                    # AbstractGpuJoinIterator sub-partitions the gather)
-                    yield from self._windowed_expand(build, probe, total,
-                                                     max_out, counts_fn,
-                                                     seen_box)
-                    continue
+                yield from self._probe_expand(build, probe, counts_fn,
+                                              seen_box)
+
+    def _pk_program(self, build: DeviceTable):
+        """-> (the fused single-match / existence program, the prepared
+        arrays of this build table it takes last), or None where the
+        build's keys repeat and the join needs every match (the counts +
+        expand path). Runs the build's prep on its first call."""
+        clone, ckey = self._canon()
+        if _resolve_join_strategy() == "hash":
+            # sort-free tier: open-addressing slot table. semi/anti only
+            # ask EXISTENCE, so duplicate build keys are fine (the chain
+            # walk finds any representative); inner/left need uniqueness
+            # for the single-match gather
+            slot_row, bv, unique = self._get_prep_hash(build)
+            if not (unique or self.how in ("left_semi", "left_anti")):
+                return None
+            fused = cached_jit(
+                ckey + f"|pkh|{self.how}",
+                lambda: clone._kernels.pk_hash_join_fn(self.how),
+                name="join_pk_hash")
+            return fused, (slot_row, bv)
+        b_order, sv, nvalid, unique = self._get_prep(build)
+        if not unique:
+            return None
+        fused = cached_jit(
+            ckey + f"|pk|{self.how}",
+            lambda: clone._kernels.pk_join_fn(self.how),
+            name="join_pk")
+        return fused, (b_order, sv, nvalid)
+
+    def _probe_expand(self, build: DeviceTable, probe: DeviceTable,
+                      counts_fn, seen_box) -> Iterator[DeviceTable]:
+        """Counts, the ``total`` sync that sizes the output, and the expand
+        of one probe batch or window: span ``join.probe.expand``, closed
+        before anything is yielded. An oversized gather goes on in probe
+        row windows, each a span of its own."""
+        outer_slots = self.how in ("left", "full") and self.condition is None
+        with get_tracer().span("join.probe.expand", "join",
+                               rows=probe.capacity):
+            b_order, starts, counts, matched = counts_fn(build, probe)
+            if matched is not None:
+                seen_box[0] = jnp.logical_or(seen_box[0], matched)
+            # output capacity is data-dependent: one batched-funnel
+            # transfer resolves the slot total (the decision boundary)
+            (total,) = resolve_scalars(
+                jnp.sum(jnp.where(
+                    probe.row_mask,
+                    jnp.maximum(counts, 1) if outer_slots else counts, 0)))
+            total = int(total)
+            max_out = self._max_out_rows()
+            outs = None
+            if total <= max_out:
                 out_cap = bucket_rows(max(total, 1), self.min_bucket)
-                yield from self._expand_one(build, probe, b_order, starts,
-                                            counts, out_cap, seen_box)
+                outs = self._expand_one(build, probe, b_order, starts,
+                                        counts, out_cap, seen_box)
+        if outs is None:
+            # oversized gather: emit in probe row windows (reference:
+            # AbstractGpuJoinIterator sub-partitions the gather)
+            outs = self._windowed_expand(build, probe, total, max_out,
+                                         counts_fn, seen_box)
+        yield from outs
 
     def _expand_one(self, build, probe, b_order, starts, counts, out_cap,
-                    seen_box) -> Iterator[DeviceTable]:
+                    seen_box) -> List[DeviceTable]:
         """One expand call on a probe batch/window (post-count)."""
         how = self.how
         out_names = tuple(self.schema.names)
@@ -1095,9 +1148,8 @@ class TpuShuffledHashJoinExec(TpuExec):
                 ckey + f"|expand{out_cap}|{eff}",
                 lambda: clone._kernels.expand_fn(out_cap, eff),
                 name="join_expand")
-            yield expand(build.canonical(), probe.canonical(), b_order,
-                         starts, counts).with_names(out_names)
-            return
+            return [expand(build.canonical(), probe.canonical(), b_order,
+                           starts, counts).with_names(out_names)]
         if how == "inner":
             clone, ckey = self._canon()
             expand = cached_jit(
@@ -1109,21 +1161,18 @@ class TpuShuffledHashJoinExec(TpuExec):
             cond_fn = cached_jit(self.plan_signature() + "|cond",
                                  lambda: _condition_filter_fn(self.condition),
                                  name="join_cond")
-            yield cond_fn(out)
-            return
+            return [cond_fn(out)]
         fn = cached_jit(self.plan_signature() + f"|condexpand{out_cap}",
                         lambda: self._kernels.expand_cond_fn(out_cap, how),
                         name="join_expand_cond")
         res = fn(build, probe, b_order, starts, counts)
         if how in ("left_semi", "left_anti"):
-            yield res
-            return
+            return [res]
         outs = list(res) if isinstance(res, tuple) else [res]
         if how in ("right", "full"):
             seen_upd = outs.pop()  # last element by expand_cond_fn contract
             seen_box[0] = jnp.logical_or(seen_box[0], seen_upd)
-        for t in outs:
-            yield t
+        return outs
 
     def _windowed_expand(self, build: DeviceTable, probe: DeviceTable,
                          total: int, max_out: int, counts_fn, seen_box=None
@@ -1140,22 +1189,27 @@ class TpuShuffledHashJoinExec(TpuExec):
         while start < nrows:
             window = slice_rows(probe, start, wsize)
             start += wsize
-            b_order, starts, counts, _ = counts_fn(build, window)
-            (wtotal,) = resolve_scalars(jnp.sum(jnp.where(
-                window.row_mask,
-                jnp.maximum(counts, 1) if outer_slots else counts, 0)))
-            wtotal = int(wtotal)
-            if wtotal == 0 and not outer_slots and self.condition is None \
-                    and self.how not in ("left_semi", "left_anti"):
-                continue
-            if wtotal > 2 * max_out and wsize > self.min_bucket:
+            outs = None
+            with get_tracer().span("join.probe.expand", "join",
+                                   rows=window.capacity):
+                b_order, starts, counts, _ = counts_fn(build, window)
+                (wtotal,) = resolve_scalars(jnp.sum(jnp.where(
+                    window.row_mask,
+                    jnp.maximum(counts, 1) if outer_slots else counts, 0)))
+                wtotal = int(wtotal)
+                if wtotal == 0 and not outer_slots \
+                        and self.condition is None \
+                        and self.how not in ("left_semi", "left_anti"):
+                    continue
+                if wtotal <= 2 * max_out or wsize <= self.min_bucket:
+                    out_cap = bucket_rows(max(wtotal, 1), self.min_bucket)
+                    outs = self._expand_one(build, window, b_order, starts,
+                                            counts, out_cap, seen_box)
+            if outs is None:
                 # skewed window: recurse with smaller windows
-                yield from self._windowed_expand(build, window, wtotal,
-                                                 max_out, counts_fn, seen_box)
-                continue
-            out_cap = bucket_rows(max(wtotal, 1), self.min_bucket)
-            yield from self._expand_one(build, window, b_order, starts,
-                                        counts, out_cap, seen_box)
+                outs = self._windowed_expand(build, window, wtotal, max_out,
+                                             counts_fn, seen_box)
+            yield from outs
 
     # -- grace-style sub-partitioned join (build side over budget) -----------
     def _grace_split(self, table: DeviceTable, keys: List[str], n_sub: int
@@ -1178,20 +1232,25 @@ class TpuShuffledHashJoinExec(TpuExec):
         from ..memory.catalog import SpillPriorities, get_catalog
         catalog = get_catalog()
         n_sub = min(64, max(2, math.ceil(build.nbytes() / self.batch_bytes)))
-        build_parts, own_build = self._grace_build_parts(build, n_sub)
-        del build
         track = self.how in ("right", "full")
+        build_parts, own_build = [], False
         probe_parts: List[List] = [[] for _ in range(n_sub)]
         try:
-            for probe in _device_batches(self.left, pidx):
-                parts = self._grace_split(probe, self.left_keys, n_sub)
-                # one batched-funnel transfer resolves every bucket's
-                # count instead of n_sub per-bucket syncs
-                ns = resolve_scalars(*[t.num_rows for t in parts])
-                for s, (t, tn) in enumerate(zip(parts, ns)):
-                    if int(tn):
-                        probe_parts[s].append(
-                            catalog.register(t, SpillPriorities.INPUT))
+            # span ``join.grace``: both sides split into ``parts`` buckets
+            # (closed before the pairwise joins yield anything)
+            with get_tracer().span("join.grace", "join", parts=n_sub):
+                build_parts, own_build = self._grace_build_parts(build,
+                                                                 n_sub)
+                del build
+                for probe in _device_batches(self.left, pidx):
+                    parts = self._grace_split(probe, self.left_keys, n_sub)
+                    # one batched-funnel transfer resolves every bucket's
+                    # count instead of n_sub per-bucket syncs
+                    ns = resolve_scalars(*[t.num_rows for t in parts])
+                    for s, (t, tn) in enumerate(zip(parts, ns)):
+                        if int(tn):
+                            probe_parts[s].append(
+                                catalog.register(t, SpillPriorities.INPUT))
             for s in range(n_sub):
                 def sub_batches():
                     for h in probe_parts[s]:
@@ -1249,32 +1308,25 @@ class TpuBroadcastHashJoinExec(TpuShuffledHashJoinExec):
     def _broadcast_handle_locked(self):
         if self._bc_handle is None:
             from ..memory.catalog import SpillPriorities, get_catalog
-            batches = []
-            for p in range(self.right.num_partitions):  # srtpu: mesh-ok(build-side INPUT drain: collecting the broadcast table's partitions, not per-shard compute)
-                batches.extend(_device_batches(self.right, p))
-            if not batches:
-                from .aggregate import _empty_device_table
-                table = _empty_device_table(self.right.schema,
-                                            self.min_bucket)
-            elif len(batches) == 1:
-                table = batches[0]
-            else:
-                # broadcast build tables are unsplittable: every probe
-                # partition needs the whole table — spill-only retry
-                from ..memory.retry import with_retry
-                table = with_retry(concat_device_tables, batches,
-                                   scope="join-build",
-                                   context=self.node_desc())
-            self._bc_handle = get_catalog().register(
-                table, SpillPriorities.BROADCAST)
+            with get_tracer().span("join.build", "join") as span:
+                batches = []
+                for p in range(self.right.num_partitions):  # srtpu: mesh-ok(build-side INPUT drain: collecting the broadcast table's partitions, not per-shard compute)
+                    batches.extend(_device_batches(self.right, p))
+                table = self._concat_build(batches)
+                span.note(rows=table.capacity, bytes=table.nbytes())
+                self._bc_handle = get_catalog().register(
+                    table, SpillPriorities.BROADCAST)
             self._own_spill_handle(self._bc_handle)
         return self._bc_handle
 
-    def _build_table(self, pidx: int) -> DeviceTable:
-        return self._broadcast_handle().get()
-
-    def _register_build(self, build: DeviceTable):
-        return self._broadcast_handle(), False
+    def _open_build(self, pidx: int):
+        """The one broadcast table, built (and ``join.build`` booked) by
+        the first partition that asks."""
+        handle = self._broadcast_handle()
+        build = handle.get()
+        if build.nbytes() > self.batch_bytes:
+            return build, None, False
+        return build, handle, False
 
     def _grace_build_parts(self, build: DeviceTable, n_sub: int):
         """Split the broadcast once; reuse the parts for every partition."""
@@ -1519,9 +1571,15 @@ class TpuBroadcastNestedLoopJoinExec(TpuExec):
 
     def _cross_window(self, window, handle, n_bslices, bws, fn, pairs_fn,
                       seen_slices, semi_like) -> Iterator[DeviceTable]:
+        """One stream window against the build table: span
+        ``join.probe.cross`` (``rows`` = window capacity) around each cross
+        program, one a window and build slice."""
         track = seen_slices is not None
+        tracer = get_tracer()
         if n_bslices == 1:
-            with self.metrics.timed(M.JOIN_TIME), handle as build:
+            with self.metrics.timed(M.JOIN_TIME), handle as build, \
+                    tracer.span("join.probe.cross", "join",
+                                rows=window.capacity):
                 window = _co_locate(window, build)
                 outs, seen = fn(window, build, seen_slices[0] if track
                                 else jnp.zeros(build.capacity, dtype=bool))
@@ -1533,7 +1591,9 @@ class TpuBroadcastNestedLoopJoinExec(TpuExec):
         # any_pass across slices for outer/semi fixup at the end
         any_pass = jnp.zeros(window.capacity, dtype=bool)
         for bi in range(n_bslices):
-            with self.metrics.timed(M.JOIN_TIME), handle as build:
+            with self.metrics.timed(M.JOIN_TIME), handle as build, \
+                    tracer.span("join.probe.cross", "join",
+                                rows=window.capacity):
                 window = _co_locate(window, build)
                 bslice = slice_rows(build, bi * bws,
                                     min(bws, build.capacity))

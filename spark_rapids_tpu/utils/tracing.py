@@ -234,13 +234,15 @@ class TraceEvent:
 ANNOTATION_PREFIX = "srt."
 
 #: spans whose time is really other spans': those that only group (the
-#: query root, a partition task, an AQE stage) and the consumer's wait on
-#: the engine's own producer thread. Their self time is booked like any
-#: phase's, but they do not count as COVERED wall: a ``task`` span tiles a
-#: whole drain and ``wait.pipeline`` the whole of a producer's work, and
-#: counting them would hide exactly the time no phase span claims
-#: (``host_unattributed_share``).
-STRUCTURAL_SPANS = frozenset({"query", "task", "stage", "wait.pipeline"})
+#: query root, a partition task, an AQE stage), the consumer's wait on
+#: the engine's own producer thread, and a join's ``join.build`` /
+#: ``join.grace``, which hold the drain of a child plan. Their self time is
+#: booked like any phase's, but they do not count as COVERED wall: a
+#: ``task`` span tiles a whole drain and ``wait.pipeline`` the whole of a
+#: producer's work, and counting them would hide exactly the time no phase
+#: span claims (``host_unattributed_share``).
+STRUCTURAL_SPANS = frozenset({"query", "task", "stage", "wait.pipeline",
+                              "join.build", "join.grace"})
 
 #: queries whose phase totals ``Tracer.recent_queries`` remembers
 RECENT_QUERIES = 256

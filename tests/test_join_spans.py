@@ -1,0 +1,291 @@
+"""The join path as the tracer sees it (``exec/joins.py``): ``join.build`` a
+build table, ``join.prep`` (``unique``, ``rounds``, ``full_rounds``) a miss
+of the node's prep cache, one ``join.probe.pk`` / ``.semi`` / ``.expand`` /
+``.cross`` a probe batch by the branch it took, ``join.grace`` where the
+build is over the batch budget — and no sync or download that the code
+without the spans did not make. Both broadcast thresholds are off, so an
+equi-join is the ``TpuShuffledHashJoinExec`` the chip plans for ``sf1.q4``,
+whose build side (``lineitem``) holds every key several times. And the slot
+table that prep builds: one row a distinct key, whatever the duplicates."""
+import numpy as np
+import pandas as pd
+import pyarrow as pa
+import pytest
+
+from spark_rapids_tpu.expr.functions import col
+from spark_rapids_tpu.session import TpuSession
+from spark_rapids_tpu.utils.tracing import get_tracer
+
+#: rows a build key is held by; the 64 rows of one key contend for one slot
+MULTIPLICITIES = {1: 40, 2: 20, 15: 5, 64: 1}
+PROBE_BATCHES = 3
+
+
+def duplicate_build():
+    keys, base = [], 100
+    for m, n in MULTIPLICITIES.items():
+        keys.append(np.repeat(np.arange(base, base + n) * 4, m))
+        base += 100
+    keys = np.concatenate(keys).astype(np.int64)
+    rng = np.random.default_rng(3)
+    rng.shuffle(keys)
+    return pa.table({"bk": keys, "w": rng.random(len(keys))})
+
+
+def unique_build():
+    return pa.table({"bk": np.arange(100, 400, dtype=np.int64) * 4,
+                     "w": np.random.default_rng(5).random(300)})
+
+
+def probe():
+    rng = np.random.default_rng(4)
+    return pa.table({"pk": (rng.integers(90, 450, 500) * 4).astype(np.int64),
+                     "v": rng.random(500)})
+
+
+@pytest.fixture
+def traced():
+    """(session factory, events reader): the ring is on for the test."""
+    tracer = get_tracer()
+    was = tracer.enabled
+    tracer.enabled = True
+    tracer.clear()
+    opened = []
+
+    def session(**extra):
+        opened.append(TpuSession({
+            "spark.rapids.tpu.batchRowsMinBucket": 64,
+            "spark.rapids.tpu.shuffle.partitions": 1,
+            "spark.rapids.sql.test.enabled": True,
+            "spark.rapids.tpu.autoBroadcastJoinThreshold": -1,
+            "spark.rapids.tpu.aqe.autoBroadcastJoinThreshold": -1,
+            **extra}))
+        return opened[-1]
+
+    def events(prefix):
+        return [e for e in tracer.events() if e.name.startswith(prefix)]
+    yield session, events
+    tracer.enabled = was
+    tracer.clear()
+    for s in opened:
+        s.close()
+
+
+def join(sess, build, how, condition=None):
+    b = sess.create_dataframe(build, num_partitions=2)
+    p = sess.create_dataframe(probe(), num_partitions=PROBE_BATCHES)
+    q = p.join(b, how=how, condition=col("pk") == col("bk")
+               if condition is None else condition)
+    return q.collect().to_pandas()
+
+
+def crossings(events):
+    """(blocking syncs + downloads, programs dispatched): what
+    ``host_syncs_per_query`` and ``programs_per_query`` count. The numbers
+    the tests hold them to were read from the code before it had the spans
+    and threw ``rounds`` away (commit d917b1e, the same plans)."""
+    return (len(events("sync")) + len(events("d2h")),
+            len(events("dispatch")))
+
+
+def names(events):
+    return sorted({e.name for e in events("join.")})
+
+
+@pytest.mark.parametrize("how", ["left_semi", "left_anti"])
+def test_existence_over_a_duplicate_keyed_build(traced, how):
+    """The hash tier probes a duplicate-keyed slot table for existence: one
+    prep a build table, whose rounds do not follow the longest run of equal
+    keys (64 rows here), and the fused program on every probe batch."""
+    session, events = traced
+    got = join(session(), duplicate_build(), how)
+    p, b = probe().to_pandas(), duplicate_build().to_pandas()
+    keep = p.pk.isin(b.bk)
+    want = p[keep if how == "left_semi" else ~keep]
+    assert sorted(got.pk) == sorted(want.pk) and len(got) == len(want)
+    assert np.isclose(got.v.sum(), want.v.sum())
+    assert 0 < keep.sum() < len(p)
+
+    assert names(events) == ["join.build", "join.prep", "join.probe.pk"]
+    (build,) = events("join.build")
+    assert build.args["rows"] == 256 and build.args["bytes"] > 0
+    # once a build table: the second and third probe batch hit the cache
+    (prep,) = events("join.prep")
+    assert prep.args["rows"] == 256 and prep.args["unique"] is False
+    assert 1 <= prep.args["full_rounds"] <= prep.args["rounds"] < 8
+    assert [e.args["rows"] for e in events("join.probe.pk")] \
+        == [256] * PROBE_BATCHES
+    # the semi join keeps a few rows a batch and shrinks them, the anti
+    # join keeps most and does not
+    assert crossings(events) == (10, 8 if how == "left_semi" else 5)
+
+
+def test_a_unique_build_takes_the_fused_single_match_program(traced):
+    session, events = traced
+    got = join(session(), unique_build(), "inner")
+    want = probe().to_pandas().merge(unique_build().to_pandas(),
+                                     left_on="pk", right_on="bk")
+    assert len(got) == len(want) and np.isclose(got.w.sum(), want.w.sum())
+    assert names(events) == ["join.build", "join.prep", "join.probe.pk"]
+    (prep,) = events("join.prep")
+    assert prep.args["unique"] is True and 1 <= prep.args["rounds"] < 8
+    assert len(events("join.probe.pk")) == PROBE_BATCHES
+    assert crossings(events) == (10, 5)
+
+
+def test_an_inner_join_over_duplicate_keys_counts_and_expands(traced):
+    """Every match is wanted, so the slot table (``unique=False``) is not
+    probed: counts, the ``total`` sync, one expand a batch."""
+    session, events = traced
+    got = join(session(), duplicate_build(), "inner")
+    want = probe().to_pandas().merge(duplicate_build().to_pandas(),
+                                     left_on="pk", right_on="bk")
+    assert len(got) == len(want) and np.isclose(got.w.sum(), want.w.sum())
+    assert names(events) == ["join.build", "join.prep", "join.probe.expand"]
+    # the hash prep that said no, then the sorted prep the counts use
+    assert [(e.args["unique"], "rounds" in e.args)
+            for e in events("join.prep")] == [(False, True), (False, False)]
+    assert len(events("join.probe.expand")) == PROBE_BATCHES
+    assert crossings(events) == (11, 9)
+
+
+def test_the_sort_tier_counts_and_masks_a_semi_join(traced):
+    session, events = traced
+    got = join(session(**{"spark.rapids.tpu.join.strategy": "sort"}),
+                  duplicate_build(), "left_semi")
+    p = probe().to_pandas()
+    assert sorted(got.pk) == sorted(
+        p.pk[p.pk.isin(duplicate_build().to_pandas().bk)])
+    assert names(events) == ["join.build", "join.prep", "join.probe.semi"]
+    (prep,) = events("join.prep")
+    assert prep.args["unique"] is False and "rounds" not in prep.args
+    assert len(events("join.probe.semi")) == PROBE_BATCHES
+    assert crossings(events) == (7, 8)
+
+
+def test_a_cross_join_books_a_span_a_window(traced):
+    session, events = traced
+    got = join(session(), unique_build().slice(0, 7), "inner",
+                  condition=col("pk") < col("bk"))
+    p, b = probe().to_pandas(), unique_build().to_pandas()[:7]
+    assert len(got) == sum(int((pk < b.bk).sum()) for pk in p.pk)
+    assert names(events) == ["join.probe.cross"]
+    assert len(events("join.probe.cross")) >= 1
+    assert crossings(events) == (5, 11)
+
+
+def test_a_build_over_the_batch_budget_goes_grace(traced):
+    """Driven on the node (no conf reaches a join's ``batch_bytes``): both
+    sides split into ``parts`` buckets under ``join.grace``, then every
+    bucket is a join of its own with its own prep."""
+    from spark_rapids_tpu.columnar.device import DeviceTable
+    from spark_rapids_tpu.columnar.host import HostTable
+    from spark_rapids_tpu.exec.joins import TpuShuffledHashJoinExec
+    from spark_rapids_tpu.plan.schema import Field, Schema
+    _, events = traced
+
+    class Source:
+        num_partitions, children = 1, ()
+
+        def __init__(self, table):
+            host = HostTable.from_arrow(table)
+            self.schema = Schema([Field(n, c.dtype, True) for n, c in
+                                  zip(host.names, host.columns)])
+            self.batch = DeviceTable.from_host(host, min_bucket=64)
+
+        def execute_columnar(self, pidx):
+            yield self.batch
+
+    node = TpuShuffledHashJoinExec(
+        Source(probe()), Source(duplicate_build()), ["pk"], ["bk"],
+        "left_semi", None, merge_keys=False, min_bucket=64, batch_bytes=2_000)
+    got = pd.concat([HostTable.to_arrow(t.to_host()).to_pandas()
+                     for t in node.execute_columnar(0)])
+    p = probe().to_pandas()
+    assert sorted(got.pk) == sorted(
+        p.pk[p.pk.isin(duplicate_build().to_pandas().bk)])
+    (grace,) = events("join.grace")
+    parts = grace.args["parts"]
+    assert parts == 3
+    (build,) = events("join.build")
+    assert build.args["bytes"] > node.batch_bytes
+    assert len(events("join.prep")) == len(events("join.probe.pk")) == parts
+    assert not any(e.args["unique"] for e in events("join.prep"))
+    assert crossings(events) == (16, 15)
+
+
+# ---- the slot table itself ---------------------------------------------------
+def keys_table(keys, valid=None, live=None):
+    from spark_rapids_tpu.columnar import dtypes as dt
+    from spark_rapids_tpu.columnar.device import (DeviceColumn, DeviceTable,
+                                                  canonical_names)
+    import jax.numpy as jnp
+    n = len(keys)
+    valid = np.ones(n, bool) if valid is None else valid
+    live = np.ones(n, bool) if live is None else live
+    col = DeviceColumn(jnp.asarray(keys, jnp.int64), jnp.asarray(valid),
+                       dt.LongType(), None)
+    return DeviceTable((col,), jnp.asarray(live),
+                       jnp.asarray(int(live.sum()), jnp.int32),
+                       canonical_names(1))
+
+
+def slot_table_cases():
+    rng = np.random.default_rng(32)
+    cap = 1 << 12
+    yield "unique-full", np.arange(cap) * 4, None, None
+    yield "one-key", np.full(cap, 7), None, None
+    yield "poisson-4", rng.integers(0, cap // 4, cap) * 4, None, None
+    yield "long-chains", np.repeat(rng.integers(0, 1 << 40, 16), cap // 16), \
+        None, None
+    yield "nulls-and-dead-rows", rng.integers(0, 600, cap), \
+        rng.random(cap) < 0.8, rng.random(cap) < 0.7
+    yield "unique-among-the-live", np.arange(cap) % (cap // 2), None, \
+        np.arange(cap) < cap // 2
+    yield "nothing-live", np.arange(cap), None, np.zeros(cap, bool)
+    yield "tiny", np.array([5, 5, 9, 5, 1, 9, 2, 2]), None, None
+
+
+@pytest.mark.parametrize("name,keys,valid,live",
+                         [pytest.param(*c, id=c[0])
+                          for c in slot_table_cases()])
+def test_the_slot_table_holds_one_row_a_distinct_key(name, keys, valid, live):
+    """What the probe's chain walk relies on, for any build: every distinct
+    usable key sits in the table once, no empty slot lies before it on its
+    chain, ``unique`` says whether a usable key repeats, and the rounds stay
+    far below the longest run of equal keys."""
+    import jax
+    from spark_rapids_tpu.exec.joins import _PREP_TAIL_SHARE, _JoinKernels
+    from spark_rapids_tpu.shuffle.manager import _fmix_device
+    import jax.numpy as jnp
+    keys = np.asarray(keys, np.int64)
+    cap = len(keys)
+    usable = (np.ones(cap, bool) if valid is None else valid) \
+        & (np.ones(cap, bool) if live is None else live)
+    slot_row, bv, unique, rounds, full_rounds = jax.jit(
+        _JoinKernels(None).build_prep_hash_fn())(keys_table(keys, valid, live))
+    slot_row = np.asarray(slot_row)
+    assert len(slot_row) == 2 * cap and (np.asarray(bv) == keys).all()
+    held = slot_row[slot_row >= 0]
+    distinct = np.unique(keys[usable])
+    assert usable[held].all()
+    assert sorted(keys[held]) == sorted(distinct)       # once each
+    assert bool(unique) == (len(distinct) == usable.sum())
+    # the walk of every usable key finds it before it finds an empty slot
+    u = keys.astype(np.uint64)
+    lo = jnp.asarray((u & np.uint64(0xFFFFFFFF)).astype(np.uint32))
+    hi = jnp.asarray((u >> np.uint64(32)).astype(np.uint32))
+    h1 = _fmix_device(lo ^ _fmix_device(hi))
+    step = np.asarray(_fmix_device(h1 ^ jnp.uint32(0x9E3779B9))
+                      | jnp.uint32(1)).astype(np.uint64)
+    h1 = np.asarray(h1).astype(np.uint64)
+    found = ~usable
+    for r in range(int(rounds) + 1):
+        row = slot_row[((h1 + np.uint64(r) * step)
+                        & np.uint64(2 * cap - 1)).astype(np.int64)]
+        assert (found | (row >= 0)).all(), (name, r)
+        found |= (row >= 0) & (keys[np.clip(row, 0, cap - 1)] == keys)
+    assert found.all()
+    assert 0 <= int(full_rounds) <= int(rounds) <= 24
+    if usable.any():
+        assert int(full_rounds) >= (usable.sum() > cap // _PREP_TAIL_SHARE)

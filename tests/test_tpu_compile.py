@@ -153,7 +153,7 @@ def test_pk_hash_join(topo, one_chip, sess, rng):
     prep = node._kernels.build_prep_hash_fn()
     build_keys = _key_view(build, node.right_keys)
     _compile(prep, (build_keys,), one_chip)
-    slot_row, bv, _unique = prep(build_keys)
+    slot_row, bv, *_ = prep(build_keys)
     clone, _ = node._canon()
     _compile(clone._kernels.pk_hash_join_fn("inner"),
              (build.canonical(), probe.canonical(),
