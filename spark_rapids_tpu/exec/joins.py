@@ -194,11 +194,40 @@ def _null_device_column(dtype: dt.DataType, capacity: int) -> DeviceColumn:
 
 _I64_MAX = np.int64(2**63 - 1)
 
-#: the hash prep runs full-capacity rounds while more than this share of
-#: the build's capacity is unplaced, then one compaction and rounds that
-#: long: a unique build at a load of 0.5 leaves a quarter of its rows after
-#: the first round and a sixteenth after the second
-_PREP_TAIL_SHARE = 16
+#: both chain walks of the hash tier (the build's insertion loop, the
+#: probe) run full rounds while more than this share of the capacity is
+#: open, then one compaction and rounds that long: at a load of 0.5 a
+#: quarter of the rows are open after the first round and a sixteenth
+#: after the second. On the chip 16 beats 8 and 32 over Q3-shaped probes
+#: of 2^19-2^20 rows (PERF.md section 6, PR 33)
+_TAIL_SHARE = 16
+
+
+def _open_rows_by_rank(open_rows: jax.Array, iota: jax.Array, tail_cap: int
+                       ) -> Tuple[jax.Array, jax.Array]:
+    """The compaction between a walk's full rounds and its tail rounds:
+    -> (the indices of the open rows by their rank, ``tail_cap`` long;
+    which of those slots hold a row). At most ``tail_cap`` rows are open.
+    The blocked ``prefix_sum``, never ``jnp.cumsum`` over a row-capacity
+    vector (17-31 s of TPU compile a program)."""
+    from ..columnar.device import prefix_sum
+    o32 = open_rows.astype(jnp.int32)
+    dest = jnp.where(open_rows, prefix_sum(o32) - o32, tail_cap)
+    rows = jnp.zeros(tail_cap, jnp.int32).at[dest].set(iota, mode="drop")
+    live = jnp.arange(tail_cap, dtype=jnp.int32) < jnp.sum(o32)
+    return rows, live
+
+
+def _chain_hashes(keys: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """-> (first bucket hash, odd step: a full cycle over a power-of-two
+    table) of monotone-int64 keys, the same for build and probe."""
+    from ..shuffle.manager import _fmix_device
+    u = jax.lax.bitcast_convert_type(keys, jnp.uint64)
+    lo = (u & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32)
+    hi = (u >> jnp.uint64(32)).astype(jnp.uint32)
+    h1 = _fmix_device(lo ^ _fmix_device(hi))
+    return h1, _fmix_device(h1 ^ jnp.uint32(0x9E3779B9)) | jnp.uint32(1)
+
 
 from ..conf import register_conf  # noqa: E402  (grouped with sibling confs)
 
@@ -333,26 +362,20 @@ class _JoinKernels:
 
         A round costs by the rows it is run over, not by the rows still
         unplaced, and the last rounds place a handful. So full-capacity
-        rounds run only while more than 1/``_PREP_TAIL_SHARE`` of the
+        rounds run only while more than 1/``_TAIL_SHARE`` of the
         capacity is unplaced; the rest are compacted once and finish in
-        rounds that long. -> (slot_row, keys, unique, rounds,
-        full_rounds): the trip counts ride on span ``join.prep``."""
+        rounds that long (``_open_rows_by_rank``; the probe's walk retires
+        and compacts its rows the same way). -> (slot_row, keys, unique,
+        rounds, full_rounds): the trip counts ride on span ``join.prep``."""
         def fn(build_keys: DeviceTable):
-            from ..columnar.device import prefix_sum
-            from ..shuffle.manager import _fmix_device
             bc = build_keys.columns[0]
             bmask = jnp.logical_and(bc.validity, build_keys.row_mask)
             bv = _monotone_i64(bc.data)
             cap = bv.shape[0]
             T = 2 * cap                       # pow2 (capacity is pow2)
-            tail_cap = max(cap // _PREP_TAIL_SHARE, 1)
+            tail_cap = max(cap // _TAIL_SHARE, 1)
             mask = jnp.uint32(T - 1)
-            u = jax.lax.bitcast_convert_type(bv, jnp.uint64)
-            lo = (u & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32)
-            hi = (u >> jnp.uint64(32)).astype(jnp.uint32)
-            h1 = _fmix_device(lo ^ _fmix_device(hi))
-            step = (_fmix_device(h1 ^ jnp.uint32(0x9E3779B9))
-                    | jnp.uint32(1))          # odd: full cycle over pow2 T
+            h1, step = _chain_hashes(bv)
             iota = jnp.arange(cap, dtype=jnp.int32)
             big = jnp.int32(cap)
 
@@ -397,12 +420,7 @@ class _JoinKernels:
                 many_left, insert(iota, bv, h1, step),
                 (jnp.int32(0), jnp.full(T, -1, jnp.int32), bmask,
                  jnp.zeros((), dtype=bool)))
-            # the rows still unplaced, at most tail_cap, by their rank
-            a32 = active.astype(jnp.int32)
-            dest = jnp.where(active, prefix_sum(a32) - a32, tail_cap)
-            rows = jnp.zeros(tail_cap, jnp.int32).at[dest].set(
-                iota, mode="drop")
-            live = jnp.arange(tail_cap, dtype=jnp.int32) < jnp.sum(a32)
+            rows, live = _open_rows_by_rank(active, iota, tail_cap)
             rounds, slot_row, _, dup = jax.lax.while_loop(
                 any_left,
                 insert(rows, jnp.take(bv, rows), jnp.take(h1, rows),
@@ -411,58 +429,91 @@ class _JoinKernels:
             return slot_row, bv, jnp.logical_not(dup), rounds, full_rounds
         return fn
 
+    def probe_slots_fn(self):
+        """The probe's chain walk alone: each usable probe row visits the
+        slots of its double-hash chain until it meets its key (found) or
+        an empty slot (absent). A *full round* visits one more slot for
+        every row of the probe batch and runs only while more than
+        1/``_TAIL_SHARE`` of the probe capacity is unresolved; the rows
+        then still open are compacted once (``_open_rows_by_rank``) and
+        finish in *tail rounds* that long, whose hits are written back by
+        row index: the walk costs by the rows that have anything left to
+        do. A table built over duplicate keys holds one row a distinct
+        key (``build_prep_hash_fn``), which answers existence. -> (found,
+        bi = the build row found or 0, rounds, full_rounds): the trip
+        counts ride on span ``join.probe.pk``."""
+        def fn(slot_row, bv, pv, pmask):
+            cap, cap_b, T = pv.shape[0], bv.shape[0], slot_row.shape[0]
+            tail_cap = max(cap // _TAIL_SHARE, 1)
+            mask = jnp.uint32(T - 1)
+            h1, step = _chain_hashes(pv)
+
+            def look(keys, h, s):
+                """The loop body over the probe rows whose keys, hashes
+                and steps these are."""
+                def body(state):
+                    r, unresolved, hit_row = state
+                    bucket = ((h + r.astype(jnp.uint32) * s) & mask) \
+                        .astype(jnp.int32)
+                    row = jnp.take(slot_row, bucket)
+                    empty = row < 0
+                    eq = jnp.logical_and(
+                        jnp.logical_not(empty),
+                        jnp.take(bv, jnp.clip(row, 0, cap_b - 1)) == keys)
+                    hit_row = jnp.where(jnp.logical_and(unresolved, eq),
+                                        row, hit_row)
+                    unresolved = jnp.logical_and(
+                        unresolved,
+                        jnp.logical_not(jnp.logical_or(empty, eq)))
+                    return r + 1, unresolved, hit_row
+                return body
+
+            def many_open(state):
+                r, unresolved, _ = state
+                return jnp.logical_and(
+                    jnp.sum(unresolved, dtype=jnp.int32) > tail_cap, r < T)
+
+            def any_open(state):
+                r, unresolved, _ = state
+                return jnp.logical_and(jnp.any(unresolved), r < T)
+
+            full_rounds, unresolved, hit_row = jax.lax.while_loop(
+                many_open, look(pv, h1, step),
+                (jnp.int32(0), pmask, jnp.full(cap, -1, jnp.int32)))
+            rows, live = _open_rows_by_rank(
+                unresolved, jnp.arange(cap, dtype=jnp.int32), tail_cap)
+            rounds, _, tail_hit = jax.lax.while_loop(
+                any_open,
+                look(jnp.take(pv, rows), jnp.take(h1, rows),
+                     jnp.take(step, rows)),
+                (full_rounds, live, jnp.take(hit_row, rows)))
+            hit_row = hit_row.at[jnp.where(live, rows, cap)].set(
+                tail_hit, mode="drop")
+            return (hit_row >= 0, jnp.maximum(hit_row, 0), rounds,
+                    full_rounds)
+        return fn
+
     def pk_hash_join_fn(self, how: str):
         """Unique-build-key join via the hash slot table: each probe row
-        walks its double-hash chain (one while_loop) until an empty slot
-        (absent) or a key match. Counts are 0/1; output capacity == probe
-        capacity; NO lax.sort in the program."""
+        walks its double-hash chain until an empty slot (absent) or a key
+        match (``probe_slots_fn``: full rounds while many rows are open,
+        tail rounds over the rest). Counts are 0/1; output capacity ==
+        probe capacity; NO lax.sort in the program. -> (joined table,
+        (rounds, full_rounds))."""
         node = self.node
+        probe_slots = self.probe_slots_fn()
 
         def fn(build: DeviceTable, probe: DeviceTable,
                probe_keys: DeviceTable, slot_row, bv):
             pc = probe_keys.columns[0]
             pmask = jnp.logical_and(pc.validity, probe.row_mask)
-            pv = _monotone_i64(pc.data)
-            cap_b = bv.shape[0]
-            T = slot_row.shape[0]
-            mask = jnp.uint32(T - 1)
-            u = jax.lax.bitcast_convert_type(pv, jnp.uint64)
-            lo = (u & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32)
-            hi = (u >> jnp.uint64(32)).astype(jnp.uint32)
-            from ..shuffle.manager import _fmix_device
-            h1 = _fmix_device(lo ^ _fmix_device(hi))
-            step = (_fmix_device(h1 ^ jnp.uint32(0x9E3779B9))
-                    | jnp.uint32(1))
-
-            def cond(state):
-                r, resolved, found, bi = state
-                return jnp.logical_and(jnp.logical_not(jnp.all(resolved)),
-                                       r < T)
-
-            def body(state):
-                r, resolved, found, bi = state
-                bucket = ((h1 + r.astype(jnp.uint32) * step) & mask) \
-                    .astype(jnp.int32)
-                row = jnp.take(slot_row, bucket)
-                empty = row < 0
-                row_safe = jnp.clip(row, 0, cap_b - 1)
-                eq = jnp.logical_and(jnp.logical_not(empty),
-                                     jnp.take(bv, row_safe) == pv)
-                hit = jnp.logical_and(jnp.logical_not(resolved), eq)
-                found = jnp.logical_or(found, hit)
-                bi = jnp.where(hit, row_safe, bi)
-                resolved = jnp.logical_or(resolved,
-                                          jnp.logical_or(empty, eq))
-                return r + 1, resolved, found, bi
-
-            n = pv.shape[0]
-            init = (jnp.int32(0), jnp.logical_not(pmask),
-                    jnp.zeros(n, dtype=bool), jnp.zeros(n, jnp.int32))
-            _, _, found, bi = jax.lax.while_loop(cond, body, init)
+            found, bi, rounds, full_rounds = probe_slots(
+                slot_row, bv, _monotone_i64(pc.data), pmask)
+            trips = (rounds, full_rounds)
             if how == "left_semi":
-                return probe.filter_mask(found)
+                return probe.filter_mask(found), trips
             if how == "left_anti":
-                return probe.filter_mask(jnp.logical_not(found))
+                return probe.filter_mask(jnp.logical_not(found)), trips
             keep = found if how == "inner" else probe.row_mask
             pcols = [c.with_validity(jnp.logical_and(c.validity, keep))
                      for c in probe.columns]
@@ -471,7 +522,7 @@ class _JoinKernels:
             out_mask = jnp.logical_and(keep, probe.row_mask)
             return DeviceTable(tuple(out_cols), out_mask,
                                jnp.sum(out_mask, dtype=jnp.int32),
-                               tuple(names))
+                               tuple(names)), trips
         return fn
 
     def pk_join_fn(self, how: str):
@@ -479,7 +530,7 @@ class _JoinKernels:
         lookup + gather, output capacity == probe capacity (counts are 0/1
         so no count sync, no windowing, no per-size expand recompiles —
         the hot TPC-H join shape; reference: GpuHashJoin's single-match
-        gather specialization)."""
+        gather specialization). -> (joined table, () — no trip counts)."""
         node = self.node
 
         def fn(build: DeviceTable, probe: DeviceTable,
@@ -493,9 +544,9 @@ class _JoinKernels:
                 jnp.logical_and(pos < nvalid,
                                 jnp.take(sv, safe) == pv), pmask)
             if how == "left_semi":
-                return probe.filter_mask(found)
+                return probe.filter_mask(found), ()
             if how == "left_anti":
-                return probe.filter_mask(jnp.logical_not(found))
+                return probe.filter_mask(jnp.logical_not(found)), ()
             bi = jnp.take(b_order, safe).astype(jnp.int32)
             keep = found if how == "inner" else probe.row_mask
             pcols = [c.with_validity(jnp.logical_and(c.validity, keep))
@@ -504,7 +555,8 @@ class _JoinKernels:
             out_cols, names = node.assemble(pcols, bcols, found)
             mask = jnp.logical_and(keep, probe.row_mask)
             return DeviceTable(tuple(out_cols), mask,
-                               jnp.sum(mask, dtype=jnp.int32), tuple(names))
+                               jnp.sum(mask, dtype=jnp.int32),
+                               tuple(names)), ()
         return fn
 
     def probe_count_fn(self, track: bool):
@@ -1040,18 +1092,28 @@ class TpuShuffledHashJoinExec(TpuExec):
                     # FK->PK or existence: counts are 0/1, output fits the
                     # probe capacity — one fused program, no count sync
                     fused, prep = pk
+                    # selective joins keep the probe CAPACITY with a mask;
+                    # shrink (one int sync) so downstream sorts/groupbys
+                    # don't run over dead padding
+                    shrinks = self.how in ("inner", "left_semi", "left_anti")
+                    n = None
                     with tracer.span("join.probe.pk", "join",
-                                     rows=probe.capacity):
-                        out = fused(build.canonical(), probe.canonical(),
-                                    _key_view(probe, self.left_keys), *prep) \
-                            .with_names(self.schema.names
-                                        if self.how in ("inner", "left")
-                                        else probe.names)
-                    if self.how in ("inner", "left_semi", "left_anti"):
-                        # selective joins keep the probe CAPACITY with
-                        # a mask; shrink (one int sync) so downstream
-                        # sorts/groupbys don't run over dead padding
-                        out = shrink_to_fit(out, self.min_bucket)
+                                     rows=probe.capacity) as span:
+                        out, trips = fused(
+                            build.canonical(), probe.canonical(),
+                            _key_view(probe, self.left_keys), *prep)
+                        out = out.with_names(
+                            self.schema.names if self.how in ("inner", "left")
+                            else probe.names)
+                        if shrinks and out.capacity > self.min_bucket:
+                            # the hash walk's trip counts ride in the
+                            # transfer that reads the shrink's row count
+                            n, *trips = resolve_scalars(out.num_rows, *trips)
+                            if trips:
+                                span.note(rounds=int(trips[0]),
+                                          full_rounds=int(trips[1]))
+                    if shrinks:
+                        out = shrink_to_fit(out, self.min_bucket, num_rows=n)
                     yield out
                     continue
                 if seen_box is not None and hasattr(seen_box[0], "devices") \
@@ -1079,7 +1141,9 @@ class TpuShuffledHashJoinExec(TpuExec):
         """-> (the fused single-match / existence program, the prepared
         arrays of this build table it takes last), or None where the
         build's keys repeat and the join needs every match (the counts +
-        expand path). Runs the build's prep on its first call."""
+        expand path). The program returns (joined table, trip counts):
+        (rounds, full_rounds) of the hash tier's chain walk, none of the
+        sorted tier's. Runs the build's prep on its first call."""
         clone, ckey = self._canon()
         if _resolve_join_strategy() == "hash":
             # sort-free tier: open-addressing slot table. semi/anti only

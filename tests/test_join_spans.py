@@ -1,12 +1,17 @@
 """The join path as the tracer sees it (``exec/joins.py``): ``join.build`` a
 build table, ``join.prep`` (``unique``, ``rounds``, ``full_rounds``) a miss
 of the node's prep cache, one ``join.probe.pk`` / ``.semi`` / ``.expand`` /
-``.cross`` a probe batch by the branch it took, ``join.grace`` where the
-build is over the batch budget — and no sync or download that the code
-without the spans did not make. Both broadcast thresholds are off, so an
-equi-join is the ``TpuShuffledHashJoinExec`` the chip plans for ``sf1.q4``,
-whose build side (``lineitem``) holds every key several times. And the slot
-table that prep builds: one row a distinct key, whatever the duplicates."""
+``.cross`` a probe batch by the branch it took (``.pk`` with the chain
+walk's ``rounds`` / ``full_rounds`` where the output's row count is read),
+``join.grace`` where the build is over the batch budget — and no sync or
+download that the code without the spans did not make. Both broadcast
+thresholds are off, so an equi-join is the ``TpuShuffledHashJoinExec`` the
+chip plans for ``sf1.q4``, whose build side (``lineitem``) holds every key
+several times. And the slot
+table that prep builds: one row a distinct key, whatever the duplicates; and
+the probe's walk of it, full rounds and tail rounds, against a numpy walk."""
+import functools
+
 import numpy as np
 import pandas as pd
 import pyarrow as pa
@@ -79,6 +84,28 @@ def join(sess, build, how, condition=None):
     return q.collect().to_pandas()
 
 
+class Source:
+    """A one-batch child plan over an arrow table, for a join node driven
+    directly; ``live`` masks rows of the batch out."""
+    num_partitions, children = 1, ()
+
+    def __init__(self, table, min_bucket=64, live=None):
+        from spark_rapids_tpu.columnar.device import DeviceTable
+        from spark_rapids_tpu.columnar.host import HostTable
+        from spark_rapids_tpu.plan.schema import Field, Schema
+        import jax.numpy as jnp
+        host = HostTable.from_arrow(table)
+        self.schema = Schema([Field(n, c.dtype, True) for n, c in
+                              zip(host.names, host.columns)])
+        self.batch = DeviceTable.from_host(host, min_bucket=min_bucket)
+        if live is not None:
+            self.batch = self.batch.filter_mask(jnp.asarray(np.pad(
+                live, (0, self.batch.capacity - len(live)))))
+
+    def execute_columnar(self, pidx):
+        yield self.batch
+
+
 def crossings(events):
     """(blocking syncs + downloads, programs dispatched): what
     ``host_syncs_per_query`` and ``programs_per_query`` count. The numbers
@@ -115,21 +142,36 @@ def test_existence_over_a_duplicate_keyed_build(traced, how):
     assert 1 <= prep.args["full_rounds"] <= prep.args["rounds"] < 8
     assert [e.args["rows"] for e in events("join.probe.pk")] \
         == [256] * PROBE_BATCHES
+    # every batch's row count is read for the shrink, the walk's trip counts
+    # in the same transfer
+    assert all(0 <= e.args["full_rounds"] <= e.args["rounds"] < 8
+               for e in events("join.probe.pk"))
     # the semi join keeps a few rows a batch and shrinks them, the anti
     # join keeps most and does not
     assert crossings(events) == (10, 8 if how == "left_semi" else 5)
 
 
-def test_a_unique_build_takes_the_fused_single_match_program(traced):
+@pytest.mark.parametrize("strategy", ["hash", "sort"])
+def test_a_unique_build_takes_the_fused_single_match_program(traced,
+                                                             strategy):
     session, events = traced
-    got = join(session(), unique_build(), "inner")
+    got = join(session(**{"spark.rapids.tpu.join.strategy": strategy}),
+               unique_build(), "inner")
     want = probe().to_pandas().merge(unique_build().to_pandas(),
                                      left_on="pk", right_on="bk")
     assert len(got) == len(want) and np.isclose(got.w.sum(), want.w.sum())
     assert names(events) == ["join.build", "join.prep", "join.probe.pk"]
     (prep,) = events("join.prep")
-    assert prep.args["unique"] is True and 1 <= prep.args["rounds"] < 8
-    assert len(events("join.probe.pk")) == PROBE_BATCHES
+    assert prep.args["unique"] is True
+    probes = events("join.probe.pk")
+    assert len(probes) == PROBE_BATCHES
+    if strategy == "hash":
+        assert 1 <= prep.args["rounds"] < 8
+        assert all(1 <= e.args["full_rounds"] <= e.args["rounds"] < 8
+                   for e in probes)
+    else:
+        # the sorted tier walks no chain
+        assert not any("rounds" in e.args for e in [prep] + probes)
     assert crossings(events) == (10, 5)
 
 
@@ -178,23 +220,9 @@ def test_a_build_over_the_batch_budget_goes_grace(traced):
     """Driven on the node (no conf reaches a join's ``batch_bytes``): both
     sides split into ``parts`` buckets under ``join.grace``, then every
     bucket is a join of its own with its own prep."""
-    from spark_rapids_tpu.columnar.device import DeviceTable
     from spark_rapids_tpu.columnar.host import HostTable
     from spark_rapids_tpu.exec.joins import TpuShuffledHashJoinExec
-    from spark_rapids_tpu.plan.schema import Field, Schema
     _, events = traced
-
-    class Source:
-        num_partitions, children = 1, ()
-
-        def __init__(self, table):
-            host = HostTable.from_arrow(table)
-            self.schema = Schema([Field(n, c.dtype, True) for n, c in
-                                  zip(host.names, host.columns)])
-            self.batch = DeviceTable.from_host(host, min_bucket=64)
-
-        def execute_columnar(self, pidx):
-            yield self.batch
 
     node = TpuShuffledHashJoinExec(
         Source(probe()), Source(duplicate_build()), ["pk"], ["bk"],
@@ -230,6 +258,20 @@ def keys_table(keys, valid=None, live=None):
                        canonical_names(1))
 
 
+def chain_hashes(keys):
+    """(first bucket hash, step) of int64 keys as uint64, computed apart
+    from ``exec/joins.py`` but for the murmur finalizer."""
+    from spark_rapids_tpu.shuffle.manager import _fmix_device
+    import jax.numpy as jnp
+    u = np.asarray(keys, np.int64).astype(np.uint64)
+    lo = jnp.asarray((u & np.uint64(0xFFFFFFFF)).astype(np.uint32))
+    hi = jnp.asarray((u >> np.uint64(32)).astype(np.uint32))
+    h1 = _fmix_device(lo ^ _fmix_device(hi))
+    step = _fmix_device(h1 ^ jnp.uint32(0x9E3779B9)) | jnp.uint32(1)
+    return (np.asarray(h1).astype(np.uint64),
+            np.asarray(step).astype(np.uint64))
+
+
 def slot_table_cases():
     rng = np.random.default_rng(32)
     cap = 1 << 12
@@ -254,16 +296,13 @@ def test_the_slot_table_holds_one_row_a_distinct_key(name, keys, valid, live):
     usable key sits in the table once, no empty slot lies before it on its
     chain, ``unique`` says whether a usable key repeats, and the rounds stay
     far below the longest run of equal keys."""
-    import jax
-    from spark_rapids_tpu.exec.joins import _PREP_TAIL_SHARE, _JoinKernels
-    from spark_rapids_tpu.shuffle.manager import _fmix_device
-    import jax.numpy as jnp
+    from spark_rapids_tpu.exec.joins import _TAIL_SHARE
     keys = np.asarray(keys, np.int64)
     cap = len(keys)
     usable = (np.ones(cap, bool) if valid is None else valid) \
         & (np.ones(cap, bool) if live is None else live)
-    slot_row, bv, unique, rounds, full_rounds = jax.jit(
-        _JoinKernels(None).build_prep_hash_fn())(keys_table(keys, valid, live))
+    slot_row, bv, unique, rounds, full_rounds = walk_programs()[0](
+        keys_table(keys, valid, live))
     slot_row = np.asarray(slot_row)
     assert len(slot_row) == 2 * cap and (np.asarray(bv) == keys).all()
     held = slot_row[slot_row >= 0]
@@ -272,13 +311,7 @@ def test_the_slot_table_holds_one_row_a_distinct_key(name, keys, valid, live):
     assert sorted(keys[held]) == sorted(distinct)       # once each
     assert bool(unique) == (len(distinct) == usable.sum())
     # the walk of every usable key finds it before it finds an empty slot
-    u = keys.astype(np.uint64)
-    lo = jnp.asarray((u & np.uint64(0xFFFFFFFF)).astype(np.uint32))
-    hi = jnp.asarray((u >> np.uint64(32)).astype(np.uint32))
-    h1 = _fmix_device(lo ^ _fmix_device(hi))
-    step = np.asarray(_fmix_device(h1 ^ jnp.uint32(0x9E3779B9))
-                      | jnp.uint32(1)).astype(np.uint64)
-    h1 = np.asarray(h1).astype(np.uint64)
+    h1, step = chain_hashes(keys)
     found = ~usable
     for r in range(int(rounds) + 1):
         row = slot_row[((h1 + np.uint64(r) * step)
@@ -288,4 +321,160 @@ def test_the_slot_table_holds_one_row_a_distinct_key(name, keys, valid, live):
     assert found.all()
     assert 0 <= int(full_rounds) <= int(rounds) <= 24
     if usable.any():
-        assert int(full_rounds) >= (usable.sum() > cap // _PREP_TAIL_SHARE)
+        assert int(full_rounds) >= (usable.sum() > cap // _TAIL_SHARE)
+
+
+# ---- the probe's walk of it --------------------------------------------------
+BUILD_CAP, PROBE_CAP, MIN_BUCKET, ABSENT = 512, 1024, 64, 200_000
+
+
+def numpy_walk(slot_row, bv, keys, usable):
+    """Every usable row down its chain until its key or an empty slot ->
+    (found, bi, slots visited a row)."""
+    h1, step = chain_hashes(keys)
+    n, slots = len(keys), len(slot_row)
+    found, bi = np.zeros(n, bool), np.zeros(n, np.int32)
+    depth, open_rows, r = np.zeros(n, np.int64), usable.copy(), 0
+    while open_rows.any() and r < slots:
+        row = slot_row[((h1 + np.uint64(r) * step)
+                        & np.uint64(slots - 1)).astype(np.int64)]
+        eq = (row >= 0) & (bv[np.clip(row, 0, len(bv) - 1)] == keys)
+        hit = open_rows & eq
+        found |= hit
+        bi[hit] = row[hit]
+        depth[open_rows] += 1
+        open_rows &= ~((row < 0) | eq)
+        r += 1
+    return found, bi, depth
+
+
+def trip_counts(depth, cap, tail_share):
+    """(rounds, full_rounds) a walk of these depths must take: full rounds
+    while more than cap / tail_share rows are open, then until none is."""
+    tail_cap, full = max(cap // tail_share, 1), 0
+    while (depth > full).sum() > tail_cap:
+        full += 1
+    return max(full, int(depth.max(initial=0))), full
+
+
+@functools.cache
+def walk_programs():
+    """(the build's prep, the probe's walk alone), jitted once."""
+    import jax
+    from spark_rapids_tpu.exec.joins import _JoinKernels
+    kernels = _JoinKernels(None)
+    return (jax.jit(kernels.build_prep_hash_fn()),
+            jax.jit(kernels.probe_slots_fn()))
+
+
+@functools.cache
+def slot_table(duplicates):
+    """-> (build keys, their slot table, its key array, a pool of candidate
+    probe keys — absent ones, then the build's own — and the depth of each
+    candidate's chain by a numpy walk of this very table)."""
+    rng = np.random.default_rng(33)
+    distinct = rng.choice(1 << 20, BUILD_CAP // (2 if duplicates else 1),
+                          replace=False).astype(np.int64) * 4
+    bkeys = np.repeat(distinct, 2) if duplicates else distinct
+    rng.shuffle(bkeys)
+    slot_row, bv, unique, _, _ = walk_programs()[0](keys_table(bkeys))
+    assert bool(unique) != duplicates
+    slot_row, bv = np.asarray(slot_row), np.asarray(bv)
+    pool = np.concatenate([rng.integers(0, 1 << 22, ABSENT) * 4 + 1,
+                           np.resize(bv, 20_000)]).astype(np.int64)
+    _, _, depth = numpy_walk(slot_row, bv, pool, np.ones(len(pool), bool))
+    return bkeys, slot_row, bv, pool, depth
+
+
+def probe_keys(shape, bv, pool, depth):
+    """-> (keys, live) of a probe batch of one of ``PROBE_SHAPES``."""
+    rng = np.random.default_rng(34)
+    n = MIN_BUCKET - 4 if shape == "minimum-bucket" else PROBE_CAP - 24
+    live = np.ones(n, bool)
+    if shape in ("mostly-absent", "minimum-bucket"):
+        keys = np.concatenate([rng.choice(bv, n // 10),
+                               rng.choice(pool[:ABSENT], n - n // 10)])
+    elif shape == "all-in-round-0":
+        keys = rng.choice(pool[depth == 1], n)
+    elif shape == "nothing-live":
+        keys, live = rng.choice(pool, n), np.zeros(n, bool)
+    else:
+        assert shape == "one-long-chain", shape
+        # forty rows down the longest chain of an absent key, the rest done
+        # in round 0: the tail phase starts at once and runs long
+        longest = pool[np.argmax(depth[:ABSENT])]
+        keys = np.concatenate([np.full(40, longest),
+                               rng.choice(pool[depth == 1], n - 40)])
+    rng.shuffle(keys)
+    return keys.astype(np.int64), live
+
+
+PROBE_SHAPES = ["mostly-absent", "all-in-round-0", "nothing-live",
+                "one-long-chain", "minimum-bucket"]
+
+
+@pytest.mark.parametrize("shape", PROBE_SHAPES)
+@pytest.mark.parametrize("how,duplicates", [
+    ("inner", False), ("left", False), ("left_semi", False),
+    ("left_anti", False), ("left_semi", True), ("left_anti", True)])
+def test_the_probe_walks_the_rows_still_open(traced, how, duplicates, shape):
+    """``probe_slots_fn`` against a numpy walk of the same slot table
+    (``found``, ``bi`` and both trip counts to the round), then the join
+    node over the same batches against pandas; ``join.probe.pk`` carries the
+    trip counts wherever the output's row count is read."""
+    from spark_rapids_tpu.columnar.host import HostTable
+    from spark_rapids_tpu.exec.joins import (_TAIL_SHARE,
+                                             TpuShuffledHashJoinExec)
+    import jax.numpy as jnp
+    _, events = traced
+    bkeys, slot_row, bv, pool, depth = slot_table(duplicates)
+    keys, live = probe_keys(shape, bv, pool, depth)
+
+    # the program alone, over the padded batch the node will see
+    cap = MIN_BUCKET if shape == "minimum-bucket" else PROBE_CAP
+    pv = np.pad(keys, (0, cap - len(keys)))
+    usable = np.pad(live, (0, cap - len(live)))
+    found, bi, rounds, full_rounds = walk_programs()[1](
+        slot_row, bv, jnp.asarray(pv), jnp.asarray(usable))
+    want_found, want_bi, depth = numpy_walk(slot_row, bv, pv, usable)
+    assert (np.asarray(found) == want_found).all()
+    assert (np.asarray(bi) == want_bi).all()
+    trips = trip_counts(depth, cap, _TAIL_SHARE)
+    assert (int(rounds), int(full_rounds)) == trips
+    if shape == "mostly-absent":
+        assert trips[1] >= 2 and trips[0] - trips[1] >= 2, trips
+    elif shape == "all-in-round-0":
+        assert trips == (1, 1)
+    elif shape == "nothing-live":
+        assert trips == (0, 0) and not want_found.any()
+    elif shape == "one-long-chain":
+        assert trips[1] == 1 and trips[0] >= 8, trips
+
+    # the join, against pandas
+    p = pd.DataFrame({"pk": keys, "v": np.arange(len(keys)) * 0.5})
+    b = pd.DataFrame({"bk": bkeys, "w": np.arange(len(bkeys)) * 0.25})
+    node = TpuShuffledHashJoinExec(
+        Source(pa.Table.from_pandas(p), MIN_BUCKET, live),
+        Source(pa.Table.from_pandas(b), MIN_BUCKET), ["pk"], ["bk"], how,
+        None, merge_keys=False, min_bucket=MIN_BUCKET)
+    got = pd.concat([HostTable.to_arrow(t.to_host()).to_pandas()
+                     for t in node.execute_columnar(0)])
+    p = p[live]
+    if how in ("inner", "left"):
+        want = p.merge(b, how=how, left_on="pk", right_on="bk")
+    else:
+        keep = p.pk.isin(b.bk)
+        want = p[keep if how == "left_semi" else ~keep]
+    pd.testing.assert_frame_equal(      # ``v`` names the probe row
+        got.sort_values("v").reset_index(drop=True),
+        want.sort_values("v").reset_index(drop=True), check_dtype=False)
+
+    (span,) = events("join.probe.pk")
+    assert span.args["rows"] == cap
+    if how == "left" or shape == "minimum-bucket":
+        # no row count is read, so no trip count is either
+        assert "rounds" not in span.args and "full_rounds" not in span.args
+        assert len(events("sync")) == 1         # the prep's ``unique``
+    else:
+        assert (span.args["rounds"], span.args["full_rounds"]) == trips
+        assert len(events("sync")) == 2         # and the row count

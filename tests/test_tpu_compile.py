@@ -202,6 +202,22 @@ def test_compact_shrink_at_a_full_batch(topo, one_chip, rng, out_cap):
     assert "while" not in compiled.as_text()
 
 
+def test_probe_walk_at_a_full_batch(topo, one_chip):
+    """The hash join's chain walk alone at ``sf1.q3``'s largest probe: a
+    2^20-row batch against a 2^19-slot table. Seconds to compile (a
+    ``jnp.cumsum`` over the batch would cost this compiler 17-31 s), and
+    what it compiled holds the two loops — full rounds, tail rounds — and
+    no windowed scan."""
+    from spark_rapids_tpu.exec.joins import _JoinKernels
+    args = (jax.ShapeDtypeStruct((1 << 19,), jnp.int32),
+            jax.ShapeDtypeStruct((1 << 18,), jnp.int64),
+            jax.ShapeDtypeStruct((1 << 20,), jnp.int64),
+            jax.ShapeDtypeStruct((1 << 20,), jnp.bool_))
+    text = _compile(_JoinKernels(None).probe_slots_fn(), args,
+                    one_chip).as_text()
+    assert text.count(" while(") == 2 and "reduce-window(" not in text
+
+
 def test_pallas_axpy_full_column(topo, one_chip, monkeypatch):
     """The gridded Pallas kernel at a 2^23-row SF1 lineitem bucket: the
     ungridded kernel ran out of VMEM from 2^22 rows up."""
