@@ -29,7 +29,7 @@ whole operator pipelines take and return DeviceTables inside one jit.
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -1108,20 +1108,17 @@ _slice_rows_jitted = named_program(_slice_rows_impl, "slice_rows",
 
 
 def shrink_to_fit(table: DeviceTable, min_bucket: Optional[int] = None,
-                  num_rows: Optional[int] = None,
-                  on_count: Optional[Callable[[int], None]] = None
-                  ) -> DeviceTable:
+                  num_rows: Optional[int] = None) -> DeviceTable:
     """Compact and shrink capacity to the bucket of the active row count,
     in one program that builds only the rows it keeps (``compact_shrink``).
 
     Syncs the row count to host (one int) — used between pipeline steps to
     stop capacities from growing across incremental merges. Callers that
-    already hold the host count pass ``num_rows`` to skip the sync; a
-    caller that wants the count this call syncs takes it through
-    ``on_count`` (not called where no count is read: a table already at
-    the minimum bucket). Span ``shrink`` (``rows_in``, ``rows_out``) when
-    the program runs, ``shrink.skip`` when the table already fits its
-    bucket."""
+    already hold the host count pass ``num_rows`` to skip the sync (a
+    caller that wants more than the count reads it with the rest in one
+    ``resolve_scalars`` first). Span ``shrink`` (``rows_in``,
+    ``rows_out``) when the program runs, ``shrink.skip`` when the table
+    already fits its bucket."""
     min_bucket = resolve_min_bucket(min_bucket)
     tracer = get_tracer()
     # cannot shrink below one bucket: skip the device sync too
@@ -1133,8 +1130,6 @@ def shrink_to_fit(table: DeviceTable, min_bucket: Optional[int] = None,
             with tracer.span("sync", "download", scalars=1):
                 n = int(table.num_rows)  # srtpu: sync-ok(capacity choice needs the host count; callers with one pass it in)
             movement.note_d2h(_MOVE_SHRINK, 4, t0)
-        if on_count is not None:
-            on_count(n)
         cap = bucket_rows(max(n, 1), min_bucket)
         if cap < table.capacity:
             with tracer.span("shrink", "shrink", rows_in=table.capacity,

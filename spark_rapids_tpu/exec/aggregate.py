@@ -261,20 +261,37 @@ def _reduce_segment(op: str, vals: jax.Array, contrib: jax.Array,
 
 _COLLECT_OPS = frozenset(
     {"collect_list", "collect_set", "merge_lists", "merge_sets"})
+#: capacity at which ``execute_columnar`` aggregates the child batches it
+#: has staged as one chunk; chunks beyond the first merge into the running
+#: state one by one (span ``agg.merge``). A merge step re-aggregates the
+#: whole running state, so a state that barely reduces pays for every step
+#: at the state's capacity: with 2^20-row chunks TPC-H Q18's 4.4 M partial
+#: rows (1.47 M groups) took six chunk aggregates and five merge steps,
+#: 24 x 2^20 rows of aggregate for 16.0 s a query on one v5e; at 2^22 two
+#: chunks and one step, 10 x 2^20 (PERF.md section 6, PR 34)
+_CHUNK_ROWS = 1 << 22
 _BIG32 = np.int32(2**31 - 1)
 
 
 def _word_bits_u32(w: jax.Array) -> jax.Array:
-    """Equality word -> u32 hash contribution (bit-exact per value)."""
+    """Equality word -> u32 hash contribution: equal values give equal
+    bits. A float64 word goes by the bits of its float32 rounding and of
+    the float32 rounding of what that leaves (both functions of the value
+    alone): the TPU holds a float64 as a pair of float32 and its compiler
+    has no bitcast of one to 64 integer bits (a float64 group-by key —
+    TPC-H Q18's ``o_totalprice`` — failed to compile there)."""
     if jnp.issubdtype(w.dtype, jnp.floating):
+        hi = w.astype(jnp.float32)
+        u = jax.lax.bitcast_convert_type(hi, jnp.uint32)
         if w.dtype == jnp.float32:
-            u = jax.lax.bitcast_convert_type(w, jnp.uint32)
             return u
-        u = jax.lax.bitcast_convert_type(w.astype(jnp.float64), jnp.uint64)
-    elif w.dtype == jnp.bool_:
+        lo = jnp.where(jnp.isfinite(hi), w - hi.astype(w.dtype),
+                       jnp.zeros_like(w)).astype(jnp.float32)
+        return u ^ (jax.lax.bitcast_convert_type(lo, jnp.uint32)
+                    * jnp.uint32(0x9E3779B1))
+    if w.dtype == jnp.bool_:
         return w.astype(jnp.uint32)
-    else:
-        u = w.astype(jnp.uint64)
+    u = w.astype(jnp.uint64)
     return (u & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32) \
         ^ (u >> jnp.uint64(32)).astype(jnp.uint32)
 
@@ -287,7 +304,9 @@ def _hash_group_ids(table: "DeviceTable", key_names: List[str]):
 
     Returns the same contract as _sorted_group_ids but with NO order
     (``None``): rows stay where they are, so a consumer reads the input
-    columns as they stand and gathers nothing by a permutation. Every
+    columns as they stand and gathers nothing by a permutation; the last
+    value is the trip count of the resolve loop (its carry's counter as
+    the loop leaves it: span ``agg.scatter`` carries it as ``rounds``). Every
     consumer (group reductions, representative gather) is order-agnostic,
     so the GROUPING contributes no lax.sort to the program — the escape
     hatch for toolchains where sort compilation is pathological (see
@@ -337,7 +356,7 @@ def _hash_group_ids(table: "DeviceTable", key_names: List[str]):
     # the scopes tie the HLO's %while / gather ops to this code in a
     # device profile (free at run time)
     with jax.named_scope("groupby_bucket_resolve"):
-        _, winner, _ = jax.lax.while_loop(
+        rounds, winner, _ = jax.lax.while_loop(
             cond, body, (jnp.int32(0), iota, active))
     with jax.named_scope("groupby_group_ids"):
         is_rep = jnp.logical_and(active, winner == iota)
@@ -345,7 +364,7 @@ def _hash_group_ids(table: "DeviceTable", key_names: List[str]):
         gid = jnp.clip(jnp.take(rep_rank, winner), 0, cap - 1)
         num_groups = jnp.sum(is_rep.astype(jnp.int32))
     boundary = is_rep
-    return None, active, gid, boundary, num_groups
+    return None, active, gid, boundary, num_groups, rounds
 
 
 GROUPBY_STRATEGY = register_conf(
@@ -375,7 +394,8 @@ def _resolve_groupby_strategy() -> str:
 
 def _sorted_group_ids(table: "DeviceTable", key_names: List[str]):
     """Lexsort rows so equal keys are adjacent (active first) and label
-    groups. -> (order, active_s, gid, boundary, num_groups).
+    groups. -> (order, active_s, gid, boundary, num_groups, rounds), with
+    ``rounds`` 0: a sort resolves no bucket.
 
     The per-key null/NaN/length flags bit-pack into shared "meta" uint64
     words (the not-active flag in the top bits of meta word 0, so active
@@ -407,7 +427,7 @@ def _sorted_group_ids(table: "DeviceTable", key_names: List[str]):
     gid = jnp.cumsum(boundary.astype(jnp.int32)) - 1
     gid = jnp.clip(gid, 0, cap - 1)
     num_groups = jnp.sum(boundary.astype(jnp.int32))
-    return order, active_s, gid, boundary, num_groups
+    return order, active_s, gid, boundary, num_groups, jnp.int32(0)
 
 
 def _first_occurrence_in_group(sv: jax.Array, gid: jax.Array,
@@ -590,20 +610,44 @@ class TpuHashAggregateExec(TpuExec):
         return not any(op in _COLLECT_OPS or dt.is_d128(out_dt)
                        for (_, op, _, out_dt) in self._columns_ops())
 
-    def book_branch(self, num_groups: int) -> None:
-        """Span ``agg.dense`` / ``agg.scatter`` (``groups=``): the branch
-        of ``grouped`` that reduced a batch of ``num_groups`` groups.
-        Booked where the host already holds the batch's group count (the
-        row-count sync of the ``shrink_to_fit`` that follows, or the
-        count an exchange resolved): the device picked the branch from
-        the same number, so this adds no sync and no program. A batch
-        whose count the host never reads books neither."""
+    def book_branch(self, num_groups: int, rows: int,
+                    rounds: Optional[int] = None) -> None:
+        """Span ``agg.dense`` / ``agg.scatter`` (``rows`` = the batch's
+        capacity, ``groups``): the branch of ``grouped`` that reduced a
+        batch of ``num_groups`` groups. Booked where the host already
+        holds the batch's group count (the row-count sync of the
+        ``shrink_to_fit`` that follows, or the count an exchange
+        resolved): the device picked the branch from the same number, so
+        this adds no sync and no program. ``agg.scatter`` carries
+        ``rounds``, the trips of the bucket-resolve loop, where the
+        aggregate ran as a program of its own, which returns them; a
+        fused stage returns its table alone. A batch whose count the
+        host never reads books neither."""
         if not self.key_names:
             return
         dense = self._dense_ok() and num_groups <= FEW_GROUPS
+        args = {"rows": rows, "groups": num_groups}
+        if rounds is not None and not dense:
+            args["rounds"] = rounds
         with get_tracer().span("agg.dense" if dense else "agg.scatter",
-                               "agg", groups=num_groups):
+                               "agg", **args):
             pass
+
+    def shrink_booked(self, fn, out: DeviceTable, rows: int
+                      ) -> Tuple[DeviceTable, Optional[int]]:
+        """``shrink_to_fit`` of a batch ``fn`` (a ``_canon_fn`` of this
+        node) aggregated from ``rows`` rows of capacity, its branch
+        booked: the resolve loop's trip count rides in the transfer that
+        reads the group count for the shrink. -> (the shrunk batch, its
+        group count or None where none is read: no keys, or a batch
+        already at the minimum bucket)."""
+        from ..columnar.device import (resolve_min_bucket, resolve_scalars,
+                                       shrink_to_fit)
+        if not self.key_names or out.capacity <= resolve_min_bucket(None):
+            return shrink_to_fit(out), None
+        n, rounds = resolve_scalars(out.num_rows, fn.rounds)
+        self.book_branch(n, rows, rounds)
+        return shrink_to_fit(out, num_rows=n), n
 
     def host_batch_fn(self):
         # host-engine partial aggregation over one downloaded batch — the
@@ -657,8 +701,12 @@ class TpuHashAggregateExec(TpuExec):
         return fn
 
     # -- kernels -------------------------------------------------------------
-    def batch_fn(self, list_width: int = 0
+    def batch_fn(self, list_width: int = 0, with_rounds: bool = False
                  ) -> Callable[[DeviceTable], DeviceTable]:
+        """The aggregate of one batch. ``with_rounds``: a grouped
+        aggregate returns (table, trips of the bucket-resolve loop), the
+        form the program of its own (``_canon_fn``) compiles; inside a
+        fused stage the table alone leaves the program."""
         cols_ops = self._columns_ops()
         key_names = self.key_names
         out_names = tuple(self.schema.names)
@@ -708,7 +756,7 @@ class TpuHashAggregateExec(TpuExec):
 
         def grouped(table: DeviceTable) -> DeviceTable:
             cap = table.capacity
-            order, active_s, gid, boundary, num_groups = \
+            order, active_s, gid, boundary, num_groups, rounds = \
                 group_ids(table, key_names)
 
             def in_order(a):
@@ -820,8 +868,9 @@ class TpuHashAggregateExec(TpuExec):
                 validity = jnp.logical_and(has, group_mask) if op != "count" \
                     else group_mask
                 out_cols.append(DeviceColumn(vals, validity, out_dt, None))
-            return DeviceTable(tuple(out_cols), group_mask,
-                               num_groups.astype(jnp.int32), out_names)
+            out = DeviceTable(tuple(out_cols), group_mask,
+                              num_groups.astype(jnp.int32), out_names)
+            return (out, rounds) if with_rounds else out
 
         return ungrouped if not key_names else grouped
 
@@ -876,7 +925,7 @@ class TpuHashAggregateExec(TpuExec):
         def sizes(table: DeviceTable) -> jax.Array:
             cap = table.capacity
             if key_names:
-                order, active_s, gid, _, _ = group_ids(
+                order, active_s, gid, _, _, _ = group_ids(
                     table, key_names)
             else:
                 order = jnp.arange(cap, dtype=jnp.int32)
@@ -911,26 +960,36 @@ class TpuHashAggregateExec(TpuExec):
         canon, ckey = self._canon_exec()
         out_names = tuple(self.schema.names)
         grouped = bool(canon.key_names)
+
+        def named(out) -> DeviceTable:
+            # a grouped program returns (table, trips of its resolve
+            # loop): the trips wait on ``fn.rounds`` for whoever reads
+            # the batch's group count (``shrink_booked``)
+            if grouped:
+                out, fn.rounds = out
+            return out.with_names(out_names)
+
         if not self._has_collect():
             base = cached_jit(
-                ckey, canon.batch_fn,
+                ckey, lambda: canon.batch_fn(with_rounds=grouped),
                 name="agg_grouped" if grouped else "agg_ungrouped")
 
             def fn(batch: DeviceTable) -> DeviceTable:
-                return base(batch.canonical()).with_names(out_names)
-            return fn
-
-        def fn(batch: DeviceTable) -> DeviceTable:
-            bc = batch.canonical()  # per-batch static width, cached per bucket
-            w = canon._collect_width(bc, ckey)
-            out = cached_jit(
-                ckey + f"|W{w}", lambda: canon.batch_fn(list_width=w),
-                name="agg_grouped" if grouped else "agg_ungrouped")(bc)
-            return out.with_names(out_names)
+                return named(base(batch.canonical()))
+        else:
+            def fn(batch: DeviceTable) -> DeviceTable:
+                # per-batch static width, cached per bucket
+                bc = batch.canonical()
+                w = canon._collect_width(bc, ckey)
+                return named(cached_jit(
+                    ckey + f"|W{w}",
+                    lambda: canon.batch_fn(list_width=w, with_rounds=grouped),
+                    name="agg_grouped" if grouped else "agg_ungrouped")(bc))
+        fn.rounds = None
         return fn
 
     def execute_columnar(self, pidx: int) -> Iterator[DeviceTable]:
-        from ..columnar.device import concat_device_tables, shrink_to_fit
+        from ..columnar.device import concat_device_tables
         from ..memory.catalog import SpillPriorities, get_catalog
         from ..memory.retry import (split_device_rows, with_retry,
                                     with_retry_split)
@@ -956,18 +1015,19 @@ class TpuHashAggregateExec(TpuExec):
         splitter = split_device_rows if self.mode == "partial" else None
 
         def chunked_inputs():
-            """Stage child batches and aggregate one CONCAT per ~1M-row
-            chunk: one sort-based groupby over the chunk replaces a
-            per-batch aggregate + pairwise merge cascade (4 batches would
-            otherwise cost 7 lexsorts; chunking costs 1). The chunk bound
-            keeps the concat out-of-core-safe; anything beyond one chunk
-            still reduces through the pairwise merge below."""
+            """Stage child batches and aggregate one CONCAT per
+            ``_CHUNK_ROWS`` of capacity: one groupby over the chunk
+            replaces a per-batch aggregate + pairwise merge cascade (4
+            batches would otherwise cost 7 groupbys; chunking costs 1).
+            The chunk bound keeps the concat out-of-core-safe; anything
+            beyond one chunk still reduces through the pairwise merge
+            below."""
             staged: List[DeviceTable] = []
             cap = 0
             for b in self.child_device_batches(pidx):
                 staged.append(b)
                 cap += b.capacity
-                if cap >= (1 << 20):
+                if cap >= _CHUNK_ROWS:
                     yield staged[0] if len(staged) == 1 \
                         else concat_device_tables(staged)
                     staged, cap = [], 0
@@ -985,10 +1045,10 @@ class TpuHashAggregateExec(TpuExec):
                         self.metrics.timed(M.AGG_TIME):
                     # shrink to the group bucket: the running state must
                     # not scale with input capacity (out-of-core bound)
-                    out = shrink_to_fit(with_retry_split(
+                    out, _ = self.shrink_booked(fn, with_retry_split(
                         fn, batch, splitter=splitter, combiner=agg_combine,
                         scope="partial-agg", context=self.node_desc()),
-                        on_count=self.book_branch)
+                        batch.capacity)
                 if pending is None:
                     pending = catalog.register(
                         out, SpillPriorities.ACTIVE_ON_DECK)
@@ -1000,16 +1060,23 @@ class TpuHashAggregateExec(TpuExec):
                     # aggregate.scala merge passes under targetSize).
                     # concat pads to a pow2 bucket, so the merge program
                     # compiles for one or two capacities, not per sum.
-                    with pending as prev:
-                        both = concat_device_tables([prev, out])
-                    if merge_fn is None:
-                        merge_fn = merged._canon_fn()
-                    # spill-only retry: the concat'd pair is already at
-                    # the group bucket — there is nothing useful to halve
-                    state = shrink_to_fit(with_retry(
-                        merge_fn, both, scope="agg-merge",
-                        context=self.node_desc()),
-                        on_count=merged.book_branch)
+                    # span agg.merge: one step of the cascade, by the
+                    # capacity it ran at and the state it leaves
+                    with get_tracer().span("agg.merge", "agg") as span:
+                        with pending as prev:
+                            both = concat_device_tables([prev, out])
+                        span.note(rows=both.capacity)
+                        if merge_fn is None:
+                            merge_fn = merged._canon_fn()
+                        # spill-only retry: the concat'd pair is already
+                        # at the group bucket — there is nothing useful
+                        # to halve
+                        state, groups = merged.shrink_booked(
+                            merge_fn, with_retry(
+                                merge_fn, both, scope="agg-merge",
+                                context=self.node_desc()), both.capacity)
+                        if groups is not None:
+                            span.note(groups=groups)
                     pending.close()
                     pending = catalog.register(
                         state, SpillPriorities.ACTIVE_ON_DECK)

@@ -501,7 +501,7 @@ class TpuLocalExchangeExec(TpuExec):
             for b, n in zip(batches, ns):
                 n = int(n)
                 if fused_agg is not None:
-                    fused_agg.book_branch(n)
+                    fused_agg.book_branch(n, b.capacity)
                 if not n:
                     continue
                 with self.metrics.timed(M.OP_TIME):

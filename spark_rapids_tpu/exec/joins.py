@@ -1108,7 +1108,10 @@ class TpuShuffledHashJoinExec(TpuExec):
                         if shrinks and out.capacity > self.min_bucket:
                             # the hash walk's trip counts ride in the
                             # transfer that reads the shrink's row count
+                            # (``rows_out``: beside ``rows``, the share
+                            # of the probe batch that matched)
                             n, *trips = resolve_scalars(out.num_rows, *trips)
+                            span.note(rows_out=int(n))
                             if trips:
                                 span.note(rounds=int(trips[0]),
                                           full_rounds=int(trips[1]))

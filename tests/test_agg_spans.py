@@ -1,0 +1,206 @@
+"""The grouped aggregate as the tracer sees it (``exec/aggregate.py``):
+``agg.dense`` / ``agg.scatter`` a batch whose group count the host reads
+(``rows`` = the batch's capacity, ``groups``; ``agg.scatter`` with ``rounds``,
+the trips of the bucket-resolve loop, where the aggregate ran as a program
+of its own), ``agg.merge`` a concat-and-merge step of the final aggregate's
+cascade (``rows`` = the concat's capacity, ``groups`` = the state it
+leaves), ``join.probe.pk`` with ``rows_out`` beside ``rows`` — and no
+program and no blocking read that the code without them did not make."""
+import numpy as np
+import pyarrow as pa
+import pytest
+
+from spark_rapids_tpu.exec import aggregate
+from spark_rapids_tpu.expr.functions import col, sum as fsum
+from spark_rapids_tpu.session import TpuSession
+from spark_rapids_tpu.tools import tpch
+from spark_rapids_tpu.utils.tracing import get_tracer
+
+MIN_BUCKET = 64
+
+
+@pytest.fixture
+def traced():
+    """(session factory, events reader): the ring is on for the test."""
+    tracer = get_tracer()
+    was = tracer.enabled
+    tracer.enabled = True
+    tracer.clear()
+    opened = []
+
+    def session(**extra):
+        opened.append(TpuSession({
+            "spark.rapids.tpu.batchRowsMinBucket": MIN_BUCKET,
+            "spark.rapids.sql.test.enabled": True, **extra}))
+        return opened[-1]
+
+    def events(prefix):
+        return [e for e in tracer.events() if e.name.startswith(prefix)]
+    yield session, events
+    tracer.enabled = was
+    tracer.clear()
+    for s in opened:
+        s.close()
+
+
+def crossings(events):
+    """(programs dispatched, blocking syncs + downloads): what
+    ``programs_per_query`` and ``host_syncs_per_query`` count."""
+    return (len(events("dispatch")),
+            len(events("sync")) + len(events("d2h")))
+
+
+# ---- three plans in the shape of the benchmark's cells ----------------------
+def q18_tables():
+    """Q18's three tables by hand: 400 orders of 60 customers, 3,000 lines
+    drawn so that some orders hold a dozen lines and pass HAVING."""
+    rng = np.random.default_rng(18)
+    n_ord, n_cust = 400, 60
+    okey = np.arange(1, n_ord + 1, dtype=np.int64) * 4
+    heavy = rng.choice(n_ord, 40, replace=False)
+    l_ord = np.concatenate([rng.integers(0, n_ord, 2400),
+                            rng.choice(heavy, 600)])
+    return {
+        "customer": pa.table({
+            "c_custkey": np.arange(1, n_cust + 1, dtype=np.int64),
+            "c_name": [f"Customer#{i:09d}" for i in range(1, n_cust + 1)]}),
+        "orders": pa.table({
+            "o_orderkey": okey,
+            "o_custkey": rng.integers(1, n_cust + 1, n_ord).astype(np.int64),
+            "o_orderdate": pa.array(
+                rng.integers(8035, 10000, n_ord).astype(np.int32),
+                pa.int32()).cast(pa.date32()),
+            "o_totalprice": np.round(rng.uniform(900, 5e5, n_ord), 2)}),
+        "lineitem": pa.table({
+            "l_orderkey": okey[l_ord],
+            "l_quantity": rng.integers(1, 51, len(l_ord)).astype(np.float64)}),
+    }
+
+
+def q18_by_pandas(t):
+    cust, orders, li = (t[n].to_pandas()
+                        for n in ("customer", "orders", "lineitem"))
+    qty = li.groupby("l_orderkey", as_index=False).agg(
+        sum_qty=("l_quantity", "sum"))
+    big = qty[qty.sum_qty > 300.0]
+    out = cust.merge(orders, left_on="c_custkey", right_on="o_custkey") \
+              .merge(big, left_on="o_orderkey", right_on="l_orderkey")
+    return out.sort_values(["o_totalprice", "o_orderdate", "o_orderkey"],
+                           ascending=[False, True, True]).head(100)
+
+
+PLANS = {
+    # name: (tables, partitions a table)
+    "q1": (lambda: {"lineitem": tpch.gen_lineitem(0, seed=1, rows=3000)}, 3),
+    "q3": (lambda: tpch.gen_all(0, tiny=True, seed=3), 2),
+    "q18": (q18_tables, 3),
+}
+#: read from commit 228df1e (PR 33) with this file's plans
+PARENT_CROSSINGS = {"q1": (11, 6), "q3": (29, 13), "q18": (48, 24)}
+
+
+def run_plan(session, name):
+    make, parts = PLANS[name]
+    tables = make()
+    frames = tpch.build_dataframes(session(), tables, num_partitions=parts)
+    return tables, tpch.QUERIES[name](frames).collect().to_pandas()
+
+
+@pytest.mark.parametrize("name", sorted(PLANS))
+def test_the_span_arguments_add_no_program_and_no_sync(traced, name):
+    session, events = traced
+    tables, got = run_plan(session, name)
+    assert len(got) > 0
+    assert crossings(events) == PARENT_CROSSINGS[name]
+    booked = events("agg.dense") + events("agg.scatter")
+    assert booked, "no aggregate batch was booked"
+    for e in booked:
+        assert e.args["rows"] >= MIN_BUCKET and e.args["rows"] & \
+            (e.args["rows"] - 1) == 0, e.args
+        assert 0 <= e.args["groups"] <= e.args["rows"]
+        assert "rounds" not in e.args or e.name == "agg.scatter"
+    if name == "q18":
+        want = q18_by_pandas(tables)
+        assert len(want) >= 5
+        assert list(got.o_orderkey) == list(want.o_orderkey)
+        assert list(got.c_name) == list(want.c_name)
+        np.testing.assert_array_equal(got.sum_qty, want.sum_qty)
+
+
+def test_q18s_wide_group_by_books_rounds_and_its_probes_match_rates(traced):
+    session, events = traced
+    tables, _ = run_plan(session, "q18")
+    orders_with_lines = tables["lineitem"]["l_orderkey"].to_pandas().nunique()
+    # the final aggregate of `big` runs as a program of its own, which
+    # returns the resolve loop's trips; at this size its three partial
+    # batches are one chunk, so one batch holds every order with a line
+    # and nothing is merged (the next test merges)
+    wide = [e for e in events("agg.scatter")
+            if e.args["groups"] == orders_with_lines]
+    assert len(wide) == 1 and wide[0].args["rounds"] >= 2
+    assert wide[0].args["rows"] >= sum(
+        e.args["groups"] for e in events("agg.scatter")
+        if "rounds" not in e.args and e.args["rows"] == 1024)
+    assert not events("agg.merge")
+    # the fused partials leave their stage as tables alone: no trips
+    assert [e for e in events("agg.scatter") if "rounds" not in e.args]
+    # the probes that read their output's count carry it beside `rows`
+    probes = events("join.probe.pk")
+    assert probes
+    for e in probes:
+        assert ("rows_out" in e.args) == ("rounds" in e.args)
+        if "rows_out" in e.args:
+            assert 0 <= e.args["rows_out"] <= e.args["rows"]
+    kept = [e.args["rows_out"] for e in probes if "rows_out" in e.args]
+    assert kept and min(kept) < max(e.args["rows"] for e in probes)
+
+
+# ---- a state that outgrows one batch's bucket --------------------------------
+def test_a_state_that_outgrows_a_batch_merges_through_two_capacities(
+        traced, monkeypatch):
+    """Three batches of 200 distinct keys each (one batch fits the 256-row
+    bucket): the running state is 400 keys after the first merge and 600
+    after the second, through the 512- and 1,024-row buckets."""
+    session, events = traced
+    # a chunk is one batch here, as a 2^20-row batch is at SF 1
+    monkeypatch.setattr(aggregate, "_CHUNK_ROWS", 256)
+    rng = np.random.default_rng(7)
+    keys = np.concatenate([np.arange(b * 200, (b + 1) * 200).repeat(2)
+                           for b in range(3)]).astype(np.int64) * 4
+    vals = rng.integers(1, 51, len(keys)).astype(np.float64)
+    df = session(**{"spark.rapids.tpu.shuffle.partitions": 1}) \
+        .create_dataframe(pa.table({"k": keys, "v": vals}), num_partitions=3)
+    got = df.group_by("k").agg(fsum(col("v")).alias("s")).collect() \
+        .to_pandas().sort_values("k")
+    want = np.bincount(keys // 4, weights=vals)
+    np.testing.assert_array_equal(got.k, np.arange(600) * 4)
+    np.testing.assert_array_equal(got.s, want)
+    merges = events("agg.merge")
+    assert [e.args["groups"] for e in merges] == [400, 600]
+    assert [e.args["rows"] for e in merges] == [512, 1024]
+    # every step's aggregate is a scatter batch at the concat's capacity
+    steps = [e for e in events("agg.scatter")
+             if e.args["groups"] in (400, 600)]
+    assert [e.args["rows"] for e in steps] == [512, 1024]
+    assert all(e.args["rounds"] >= 1 for e in steps)
+
+
+def test_rounds_count_the_trips_of_the_resolve_loop():
+    """The program of its own returns the loop's trip count: one trip for
+    keys that all land in buckets of their own, more where keys share a
+    bucket (300 keys in 512 buckets cannot all be alone: the first round
+    resolves one key a bucket and leaves the rest)."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.columnar.device import DeviceTable
+    from spark_rapids_tpu.columnar.host import HostTable
+
+    def trips(keys):
+        t = DeviceTable.from_host(HostTable.from_arrow(
+            pa.table({"k": np.asarray(keys, np.int64)})), min_bucket=512)
+        *_, groups, rounds = aggregate._hash_group_ids(t, ["k"])
+        return int(groups), int(rounds)
+
+    assert trips([5] * 100) == (1, 1)
+    groups, rounds = trips(np.arange(300) * 4)
+    assert groups == 300 and rounds >= 2
+    assert trips([]) == (0, 0)

@@ -246,10 +246,10 @@ def _identity_gathers(jaxpr, iota_invars=()):
 
 
 @pytest.fixture(scope="module")
-def q1_shaped_jaxpr():
-    """``batch_fn()`` of a Q1-shaped partial aggregate (2 string keys, 11
-    buffers) traced at capacity 2^14 under the hash strategy."""
-    import jax
+def q1_shaped_partial():
+    """(node, batch): a Q1-shaped partial aggregate (2 string keys, 11
+    buffers) and an input batch of capacity 2^14 for its ``batch_fn``,
+    inside a session under the hash strategy."""
     import numpy as np
     from spark_rapids_tpu.exec.wholestage import TpuWholeStageExec
     from spark_rapids_tpu.session import TpuSession
@@ -284,9 +284,69 @@ def q1_shaped_jaxpr():
         for node in stage.chain[:-1]:
             batch = node.batch_fn()(batch)
         assert batch.capacity == _GUARD_CAP
-        return jax.make_jaxpr(partial.batch_fn())(batch).jaxpr
+        yield partial, batch
     finally:
         sess.close()
+
+
+@pytest.fixture(scope="module")
+def q1_shaped_jaxpr(q1_shaped_partial):
+    """``batch_fn()`` of that aggregate, traced: the form a fused stage
+    holds."""
+    import jax
+    partial, batch = q1_shaped_partial
+    return jax.make_jaxpr(partial.batch_fn())(batch).jaxpr
+
+
+def test_returning_the_resolve_loops_trips_adds_no_equation(
+        q1_shaped_partial, q1_shaped_jaxpr):
+    """The aggregate's program of its own returns (table, trips): the same
+    equations as the fused form, and one more output, which is the first
+    value of the bucket-resolve loop's carry as the loop leaves it (the
+    carry keeps its order: XLA's memory-space assignment follows it)."""
+    import jax
+    partial, batch = q1_shaped_partial
+    alone = jax.make_jaxpr(partial.batch_fn(with_rounds=True))(batch).jaxpr
+    fused = q1_shaped_jaxpr
+    assert [str(e.primitive) for e in alone.eqns] \
+        == [str(e.primitive) for e in fused.eqns]
+    assert len(alone.outvars) == len(fused.outvars) + 1
+    loops = [e for e in alone.eqns if e.primitive.name == "while"]
+    assert len(loops) == 1 and alone.outvars[-1] is loops[0].outvars[0]
+    assert [v.aval for v in loops[0].outvars] == [
+        v.aval for v in next(e for e in fused.eqns
+                             if e.primitive.name == "while").outvars]
+
+
+@pytest.mark.parametrize("strategy,trips", [
+    ("hash", lambda r: r >= 2), ("sort", lambda r: r == 0)])
+def test_agg_scatter_books_rows_groups_and_rounds(strategy, trips):
+    """500 distinct keys in one 512-row batch: under the hash strategy keys
+    share buckets, so the resolve loop takes a second trip; a sort resolves
+    no bucket and books 0."""
+    import numpy as np
+    from spark_rapids_tpu.session import TpuSession
+    from spark_rapids_tpu.utils.tracing import get_tracer
+    tracer = get_tracer()
+    was, tracer.enabled = tracer.enabled, True
+    tracer.clear()
+    sess = TpuSession({"spark.rapids.tpu.batchRowsMinBucket": 64,
+                       "spark.rapids.tpu.groupby.strategy": strategy})
+    try:
+        keys = np.arange(500, dtype=np.int64) * 4
+        df = sess.create_dataframe(
+            pa.table({"k": keys, "v": np.ones(500)}), num_partitions=1)
+        got = df.group_by("k").agg(fsum(col("v")).alias("s")).collect()
+        booked = [e.args for e in tracer.events() if e.name == "agg.scatter"]
+    finally:
+        sess.close()
+        tracer.enabled = was
+        tracer.clear()
+    assert got.num_rows == 500
+    alone = [a for a in booked if "rounds" in a]
+    assert alone and all(a["rows"] == 512 and a["groups"] == 500
+                         for a in booked)
+    assert all(trips(a["rounds"]) for a in alone)
 
 
 def test_hash_grouping_gathers_by_no_identity_permutation(q1_shaped_jaxpr):

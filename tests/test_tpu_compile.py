@@ -134,6 +134,26 @@ def test_grouped_hash_aggregate(topo, one_chip, sess, rng):
     assert text.count(" conditional(") == 1
 
 
+def test_grouped_aggregate_by_a_float64_key(topo, one_chip, sess, rng):
+    """TPC-H Q18's last group-by holds ``o_totalprice``, a float64 key
+    beside a string and an int64: the TPU keeps a float64 as two float32,
+    and its compiler has no bitcast of one to 64 integer bits (the key
+    hash took one until PR 34: UNIMPLEMENTED, the first run of the cell)."""
+    from spark_rapids_tpu.exec.aggregate import TpuHashAggregateExec
+    from spark_rapids_tpu.exec.wholestage import TpuWholeStageExec
+    from spark_rapids_tpu.expr.functions import col, sum
+    df = sess.create_dataframe(_table(rng, ROWS - 7))
+    q = df.group_by("s", "k", "v").agg(sum(col("g")).alias("t"))
+    plan = sess._physical(q.logical, device=True)
+    final = _find(plan, TpuHashAggregateExec)
+    stage = _find(plan, TpuWholeStageExec)
+    assert final is not None and final.mode == "final" and stage is not None
+    batch = next(stage.source.execute_columnar(0))
+    partial = stage.batch_fn()
+    _compile(partial, (batch,), one_chip)
+    _compile(final.batch_fn(with_rounds=True), (partial(batch),), one_chip)
+
+
 def test_pk_hash_join(topo, one_chip, sess, rng):
     """FK->PK join on the sort-free slot table: build prep and fused probe
     (exec/joins.py pk_hash_join_fn)."""
