@@ -296,75 +296,166 @@ def _word_bits_u32(w: jax.Array) -> jax.Array:
         ^ (u >> jnp.uint64(32)).astype(jnp.uint32)
 
 
-def _hash_group_ids(table: "DeviceTable", key_names: List[str]):
-    """SORT-FREE exact grouping: hash keys into row-count buckets, resolve
-    each bucket's minimum-index candidate's whole key-class per round, and
-    rehash unresolved rows until none remain (a lax.while_loop — compile
-    cost is one body regardless of rounds; expected 2-4 rounds).
+#: the bucket-resolve loop runs full rounds while more than this share of
+#: the capacity is open, then one compaction and tail rounds that long. The
+#: joins' walks switch at a sixteenth (``exec/joins.py``): they take ~10
+#: tail rounds, a slot a round, so a short tail pays. This loop takes 2-3 (a
+#: round resolves a key a BUCKET), so a tail twice as long costs it little
+#: and a full round saved is worth 4-20 tail rounds: a final aggregate's
+#: 2^22-row chunk — a concat of batches, two thirds live, a third as many
+#: keys as buckets — leaves a tenth of its capacity open after round 1 and
+#: runs one full round at 8 where 16 runs two (536 against 870 ms on the
+#: chip; over a query of ``sf1.q18`` 8 beats 16 by 16-19 % of the loop:
+#: PERF.md section 6, PR 35)
+_TAIL_SHARE = 8
+#: buckets a tail row: a quarter of the load saves one tail round of three
+#: on every measured shape (PERF.md section 6, PR 35)
+_TAIL_BUCKETS = 4
 
-    Returns the same contract as _sorted_group_ids but with NO order
-    (``None``): rows stay where they are, so a consumer reads the input
-    columns as they stand and gathers nothing by a permutation; the last
-    value is the trip count of the resolve loop (its carry's counter as
-    the loop leaves it: span ``agg.scatter`` carries it as ``rounds``). Every
-    consumer (group reductions, representative gather) is order-agnostic,
-    so the GROUPING contributes no lax.sort to the program — the escape
-    hatch for toolchains where sort compilation is pathological (see
-    spark.rapids.tpu.groupby.strategy), and the closest analogue of the
-    reference's cuDF HASH groupby."""
+
+def _hashed_key_words(table: "DeviceTable", key_names: List[str]
+                      ) -> "Tuple[jax.Array, List[jax.Array]]":
+    """-> (u32 hash a row, the equality words it was taken over: every key
+    column's value words, then the null / NaN / length flags bit-packed
+    into shared meta words)."""
     from ..shuffle.manager import _fmix_device
-    cap = table.capacity
-    active = table.row_mask
-    key_cols = [table.column(k) for k in key_names]
     bit_fields = []
     value_words: List[jax.Array] = []
-    for kc in key_cols:
-        words, smalls = _key_small_fields(kc)
+    for k in key_names:
+        words, smalls = _key_small_fields(table.column(k))
         value_words.extend(words)
         bit_fields.extend(smalls)
     words = value_words + _pack_meta_words(bit_fields)
-
     with jax.named_scope("groupby_key_hash"):
-        h = jnp.zeros(cap, dtype=jnp.uint32)
+        h = jnp.zeros(table.capacity, dtype=jnp.uint32)
         for i, w in enumerate(words):
             h = h ^ _fmix_device(_word_bits_u32(w) ^ jnp.uint32(i + 1))
             h = h * jnp.uint32(5) + jnp.uint32(0xE6546B64)
+    return h, words
 
+
+def _resolve_buckets(h: jax.Array, words: List[jax.Array],
+                     active: jax.Array):
+    """The bucket-resolve loops of ``_hash_group_ids`` alone: rows of key
+    hashes ``h`` and key words ``words`` -> (winner = each active row's
+    representative, the lowest active row of its key class; rounds;
+    full_rounds). The tail loop is not free to a batch that skips it: on
+    the chip its buffers take fast memory from the full rounds' fusions in
+    the same program (Q1's one round a batch reads +14 %: PERF.md section
+    6, PR 35)."""
+    from ..columnar.device import open_rows_by_rank
+    from ..shuffle.manager import _fmix_device
+    cap = h.shape[0]
+    tail_cap = max(cap // _TAIL_SHARE, 1)
     iota = jnp.arange(cap, dtype=jnp.int32)
 
-    def cond(state):
-        r, winner, unresolved = state
+    def resolve(h, words, iota, buckets):
+        """The loop body over the rows whose hashes and key words these
+        are, hashed to ``buckets`` buckets; ``winner`` holds positions
+        among the rows."""
+        n = iota.shape[0]
+
+        def body(state):
+            r, winner, unresolved = state
+            hr = _fmix_device(h ^ (r.astype(jnp.uint32)
+                                   * jnp.uint32(2654435761)))
+            bucket = (hr % jnp.uint32(buckets)).astype(jnp.int32)
+            cand_src = jnp.where(unresolved, iota, n)
+            cand = jax.ops.segment_min(cand_src, bucket,
+                                       num_segments=buckets)
+            w = jnp.take(cand, bucket)
+            w_safe = jnp.clip(w, 0, n - 1)
+            eq = jnp.logical_and(unresolved, w < n)
+            for word in words:
+                eq = jnp.logical_and(
+                    eq, word == jnp.take(word, w_safe, axis=0))
+            winner = jnp.where(eq, w_safe, winner)
+            unresolved = jnp.logical_and(unresolved, jnp.logical_not(eq))
+            return r + 1, winner, unresolved
+        return body
+
+    def many_open(state):
+        r, _, unresolved = state
+        return jnp.logical_and(
+            jnp.sum(unresolved, dtype=jnp.int32) > tail_cap, r < cap)
+
+    def any_open(state):
+        r, _, unresolved = state
         return jnp.logical_and(jnp.any(unresolved), r < cap)
 
-    def body(state):
-        r, winner, unresolved = state
-        hr = _fmix_device(h ^ (r.astype(jnp.uint32)
-                               * jnp.uint32(2654435761)))
-        bucket = (hr % jnp.uint32(cap)).astype(jnp.int32)
-        cand_src = jnp.where(unresolved, iota, cap)
-        cand = jax.ops.segment_min(cand_src, bucket, num_segments=cap)
-        w = jnp.take(cand, bucket)
-        w_safe = jnp.clip(w, 0, cap - 1)
-        eq = jnp.logical_and(unresolved, w < cap)
-        for word in words:
-            eq = jnp.logical_and(
-                eq, word == jnp.take(word, w_safe, axis=0))
-        winner = jnp.where(eq, w_safe, winner)
-        unresolved = jnp.logical_and(unresolved, jnp.logical_not(eq))
-        return r + 1, winner, unresolved
+    def tail(full_rounds, winner, unresolved):
+        rows, live = open_rows_by_rank(unresolved, iota, tail_cap)
+        tail_iota = jnp.arange(tail_cap, dtype=jnp.int32)
+        rounds, tail_winner, _ = jax.lax.while_loop(
+            any_open,
+            resolve(jnp.take(h, rows),
+                    [jnp.take(w, rows, axis=0) for w in words], tail_iota,
+                    _TAIL_BUCKETS * tail_cap),
+            (full_rounds, tail_iota, live))
+        return rounds, winner.at[jnp.where(live, rows, cap)].set(
+            jnp.take(rows, tail_winner), mode="drop")
 
     # the scopes tie the HLO's %while / gather ops to this code in a
     # device profile (free at run time)
     with jax.named_scope("groupby_bucket_resolve"):
-        rounds, winner, _ = jax.lax.while_loop(
-            cond, body, (jnp.int32(0), iota, active))
+        full_rounds, winner, unresolved = jax.lax.while_loop(
+            many_open, resolve(h, words, iota, cap),
+            (jnp.int32(0), iota, active))
+    with jax.named_scope("groupby_tail_resolve"):
+        rounds, winner = jax.lax.cond(
+            jnp.any(unresolved), tail, lambda r, w, _: (r, w),
+            full_rounds, winner, unresolved)
+    return winner, rounds, full_rounds
+
+
+def _hash_group_ids(table: "DeviceTable", key_names: List[str]):
+    """SORT-FREE exact grouping: hash keys into buckets, resolve each
+    bucket's minimum-index candidate's whole key-class per round, and
+    rehash unresolved rows until none remain (``lax.while_loop``s — compile
+    cost is a body a loop regardless of rounds; expected 2-4 rounds).
+
+    A round costs by the rows it is run over, not by the rows still open,
+    and a round resolves one key a bucket: the last rounds are run for a
+    few percent of the rows. So a *full round* — every row hashed to one
+    of ``cap`` buckets, each bucket's lowest open row taken as candidate,
+    every key word of the candidate gathered by every row — runs only
+    while more than 1/``_TAIL_SHARE`` of the capacity is open. The rows
+    then still open are compacted once by rank (``open_rows_by_rank``),
+    their hash and key words gathered by those ``cap // _TAIL_SHARE``
+    indices, and *tail rounds* that long (the same step, over
+    ``_TAIL_BUCKETS`` buckets a tail row) finish them; their winners are
+    written back by row index in one scatter. A round resolves a
+    candidate's whole class, so the open rows are whole classes, and
+    compaction keeps row order, so a class's lowest row is its
+    representative whichever phase resolves it: the grouping is the same
+    for every input. Compaction, tail rounds and write-back sit under one
+    ``lax.cond`` on "any row still open": a batch the full rounds finish
+    (Q1's four groups: one round) pays one scalar test, and a batch with
+    no more than ``cap // _TAIL_SHARE`` live rows runs no full round at
+    all.
+
+    Returns the same contract as _sorted_group_ids but with NO order
+    (``None``): rows stay where they are, so a consumer reads the input
+    columns as they stand and gathers nothing by a permutation; the last
+    value is (rounds, full_rounds): all trips of the resolve loops, full
+    and tail, and the full rounds among them (span ``agg.scatter`` carries
+    both). Every consumer (group reductions, representative gather) is
+    order-agnostic, so the GROUPING contributes no lax.sort to the program
+    — the escape hatch for toolchains where sort compilation is
+    pathological (see spark.rapids.tpu.groupby.strategy), and the closest
+    analogue of the reference's cuDF HASH groupby."""
+    cap = table.capacity
+    active = table.row_mask
+    h, words = _hashed_key_words(table, key_names)
+    winner, rounds, full_rounds = _resolve_buckets(h, words, active)
     with jax.named_scope("groupby_group_ids"):
+        iota = jnp.arange(cap, dtype=jnp.int32)
         is_rep = jnp.logical_and(active, winner == iota)
         rep_rank = jnp.cumsum(is_rep.astype(jnp.int32)) - 1
         gid = jnp.clip(jnp.take(rep_rank, winner), 0, cap - 1)
         num_groups = jnp.sum(is_rep.astype(jnp.int32))
     boundary = is_rep
-    return None, active, gid, boundary, num_groups, rounds
+    return None, active, gid, boundary, num_groups, (rounds, full_rounds)
 
 
 GROUPBY_STRATEGY = register_conf(
@@ -394,8 +485,8 @@ def _resolve_groupby_strategy() -> str:
 
 def _sorted_group_ids(table: "DeviceTable", key_names: List[str]):
     """Lexsort rows so equal keys are adjacent (active first) and label
-    groups. -> (order, active_s, gid, boundary, num_groups, rounds), with
-    ``rounds`` 0: a sort resolves no bucket.
+    groups. -> (order, active_s, gid, boundary, num_groups, (rounds,
+    full_rounds)), both 0: a sort resolves no bucket.
 
     The per-key null/NaN/length flags bit-pack into shared "meta" uint64
     words (the not-active flag in the top bits of meta word 0, so active
@@ -427,7 +518,8 @@ def _sorted_group_ids(table: "DeviceTable", key_names: List[str]):
     gid = jnp.cumsum(boundary.astype(jnp.int32)) - 1
     gid = jnp.clip(gid, 0, cap - 1)
     num_groups = jnp.sum(boundary.astype(jnp.int32))
-    return order, active_s, gid, boundary, num_groups, jnp.int32(0)
+    return order, active_s, gid, boundary, num_groups, \
+        (jnp.int32(0), jnp.int32(0))
 
 
 def _first_occurrence_in_group(sv: jax.Array, gid: jax.Array,
@@ -611,7 +703,7 @@ class TpuHashAggregateExec(TpuExec):
                        for (_, op, _, out_dt) in self._columns_ops())
 
     def book_branch(self, num_groups: int, rows: int,
-                    rounds: Optional[int] = None) -> None:
+                    trips: Optional[Sequence[int]] = None) -> None:
         """Span ``agg.dense`` / ``agg.scatter`` (``rows`` = the batch's
         capacity, ``groups``): the branch of ``grouped`` that reduced a
         batch of ``num_groups`` groups. Booked where the host already
@@ -619,16 +711,17 @@ class TpuHashAggregateExec(TpuExec):
         ``shrink_to_fit`` that follows, or the count an exchange
         resolved): the device picked the branch from the same number, so
         this adds no sync and no program. ``agg.scatter`` carries
-        ``rounds``, the trips of the bucket-resolve loop, where the
-        aggregate ran as a program of its own, which returns them; a
-        fused stage returns its table alone. A batch whose count the
-        host never reads books neither."""
+        ``rounds`` and ``full_rounds`` (``trips``: all trips of the
+        bucket-resolve loops, and those of them that ran over the whole
+        batch) where the aggregate ran as a program of its own, which
+        returns them; a fused stage returns its table alone. A batch
+        whose count the host never reads books neither."""
         if not self.key_names:
             return
         dense = self._dense_ok() and num_groups <= FEW_GROUPS
         args = {"rows": rows, "groups": num_groups}
-        if rounds is not None and not dense:
-            args["rounds"] = rounds
+        if trips is not None and not dense:
+            args["rounds"], args["full_rounds"] = trips
         with get_tracer().span("agg.dense" if dense else "agg.scatter",
                                "agg", **args):
             pass
@@ -637,7 +730,7 @@ class TpuHashAggregateExec(TpuExec):
                       ) -> Tuple[DeviceTable, Optional[int]]:
         """``shrink_to_fit`` of a batch ``fn`` (a ``_canon_fn`` of this
         node) aggregated from ``rows`` rows of capacity, its branch
-        booked: the resolve loop's trip count rides in the transfer that
+        booked: the resolve loops' trip counts ride in the transfer that
         reads the group count for the shrink. -> (the shrunk batch, its
         group count or None where none is read: no keys, or a batch
         already at the minimum bucket)."""
@@ -645,8 +738,8 @@ class TpuHashAggregateExec(TpuExec):
                                        shrink_to_fit)
         if not self.key_names or out.capacity <= resolve_min_bucket(None):
             return shrink_to_fit(out), None
-        n, rounds = resolve_scalars(out.num_rows, fn.rounds)
-        self.book_branch(n, rows, rounds)
+        n, *trips = resolve_scalars(out.num_rows, *fn.trips)
+        self.book_branch(n, rows, trips)
         return shrink_to_fit(out, num_rows=n), n
 
     def host_batch_fn(self):
@@ -704,9 +797,10 @@ class TpuHashAggregateExec(TpuExec):
     def batch_fn(self, list_width: int = 0, with_rounds: bool = False
                  ) -> Callable[[DeviceTable], DeviceTable]:
         """The aggregate of one batch. ``with_rounds``: a grouped
-        aggregate returns (table, trips of the bucket-resolve loop), the
-        form the program of its own (``_canon_fn``) compiles; inside a
-        fused stage the table alone leaves the program."""
+        aggregate returns (table, (rounds, full_rounds) of the
+        bucket-resolve loops), the form the program of its own
+        (``_canon_fn``) compiles; inside a fused stage the table alone
+        leaves the program."""
         cols_ops = self._columns_ops()
         key_names = self.key_names
         out_names = tuple(self.schema.names)
@@ -756,7 +850,7 @@ class TpuHashAggregateExec(TpuExec):
 
         def grouped(table: DeviceTable) -> DeviceTable:
             cap = table.capacity
-            order, active_s, gid, boundary, num_groups, rounds = \
+            order, active_s, gid, boundary, num_groups, trips = \
                 group_ids(table, key_names)
 
             def in_order(a):
@@ -870,7 +964,7 @@ class TpuHashAggregateExec(TpuExec):
                 out_cols.append(DeviceColumn(vals, validity, out_dt, None))
             out = DeviceTable(tuple(out_cols), group_mask,
                               num_groups.astype(jnp.int32), out_names)
-            return (out, rounds) if with_rounds else out
+            return (out, trips) if with_rounds else out
 
         return ungrouped if not key_names else grouped
 
@@ -963,10 +1057,10 @@ class TpuHashAggregateExec(TpuExec):
 
         def named(out) -> DeviceTable:
             # a grouped program returns (table, trips of its resolve
-            # loop): the trips wait on ``fn.rounds`` for whoever reads
+            # loops): the trips wait on ``fn.trips`` for whoever reads
             # the batch's group count (``shrink_booked``)
             if grouped:
-                out, fn.rounds = out
+                out, fn.trips = out
             return out.with_names(out_names)
 
         if not self._has_collect():
@@ -985,7 +1079,7 @@ class TpuHashAggregateExec(TpuExec):
                     ckey + f"|W{w}",
                     lambda: canon.batch_fn(list_width=w, with_rounds=grouped),
                     name="agg_grouped" if grouped else "agg_ungrouped")(bc))
-        fn.rounds = None
+        fn.trips = None
         return fn
 
     def execute_columnar(self, pidx: int) -> Iterator[DeviceTable]:

@@ -35,8 +35,8 @@ import numpy as np
 from ..columnar import dtypes as dt
 from ..columnar.device import (DeviceColumn, DeviceTable, bucket_rows,
                                resolve_min_bucket, resolve_scalars,
-                               concat_device_tables, shrink_to_fit,
-                               slice_rows)
+                               concat_device_tables, open_rows_by_rank,
+                               shrink_to_fit, slice_rows)
 from ..expr.base import EvalContext, Expression
 from ..plan.logical import _join_schema
 from ..plan.physical import PhysicalPlan
@@ -203,21 +203,6 @@ _I64_MAX = np.int64(2**63 - 1)
 _TAIL_SHARE = 16
 
 
-def _open_rows_by_rank(open_rows: jax.Array, iota: jax.Array, tail_cap: int
-                       ) -> Tuple[jax.Array, jax.Array]:
-    """The compaction between a walk's full rounds and its tail rounds:
-    -> (the indices of the open rows by their rank, ``tail_cap`` long;
-    which of those slots hold a row). At most ``tail_cap`` rows are open.
-    The blocked ``prefix_sum``, never ``jnp.cumsum`` over a row-capacity
-    vector (17-31 s of TPU compile a program)."""
-    from ..columnar.device import prefix_sum
-    o32 = open_rows.astype(jnp.int32)
-    dest = jnp.where(open_rows, prefix_sum(o32) - o32, tail_cap)
-    rows = jnp.zeros(tail_cap, jnp.int32).at[dest].set(iota, mode="drop")
-    live = jnp.arange(tail_cap, dtype=jnp.int32) < jnp.sum(o32)
-    return rows, live
-
-
 def _chain_hashes(keys: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """-> (first bucket hash, odd step: a full cycle over a power-of-two
     table) of monotone-int64 keys, the same for build and probe."""
@@ -364,7 +349,7 @@ class _JoinKernels:
         unplaced, and the last rounds place a handful. So full-capacity
         rounds run only while more than 1/``_TAIL_SHARE`` of the
         capacity is unplaced; the rest are compacted once and finish in
-        rounds that long (``_open_rows_by_rank``; the probe's walk retires
+        rounds that long (``open_rows_by_rank``; the probe's walk retires
         and compacts its rows the same way). -> (slot_row, keys, unique,
         rounds, full_rounds): the trip counts ride on span ``join.prep``."""
         def fn(build_keys: DeviceTable):
@@ -420,7 +405,7 @@ class _JoinKernels:
                 many_left, insert(iota, bv, h1, step),
                 (jnp.int32(0), jnp.full(T, -1, jnp.int32), bmask,
                  jnp.zeros((), dtype=bool)))
-            rows, live = _open_rows_by_rank(active, iota, tail_cap)
+            rows, live = open_rows_by_rank(active, iota, tail_cap)
             rounds, slot_row, _, dup = jax.lax.while_loop(
                 any_left,
                 insert(rows, jnp.take(bv, rows), jnp.take(h1, rows),
@@ -435,7 +420,7 @@ class _JoinKernels:
         an empty slot (absent). A *full round* visits one more slot for
         every row of the probe batch and runs only while more than
         1/``_TAIL_SHARE`` of the probe capacity is unresolved; the rows
-        then still open are compacted once (``_open_rows_by_rank``) and
+        then still open are compacted once (``open_rows_by_rank``) and
         finish in *tail rounds* that long, whose hits are written back by
         row index: the walk costs by the rows that have anything left to
         do. A table built over duplicate keys holds one row a distinct
@@ -480,7 +465,7 @@ class _JoinKernels:
             full_rounds, unresolved, hit_row = jax.lax.while_loop(
                 many_open, look(pv, h1, step),
                 (jnp.int32(0), pmask, jnp.full(cap, -1, jnp.int32)))
-            rows, live = _open_rows_by_rank(
+            rows, live = open_rows_by_rank(
                 unresolved, jnp.arange(cap, dtype=jnp.int32), tail_cap)
             rounds, _, tail_hit = jax.lax.while_loop(
                 any_open,

@@ -1,8 +1,9 @@
 """The grouped aggregate as the tracer sees it (``exec/aggregate.py``):
 ``agg.dense`` / ``agg.scatter`` a batch whose group count the host reads
-(``rows`` = the batch's capacity, ``groups``; ``agg.scatter`` with ``rounds``,
-the trips of the bucket-resolve loop, where the aggregate ran as a program
-of its own), ``agg.merge`` a concat-and-merge step of the final aggregate's
+(``rows`` = the batch's capacity, ``groups``; ``agg.scatter`` with ``rounds``
+and ``full_rounds``, the trips of the bucket-resolve loops and those of them
+that ran over the whole batch, where the aggregate ran as a program of its
+own), ``agg.merge`` a concat-and-merge step of the final aggregate's
 cascade (``rows`` = the concat's capacity, ``groups`` = the state it
 leaves), ``join.probe.pk`` with ``rows_out`` beside ``rows`` — and no
 program and no blocking read that the code without them did not make."""
@@ -89,21 +90,36 @@ def q18_by_pandas(t):
                            ascending=[False, True, True]).head(100)
 
 
+def distinct_keys():
+    """3,000 rows of 2,900 keys in one batch: the final aggregate's resolve
+    loop leaves over an eighth of its 4,096 rows open after a round, so
+    full rounds, a compaction and tail rounds all run."""
+    rng = np.random.default_rng(35)
+    return {"t": pa.table({
+        "k": rng.permutation(np.arange(3000) % 2900).astype(np.int64) * 4,
+        "v": rng.integers(1, 51, 3000).astype(np.float64)})}
+
+
 PLANS = {
     # name: (tables, partitions a table)
     "q1": (lambda: {"lineitem": tpch.gen_lineitem(0, seed=1, rows=3000)}, 3),
     "q3": (lambda: tpch.gen_all(0, tiny=True, seed=3), 2),
     "q18": (q18_tables, 3),
+    "distinct": (distinct_keys, 1),
 }
-#: read from commit 228df1e (PR 33) with this file's plans
-PARENT_CROSSINGS = {"q1": (11, 6), "q3": (29, 13), "q18": (48, 24)}
+QUERIES = {**tpch.QUERIES, "distinct": lambda f: f["t"].group_by("k").agg(
+    fsum(col("v")).alias("s"))}
+#: read from commit 228df1e (PR 33) with this file's plans; "distinct" from
+#: commit 5e4fda7 (PR 34)
+PARENT_CROSSINGS = {"q1": (11, 6), "q3": (29, 13), "q18": (48, 24),
+                    "distinct": (3, 2)}
 
 
 def run_plan(session, name):
     make, parts = PLANS[name]
     tables = make()
     frames = tpch.build_dataframes(session(), tables, num_partitions=parts)
-    return tables, tpch.QUERIES[name](frames).collect().to_pandas()
+    return tables, QUERIES[name](frames).collect().to_pandas()
 
 
 @pytest.mark.parametrize("name", sorted(PLANS))
@@ -119,6 +135,15 @@ def test_the_span_arguments_add_no_program_and_no_sync(traced, name):
             (e.args["rows"] - 1) == 0, e.args
         assert 0 <= e.args["groups"] <= e.args["rows"]
         assert "rounds" not in e.args or e.name == "agg.scatter"
+        assert ("full_rounds" in e.args) == ("rounds" in e.args)
+        if "rounds" in e.args:
+            assert 0 <= e.args["full_rounds"] <= e.args["rounds"]
+    if name == "distinct":
+        # both phases of the resolve loop ran, and the answer is right
+        (wide,) = [e for e in booked if e.args["groups"] == 2900]
+        assert 1 <= wide.args["full_rounds"] < wide.args["rounds"]
+        want = tables["t"].to_pandas().groupby("k").v.sum()
+        np.testing.assert_array_equal(got.sort_values("k").s, want)
     if name == "q18":
         want = q18_by_pandas(tables)
         assert len(want) >= 5
@@ -186,10 +211,11 @@ def test_a_state_that_outgrows_a_batch_merges_through_two_capacities(
 
 
 def test_rounds_count_the_trips_of_the_resolve_loop():
-    """The program of its own returns the loop's trip count: one trip for
-    keys that all land in buckets of their own, more where keys share a
-    bucket (300 keys in 512 buckets cannot all be alone: the first round
-    resolves one key a bucket and leaves the rest)."""
+    """The program of its own returns the loops' trip counts, all of them
+    and the full rounds among them: one full trip for keys that all land
+    in buckets of their own, more where keys share a bucket (300 keys in
+    512 buckets cannot all be alone: the first round resolves one key a
+    bucket and leaves the rest)."""
     import jax.numpy as jnp
     from spark_rapids_tpu.columnar.device import DeviceTable
     from spark_rapids_tpu.columnar.host import HostTable
@@ -197,10 +223,11 @@ def test_rounds_count_the_trips_of_the_resolve_loop():
     def trips(keys):
         t = DeviceTable.from_host(HostTable.from_arrow(
             pa.table({"k": np.asarray(keys, np.int64)})), min_bucket=512)
-        *_, groups, rounds = aggregate._hash_group_ids(t, ["k"])
-        return int(groups), int(rounds)
+        *_, groups, (rounds, full_rounds) = aggregate._hash_group_ids(
+            t, ["k"])
+        return int(groups), int(rounds), int(full_rounds)
 
-    assert trips([5] * 100) == (1, 1)
-    groups, rounds = trips(np.arange(300) * 4)
-    assert groups == 300 and rounds >= 2
-    assert trips([]) == (0, 0)
+    assert trips([5] * 100) == (1, 1, 1)
+    groups, rounds, full_rounds = trips(np.arange(300) * 4)
+    assert groups == 300 and rounds >= 2 and 1 <= full_rounds <= rounds
+    assert trips([]) == (0, 0, 0)

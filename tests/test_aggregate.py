@@ -298,11 +298,23 @@ def q1_shaped_jaxpr(q1_shaped_partial):
     return jax.make_jaxpr(partial.batch_fn())(batch).jaxpr
 
 
+def _tail_conds(jaxpr):
+    """The ``cond``s of a jaxpr with a branch that does nothing and one that
+    loops: the bucket resolve's compaction, tail rounds and write-back."""
+    def loops(branch):
+        return any(q.primitive.name == "while" for q in branch.jaxpr.eqns)
+    return [e for e in jaxpr.eqns if e.primitive.name == "cond"
+            and sorted((len(b.jaxpr.eqns) > 0, loops(b))
+                       for b in e.params["branches"])
+            == [(False, False), (True, True)]]
+
+
 def test_returning_the_resolve_loops_trips_adds_no_equation(
         q1_shaped_partial, q1_shaped_jaxpr):
-    """The aggregate's program of its own returns (table, trips): the same
-    equations as the fused form, and one more output, which is the first
-    value of the bucket-resolve loop's carry as the loop leaves it (the
+    """The aggregate's program of its own returns (table, (rounds,
+    full_rounds)): the same equations as the fused form, and two more
+    outputs — the counter as the tail's ``cond`` hands it on, and the
+    first value of the full rounds' carry as that loop leaves it (the
     carry keeps its order: XLA's memory-space assignment follows it)."""
     import jax
     partial, batch = q1_shaped_partial
@@ -310,12 +322,43 @@ def test_returning_the_resolve_loops_trips_adds_no_equation(
     fused = q1_shaped_jaxpr
     assert [str(e.primitive) for e in alone.eqns] \
         == [str(e.primitive) for e in fused.eqns]
-    assert len(alone.outvars) == len(fused.outvars) + 1
+    assert len(alone.outvars) == len(fused.outvars) + 2
+    rounds, full_rounds = alone.outvars[-2:]
     loops = [e for e in alone.eqns if e.primitive.name == "while"]
-    assert len(loops) == 1 and alone.outvars[-1] is loops[0].outvars[0]
+    assert len(loops) == 1 and full_rounds is loops[0].outvars[0]
+    (tail,) = _tail_conds(alone)
+    assert rounds is tail.outvars[0]
     assert [v.aval for v in loops[0].outvars] == [
         v.aval for v in next(e for e in fused.eqns
                              if e.primitive.name == "while").outvars]
+    r, winner, unresolved = (v.aval for v in loops[0].outvars)
+    assert (r.shape, winner.dtype.name, unresolved.dtype.name) \
+        == ((), "int32", "bool")
+
+
+def test_the_tail_of_the_resolve_loop_is_its_share_of_the_batch(
+        q1_shaped_jaxpr):
+    """Under the tail's ``cond``: one compaction (a prefix sum, no
+    ``cumsum``; one index scatter of the batch's length), gathers of no
+    more than ``cap // _TAIL_SHARE`` indices inside and outside its loop,
+    and a write-back of as many updates; the other branch holds
+    nothing."""
+    from spark_rapids_tpu.exec.aggregate import _TAIL_SHARE
+    tail_cap = _GUARD_CAP // _TAIL_SHARE
+    (cond,) = _tail_conds(q1_shaped_jaxpr)
+    skip, tail = (b.jaxpr for b in cond.params["branches"])
+    assert not skip.eqns
+    eqns = list(_eqns(tail))
+    names = [e.primitive.name for e in eqns]
+    assert names.count("while") == 1 and "cumsum" not in names
+    assert "sort" not in names
+    gathers = [e for e in eqns if e.primitive.name == "gather"]
+    assert gathers and all(
+        e.invars[1].aval.shape[0] == tail_cap for e in gathers)
+    updates = sorted(e.invars[2].aval.shape[0] for e in eqns
+                     if e.primitive.name.startswith("scatter"))
+    # compaction's index scatter; write-back; the tail rounds' scatter-min
+    assert updates == [tail_cap, tail_cap, _GUARD_CAP]
 
 
 @pytest.mark.parametrize("strategy,trips", [
@@ -359,9 +402,10 @@ def test_grouped_holds_one_cond_with_a_dense_and_a_scatter_branch(
         q1_shaped_jaxpr):
     from spark_rapids_tpu.exec.aggregate import FEW_GROUPS
     conds = [e for e in _eqns(q1_shaped_jaxpr) if e.primitive.name == "cond"]
-    assert len(conds) == 1
+    # the other one is the bucket resolve's tail
+    assert len(conds) == 2 and conds[0] in _tail_conds(q1_shaped_jaxpr)
     scatter, dense = (list(_eqns(b.jaxpr))
-                      for b in conds[0].params["branches"])
+                      for b in conds[1].params["branches"])
     # the dense branch: no scatter, no gather longer than FEW_GROUPS
     assert not [e for e in dense if e.primitive.name.startswith("scatter")]
     gathers = [e for e in dense if e.primitive.name == "gather"]
@@ -376,7 +420,155 @@ def test_grouped_holds_one_cond_with_a_dense_and_a_scatter_branch(
     assert names.count("scatter-add") <= 18, names.count("scatter-add")
     assert [n for n in names if n.startswith("scatter")
             and n != "scatter-add"] == ["scatter-min"]
-    # outside the branches only the bucket-resolve loop scatters
+    # outside the branches only the bucket-resolve loops scatter
     outside = [e.primitive.name for e in q1_shaped_jaxpr.eqns
                if e.primitive.name.startswith("scatter")]
     assert not outside, outside
+
+
+# ---------------------------------------------------------------------------
+# the hash grouping itself: full rounds while over 1/_TAIL_SHARE of the batch
+# is open, one compaction, tail rounds over the rest — against a grouping
+# defined in numpy
+# ---------------------------------------------------------------------------
+def _first_round_buckets(table, key_names):
+    """The bucket each row falls in on the resolve loop's first round, from
+    the module's own key hash: used to BUILD a case that leaves a chosen
+    number of rows open, never to judge one."""
+    import numpy as np
+    import spark_rapids_tpu.exec.aggregate as A
+    from spark_rapids_tpu.shuffle.manager import _fmix_device
+    return np.asarray(_fmix_device(A._hashed_key_words(table, key_names)[0]))
+
+
+def _open_after_one_round(cap, n_open):
+    """``cap`` int64 keys of which exactly ``n_open`` rows are open after
+    the first full round: a key that owns its bucket (its first row is the
+    bucket's lowest) resolves with all its duplicates; ``n_open`` other
+    keys, one row each, sit behind an owner of their bucket."""
+    import numpy as np
+    from spark_rapids_tpu.columnar.device import DeviceTable
+    from spark_rapids_tpu.columnar.host import HostTable
+    pool = np.arange(4 * cap, dtype=np.int64) * 7 + 3
+    bucket = _first_round_buckets(DeviceTable.from_host(
+        HostTable.from_arrow(pa.table({"k": pool})), capacity=len(pool)),
+        ["k"]) % cap
+    owners, losers, seen = [], [], set()
+    for key, b in zip(pool, bucket):
+        if b not in seen:
+            seen.add(b)
+            owners.append(key)
+        elif len(losers) < n_open:
+            losers.append(key)
+    owners = owners[:cap // 2]
+    assert len(losers) == n_open
+    own = set(bucket[np.isin(pool, owners)])
+    assert all(b in own for b in bucket[np.isin(pool, losers)])
+    fill = np.resize(owners, cap - len(owners) - n_open)
+    return np.concatenate([owners, fill, losers])
+
+
+def _grouping_case(case):
+    """-> (key columns, rows in the batch, capacity, rows switched off,
+    what the trip counts must satisfy)."""
+    import numpy as np
+    from spark_rapids_tpu.exec.aggregate import _TAIL_SHARE as TAIL_SHARE
+    rng = np.random.default_rng([35, sum(map(ord, case))])
+    off = None
+    cap = 1024
+    if case == "one_key":
+        keys = {"k": np.full(1000, 5, np.int64)}
+        trips = lambda r, f: (r, f) == (1, 1)
+    elif case == "all_distinct_full_batch":
+        cap = 4096
+        keys = {"k": rng.permutation(cap).astype(np.int64) * 4}
+        trips = lambda r, f: 1 <= f and r - f >= 2
+    elif case in ("open_equals_the_tail", "open_one_over_the_tail"):
+        over = case == "open_one_over_the_tail"
+        keys = {"k": _open_after_one_round(cap, cap // TAIL_SHARE + over)}
+        trips = lambda r, f: f == 1 + over and r > f
+    elif case == "few_live_rows_in_a_large_batch":
+        cap = 4096
+        keys = {"k": rng.integers(0, 120, cap // TAIL_SHARE - 1) * 4}
+        trips = lambda r, f: f == 0 and r >= 1
+    elif case == "inactive_rows_interleaved":
+        keys = {"k": rng.integers(0, 300, cap).astype(np.int64)}
+        off = rng.random(cap) < 0.4
+        trips = lambda r, f: 1 <= f <= r
+    elif case == "null_nan_and_negative_zero":
+        fk = rng.choice(np.array([np.nan, -0.0, 0.0, 1.5, -2.25]), 900)
+        keys = {"f": pa.array(fk, mask=rng.random(900) < 0.1),
+                "i": pa.array(rng.integers(0, 40, 900),
+                              mask=rng.random(900) < 0.1)}
+        trips = lambda r, f: 1 <= f <= r
+    elif case == "int_and_wide_string":
+        names = np.array([f"Customer#{i:09d}" for i in range(150)]
+                         + ["Customer#000000001\x00", "", None], dtype=object)
+        keys = {"i": rng.integers(0, 3, cap).astype(np.int64),
+                "s": pa.array(rng.choice(names, cap).tolist(),
+                              type=pa.string())}
+        trips = lambda r, f: 1 <= f <= r
+    elif case == "minimum_bucket":
+        # 200 rows in the default 1,024-row bucket: over an eighth, so one
+        # full round at least, then the tail
+        keys = {"k": np.arange(200, dtype=np.int64) * 4}
+        trips = lambda r, f: 1 <= f <= r
+    elif case == "empty":
+        keys = {"k": np.zeros(0, np.int64)}
+        trips = lambda r, f: (r, f) == (0, 0)
+    return keys, cap, off, trips
+
+
+def _key_classes(table: pa.Table):
+    """One hashable value a row: equal exactly where Spark's grouping holds
+    the keys equal (null == null, NaN == NaN, -0.0 == 0.0)."""
+    import math
+
+    def norm(v):
+        if isinstance(v, float):
+            return "nan" if math.isnan(v) else v + 0.0
+        return v
+    return list(zip(*[[norm(v) for v in table.column(n).to_pylist()]
+                      for n in table.column_names]))
+
+
+@pytest.mark.parametrize("case", [
+    "one_key", "all_distinct_full_batch", "open_equals_the_tail",
+    "open_one_over_the_tail", "few_live_rows_in_a_large_batch",
+    "inactive_rows_interleaved", "null_nan_and_negative_zero",
+    "int_and_wide_string", "minimum_bucket", "empty"])
+def test_hash_grouping_against_numpy(case):
+    """``_hash_group_ids`` gives the grouping defined here in numpy:
+    a class's representative is its lowest active row, a group's id the
+    rank of its representative among the representatives, whichever of
+    the loop's phases resolved the class."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    import spark_rapids_tpu.exec.aggregate as A
+    from spark_rapids_tpu.columnar.device import DeviceTable
+    from spark_rapids_tpu.columnar.host import HostTable
+    keys, cap, off, trips = _grouping_case(case)
+    t = pa.table(keys)
+    table = DeviceTable.from_host(HostTable.from_arrow(t), capacity=cap)
+    if off is not None:
+        table = table.filter_mask(jnp.asarray(~off))
+    cap = table.capacity
+    active = np.arange(cap) < t.num_rows
+    if off is not None:
+        active &= ~off
+    first, winner = {}, np.arange(cap)
+    for i, key in enumerate(_key_classes(t)):
+        if active[i]:
+            winner[i] = first.setdefault(key, i)
+    is_rep = active & (winner == np.arange(cap))
+    want_gid = (np.cumsum(is_rep) - 1)[winner]
+
+    order, got_active, gid, boundary, num_groups, (rounds, full_rounds) = \
+        jax.jit(lambda tb: A._hash_group_ids(tb, list(t.column_names)))(table)
+    assert order is None
+    np.testing.assert_array_equal(np.asarray(got_active), active)
+    np.testing.assert_array_equal(np.asarray(boundary), is_rep)
+    assert int(num_groups) == is_rep.sum() == len(first)
+    np.testing.assert_array_equal(np.asarray(gid)[active], want_gid[active])
+    assert trips(int(rounds), int(full_rounds)), (rounds, full_rounds)

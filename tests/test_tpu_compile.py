@@ -129,9 +129,11 @@ def test_grouped_hash_aggregate(topo, one_chip, sess, rng):
     partial = stage.batch_fn()
     text = _compile(partial, (batch,), one_chip).as_text()
     _compile(final.batch_fn(), (partial(batch),), one_chip)
-    # the few-groups branch of `grouped`: one conditional, whose dense side
-    # loops over the live groups
-    assert text.count(" conditional(") == 1
+    # two conditionals: the bucket resolve's tail (compaction, tail rounds,
+    # write-back, skipped where the full rounds left nothing open) and the
+    # few-groups branch of `grouped`, whose dense side loops over the live
+    # groups
+    assert text.count(" conditional(") == 2
 
 
 def test_grouped_aggregate_by_a_float64_key(topo, one_chip, sess, rng):
@@ -236,6 +238,30 @@ def test_probe_walk_at_a_full_batch(topo, one_chip):
     text = _compile(_JoinKernels(None).probe_slots_fn(), args,
                     one_chip).as_text()
     assert text.count(" while(") == 2 and "reduce-window(" not in text
+
+
+@pytest.mark.parametrize("keys", [
+    {"k": pa.array([7, 8], pa.int64())},                  # Q18's, Q3's
+    {"rf": ["A", "N"], "ls": ["F", "O"]}], ids=["int64", "q1_strings"])
+def test_bucket_resolve_at_a_full_batch(topo, one_chip, keys):
+    """The group-by's bucket-resolve loops alone over a full 2^20-row
+    batch, by an int64 key and by Q1's two string keys: seconds to compile,
+    and what compiled holds the two loops — full rounds, tail rounds — no
+    sort and no windowed scan (the compaction is the blocked prefix sum; a
+    ``jnp.cumsum`` over the batch costs this compiler ~20 s, as the one
+    ``_hash_group_ids`` still takes for its group ids does)."""
+    from spark_rapids_tpu.columnar.device import DeviceTable
+    from spark_rapids_tpu.columnar.host import HostTable
+    from spark_rapids_tpu.exec import aggregate
+    table = DeviceTable.from_host(HostTable.from_arrow(pa.table(keys)),
+                                  capacity=1 << 20)
+
+    def loops(tb):
+        h, words = aggregate._hashed_key_words(tb, list(keys))
+        return aggregate._resolve_buckets(h, words, tb.row_mask)
+    text = _compile(loops, (table,), one_chip).as_text()
+    assert text.count(" while(") == 2 and " sort(" not in text
+    assert "reduce-window(" not in text
 
 
 def test_pallas_axpy_full_column(topo, one_chip, monkeypatch):
