@@ -110,7 +110,8 @@ def resolve_scalars(*values) -> Tuple:
         return ()
     if _ASYNC_ENABLED:
         t0 = movement.clock()
-        with get_tracer().span("sync", "download", scalars=len(values)):
+        with get_tracer().span("sync", "download", on=values,
+                               scalars=len(values)):
             got = jax.device_get(list(values))  # srtpu: sync-ok(the deliberate batched-scalar funnel: one transfer per decision boundary)
         movement.note_d2h(_MOVE_RESOLVE, 4 * len(values), t0)
     else:
@@ -120,7 +121,7 @@ def resolve_scalars(*values) -> Tuple:
         got = []
         for v in values:
             t0 = movement.clock()
-            with get_tracer().span("sync", "download", scalars=1):
+            with get_tracer().span("sync", "download", on=v, scalars=1):
                 got.append(jax.device_get(v))  # srtpu: sync-ok(sync-forcing debug mode: per-scalar blocking transfers localize stalls)
             movement.note_d2h(_MOVE_RESOLVE, 4, t0)
     return tuple(v.item() if hasattr(v, "item") else v for v in got)  # srtpu: sync-ok(item on numpy scalars the device_get above already fetched — no extra transfer)
@@ -604,7 +605,8 @@ class DeviceTable:
         """Download and compact to exactly num_rows host rows."""
         _note_host_sync()
         t0 = movement.clock()
-        with get_tracer().span("d2h", "download", bytes=self.nbytes()):
+        with get_tracer().span("d2h", "download", on=self,
+                               bytes=self.nbytes()):
             mask = np.asarray(self.row_mask)  # srtpu: sync-ok(result materialization: the deliberate D2H funnel)
             n = int(np.asarray(self.num_rows))  # srtpu: sync-ok(result materialization: the deliberate D2H funnel)
             # row_mask may be non-prefix (post-filter); boolean-index on host
@@ -690,7 +692,7 @@ def to_host_batched(tables: Sequence[DeviceTable]) -> List[HostTable]:
     _note_host_sync()
     t0 = movement.clock()
     nbytes = sum(t.nbytes() for t in tables)
-    with get_tracer().span("d2h", "download", bytes=nbytes,
+    with get_tracer().span("d2h", "download", on=tables, bytes=nbytes,
                            batches=len(tables)):
         host_np = jax.device_get(tables)  # srtpu: sync-ok(the deliberate bulk-download funnel: one transfer for the whole drain)
         out: List[HostTable] = []
@@ -1143,7 +1145,8 @@ def shrink_to_fit(table: DeviceTable, min_bucket: Optional[int] = None,
             n = num_rows
         else:
             t0 = movement.clock()
-            with tracer.span("sync", "download", scalars=1):
+            with tracer.span("sync", "download", on=table.num_rows,
+                             scalars=1):
                 n = int(table.num_rows)  # srtpu: sync-ok(capacity choice needs the host count; callers with one pass it in)
             movement.note_d2h(_MOVE_SHRINK, 4, t0)
         cap = bucket_rows(max(n, 1), min_bucket)

@@ -312,7 +312,8 @@ class TpuShuffleExchangeExec(TpuExec):
                     pid = _pid_program(keys, n)(table)
                     count.note(bytes=pid.nbytes)
                     t0 = movement.clock()
-                    with tracer.span("d2h", "download", bytes=pid.nbytes):
+                    with tracer.span("d2h", "download", on=pid,
+                                     bytes=pid.nbytes):
                         pid_host = np.asarray(jax.device_get(pid))  # srtpu: sync-ok(the deliberate partition-count funnel: one transfer sizes every shard buffer for the chunk)
                     movement.note_d2h(_MOVE_CHUNK, pid_host.nbytes, t0)
                     src = np.arange(table.capacity) // per_shard
@@ -352,7 +353,8 @@ class TpuShuffleExchangeExec(TpuExec):
                     # per-destination row counts sync, for skew + quota
                     # telemetry parity with the split path
                     t0 = movement.clock()
-                    with tracer.span("sync", "download", scalars=n):
+                    with tracer.span("sync", "download",
+                                     on=exchanged.row_mask, scalars=n):
                         shard_rows = jax.device_get(  # srtpu: sync-ok(batched count sync, 4B per shard once per chunk)
                             shard_row_counts(exchanged, n))
                     movement.note_d2h(_MOVE_CHUNK, 4 * len(shard_rows), t0)
@@ -390,7 +392,8 @@ class TpuShuffleExchangeExec(TpuExec):
             # ONE bulk D2H of n 4-byte scalars replaces a blocking round
             # trip per shard plus one more for the row total
             t0 = movement.clock()
-            with tracer.span("sync", "download", scalars=n):
+            with tracer.span("sync", "download", on=exchanged.row_mask,
+                             scalars=n):
                 shard_rows = jax.device_get(  # srtpu: sync-ok(batched count sync, 4B per shard once per chunk)
                     [t.num_rows for t in parts])
             movement.note_d2h(_MOVE_CHUNK, 4 * len(shard_rows), t0)

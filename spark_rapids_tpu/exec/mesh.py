@@ -255,13 +255,15 @@ class TpuMeshStageExec(TpuExec):
         prog = self._program(chunk)
         with self.metrics.timed(M.OP_TIME):
             t0 = shuffle_telemetry.clock()
-            with get_tracer().span("dispatch", "dispatch", program=_PROGRAM):
+            with get_tracer().span("dispatch", "dispatch",
+                                   on=chunk.row_mask, program=_PROGRAM):
                 out_cols, out_mask = prog(chunk.columns, chunk.row_mask)
+                # the eager sum over the sharded mask is this step's too
+                total = jnp.sum(out_mask, dtype=jnp.int32)
             shuffle_telemetry.note_transfer(
                 "ici", "mesh_stage", shuffle_id=self.exchange.telemetry_sid,
                 t0=t0, queue_depth=n, wire_bytes=lambda: chunk.nbytes())
-        out = DeviceTable(tuple(out_cols), out_mask,
-                          jnp.sum(out_mask, dtype=jnp.int32),
+        out = DeviceTable(tuple(out_cols), out_mask, total,
                           tuple(self.schema.names))
         return _split_sharded(out, n)
 

@@ -277,62 +277,68 @@ class MappedStageReader(PhysicalPlan):
 # ---------------------------------------------------------------------------
 # Stage materialization
 # ---------------------------------------------------------------------------
-def materialize_stage(cpu_exchange: ShuffleExchangeExec, conf: RapidsConf,
-                      use_device: bool, events: List[str],
-                      hook=None) -> ShuffleStageExec:
-    from .overrides import apply_overrides
-    converted = apply_overrides(cpu_exchange, conf) if use_device \
-        else cpu_exchange
-    # apply_overrides caps a device root with DeviceToHost for the collect
-    # boundary; a stage is consumed by the next segment, so unwrap it
-    from ..exec.transitions import DeviceToHostExec
-    if isinstance(converted, DeviceToHostExec):
-        converted = converted.child
-    if hook is not None:
-        hook(converted)  # event-log instrumentation of the stage segment
-    from ..exec.exchange import TpuLocalExchangeExec, TpuShuffleExchangeExec
-
-    def _scaled_device_bytes(t) -> int:
+def _device_shard_stats(handles) -> tuple:
+    """(rows, bytes) of one output partition's spill handles. Every
+    handle's row count is a read of a device scalar, a ``sync`` span each:
+    the exchange's own count sync read the same numbers, but from other
+    arrays (a ``shrink``, a gather or the split made these since), so each
+    is a round trip of its own."""
+    tracer = get_tracer()
+    prows = pbytes = 0
+    for h in handles:
+        t = h.get()
+        with tracer.span("sync", "download", on=t.num_rows, scalars=1):
+            nrows = int(t.num_rows)  # srtpu: sync-ok(per-stage AQE statistics at materialization, not per-batch)
+        prows += nrows
         # buffers are capacity-padded (pow2 buckets, min 1024 rows); scale
         # to the compacted row count so device-tier stats are comparable
         # with the host tier's true bytes — otherwise tiny build sides
         # look big and suppress AQE broadcast demotion
-        nrows = int(t.num_rows)  # srtpu: sync-ok(per-stage AQE statistics at materialization, not per-batch)
-        total = 0
         for c in t.columns:
             cap = max(int(c.data.shape[0]), 1)
-            total += int(c.data.nbytes) * nrows // cap
-        return total
+            pbytes += int(c.data.nbytes) * nrows // cap
+    return prows, pbytes
 
+
+def materialize_stage(cpu_exchange: ShuffleExchangeExec, conf: RapidsConf,
+                      use_device: bool, events: List[str],
+                      hook=None, stage: int = 0) -> ShuffleStageExec:
+    """Plan the segment under ``cpu_exchange`` (span ``plan.aqe``), run it
+    (``stage``), read its partition statistics (``stage.stats``); ``stage``
+    numbers the query's stages for the spans."""
+    from .overrides import apply_overrides
+    tracer = get_tracer()
+    with tracer.span("plan.aqe", "plan", stage=stage):
+        converted = apply_overrides(cpu_exchange, conf) if use_device \
+            else cpu_exchange
+        # apply_overrides caps a device root with DeviceToHost for the
+        # collect boundary; a stage is consumed by the next segment, so
+        # unwrap it
+        from ..exec.transitions import DeviceToHostExec
+        if isinstance(converted, DeviceToHostExec):
+            converted = converted.child
+        if hook is not None:
+            hook(converted)  # event-log instrumentation of the stage segment
+    from ..exec.exchange import TpuLocalExchangeExec, TpuShuffleExchangeExec
+
+    with tracer.span("stage", "stage", stage=stage,
+                     exchange=type(converted).__name__):
+        converted._materialize()
     if isinstance(converted, TpuLocalExchangeExec):
-        with get_tracer().span("stage", "stage",
-                               exchange=type(converted).__name__):
-            converted._materialize()
-        prows = pbytes = 0
-        for h in converted._handles:
-            t = h.get()
-            prows += int(t.num_rows)  # srtpu: sync-ok(per-stage AQE statistics at materialization, not per-batch)
-            pbytes += _scaled_device_bytes(t)
+        with tracer.span("stage.stats", "stage", stage=stage, shards=1,
+                         handles=len(converted._handles)):
+            prows, pbytes = _device_shard_stats(converted._handles)
         stats = PartitionStats([prows], [pbytes])
     elif isinstance(converted, TpuShuffleExchangeExec):
-        with get_tracer().span("stage", "stage",
-                               exchange=type(converted).__name__):
-            converted._materialize()
-        rows, nbytes = [], []
-        for handles in converted._shards:
-            prows = pbytes = 0
-            for h in handles:
-                t = h.get()
-                prows += int(t.num_rows)  # srtpu: sync-ok(per-stage AQE statistics at materialization, not per-batch)
-                pbytes += _scaled_device_bytes(t)
-            rows.append(prows)
-            nbytes.append(pbytes)
-        stats = PartitionStats(rows, nbytes)
+        with tracer.span("stage.stats", "stage", stage=stage,
+                         shards=len(converted._shards),
+                         handles=sum(map(len, converted._shards))):
+            per_shard = [_device_shard_stats(handles)
+                         for handles in converted._shards]
+        stats = PartitionStats([r for r, _ in per_shard],
+                               [b for _, b in per_shard])
     else:
         assert isinstance(converted, ShuffleExchangeExec), type(converted)
-        with get_tracer().span("stage", "stage",
-                               exchange=type(converted).__name__):
-            converted._materialize()
         rows, nbytes = [], []
         for batches in converted._materialized:
             rows.append(sum(b.num_rows for b in batches))
@@ -459,17 +465,26 @@ class AdaptiveExec(PhysicalPlan):
             return self._final
 
     def _run(self) -> PhysicalPlan:
+        """The adaptive loop. Planning between stages is span ``plan.aqe``
+        (``stage`` = the stage it plans; the final segment's is the count
+        of stages), apart from ``plan``, the static planning before it."""
         hook = getattr(self, "_instrument_hook", None)
         plan = self.cpu_plan
+        n = 0
         while True:
-            plan = self._demote_joins(plan)
-            frontier = _frontier_exchanges(plan)
-            if not frontier:
-                break
-            ex = self._pick(frontier, plan)
+            with get_tracer().span("plan.aqe", "plan", stage=n):
+                plan = self._demote_joins(plan)
+                frontier = _frontier_exchanges(plan)
+                if not frontier:
+                    return self._final_segment(plan, hook)
+                ex = self._pick(frontier, plan)
             stage = materialize_stage(ex, self.conf, self.use_device,
-                                      self.events, hook)
+                                      self.events, hook, stage=n)
             plan = _replace_node(plan, ex, stage)
+            n += 1
+
+    def _final_segment(self, plan: PhysicalPlan, hook) -> PhysicalPlan:
+        """The plan above the last stage, once no exchange is left."""
         plan = self._demote_joins(plan)
         if self.conf.get(AQE_SKEW_ENABLED):
             plan = self._apply_skew(plan)

@@ -180,11 +180,14 @@ def ici_all_to_all_exchange(table: DeviceTable, key_names: List[str],
     # input actually crossing ICI links (vs the pre-padding logical bytes
     # the exchange exec notes at enqueue)
     t0 = telemetry.clock()
-    with tracer.span("dispatch", "dispatch", program=_PROGRAM,
+    with tracer.span("dispatch", "dispatch", on=table.row_mask,
+                     program=_PROGRAM,
                      bytes=_crossing_bytes(table, n, quota)):
         out_cols, mask = prog(table.columns, table.row_mask)
+        # the eager sum over the sharded mask is this step's too: its
+        # host dispatch is booked here, not to the ``stage`` around it
+        total = jnp.sum(mask, dtype=jnp.int32)
     telemetry.note_transfer("ici", "dispatch", shuffle_id=telemetry_sid,
                             t0=t0, queue_depth=n,
                             wire_bytes=lambda: table.nbytes())
-    total = jnp.sum(mask, dtype=jnp.int32)
     return DeviceTable(tuple(out_cols), mask, total, names)

@@ -42,8 +42,10 @@ CLI::
 ring: every ``Tracer.span`` is also a ``TraceAnnotation("srt.<name>")``, so
 under any profiler session the engine's spans lie in the xplane's host
 plane on the clock the device planes are synchronised to. The command
-books the device's idle time inside each query to the phase span the host
-was in.
+books the busiest device's idle time inside each query to the phase span
+the host was in and to a cause — the module another device was running,
+or that host span while every device was idle — and prints every
+device's busy seconds by module and the longest gaps.
 """
 from __future__ import annotations
 
@@ -432,12 +434,23 @@ def query_trace_ids(events: Iterable[dict]) -> List[Tuple[str, float]]:
 
 
 # ---------------------------------------------------------------------------
-# device idle time by host phase, from a jax.profiler capture
+# device idle time by host phase and by cause, from a jax.profiler capture
 # ---------------------------------------------------------------------------
 _HOST_PLANE = "/host:CPU"
 _DEVICE_PLANE_PREFIX = "/device:TPU:"
 _DEVICE_OPS_LINE = "XLA Ops"
+_DEVICE_MODULES_LINE = "XLA Modules"
 _NO_SPAN = "(no srt span open)"
+_NO_MODULE = "(no module)"
+_PROGRAM_MODULE_PREFIX = "jit_srt_"
+#: how many of the busiest device's gaps ``longest_gaps`` describes
+_LONGEST_GAPS = 8
+#: how many causes a gap's row lists, and how many rows of the table of
+#: causes the CLI prints (``--json`` has them all)
+_GAP_CAUSES = 4
+_CAUSE_ROWS = 24
+#: what of a host span's arguments a gap's row carries
+_GAP_ARGS = ("program", "device", "partition", "scalars")
 
 
 def _span_rank(name: str, structural) -> int:
@@ -450,18 +463,62 @@ def _span_rank(name: str, structural) -> int:
     return 1 if name.startswith("wait.") else 2
 
 
+def _merged(ivals) -> List[List[float]]:
+    out: List[List[float]] = []
+    for s, e in sorted(ivals):
+        if out and s <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], e)
+        else:
+            out.append([s, e])
+    return out
+
+
+def _inside(ivals, lo, hi):
+    return [(max(s, lo), min(e, hi)) for s, e in ivals
+            if min(e, hi) > max(s, lo)]
+
+
+def _module_name(name: str) -> str:
+    """``jit_srt_stage(123)`` -> ``jit_srt_stage``."""
+    return name[:name.rindex("(")] if name.endswith(")") and "(" in name \
+        else name
+
+
+def _cause_label(device: Optional[str], what: str) -> str:
+    return f"{device}: {what}" if device else f"host: {what}"
+
+
 def idle_by_phase(profile) -> Optional[Dict]:
-    """For the busiest device of a ``jax.profiler`` capture: its idle
-    nanoseconds inside ``srt.query`` spans, booked to the innermost
-    ``srt.*`` span open on any host thread during each gap (ranked by
-    ``_span_rank``, then the latest opened), and the remainder no span
-    covers. ``profile`` is a ``jax.profiler.ProfileData`` or anything
-    shaped like it (planes -> lines -> events with name, start_ns,
-    duration_ns). None when the capture has no device plane or no query
-    span. Reuses nothing of ``benchmark/``."""
+    """Where the busiest device's idle time inside ``srt.query`` spans went,
+    from a ``jax.profiler`` capture. ``profile`` is a
+    ``jax.profiler.ProfileData`` or anything shaped like it (planes ->
+    lines -> events with name, start_ns, duration_ns, stats). None when
+    the capture has no device plane or no query span. Reuses nothing of
+    ``benchmark/``.
+
+    Every elementary interval of the busiest device's gaps (between two
+    neighbouring boundaries of any span, gap or other device's work) is
+    booked twice:
+
+    - ``idle_by_phase_s``: to the innermost ``srt.*`` span open on any
+      host thread (ranked by ``_span_rank``, then the latest opened), the
+      remainder to ``(no srt span open)``;
+    - ``idle_by_cause_s``: to ONE cause, with that host span beside it.
+      While another device is busy the cause is that device and the module
+      it runs (``XLA Modules`` line; the busiest such device where several
+      are); while every device is idle it is the host span.
+
+    ``devices`` has every device plane's busy and idle seconds inside the
+    queries and its seconds by module; ``longest_gaps`` the busiest
+    device's longest gaps, each with its offset in its query, its causes
+    and the arguments of the host span that held most of it.
+    ``named_share`` is the share of the idle time whose cause has a name:
+    a ``jit_srt_*`` module of another device, or a host span that is not
+    structural."""
     from ..utils.tracing import ANNOTATION_PREFIX, STRUCTURAL_SPANS
-    spans: List[Tuple[float, float, str]] = []
+    spans: List[Tuple[float, float, str, Dict]] = []
     busy: Dict[str, List[Tuple[float, float]]] = {}
+    modules: Dict[str, List[Tuple[float, float, str]]] = {}
     for plane in profile.planes:
         if plane.name == _HOST_PLANE:
             for line in plane.lines:
@@ -469,90 +526,160 @@ def idle_by_phase(profile) -> Optional[Dict]:
                     if e.name.startswith(ANNOTATION_PREFIX):
                         spans.append((e.start_ns,
                                       e.start_ns + e.duration_ns,
-                                      e.name[len(ANNOTATION_PREFIX):]))
+                                      e.name[len(ANNOTATION_PREFIX):],
+                                      dict(getattr(e, "stats", None) or ())))
         elif plane.name.startswith(_DEVICE_PLANE_PREFIX):
             for line in plane.lines:
                 if line.name == _DEVICE_OPS_LINE:
                     busy.setdefault(plane.name, []).extend(
                         (e.start_ns, e.start_ns + e.duration_ns)
                         for e in line.events)
-    queries = sorted((s, e) for s, e, n in spans if n == "query")
+                elif line.name == _DEVICE_MODULES_LINE:
+                    modules.setdefault(plane.name, []).extend(
+                        (e.start_ns, e.start_ns + e.duration_ns,
+                         _module_name(e.name)) for e in line.events)
+    query_spans = [(s, e) for s, e, n, _ in spans if n == "query"]
+    queries = _merged(query_spans)
     if not busy or not queries:
         return None
 
-    def merged(ivals):
-        out: List[List[float]] = []
-        for s, e in sorted(ivals):
-            if out and s <= out[-1][1]:
-                out[-1][1] = max(out[-1][1], e)
-            else:
-                out.append([s, e])
-        return out
-
-    def inside(ivals, lo, hi):
-        return [(max(s, lo), min(e, hi)) for s, e in ivals
-                if min(e, hi) > max(s, lo)]
-
-    def busy_in_queries(ivals):
+    def in_queries(ivals):
         return sum(e - s for qs, qe in queries
-                   for s, e in inside(ivals, qs, qe))
+                   for s, e in _inside(ivals, qs, qe))
 
-    device = max(busy, key=lambda d: busy_in_queries(merged(busy[d])))
-    ops = merged(busy[device])
-    gaps: List[Tuple[float, float]] = []
-    for qs, qe in merged(queries):
+    ops = {d: _merged(iv) for d, iv in busy.items()}
+    busy_ns = {d: in_queries(iv) for d, iv in ops.items()}
+    device = max(busy_ns, key=busy_ns.get)
+    # the other devices, busiest first: who a shared gap is booked to
+    others = sorted((d for d in ops if d != device),
+                    key=lambda d: -busy_ns[d])
+    gaps: List[Tuple[float, float, float]] = []     # start, end, query start
+    for qs, qe in queries:
         edge = qs
-        for s, e in inside(ops, qs, qe):
+        for s, e in _inside(ops[device], qs, qe):
             if s > edge:
-                gaps.append((edge, s))
+                gaps.append((edge, s, qs))
             edge = max(edge, e)
         if qe > edge:
-            gaps.append((edge, qe))
+            gaps.append((edge, qe, qs))
+    longest = sorted(range(len(gaps)),
+                     key=lambda i: gaps[i][0] - gaps[i][1])[:_LONGEST_GAPS]
+    in_longest: Dict[int, Dict] = {i: {} for i in longest}
 
     # one sweep over every boundary: between two neighbours the set of
-    # open spans is constant, so the elementary interval has one owner
-    rank = {n: _span_rank(n, STRUCTURAL_SPANS) for _, _, n in spans}
+    # open spans and of busy devices is constant, so the elementary
+    # interval has one owner. Closings sort before openings at one instant
+    rank = {n: _span_rank(n, STRUCTURAL_SPANS) for _, _, n, _ in spans}
     points = []
-    for s, e, n in spans:
-        points.append((s, 1, (s, n)))
-        points.append((e, 0, (s, n)))
-    for s, e in gaps:
-        points.append((s, 3, None))
-        points.append((e, 2, None))
+    for i, (s, e, n, _) in enumerate(spans):
+        points.append((s, 1, (s, n, i)))    # latest opened, name, which
+        points.append((e, 0, (s, n, i)))
+    for i, (s, e, _) in enumerate(gaps):
+        points.append((s, 3, i))
+        points.append((e, 2, i))
+    for d in others:
+        for s, e in ops[d]:
+            points.append((s, 5, d))
+            points.append((e, 4, d))
+        for s, e, n in modules.get(d, ()):
+            points.append((s, 7, (d, n)))
+            points.append((e, 6, (d, n)))
     points.sort(key=lambda p: (p[0], p[1]))
-    open_spans: Dict[Tuple[float, str], int] = {}
-    in_gap = False
+    open_spans: set = set()
+    busy_now = dict.fromkeys(others, False)
+    running: Dict[str, List[str]] = {d: [] for d in others}
+    gap = None
     by_phase: Dict[str, float] = {}
+    by_cause: Dict[Tuple, float] = {}
     prev = None
     for t, kind, key in points:
-        if in_gap and prev is not None and t > prev:
+        if gap is not None and prev is not None and t > prev:
             owner = max(open_spans, default=None,
                         key=lambda k: (rank[k[1]], k[0]))
             name = _NO_SPAN if owner is None else owner[1]
             by_phase[name] = by_phase.get(name, 0.0) + (t - prev)
+            at = next((d for d in others if busy_now[d]), None)
+            what = name if at is None else \
+                (running[at][-1] if running[at] else _NO_MODULE)
+            on = None if owner is None else spans[owner[2]][3].get("device")
+            cause = (at, what, name, on)
+            by_cause[cause] = by_cause.get(cause, 0.0) + (t - prev)
+            if gap in in_longest:
+                held = in_longest[gap]
+                k = (cause, None if owner is None else owner[2])
+                held[k] = held.get(k, 0.0) + (t - prev)
         prev = t
         if kind == 1:
-            open_spans[key] = open_spans.get(key, 0) + 1
+            open_spans.add(key)
         elif kind == 0:
-            if open_spans.get(key, 0) <= 1:
-                open_spans.pop(key, None)
-            else:
-                open_spans[key] -= 1
-        else:
-            in_gap = kind == 3
+            open_spans.discard(key)
+        elif kind in (2, 3):
+            gap = key if kind == 3 else None
+        elif kind in (4, 5):
+            busy_now[key] = kind == 5
+        elif kind == 7:
+            running[key[0]].append(key[1])
+        elif key[1] in running[key[0]]:
+            running[key[0]].remove(key[1])
+
     ns = 1e-9
-    idle = sum(e - s for s, e in gaps)
-    named = sum(v for n, v in by_phase.items()
-                if n != _NO_SPAN and rank[n] > 0)
+
+    def cause_row(cause, sec):
+        at, what, name, on = cause
+        return {"cause": _cause_label(at, what), "device": at,
+                "module": what if at else None, "host_span": name,
+                "host_device": on, "s": sec * ns}
+
+    def has_name(cause) -> bool:
+        at, what, name, _ = cause
+        if at is not None:
+            return what.startswith(_PROGRAM_MODULE_PREFIX)
+        return name != _NO_SPAN and rank[name] > 0
+
+    def device_row(d):
+        secs: Dict[str, float] = {}
+        for s, e, n in modules.get(d, ()):
+            secs[n] = secs.get(n, 0.0) + in_queries([(s, e)])
+        return {"busy_s": busy_ns[d] * ns,
+                "idle_s": (query_ns - busy_ns[d]) * ns,
+                "modules_s": {n: v * ns for n, v in sorted(
+                    secs.items(), key=lambda kv: -kv[1]) if v}}
+
+    def gap_row(i):
+        s, e, qs = gaps[i]
+        causes: Dict[Tuple, float] = {}     # (device, module or host span)
+        for (cause, _), sec in in_longest[i].items():
+            causes[cause[:2]] = causes.get(cause[:2], 0.0) + sec
+        top = max(causes, key=causes.get)
+        # the host span that held most of the gap's leading cause
+        (cause, span), _ = max(
+            (kv for kv in in_longest[i].items() if kv[0][0][:2] == top),
+            key=lambda kv: kv[1])
+        args = {} if span is None else spans[span][3]
+        return {"offset_s": (s - qs) * ns, "length_s": (e - s) * ns,
+                "cause": _cause_label(*top),
+                "cause_share": causes[top] / (e - s),
+                "host_span": cause[2],
+                "args": {k: args[k] for k in _GAP_ARGS if k in args},
+                "causes": {_cause_label(*c): v * ns for c, v in sorted(
+                    causes.items(), key=lambda kv: -kv[1])[:_GAP_CAUSES]}}
+
+    query_ns = sum(e - s for s, e in queries)
+    idle = sum(e - s for s, e, _ in gaps)
+    named = sum(v for c, v in by_cause.items() if has_name(c))
     return {
         "device": device,
-        "queries": len(queries),
-        "query_s": sum(e - s for s, e in queries) * ns,
-        "busy_s": busy_in_queries(ops) * ns,
+        "queries": len(query_spans),
+        "query_s": sum(e - s for s, e in query_spans) * ns,
+        "busy_s": busy_ns[device] * ns,
         "idle_s": idle * ns,
         "idle_by_phase_s": {n: v * ns for n, v in sorted(
             by_phase.items(), key=lambda kv: -kv[1])},
         "named_share": named / idle if idle else 0.0,
+        "devices": {d: device_row(d) for d in sorted(ops)},
+        "idle_by_cause_s": [cause_row(c, v) for c, v in sorted(
+            by_cause.items(), key=lambda kv: -kv[1])],
+        "longest_gaps": [gap_row(i) for i in longest],
     }
 
 
@@ -560,8 +687,10 @@ def _cmd_gaps(argv: List[str]) -> int:
     import argparse
     ap = argparse.ArgumentParser(
         prog="spark_rapids_tpu.tools.trace gaps",
-        description="Device idle time inside each query, by the engine "
-                    "span the host was in (from a jax.profiler capture).")
+        description="The busiest device's idle time inside each query, by "
+                    "the engine span the host was in and by cause: another "
+                    "device's module, or the host span while every device "
+                    "is idle (from a jax.profiler capture).")
     ap.add_argument("xplane", help="a .xplane.pb file of a jax.profiler "
                                    "capture taken while queries ran")
     ap.add_argument("--json", action="store_true",
@@ -576,15 +705,45 @@ def _cmd_gaps(argv: List[str]) -> int:
     if ns.json:
         print(json.dumps(got, indent=2))
         return 0
-    print(f"{got['device']}: {got['queries']} queries, "
+    idle, n = got["idle_s"], got["queries"]
+
+    def share(sec):
+        return 100 * sec / idle if idle else 0.0
+
+    print(f"{got['device']}: {n} queries, "
           f"{got['query_s']:.6f} s inside srt.query; device busy "
           f"{got['busy_s']:.6f} s, idle {got['idle_s']:.6f} s")
     print(f"idle seconds by the span the host was in "
-          f"({100 * got['named_share']:.1f}% in a named phase):")
+          f"({100 * got['named_share']:.1f}% of them have a named cause):")
     for name, sec in got["idle_by_phase_s"].items():
-        share = 100 * sec / got["idle_s"] if got["idle_s"] else 0.0
-        print(f"  {name:<22} {sec:12.6f} s {share:6.2f}%"
-              f" {sec / got['queries']:12.6f} s/query")
+        print(f"  {name:<22} {sec:12.6f} s {share(sec):6.2f}%"
+              f" {sec / n:12.6f} s/query")
+    print("devices (seconds inside srt.query; largest modules):")
+    for d, row in got["devices"].items():
+        top = ", ".join(f"{m} {sec:.6f}" for m, sec in
+                        list(row["modules_s"].items())[:6])
+        print(f"  {d:<16} busy {row['busy_s']:12.6f} s idle "
+              f"{row['idle_s']:12.6f} s  {top}")
+    print(f"idle seconds of {got['device']} by cause (another device's "
+          f"module, else the host span), and the host span beside it:")
+    rows = got["idle_by_cause_s"]
+    for row in rows[:_CAUSE_ROWS]:
+        host = row["host_span"] + ("" if row["host_device"] is None
+                                   else f" [device {row['host_device']}]")
+        print(f"  {row['cause']:<46} host in {host:<28} "
+              f"{row['s']:12.6f} s {share(row['s']):6.2f}%")
+    if len(rows) > _CAUSE_ROWS:
+        rest = sum(r["s"] for r in rows[_CAUSE_ROWS:])
+        print(f"  {len(rows) - _CAUSE_ROWS} more rows (--json has them)"
+              f"{'':<52} {rest:12.6f} s {share(rest):6.2f}%")
+    print(f"the {len(got['longest_gaps'])} longest gaps (offset in the "
+          f"query, length, causes by share, the host span of the first):")
+    for g in got["longest_gaps"]:
+        args = " ".join(f"{k}={v}" for k, v in g["args"].items())
+        causes = ", ".join(f"{c} {100 * v / g['length_s']:.0f}%"
+                           for c, v in g["causes"].items())
+        print(f"  at {g['offset_s']:10.6f} s  {g['length_s']:10.6f} s  "
+              f"{causes}; host in {g['host_span']} {args}")
     return 0
 
 

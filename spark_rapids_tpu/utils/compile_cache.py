@@ -137,7 +137,8 @@ def named_program(fn: Callable, name: str, **jit_kwargs) -> Callable:
 
     @functools.wraps(fn)
     def dispatch(*args, **kwargs):
-        with get_tracer().span("dispatch", "dispatch", program=program):
+        with get_tracer().span("dispatch", "dispatch", on=(args, kwargs),
+                               program=program):
             return jitted(*args, **kwargs)
     return dispatch
 
@@ -408,10 +409,12 @@ def _time_first_call(key: str, fn: Callable,
     def wrapped(*args, **kwargs):
         global _COMPILES, _COMPILE_SECONDS
         if state["done"]:
-            with get_tracer().span("dispatch", "dispatch", program=program):
+            with get_tracer().span("dispatch", "dispatch",
+                                   on=(args, kwargs), program=program):
                 return fn(*args, **kwargs)
         t0 = time.perf_counter()
-        with get_tracer().span("dispatch", "dispatch", program=program), \
+        with get_tracer().span("dispatch", "dispatch", on=(args, kwargs),
+                               program=program), \
                 get_tracer().span("compile", "compile", program=program,
                                   key=key[:160]):
             out = fn(*args, **kwargs)

@@ -17,10 +17,12 @@ Design constraints:
   ``jax.profiler.TraceAnnotation("srt.<name>")`` when a profiler session
   is capturing, so the engine's spans lie in the xplane's host plane on
   the clock the device planes are synchronised to, and (2) books its self
-  time to the running query's phase totals (``recent_queries``); only
-  (3), the ring buffer + Chrome export, is gated by
-  ``spark.rapids.tpu.trace.enabled``. With no profiler session and the
-  ring off a span costs two clock reads and a dict update.
+  time, its bytes and its counted arguments (``COUNTED_ARGS``) to the
+  running query's phase totals (``recent_queries``); only (3), the ring
+  buffer + Chrome export, is gated by ``spark.rapids.tpu.trace.enabled``.
+  With no profiler session and the ring off a span costs two clock reads
+  and a dict update; ``device`` (``span(on=...)``) is worked out only
+  while one of the two is on.
 
 The export format is the Chrome trace-event JSON (``ph: "X"`` complete
 events with microsecond timestamps), loadable in Perfetto / chrome://tracing
@@ -45,6 +47,7 @@ from ..conf import register_conf
 
 __all__ = ["TraceEvent", "Tracer", "TraceContext", "QuerySummary",
            "get_tracer", "ANNOTATION_PREFIX", "STRUCTURAL_SPANS",
+           "COUNTED_ARGS", "device_of",
            "set_tracer", "configure_tracer", "tracer_stats",
            "mint_trace_context", "current_trace_context",
            "activate_trace_context", "new_span_id",
@@ -244,6 +247,16 @@ ANNOTATION_PREFIX = "srt."
 STRUCTURAL_SPANS = frozenset({"query", "task", "stage", "wait.pipeline",
                               "join.build", "join.grace"})
 
+#: span arguments that are summed per phase beside ``calls`` / ``self_s`` /
+#: ``bytes`` (a boolean counts 0 / 1): row and group counts, the trip counts
+#: of the hash loops, scalars a ``sync`` read, what ``stage.stats`` walked.
+#: ``to_dict`` emits them flat where non-zero, so a reader that takes
+#: ``phases[name].get(field, 0)`` reads ``field="rounds"`` as it reads
+#: ``"calls"``
+COUNTED_ARGS = frozenset({"rows", "rows_out", "groups", "rounds",
+                          "full_rounds", "parts", "scalars", "unique",
+                          "shards", "handles"})
+
 #: queries whose phase totals ``Tracer.recent_queries`` remembers
 RECENT_QUERIES = 256
 #: phase-span intervals remembered per query for the covered-wall union;
@@ -274,18 +287,21 @@ class QuerySummary:
 
     ``phases[name]`` is ``[calls, self_s, bytes]``: thread-seconds of
     SELF time (duration minus what child spans on the same thread
-    cover), so phases sum to at most ``wall_s`` x ``threads``.
+    cover), so phases sum to at most ``wall_s`` x ``threads``;
+    ``counts[name]`` holds the sums of the spans' ``COUNTED_ARGS``.
     ``covered_s`` is the union over all threads of the non-structural
     spans' intervals, clipped to the query span."""
 
-    __slots__ = ("query_id", "t0", "wall_s", "phases", "covered_s",
-                 "spans_dropped", "_intervals", "_threads", "_lock")
+    __slots__ = ("query_id", "t0", "wall_s", "phases", "counts",
+                 "covered_s", "spans_dropped", "_intervals", "_threads",
+                 "_lock")
 
     def __init__(self, query_id: int, t0: float):
         self.query_id = query_id
         self.t0 = t0
         self.wall_s: Optional[float] = None     # None while the query runs
         self.phases: Dict[str, List] = {}
+        self.counts: Dict[str, Dict[str, float]] = {}
         self.covered_s = 0.0
         self.spans_dropped = 0
         self._intervals: List = []
@@ -293,7 +309,7 @@ class QuerySummary:
         self._lock = threading.Lock()
 
     def _book(self, name: str, t0: float, t1: float, self_s: float,
-              nbytes: int) -> None:
+              nbytes: int, counted: Optional[Dict] = None) -> None:
         with self._lock:
             if self.wall_s is not None:
                 return      # a straggler of a query that already returned
@@ -303,6 +319,10 @@ class QuerySummary:
             p[0] += 1
             p[1] += self_s
             p[2] += nbytes
+            if counted:
+                sums = self.counts.setdefault(name, {})
+                for k, v in counted.items():
+                    sums[k] = sums.get(k, 0) + v
             self._threads.add(threading.get_ident())
             if name not in STRUCTURAL_SPANS:
                 if len(self._intervals) < QUERY_SPAN_CAP:
@@ -325,7 +345,9 @@ class QuerySummary:
                 "covered_s": self.covered_s,
                 "threads": len(self._threads),
                 "spans_dropped": self.spans_dropped,
-                "phases": {n: {"calls": p[0], "self_s": p[1], "bytes": p[2]}
+                "phases": {n: {"calls": p[0], "self_s": p[1], "bytes": p[2],
+                               **{k: v for k, v in
+                                  self.counts.get(n, {}).items() if v}}
                            for n, p in self.phases.items()}}
 
 
@@ -338,6 +360,28 @@ def _small_args(args: Dict) -> Dict:
             or (isinstance(v, str) and len(v) <= 64)}
 
 
+def device_of(values) -> int:
+    """The id of the ONE device every ``jax.Array`` leaf of ``values`` lives
+    on; -1 where there is no such device: an array sharded over a mesh,
+    arrays on several devices, or nothing that lives on a device. Called by
+    a span given ``on=`` and only while a profiler session captures or the
+    ring is on."""
+    import jax
+    found = -1
+    for leaf in jax.tree_util.tree_leaves(values):
+        if not isinstance(leaf, jax.Array) \
+                or isinstance(leaf, jax.core.Tracer):
+            continue
+        devices = leaf.devices()
+        if len(devices) != 1:
+            return -1
+        (d,) = devices
+        if found not in (-1, d.id):
+            return -1
+        found = d.id
+    return found
+
+
 class _Span:
     """One open span (``Tracer.span`` / ``Tracer.query``): a small class
     with ``__enter__``/``__exit__``, not a generator — a disabled
@@ -346,14 +390,16 @@ class _Span:
 
     __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_child_s",
                  "_parent", "_query", "_ann", "_ring", "_ctx", "_span_id",
-                 "_root", "_pushed")
+                 "_root", "_pushed", "_on")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, args: Dict,
-                 root: bool = False, ctx: Optional[TraceContext] = None):
+                 root: bool = False, ctx: Optional[TraceContext] = None,
+                 on=None):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.args = args
+        self._on = on
         self._root = root
         self._ctx = ctx
         self._child_s = 0.0
@@ -398,7 +444,12 @@ class _Span:
                 self._span_id = new_span_id()
                 cstack.append(ctx.child(self._span_id))
                 self._pushed += 1
-        if TraceAnnotation.is_enabled():
+        capturing = TraceAnnotation.is_enabled()
+        if self._on is not None:
+            if capturing or self._ring:
+                self.args["device"] = device_of(self._on)
+            self._on = None     # hold no array past the block's start
+        if capturing:
             small = _small_args(self.args)
             if query is not None:
                 small["query_id"] = query.query_id
@@ -427,9 +478,13 @@ class _Span:
             self._parent._child_s += dur
         query = self._query
         if query is not None:
+            args = self.args
+            counted = None if COUNTED_ARGS.isdisjoint(args) else \
+                {k: v for k, v in args.items()
+                 if k in COUNTED_ARGS and isinstance(v, (int, float))}
             query._book(self.name, self._t0, t1,
                         max(0.0, dur - self._child_s),
-                        int(self.args.get("bytes", 0) or 0))
+                        int(args.get("bytes", 0) or 0), counted)
             if self._root:
                 query._seal(t1)
                 tls.query = None
@@ -539,14 +594,17 @@ class Tracer:
             out["query_id"] = out.get("query_id", ctx.query_id)
         return out
 
-    def span(self, name: str, cat: str = "misc", **args) -> _Span:
+    def span(self, name: str, cat: str = "misc", on=None, **args) -> _Span:
         """A span around the with-block, into all three sinks (module
         docstring). Nesting is tracked per thread: a span's self time is
         its duration minus its children's. Under an active TraceContext
         (ring on) the span gets its own span id and re-parents the context
         for the block, so nested spans (this thread or a remote process
-        the block talks to) chain under it."""
-        return _Span(self, name, cat, args)
+        the block talks to) chain under it. ``on`` is the arrays (any
+        pytree) the block reads or runs on: while a profiler session
+        captures or the ring is on the span carries ``device``
+        (``device_of(on)``); otherwise ``on`` is not looked at."""
+        return _Span(self, name, cat, args, on=on)
 
     def complete(self, name: str, cat: str, start_s: float, dur_s: float,
                  **args) -> None:

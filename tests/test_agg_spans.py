@@ -46,9 +46,15 @@ def traced():
 
 def crossings(events):
     """(programs dispatched, blocking syncs + downloads): what
-    ``programs_per_query`` and ``host_syncs_per_query`` count."""
+    ``programs_per_query`` and ``host_syncs_per_query`` count. The reads of
+    a stage's statistics (one a handle: ``plan/aqe.py``
+    ``_device_shard_stats``), which the commits the numbers were read from
+    made under no span, are held apart."""
+    syncs = events("sync")
+    stats = [e for e in syncs if e.args.get("parent") == "stage.stats"]
+    assert len(stats) == sum(e.args["handles"] for e in events("stage.stats"))
     return (len(events("dispatch")),
-            len(events("sync")) + len(events("d2h")))
+            len(syncs) - len(stats) + len(events("d2h")))
 
 
 # ---- three plans in the shape of the benchmark's cells ----------------------
@@ -178,6 +184,40 @@ def test_q18s_wide_group_by_books_rounds_and_its_probes_match_rates(traced):
             assert 0 <= e.args["rows_out"] <= e.args["rows"]
     kept = [e.args["rows_out"] for e in probes if "rows_out" in e.args]
     assert kept and min(kept) < max(e.args["rows"] for e in probes)
+
+
+def test_the_phase_totals_hold_the_sums_of_the_spans_arguments(traced):
+    """``last_query_phases()`` after a Q18-shaped query: every counted
+    argument of every span summed per phase, ``rounds`` / ``full_rounds``
+    of the wide group-by and of the PK probes and ``rows`` of the builds
+    among them, beside ``calls`` / ``self_s`` / ``bytes``."""
+    from spark_rapids_tpu.utils.tracing import COUNTED_ARGS
+    session, events = traced
+    make, parts = PLANS["q18"]
+    sess = session()
+    frames = tpch.build_dataframes(sess, make(), num_partitions=parts)
+    QUERIES["q18"](frames).collect()
+    phases = sess.last_query_phases()["phases"]
+    sums = {}
+    for e in events(""):
+        for k in COUNTED_ARGS & set(e.args):
+            by = sums.setdefault(e.name, {})
+            by[k] = by.get(k, 0) + e.args[k]
+    for name, by in sums.items():
+        got = {k: v for k, v in phases[name].items()
+               if k not in ("calls", "self_s", "bytes")}
+        assert got == {k: v for k, v in by.items() if v}, name
+    for name, fields in {"agg.scatter": ("rows", "groups", "rounds",
+                                         "full_rounds"),
+                         "join.probe.pk": ("rows", "rows_out", "rounds",
+                                           "full_rounds"),
+                         "join.prep": ("rows", "unique", "rounds"),
+                         "join.build": ("rows",), "sync": ("scalars",),
+                         "stage.stats": ("shards", "handles")}.items():
+        assert all(phases[name][f] > 0 for f in fields), (name, phases[name])
+    assert phases["join.build"]["rows"] == sum(
+        e.args["rows"] for e in events("join.build"))
+    assert "rounds" not in phases["dispatch"]
 
 
 # ---- a state that outgrows one batch's bucket --------------------------------

@@ -215,3 +215,83 @@ def test_range_partitioning_still_stays_on_the_host_tier_under_a_mesh():
     if "ShuffleExchangeExec" not in names:
         pytest.skip("the planner sorted without a range exchange")
     assert "TpuLocalExchangeExec" not in names
+
+
+# ---- the staged path (AQE): its planning, its statistics, its reads -----------
+#: (programs, syncs, downloads) of the plan below at commit ec800b2 (PR 35),
+#: which read every handle's row count under no span
+PARENT_Q3_STAGED = (83, 37, 6)
+
+
+def test_a_staged_q3_books_its_planning_its_statistics_and_their_reads(
+        monkeypatch):
+    """Q3 as the mesh cell plans it — both joins shuffled, every exchange a
+    stage of its own — on four devices: planning between stages is
+    ``plan.aqe``, the statistics loop ``stage.stats``, each read of a
+    handle's row count a ``sync`` that says which device it read; no
+    program and no transfer that commit ec800b2 did not make."""
+    from spark_rapids_tpu.plan import aqe
+    from spark_rapids_tpu.tools import tpch
+    sess = session(**{
+        "spark.rapids.tpu.aqe.enabled": True,
+        "spark.rapids.tpu.batchRowsMinBucket": 64,
+        "spark.rapids.tpu.autoBroadcastJoinThreshold": -1,
+        "spark.rapids.tpu.aqe.autoBroadcastJoinThreshold": -1})
+    reads = []      # what the statistics loop read, and from which device
+    real = aqe._device_shard_stats
+
+    def spied(handles):
+        reads.extend(next(iter(h.get().num_rows.devices())).id
+                     for h in handles)
+        return real(handles)
+    monkeypatch.setattr(aqe, "_device_shard_stats", spied)
+    frames = {n: sess.create_dataframe(t, num_partitions=2)
+              for n, t in tpch.gen_all(0, tiny=True, seed=3).items()}
+    q3 = tpch.QUERIES["q3"](frames)
+    q3.collect()                 # compile
+    tracer = get_tracer()
+    was = tracer.enabled
+    tracer.enabled = True
+    tracer.clear()
+    del reads[:]
+    try:
+        got = q3.collect()
+        events = tracer.events()
+    finally:
+        tracer.enabled = was
+        tracer.clear()
+    phases = sess.last_query_phases()["phases"]
+    sess.close()
+    assert got.num_rows > 0
+    stages = phases["stage"]["calls"]
+    assert stages == 6          # five hash exchanges and the top-n's gather
+    # one stage.stats a stage; plan.aqe: the loop's pick and the segment's
+    # overrides a stage, and the final segment
+    stats = [e for e in events if e.name == "stage.stats"]
+    assert [e.args["stage"] for e in stats] == list(range(stages))
+    assert phases["stage.stats"]["calls"] == stages
+    assert phases["plan.aqe"]["calls"] == 2 * stages + 1
+    assert sorted({e.args["stage"] for e in events if e.name == "plan.aqe"}) \
+        == list(range(stages + 1))
+    assert phases["plan"]["calls"] == 1
+    # every read of the statistics loop is a sync of one scalar, on the
+    # device the spy saw; the counters say how many
+    under = [e for e in events if e.name == "sync"
+             and e.args.get("parent") == "stage.stats"]
+    assert [e.args["device"] for e in under] == reads and len(reads) > stages
+    assert all(e.args["scalars"] == 1 for e in under)
+    assert set(reads) == {0, 1, 2, 3}
+    assert phases["stage.stats"]["handles"] == len(reads)
+    assert phases["stage.stats"]["shards"] == 4 * (stages - 1) + 1
+    # the parent's programs and transfers, and the reads it did not count
+    assert (phases["dispatch"]["calls"],
+            phases["sync"]["calls"] - len(reads),
+            phases["d2h"]["calls"]) == PARENT_Q3_STAGED
+    # a span that reads or runs on the mesh says -1
+    a2a = [e for e in events if e.name == "dispatch"
+           and e.args["program"] == "srt_ici_all_to_all"]
+    assert len(a2a) == stages - 1 and {e.args["device"] for e in a2a} == {-1}
+    # the per-partition joins run, and are read, on every device in turn
+    preps = [e.args["device"] for e in events if e.name == "dispatch"
+             and e.args["program"] == "srt_join_prep_hash"]
+    assert sorted(preps) == [0, 0, 1, 1, 2, 2, 3, 3]

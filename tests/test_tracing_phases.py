@@ -6,6 +6,7 @@ import os
 import threading
 
 import jax
+import numpy as np
 import pyarrow.parquet as pq
 import pytest
 
@@ -272,9 +273,123 @@ def test_under_a_profiler_session_the_spans_are_in_the_xplane(
     assert idle_by_phase(profile) is None
 
 
-def ev(name, start, dur):
+# -- which device: sync / d2h / dispatch ---------------------------------------
+def test_the_device_is_worked_out_only_while_someone_records_it(
+        sess, lineitem_dir, monkeypatch, tmp_path):
+    tracer = get_tracer()
+    assert not tracer.enabled
+    tracer.clear()
+    asked = []
+    real = tracing.device_of
+    monkeypatch.setattr(tracing, "device_of",
+                        lambda on: (asked.append(on), real(on))[1])
+    phases = run_query(sess, lineitem_dir, "q1")["phases"]
+    spans = sum(phases[n]["calls"] for n in ("sync", "d2h", "dispatch"))
+    # profiler off, ring off: nobody looks at what a span is ``on``
+    assert asked == [] and spans > 10
+    df = tpch.QUERIES["q1"]({"lineitem": sess.read_parquet(lineitem_dir)})
+    tracer.enabled = True
+    try:
+        df.collect()
+    finally:
+        tracer.enabled = False
+        events = tracer.events()
+        tracer.clear()
+    said = [e for e in events if e.name in ("sync", "d2h", "dispatch")]
+    assert len(said) == len(asked) == spans
+    # one CPU device holds every array of the query; a program whose
+    # arguments hold no array says -1, as one over a mesh would
+    assert {e.args["device"] for e in said} <= {0, -1}
+    assert all(e.args["device"] == 0 for e in said if e.name != "dispatch")
+    assert "device" not in {k for e in events if e not in said
+                            for k in e.args}
+    # under a profiler session the device rides on the annotation
+    del asked[:]
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        df.collect()
+    finally:
+        jax.profiler.stop_trace()
+    assert len(asked) == spans and tracer.events() == []
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    profile = jax.profiler.ProfileData.from_file(path)
+    stats = [dict(e.stats) for plane in profile.planes
+             if plane.name == "/host:CPU" for line in plane.lines
+             for e in line.events
+             if e.name in ("srt.sync", "srt.d2h", "srt.dispatch")]
+    assert len(stats) == spans and all("device" in st for st in stats)
+
+
+@pytest.mark.parametrize("values,want", [
+    (lambda d: [jax.device_put(1, d[2]), 7, "x"], 2),
+    (lambda d: {"a": jax.device_put(1, d[1]), "b": jax.device_put(2, d[1])}, 1),
+    (lambda d: [jax.device_put(1, d[0]), jax.device_put(1, d[3])], -1),
+    (lambda d: jax.device_put(
+        np.arange(8), jax.sharding.NamedSharding(
+            jax.sharding.Mesh(np.array(d[:4]), ("dp",)),
+            jax.sharding.PartitionSpec("dp"))), -1),
+    (lambda d: (3, np.arange(4)), -1),
+], ids=["one", "same", "several", "sharded", "none"])
+def test_device_of_names_the_one_device_or_none(values, want):
+    devices = jax.devices()
+    if len(devices) < 4:
+        pytest.skip("needs 4 virtual devices")
+    assert tracing.device_of(values(devices)) == want
+
+
+# -- span arguments as per-query counters ---------------------------------------
+def test_counted_arguments_are_summed_per_phase_and_read_by_field(
+        monkeypatch):
+    tracer = Tracer()
+    for rounds in (4, 6):
+        with tracer.query():
+            with tracer.span("agg.scatter", "agg", rows=1024, groups=7) as sp:
+                sp.note(rounds=rounds, full_rounds=1)   # known after the run
+            with tracer.span("agg.scatter", "agg", rows=2048, groups=0):
+                pass
+            with tracer.span("join.prep", "join", rows=512, unique=True):
+                pass
+            with tracer.span("join.prep", "join", rows=512, unique=False,
+                             rounds=0):
+                pass
+            with tracer.span("dispatch", "dispatch", program="srt_x",
+                             rows="many"):
+                pass
+            with tracer.span("sync", "download", scalars=3):
+                pass
+    first, last = tracer.recent_queries()
+    phases = last["phases"]
+    assert phases["agg.scatter"] == {
+        "calls": 2, "self_s": phases["agg.scatter"]["self_s"], "bytes": 0,
+        "rows": 3072, "groups": 7, "rounds": 6, "full_rounds": 1}
+    # a boolean counts 0 / 1; a sum of zero is left out, as is what is not
+    # a number or not in COUNTED_ARGS
+    assert phases["join.prep"] == {
+        "calls": 2, "self_s": phases["join.prep"]["self_s"], "bytes": 0,
+        "rows": 1024, "unique": 1}
+    assert set(phases["dispatch"]) == {"calls", "self_s", "bytes"}
+    assert phases["sync"]["scalars"] == 3
+    assert tracing.COUNTED_ARGS >= {"rows", "rows_out", "groups", "rounds",
+                                    "full_rounds", "parts", "scalars"}
+    # the benchmark's reader, as it stands, reads a counter through ``field``
+    from benchmark.readers import query_phases
+    monkeypatch.setattr(tracing, "get_tracer", lambda: tracer)
+    run = {"trace": {"queries": 2,
+                     "span_s": first["wall_s"] + last["wall_s"]}}
+    read = query_phases.read
+    assert read(run, phases=["agg.scatter"], field="rounds") == (4 + 6) / 2
+    assert read(run, phases=["agg.scatter", "join.prep"], field="rows") \
+        == 3072 + 1024
+    assert read(run, phases=["join.prep"], field="rounds") == 0
+    assert read(run, phases=["sync"], field="calls") == 1
+
+
+def ev(name, start, dur, **stats):
     from types import SimpleNamespace as NS
-    return NS(name=name, start_ns=start, duration_ns=dur, stats=())
+    return NS(name=name, start_ns=start, duration_ns=dur,
+              stats=tuple(stats.items()))
 
 
 def test_gaps_books_device_idle_time_to_the_innermost_working_span():
@@ -304,6 +419,110 @@ def test_gaps_books_device_idle_time_to_the_innermost_working_span():
     assert by == {"plan": 100, "scan.read": 350, "h2d": 50, "d2h": 100,
                   "wait.pipeline": 90, "task": 60, "query": 100}
     assert got["named_share"] == pytest.approx(600 / 850)
+
+
+def mesh_profile():
+    """Four device planes. Device 0 is the busiest (300 ns) and idles over
+    100-600 and 800-1000 of a 1000-ns query; meanwhile the host sits in a
+    ``join.prep`` whose ``sync`` waits first for device 1, then for device
+    2, each in a prep program, and later under nothing but a ``stage``."""
+    from types import SimpleNamespace as NS
+
+    def dev(n, ops, mods):
+        return NS(name=f"/device:TPU:{n}", lines=[
+            NS(name="XLA Ops", events=[ev(f"fusion.{i}", s, d)
+                                       for i, (s, d) in enumerate(ops)]),
+            NS(name="XLA Modules", events=[ev(m, s, d) for m, s, d in mods])])
+    host = NS(name="/host:CPU", lines=[NS(name="main", events=[
+        ev("srt.query", 0, 1000),
+        ev("srt.join.prep", 120, 330, rows=512),
+        ev("srt.sync", 140, 160, scalars=3, device=1),
+        ev("srt.sync", 300, 120, scalars=1, device=2),
+        ev("srt.stage", 450, 550), ev("srt.stage.stats", 460, 40)])])
+    return NS(planes=[
+        host,
+        dev(0, [(0, 100), (600, 200)],
+            [("jit_srt_stage(1)", 0, 100), ("jit_srt_concat(2)", 600, 200)]),
+        dev(1, [(150, 150)], [("jit_srt_join_prep_hash(3)", 150, 150)]),
+        dev(2, [(250, 170), (850, 50)],
+            [("jit_srt_join_prep_hash(4)", 250, 170),
+             ("jit_convert_element_type(9)", 850, 50)]),
+        dev(3, [], [])])
+
+
+def test_gaps_books_a_mesh_gap_to_the_device_that_was_busy():
+    got = idle_by_phase(mesh_profile())
+    ns = 1e-9
+    assert got["device"] == "/device:TPU:0"
+    assert (round(got["busy_s"] / ns), round(got["idle_s"] / ns)) == (300, 700)
+    # the host's view is the one-device one: every gap to a host span
+    assert {k: round(v / ns) for k, v in got["idle_by_phase_s"].items()} \
+        == {"stage": 310, "sync": 280, "join.prep": 50, "stage.stats": 40,
+            "query": 20}
+    by_cause = {(r["cause"], r["host_span"], r["host_device"]):
+                round(r["s"] / ns) for r in got["idle_by_cause_s"]}
+    prep = "jit_srt_join_prep_hash"
+    assert by_cause == {
+        # another device busy: that device and the module it ran; where two
+        # are (250-300), the busier of them; the host span stands beside it
+        ("/device:TPU:1: " + prep, "sync", 1): 100,
+        ("/device:TPU:2: " + prep, "sync", 1): 50,
+        ("/device:TPU:2: " + prep, "sync", 2): 120,
+        ("/device:TPU:2: jit_convert_element_type", "stage", None): 50,
+        # every device idle: the host span, ranked as on one device
+        ("host: query", "query", None): 20,
+        ("host: join.prep", "join.prep", None): 50,
+        ("host: sync", "sync", 1): 10,
+        ("host: stage", "stage", None): 260,
+        ("host: stage.stats", "stage.stats", None): 40}
+    assert [r["s"] for r in got["idle_by_cause_s"]] \
+        == sorted((r["s"] for r in got["idle_by_cause_s"]), reverse=True)
+    # named: a jit_srt_* module of another device, or a working host span
+    assert got["named_share"] == pytest.approx(370 / 700)
+    devices = {d: (round(r["busy_s"] / ns), round(r["idle_s"] / ns),
+                   {m: round(v / ns) for m, v in r["modules_s"].items()})
+               for d, r in got["devices"].items()}
+    assert devices == {
+        "/device:TPU:0": (300, 700, {"jit_srt_concat": 200,
+                                     "jit_srt_stage": 100}),
+        "/device:TPU:1": (150, 850, {prep: 150}),
+        "/device:TPU:2": (220, 780, {prep: 170,
+                                     "jit_convert_element_type": 50}),
+        "/device:TPU:3": (0, 1000, {})}
+    first, second = got["longest_gaps"]
+    assert (round(first["offset_s"] / ns), round(first["length_s"] / ns)) \
+        == (100, 500)
+    assert first["cause"] == "/device:TPU:2: " + prep
+    assert first["cause_share"] == pytest.approx(170 / 500)
+    assert (first["host_span"], first["args"]) \
+        == ("sync", {"device": 2, "scalars": 1})
+    assert (round(second["offset_s"] / ns), second["cause"],
+            second["host_span"], second["args"]) \
+        == (800, "host: stage", "stage", {})
+
+
+def test_on_one_device_every_gap_has_a_host_span_for_its_cause():
+    """What ``gaps`` added for a mesh says nothing new on one device: the
+    causes are the host spans of ``idle_by_phase_s``, second for second."""
+    from types import SimpleNamespace as NS
+    host = NS(name="/host:CPU", lines=[NS(name="main", events=[
+        ev("srt.query", 0, 1000), ev("srt.plan", 0, 100),
+        ev("srt.task", 100, 800), ev("srt.d2h", 700, 250, device=0)])])
+    dev = NS(name="/device:TPU:0", lines=[
+        NS(name="XLA Ops", events=[ev("fusion.1", 600, 50),
+                                   ev("fusion.2", 700, 100)]),
+        NS(name="XLA Modules", events=[ev("jit_srt_stage(1)", 600, 50)])])
+    got = idle_by_phase(NS(planes=[host, dev]))
+    assert {r["cause"]: r["s"] for r in got["idle_by_cause_s"]} \
+        == {"host: " + k: v for k, v in got["idle_by_phase_s"].items()}
+    assert all(r["device"] is None for r in got["idle_by_cause_s"])
+    assert list(got["devices"]) == ["/device:TPU:0"]
+    assert got["devices"]["/device:TPU:0"]["busy_s"] == got["busy_s"]
+    # 0-600 (plan, then task), 800-1000 (d2h, then query), 650-700
+    assert [(g["cause"], round(g["length_s"] / 1e-9))
+            for g in got["longest_gaps"]] \
+        == [("host: task", 600), ("host: d2h", 200), ("host: task", 50)]
+    assert got["longest_gaps"][1]["args"] == {"device": 0}
 
 
 # -- stable program names ---------------------------------------------------
