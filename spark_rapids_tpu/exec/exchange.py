@@ -226,7 +226,8 @@ class TpuShuffleExchangeExec(TpuExec):
                 self._materialize_locked()
 
     def _materialize_locked(self) -> None:
-        from ..parallel.pipeline import maybe_prefetched
+        from functools import partial
+        from ..parallel.pipeline import OrderedFanIn
         n = self.num_partitions
         shards: List[List] = [[] for _ in range(n)]
         if self._keep_sharded:
@@ -240,30 +241,32 @@ class TpuShuffleExchangeExec(TpuExec):
         # work (concat/count/all-to-all, inside _exchange_chunk) is ours
         pending: List[DeviceTable] = []
         staged = 0
-
-        def all_child_batches():
-            """Map-side production across every input partition; the ICI
-            collective itself must stay on one thread, so the overlap is a
-            bounded prefetch of child batches under it."""
-            for p in range(self.child.num_partitions):  # srtpu: mesh-ok(map-side INPUT production: upstream partitions stream into the collective, the ICI all-to-all itself runs mesh-wide)
-                yield from self.child_device_batches(p)
-
-        batches = maybe_prefetched(all_child_batches, stage="shuffle_map",
-                                   registry=self.metrics)
-        for b in batches:
-            # no per-batch row-count sync here: int(b.num_rows) would
-            # block the map loop on every upstream batch (ROADMAP item
-            # 1). All-masked batches flow through — the count pass parks
-            # their rows and the quota ignores them.
-            if not b.capacity:
-                continue
-            pending.append(b)
-            staged += b.capacity
-            if staged >= self.chunk_rows:
+        # Map-side production: one bounded producer an input partition, all
+        # started together, so every device runs its partition of the child
+        # (a join's build, prep and probe) while this thread consumes them
+        # in partition order: the batches reach _exchange_chunk in the order
+        # of the serial drain, and the ICI collective itself stays on this
+        # one thread.
+        batches = OrderedFanIn(
+            [partial(self.child_device_batches, p)
+             for p in range(self.child.num_partitions)],  # srtpu: mesh-ok(map-side INPUT production: upstream partitions stream into the collective, the ICI all-to-all itself runs mesh-wide)
+            stage="shuffle_map", registry=self.metrics)
+        with get_tracer().span("exchange.map", "exchange",
+                               producers=batches.producers):
+            for b in batches:
+                # no per-batch row-count sync here: int(b.num_rows) would
+                # block the map loop on every upstream batch (ROADMAP item
+                # 1). All-masked batches flow through — the count pass parks
+                # their rows and the quota ignores them.
+                if not b.capacity:
+                    continue
+                pending.append(b)
+                staged += b.capacity
+                if staged >= self.chunk_rows:
+                    total_rows += self._exchange_chunk(pending, shards)
+                    pending, staged = [], 0
+            if pending:
                 total_rows += self._exchange_chunk(pending, shards)
-                pending, staged = [], 0
-        if pending:
-            total_rows += self._exchange_chunk(pending, shards)
         if self._keep_sharded:
             # output stays one sharded table per chunk (the mesh stage
             # dispatches over all shards at once); _shards stays None

@@ -26,6 +26,7 @@ windows so no expand exceeds the budget.
 from __future__ import annotations
 
 import math
+import threading
 from typing import Iterator, List, Optional, Sequence, Tuple
 
 import jax
@@ -727,6 +728,10 @@ class TpuShuffledHashJoinExec(TpuExec):
         on = self.left_keys if merge_keys else None
         self.schema = _join_schema(left.schema, right.schema, on, how)
         self._kernels = _JoinKernels(self)
+        # prep entries, one a live build table (``_prep_slot``); the lock
+        # guards the dict alone
+        self._preps: dict = {}
+        self._prep_lock = threading.Lock()
 
     @property
     def num_partitions(self) -> int:
@@ -898,6 +903,7 @@ class TpuShuffledHashJoinExec(TpuExec):
         finally:
             if own:
                 handle.close()
+                self._close_preps(handle)
 
     def _leftover_fn(self):
         """Cached canonical leftover kernel (right/full build-side rows).
@@ -924,11 +930,12 @@ class TpuShuffledHashJoinExec(TpuExec):
         bad = (dt.StringType, dt.BinaryType, dt.ArrayType)
         return lt == rt and not isinstance(lt, bad) and not dt.is_d128(lt)
 
-    def _counts_fn(self, track: bool = False):
+    def _counts_fn(self, owner, track: bool = False):
         """Shared count kernel over key views -> (b_order, starts, counts,
         matched_or_None). One program per key LAYOUT (count of keys +
         direct/general + track), retraced per dtype/capacity inside the
-        shared jit."""
+        shared jit. ``owner`` is the spill handle of the build table the
+        returned callable will be handed (its prep entry's key)."""
         lkeys, rkeys = self.left_keys, self.right_keys
         if self._direct_key_ok():
             cnt = cached_jit(f"JoinC|probeD|t{int(track)}",
@@ -936,7 +943,7 @@ class TpuShuffledHashJoinExec(TpuExec):
                              name="join_probe_count")
 
             def run(build: DeviceTable, probe: DeviceTable):
-                b_order, sv, nvalid, _uniq = self._get_prep(build)
+                b_order, sv, nvalid, _uniq = self._get_prep(build, owner)
                 starts, counts, matched = cnt(b_order, sv, nvalid,
                                               _key_view(probe, lkeys))
                 return b_order, starts, counts, (matched if track else None)
@@ -954,17 +961,41 @@ class TpuShuffledHashJoinExec(TpuExec):
             return b_order, starts, counts, matched
         return run
 
-    def _get_prep_hash(self, build: DeviceTable):
+    def _prep_slot(self, owner) -> "_PrepSlot":
+        """The prep entry of the build table behind spill handle ``owner``:
+        one entry a live build table of this node (a shuffled join has one
+        build table a partition, and the mesh exchange's map side runs the
+        partitions together; a broadcast join has the one every probe
+        partition shares). The node's lock guards the dict alone: what is
+        held across a prep's dispatch and its ``resolve_scalars`` is the
+        ENTRY's lock, which only probes of the same build table wait on."""
+        with self._prep_lock:
+            slot = self._preps.get(id(owner))
+            if slot is None:
+                slot = self._preps[id(owner)] = _PrepSlot(owner)
+            return slot
+
+    def _close_preps(self, owner) -> None:
+        """Close the preps of a build table with it (its owner calls this
+        where it closes the build's handle); a no-op for a table that was
+        never prepped."""
+        with self._prep_lock:
+            slot = self._preps.pop(id(owner), None)
+        if slot is not None:
+            for hit in (slot.hash, slot.dense):
+                if hit is not None:
+                    _close_quietly(hit[1][0])
+
+    def _get_prep_hash(self, build: DeviceTable, owner):
         """Per-build-table HASH prep (slot table + key array + uniqueness),
         cached like the sorted prep; no lax.sort in the prep program. A
         miss books span ``join.prep`` (``rows``, ``unique``, ``rounds``,
         ``full_rounds``), a hit nothing."""
         prep = cached_jit("JoinC|prepH", self._kernels.build_prep_hash_fn,
                           name="join_prep_hash")
-        lock = self.__dict__.setdefault("_prep_lock",
-                                        __import__("threading").Lock())
-        with lock:
-            hit = self.__dict__.get("_prep_cache_hash")
+        slot = self._prep_slot(owner)
+        with slot.lock:
+            hit = slot.hash
             if hit is None or hit[0] is not build.row_mask:
                 with get_tracer().span("join.prep", "join",
                                        rows=build.capacity) as span:
@@ -979,11 +1010,9 @@ class TpuShuffledHashJoinExec(TpuExec):
                         unique, rounds, full_rounds)
                     span.note(unique=bool(uniq), rounds=int(rounds),
                               full_rounds=int(full_rounds))
-                hit = (build.row_mask, (handle, bool(uniq)))
-                old = self.__dict__.get("_prep_cache_hash")
-                if old is not None:
-                    _close_quietly(old[1][0])
-                self.__dict__["_prep_cache_hash"] = hit
+                if hit is not None:     # the table came back from a spill
+                    _close_quietly(hit[1][0])
+                hit = slot.hash = (build.row_mask, (handle, bool(uniq)))
         handle, unique = hit[1]
         pt = handle.get()
         cap = pt.capacity // 2
@@ -1003,34 +1032,35 @@ class TpuShuffledHashJoinExec(TpuExec):
         self._own_spill_handle(h)
         return h
 
-    def _get_prep(self, build: DeviceTable):
+    def _get_prep(self, build: DeviceTable, owner):
         """Per-build-table sorted-key prep: (b_order, sv, nvalid, unique).
 
-        Node-level cache: broadcast joins re-enter _probe_join once per
-        probe partition with the SAME build table — the prep must survive
-        across those entries. The sorted-key arrays live in a catalog-
-        registered spillable so memory pressure can evict them; single
-        entry, replaced on build change, race-safe (each thread uses the
-        tuple it computed or read, never a second dict lookup). ``unique``
+        Cached on the node, one entry a live build table, keyed by the
+        build's spill handle ``owner`` (``_prep_slot``): a broadcast join
+        re-enters _probe_join once per probe partition with the SAME build
+        table, and the prep must survive across those entries; the
+        partitions of a shuffled join each have a build table of their own
+        and may run at once. A hit is the entry's table being this one
+        (``row_mask`` identity: a table restored from a spill is prepped
+        again and replaces the entry); the entry is closed with its build
+        (``_close_preps``). The sorted-key arrays live in a catalog-
+        registered spillable so memory pressure can evict them. ``unique``
         is host-synced once per build (it gates the PK fast path). A miss
         books span ``join.prep`` (``rows``, ``unique``)."""
         prep = cached_jit("JoinC|prepD", self._kernels.build_prep_fn,
                           name="join_prep_dense")
-        lock = self.__dict__.setdefault("_prep_lock",
-                                        __import__("threading").Lock())
-        with lock:
-            hit = self.__dict__.get("_prep_cache")
+        slot = self._prep_slot(owner)
+        with slot.lock:
+            hit = slot.dense
             if hit is None or hit[0] is not build.row_mask:
                 with get_tracer().span("join.prep", "join",
                                        rows=build.capacity) as span:
                     pr = self._register_prep(
                         prep(_key_view(build, self.right_keys)))
                     span.note(unique=pr[2])
-                hit = (build.row_mask, pr)
-                old = self.__dict__.get("_prep_cache")
-                if old is not None:
-                    _close_quietly(old[1][0])
-                self.__dict__["_prep_cache"] = hit
+                if hit is not None:     # the table came back from a spill
+                    _close_quietly(hit[1][0])
+                hit = slot.dense = (build.row_mask, pr)
         handle, nvalid, unique = hit[1]
         pt = handle.get()
         return pt.columns[0].data, pt.columns[1].data, nvalid, unique
@@ -1064,7 +1094,7 @@ class TpuShuffledHashJoinExec(TpuExec):
         """
         has_cond = self.condition is not None
         track = seen_box is not None and not has_cond
-        counts_fn = self._counts_fn(track=track)
+        counts_fn = self._counts_fn(build_handle, track=track)
         pk_eligible = (not has_cond and self._direct_key_ok()
                        and self.how in ("inner", "left", "left_semi",
                                         "left_anti"))
@@ -1072,7 +1102,8 @@ class TpuShuffledHashJoinExec(TpuExec):
         for probe in probe_batches:
             with self.metrics.timed(M.JOIN_TIME), build_handle as build:
                 probe = _co_locate(probe, build)
-                pk = self._pk_program(build) if pk_eligible else None
+                pk = self._pk_program(build, build_handle) \
+                    if pk_eligible else None
                 if pk is not None:
                     # FK->PK or existence: counts are 0/1, output fits the
                     # probe capacity — one fused program, no count sync
@@ -1125,7 +1156,7 @@ class TpuShuffledHashJoinExec(TpuExec):
                 yield from self._probe_expand(build, probe, counts_fn,
                                               seen_box)
 
-    def _pk_program(self, build: DeviceTable):
+    def _pk_program(self, build: DeviceTable, owner):
         """-> (the fused single-match / existence program, the prepared
         arrays of this build table it takes last), or None where the
         build's keys repeat and the join needs every match (the counts +
@@ -1138,7 +1169,7 @@ class TpuShuffledHashJoinExec(TpuExec):
             # ask EXISTENCE, so duplicate build keys are fine (the chain
             # walk finds any representative); inner/left need uniqueness
             # for the single-match gather
-            slot_row, bv, unique = self._get_prep_hash(build)
+            slot_row, bv, unique = self._get_prep_hash(build, owner)
             if not (unique or self.how in ("left_semi", "left_anti")):
                 return None
             fused = cached_jit(
@@ -1146,7 +1177,7 @@ class TpuShuffledHashJoinExec(TpuExec):
                 lambda: clone._kernels.pk_hash_join_fn(self.how),
                 name="join_pk_hash")
             return fused, (slot_row, bv)
-        b_order, sv, nvalid, unique = self._get_prep(build)
+        b_order, sv, nvalid, unique = self._get_prep(build, owner)
         if not unique:
             return None
         fused = cached_jit(
@@ -1320,10 +1351,13 @@ class TpuShuffledHashJoinExec(TpuExec):
                     leftover = self._leftover_fn()
                     with build_parts[s] as bt:
                         yield leftover(bt, seen_box[0])
+                if own_build:   # one part's prep alive at a time
+                    self._close_preps(build_parts[s])
         finally:
             if own_build:
                 for h in build_parts:
                     h.close()
+                    self._close_preps(h)
             for hs in probe_parts:
                 for h in hs:
                     h.close()
@@ -1391,6 +1425,21 @@ class TpuBroadcastHashJoinExec(TpuShuffledHashJoinExec):
                 for h in parts:
                     self._own_spill_handle(h)
             return self._bc_grace_parts, False
+
+
+class _PrepSlot:
+    """The prep entry of one live build table (``_prep_slot``): per tier
+    ``(the table's row_mask, the prep's payload)`` or None, under a lock of
+    its own. It pins ``owner``, so the ``id`` it is filed under stays the
+    owner's for as long as the entry lives."""
+
+    __slots__ = ("owner", "lock", "hash", "dense")
+
+    def __init__(self, owner):
+        self.owner = owner
+        self.lock = threading.Lock()
+        self.hash = None
+        self.dense = None
 
 
 def _close_quietly(handle):

@@ -5,7 +5,7 @@ concurrently against one device, gated by ``GpuSemaphore``, so host-side
 decode/serialization overlaps device kernels (Plugin.scala +
 GpuSemaphore.scala). The sequential port executed partitions one at a time
 through synchronous iterators, leaving the TPU idle during every host
-decode, H2D upload and shuffle write. This module supplies the two
+decode, H2D upload and shuffle write. This module supplies the three
 overlap mechanisms:
 
 - ``pipelined_collect(plan, conf)``: drains multiple partitions
@@ -18,7 +18,13 @@ overlap mechanisms:
   ``HostToDeviceExec`` upload, jitted compute (riding JAX async dispatch)
   and downloads/shuffle writes run double-buffered within one partition.
   Exec nodes opt in at their stage boundaries (exec/transitions.py,
-  exec/wholestage.py, exec/exchange.py).
+  exec/wholestage.py).
+- ``OrderedFanIn(makers, ...)``: many producers, one ordered consumer —
+  one such bounded queue and worker a maker, all started together, drained
+  maker 0 to its end, then 1, and so on. The mesh exchange's map side
+  (exec/exchange.py) produces its child's partitions through it, so each
+  device runs its partition while the collective's thread consumes them in
+  partition order.
 
 Design rules:
 
@@ -54,9 +60,10 @@ from ..conf import register_conf
 __all__ = ["PIPELINE_ENABLED", "PIPELINE_PREFETCH_DEPTH",
            "PIPELINE_TASK_POOL", "configure_pipeline", "pipeline_enabled",
            "prefetch_depth", "task_pool_size", "prefetched",
-           "maybe_prefetched", "pipelined_collect", "parallel_map",
-           "active_workers", "shutdown_workers", "pipeline_stats",
-           "pipeline_snapshot", "note_progress", "stage_name"]
+           "maybe_prefetched", "OrderedFanIn", "pipelined_collect",
+           "parallel_map", "active_workers", "shutdown_workers",
+           "pipeline_stats", "pipeline_snapshot", "note_progress",
+           "stage_name"]
 
 
 def stage_name(node) -> str:
@@ -317,6 +324,144 @@ def _attach_context(exc: BaseException, stage: str) -> BaseException:
     return exc
 
 
+class _Producer:
+    """One bounded producer: ``make_iter()`` runs on a worker thread of its
+    own, STARTED BY THE CONSTRUCTOR, and hands its items through a queue of
+    ``depth``. ``drain`` yields them on the calling thread; ``cancel`` stops
+    the worker and empties the queue. The worker's spans belong to the query
+    of the thread that made the producer."""
+
+    def __init__(self, make_iter: Callable[[], Iterator], stage: str,
+                 depth: Optional[int] = None):
+        from ..utils.tracing import get_tracer
+        depth = prefetch_depth() if depth is None else max(1, int(depth))
+        self.stage = stage
+        self._make_iter = make_iter
+        self._q: "queue.Queue" = queue.Queue(maxsize=depth)
+        self._cancel = threading.Event()
+        self._abandoned = False     # the CONSUMER cancelled: nobody reads
+        t = threading.Thread(target=get_tracer().bind_query(self._produce),
+                             daemon=True, name=f"tpu-prefetch:{stage}")
+        self._qid = next(_QUEUE_IDS)
+        with _WORKERS_LOCK:
+            _WORKERS[t] = self._cancel
+            _STATS["workers_started"] += 1
+            _QUEUES[self._qid] = {"stage": stage, "queue": self._q,
+                                  "created": time.monotonic()}
+            # opportunistic GC of finished workers so the registry stays small
+            for dead in [w for w in _WORKERS
+                         if not w.is_alive() and w is not t]:
+                _WORKERS.pop(dead, None)
+        t.start()
+
+    def _put(self, item) -> bool:
+        """put that never blocks forever: gives up when cancelled."""
+        while not self._cancel.is_set():
+            try:
+                self._q.put(item, timeout=0.05)
+                return True
+            except queue.Full:
+                continue
+        return False
+
+    def _put_final(self, item) -> None:
+        """Best-effort sentinel delivery AFTER cancellation: a consumer
+        still blocked in get() must never hang just because its producer
+        was shut down. A consumer that cancelled the producer itself reads
+        no more, and is owed nothing."""
+        deadline = time.monotonic() + 5.0
+        while time.monotonic() < deadline and not self._abandoned:
+            try:
+                self._q.put(item, timeout=0.05)
+                return
+            except queue.Full:
+                continue
+
+    def _produce(self) -> None:
+        from ..io.file_block import current_input_file
+        stage = self.stage
+        _WORKER_TLS.exempt = True  # runs under the owning task's admission
+        try:
+            it = self._make_iter()
+            try:
+                for item in it:
+                    with _WORKERS_LOCK:
+                        _STATS["items_queued"] += 1
+                    note_progress()
+                    # carry the thread-local input-file holder across the
+                    # thread hop (io/file_block.py contract)
+                    if not self._put((item, current_input_file())):
+                        self._put_final(_Failure(_attach_context(
+                            RuntimeError("pipeline stage cancelled "
+                                         "(shutdown)"), stage)))
+                        return
+            finally:
+                close = getattr(it, "close", None)
+                if close is not None:
+                    close()
+            if not self._put(_Done):
+                self._put_final(_Done)
+        except BaseException as e:  # noqa: BLE001 — crosses the queue  # srtpu: degrade-ok(the failure is forwarded through the queue and re-raised in the consumer)
+            with _WORKERS_LOCK:
+                _STATS["stage_errors"] += 1
+            if not self._put(_Failure(_attach_context(e, stage))):
+                self._put_final(_Failure(_attach_context(e, stage)))
+
+    def _get(self):
+        # cooperative deadline: the consumer must not block forever on a
+        # producer that wedged after the query's deadline passed — poll
+        # with a short timeout only while a deadline is armed (the plain
+        # blocking get stays on the hot path otherwise)
+        from ..utils.deadline import check_deadline, deadline_active
+        if not deadline_active():
+            return self._q.get()
+        while True:
+            check_deadline()
+            try:
+                return self._q.get(timeout=0.25)
+            except queue.Empty:
+                continue
+
+    def drain(self, registry=None) -> Iterator:
+        """The producer's items in order, on the calling thread; cancels the
+        producer when it ends, fails or is closed early."""
+        from ..io.file_block import set_input_file
+        from ..utils import metrics as M
+        from ..utils.tracing import get_tracer
+        tracer = get_tracer()
+        try:
+            while True:
+                t0 = time.perf_counter()
+                with tracer.span("wait.pipeline", "pipeline",
+                                 stage=self.stage):
+                    item = self._get()
+                if registry is not None:
+                    registry.add(M.PIPELINE_WAIT, time.perf_counter() - t0)
+                    registry.observe(M.PREFETCH_QUEUE_DEPTH, self._q.qsize())
+                if item is _Done:
+                    return
+                if isinstance(item, _Failure):
+                    raise item.exc
+                batch, file_info = item
+                note_progress()
+                set_input_file(*file_info)
+                yield batch
+        finally:
+            self.cancel()
+
+    def cancel(self) -> None:
+        with _WORKERS_LOCK:
+            _QUEUES.pop(self._qid, None)
+        self._abandoned = True
+        self._cancel.set()
+        # unblock a producer stuck in put()
+        try:
+            while True:
+                self._q.get_nowait()
+        except queue.Empty:
+            pass
+
+
 def prefetched(make_iter: Callable[[], Iterator], *, stage: str,
                depth: Optional[int] = None, registry=None) -> Iterator:
     """Run ``make_iter()`` on a worker thread, handing items through a
@@ -327,123 +472,9 @@ def prefetched(make_iter: Callable[[], Iterator], *, stage: str,
     same wait is a ``pipeline`` trace span so overlapped stages show up in
     the Chrome trace. Early consumer exit (close/throw) cancels the worker
     and drains the queue; a producer exception re-raises here with the
-    stage context attached."""
-    from ..io.file_block import current_input_file, set_input_file
-    from ..utils import metrics as M
-    from ..utils.tracing import get_tracer
-
-    depth = prefetch_depth() if depth is None else max(1, int(depth))
-    q: "queue.Queue" = queue.Queue(maxsize=depth)
-    cancel = threading.Event()
-
-    def _put(item) -> bool:
-        """put that never blocks forever: gives up when cancelled."""
-        while not cancel.is_set():
-            try:
-                q.put(item, timeout=0.05)
-                return True
-            except queue.Full:
-                continue
-        return False
-
-    def _put_final(item) -> None:
-        """Best-effort sentinel delivery AFTER cancellation: a consumer
-        still blocked in get() must never hang just because its producer
-        was shut down (an abandoned consumer's finally-drain keeps the
-        queue emptying, so this terminates)."""
-        deadline = time.monotonic() + 5.0
-        while time.monotonic() < deadline:
-            try:
-                q.put(item, timeout=0.05)
-                return
-            except queue.Full:
-                continue
-
-    tracer = get_tracer()
-
-    @tracer.bind_query      # the producer's spans belong to the consumer's query
-    def produce():
-        _WORKER_TLS.exempt = True  # runs under the owning task's admission
-        try:
-            it = make_iter()
-            try:
-                for item in it:
-                    with _WORKERS_LOCK:
-                        _STATS["items_queued"] += 1
-                    note_progress()
-                    # carry the thread-local input-file holder across the
-                    # thread hop (io/file_block.py contract)
-                    if not _put((item, current_input_file())):
-                        _put_final(_Failure(_attach_context(
-                            RuntimeError("pipeline stage cancelled "
-                                         "(shutdown)"), stage)))
-                        return
-            finally:
-                close = getattr(it, "close", None)
-                if close is not None:
-                    close()
-            if not _put(_Done):
-                _put_final(_Done)
-        except BaseException as e:  # noqa: BLE001 — crosses the queue  # srtpu: degrade-ok(the failure is forwarded through the queue and re-raised in the consumer)
-            with _WORKERS_LOCK:
-                _STATS["stage_errors"] += 1
-            if not _put(_Failure(_attach_context(e, stage))):
-                _put_final(_Failure(_attach_context(e, stage)))
-
-    t = threading.Thread(target=produce, daemon=True,
-                         name=f"tpu-prefetch:{stage}")
-    qid = next(_QUEUE_IDS)
-    with _WORKERS_LOCK:
-        _WORKERS[t] = cancel
-        _STATS["workers_started"] += 1
-        _QUEUES[qid] = {"stage": stage, "queue": q,
-                        "created": time.monotonic()}
-        # opportunistic GC of finished workers so the registry stays small
-        for dead in [w for w in _WORKERS if not w.is_alive() and w is not t]:
-            _WORKERS.pop(dead, None)
-    t.start()
-
-    def _get():
-        # cooperative deadline: the consumer must not block forever on a
-        # producer that wedged after the query's deadline passed — poll
-        # with a short timeout only while a deadline is armed (the plain
-        # blocking get stays on the hot path otherwise)
-        from ..utils.deadline import check_deadline, deadline_active
-        if not deadline_active():
-            return q.get()
-        while True:
-            check_deadline()
-            try:
-                return q.get(timeout=0.25)
-            except queue.Empty:
-                continue
-
-    try:
-        while True:
-            t0 = time.perf_counter()
-            with tracer.span("wait.pipeline", "pipeline", stage=stage):
-                item = _get()
-            if registry is not None:
-                registry.add(M.PIPELINE_WAIT, time.perf_counter() - t0)
-                registry.observe(M.PREFETCH_QUEUE_DEPTH, q.qsize())
-            if item is _Done:
-                return
-            if isinstance(item, _Failure):
-                raise item.exc
-            batch, file_info = item
-            note_progress()
-            set_input_file(*file_info)
-            yield batch
-    finally:
-        with _WORKERS_LOCK:
-            _QUEUES.pop(qid, None)
-        cancel.set()
-        # unblock a producer stuck in put()
-        try:
-            while True:
-                q.get_nowait()
-        except queue.Empty:
-            pass
+    stage context attached. A generator: the worker starts at the first
+    ``next()``."""
+    yield from _Producer(make_iter, stage, depth).drain(registry)
 
 
 def maybe_prefetched(make_iter: Callable[[], Iterator], *, stage: str,
@@ -454,6 +485,52 @@ def maybe_prefetched(make_iter: Callable[[], Iterator], *, stage: str,
     if not pipeline_enabled():
         return make_iter()
     return prefetched(make_iter, stage=stage, registry=registry, depth=depth)
+
+
+class OrderedFanIn:
+    """Many producers, one ordered consumer: ``makers[p]()`` each runs on a
+    bounded producer of its own (``prefetched``'s worker and queue), ALL
+    STARTED TOGETHER at the first ``next()``, and the items are yielded
+    producer 0 to its end, then 1, and so on: the order of the plain serial
+    chain, whichever producer finishes first. A producer runs at most its
+    queue's ``depth`` (plus the item in its hand) ahead of the consumer. The
+    first failure the consumer reaches re-raises with the stage context, and
+    ending, failing or closing early cancels every producer. With pipelining
+    off it IS the serial chain on the calling thread; a single maker is
+    ``prefetched``. ``producers`` says how many were started together (1 for
+    the serial chain)."""
+
+    def __init__(self, makers: Sequence[Callable[[], Iterator]], *,
+                 stage: str, depth: Optional[int] = None, registry=None):
+        makers = list(makers)
+        serial = not pipeline_enabled()
+        self.producers = 1 if serial else len(makers)
+        self._it = self._serial(makers) if serial \
+            else self._fan_in(makers, stage, depth, registry)
+
+    @staticmethod
+    def _serial(makers) -> Iterator:
+        for make in makers:
+            yield from make()
+
+    @staticmethod
+    def _fan_in(makers, stage, depth, registry) -> Iterator:
+        started = [_Producer(make, stage, depth) for make in makers]
+        try:
+            for producer in started:
+                yield from producer.drain(registry)
+        finally:
+            for producer in started:
+                producer.cancel()
+
+    def __iter__(self) -> "OrderedFanIn":
+        return self
+
+    def __next__(self):
+        return next(self._it)
+
+    def close(self) -> None:
+        self._it.close()
 
 
 # ---------------------------------------------------------------------------

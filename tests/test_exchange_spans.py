@@ -295,3 +295,96 @@ def test_a_staged_q3_books_its_planning_its_statistics_and_their_reads(
     preps = [e.args["device"] for e in events if e.name == "dispatch"
              and e.args["program"] == "srt_join_prep_hash"]
     assert sorted(preps) == [0, 0, 1, 1, 2, 2, 3, 3]
+
+
+# ---- the map side: one producer an input partition, drained in order ----------
+def staged_q3(monkeypatch, pipelined):
+    """Q3 as above, warm, with the ring on: (answer, events, phase totals,
+    what reached ``_exchange_chunk``: a fingerprint a batch, in order)."""
+    import hashlib
+    from spark_rapids_tpu.exec.exchange import TpuShuffleExchangeExec
+    from spark_rapids_tpu.tools import tpch
+    sess = session(**{
+        "spark.rapids.tpu.aqe.enabled": True,
+        "spark.rapids.tpu.batchRowsMinBucket": 64,
+        "spark.rapids.tpu.autoBroadcastJoinThreshold": -1,
+        "spark.rapids.tpu.aqe.autoBroadcastJoinThreshold": -1,
+        "spark.rapids.tpu.pipeline.enabled": pipelined})
+    fed = []
+    real = TpuShuffleExchangeExec._exchange_chunk
+    real = getattr(real, "real", real)      # a second call in one test
+
+    def spied(self, batches, shards):
+        for b in batches:
+            digest = hashlib.sha1(np.asarray(b.row_mask).tobytes())
+            for c in b.columns:
+                digest.update(np.asarray(c.data).tobytes())
+            fed.append((b.capacity, next(iter(b.row_mask.devices())).id,
+                        digest.hexdigest()))
+        return real(self, batches, shards)
+    spied.real = real
+    monkeypatch.setattr(TpuShuffleExchangeExec, "_exchange_chunk", spied)
+    frames = {n: sess.create_dataframe(t, num_partitions=2)
+              for n, t in tpch.gen_all(0, tiny=True, seed=3).items()}
+    q3 = tpch.QUERIES["q3"](frames)
+    q3.collect()                 # compile
+    tracer = get_tracer()
+    was = tracer.enabled
+    tracer.enabled = True
+    tracer.clear()
+    del fed[:]
+    try:
+        got = q3.collect()
+        events = tracer.events()
+    finally:
+        tracer.enabled = was
+        tracer.clear()
+    phases = sess.last_query_phases()["phases"]
+    sess.close()
+    TpuSession({"spark.rapids.tpu.pipeline.enabled": True}).close()
+    return got, events, phases, fed
+
+
+@pytest.mark.parametrize("pipelined", [True, False],
+                         ids=["pipelined", "sequential"])
+def test_the_map_side_books_its_producers(monkeypatch, pipelined):
+    """One ``exchange.map`` a materialisation of a mesh exchange, with
+    ``producers`` = the partitions of its child, all started together: two a
+    scan of two files, four a shuffled join (1 with pipelining off: the
+    serial drain). The phase totals carry the sum flat."""
+    got, events, phases, _ = staged_q3(monkeypatch, pipelined)
+    assert got.num_rows > 0
+    maps = [e for e in events if e.name == "exchange.map"]
+    want = [2, 2, 2, 4, 4] if pipelined else [1] * 5
+    assert sorted(e.args["producers"] for e in maps) == want
+    assert phases["exchange.map"]["calls"] == 5
+    assert phases["exchange.map"]["producers"] == sum(want)
+    # the chunks' spans nest in it, on the collective's one thread
+    tids = {e.tid for e in maps}
+    assert len(tids) == 1
+    for name in ("exchange.count", "exchange.shard", "exchange.split"):
+        inside = [e for e in events if e.name == name]
+        assert inside and {e.tid for e in inside} == tids
+        assert {e.args["parent"] for e in inside} == {"exchange.map"}
+    # no build table is prepped twice: two joins x four partitions x (the
+    # hash prep that says no + the sorted prep), under four threads or one
+    assert phases["join.prep"]["calls"] == 16
+    assert phases["join.build"]["calls"] == 8
+    threads = {e.tid for e in events if e.name == "join.prep"}
+    assert len(threads) >= 4 if pipelined else threads == tids
+
+
+def test_the_map_side_feeds_the_exchange_what_the_serial_drain_feeds_it(
+        monkeypatch):
+    """Bit for bit: the same batches, from the same devices, in the same
+    order reach ``_exchange_chunk``, so chunking, quotas, float summation
+    order and the answer are those of ``pipeline.enabled=false``; and the
+    same programs and transfers."""
+    got, _, phases, fed = staged_q3(monkeypatch, True)
+    want, _, serial, fed_serial = staged_q3(monkeypatch, False)
+    assert fed == fed_serial and len(fed) >= 10
+    assert {device for _, device, _ in fed} == {0, 1, 2, 3}
+    assert got.equals(want)
+    for name in ("dispatch", "sync", "d2h", "join.prep", "join.probe.expand",
+                 "exchange.count", "exchange.shard", "exchange.split"):
+        assert phases[name]["calls"] == serial[name]["calls"], name

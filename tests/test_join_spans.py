@@ -7,7 +7,8 @@ walk's ``rounds`` / ``full_rounds`` where the output's row count is read),
 download that the code without the spans did not make. Both broadcast
 thresholds are off, so an equi-join is the ``TpuShuffledHashJoinExec`` the
 chip plans for ``sf1.q4``, whose build side (``lineitem``) holds every key
-several times. And the slot
+several times. The prep cache itself: one entry a live build table of a
+node, each under its own lock, closed with its build. And the slot
 table that prep builds: one row a distinct key, whatever the duplicates; and
 the probe's walk of it, full rounds and tail rounds, against a numpy walk."""
 import functools
@@ -246,6 +247,217 @@ def test_a_build_over_the_batch_budget_goes_grace(traced):
     assert len(events("join.prep")) == len(events("join.probe.pk")) == parts
     assert not any(e.args["unique"] for e in events("join.prep"))
     assert crossings(events) == (16, 15)
+
+
+# ---- the prep cache: one entry a live build table -----------------------------
+def other_build():
+    """A second duplicate-keyed build table of the shape of the first."""
+    t = duplicate_build()
+    return pa.table({"bk": np.asarray(t["bk"]) + 4, "w": np.asarray(t["w"])})
+
+
+def registered(table):
+    """(spill handle of the build table, its device table)."""
+    from spark_rapids_tpu.memory.catalog import SpillPriorities, get_catalog
+    batch = Source(table).batch
+    return get_catalog().register(batch, SpillPriorities.ACTIVE_ON_DECK), batch
+
+
+def live(handle):
+    return handle.buffer_id in handle.catalog._buffers
+
+
+def prep_handles(node, owner):
+    slot = node._preps[id(owner)]
+    return [hit[1][0] for hit in (slot.hash, slot.dense) if hit is not None]
+
+
+@pytest.mark.parametrize("how,strategy,preps_a_table", [
+    ("left_semi", "hash", 1),   # the slot table answers existence
+    ("inner", "hash", 2),       # the hash prep says no, the sorted prep counts
+    ("left_semi", "sort", 1),
+], ids=["hash-existence", "hash-then-sorted", "sorted"])
+def test_two_build_tables_of_one_node_keep_a_prep_each(
+        traced, how, strategy, preps_a_table):
+    """The partitions of a shuffled join run together under the mesh
+    exchange's map side, each with its own build table: probed alternately,
+    a single-entry cache would prep A, B, A, B."""
+    from spark_rapids_tpu.exec.joins import TpuShuffledHashJoinExec
+    session, events = traced
+    session(**{"spark.rapids.tpu.join.strategy": strategy})
+    node = TpuShuffledHashJoinExec(
+        Source(probe()), Source(duplicate_build()), ["pk"], ["bk"], how, None,
+        merge_keys=False, min_bucket=64)
+    (ha, _), (hb, _) = registered(duplicate_build()), registered(other_build())
+    batch = Source(probe()).batch
+    p = probe().to_pandas()
+    rows = {}
+    for h, build in ((ha, duplicate_build()), (hb, other_build())) * 2:
+        got = sum(int(t.num_rows) for t in node._probe_join(h, [batch]))
+        b = build.to_pandas()
+        want = p.pk.isin(b.bk).sum() if how == "left_semi" \
+            else len(p.merge(b, left_on="pk", right_on="bk"))
+        assert got == want
+        rows.setdefault(id(h), []).append(got)
+    assert len(events("join.prep")) == 2 * preps_a_table
+    assert len(node._preps) == 2
+
+    # closing A's build closes A's preps and leaves B's
+    of_a, of_b = prep_handles(node, ha), prep_handles(node, hb)
+    assert len(of_a) == len(of_b) == preps_a_table
+    node._close_preps(ha)
+    assert not any(live(h) for h in of_a) and all(live(h) for h in of_b)
+    assert list(node._preps) == [id(hb)]
+    list(node._probe_join(hb, [batch]))
+    assert len(events("join.prep")) == 2 * preps_a_table     # B: a hit
+    list(node._probe_join(ha, [batch]))
+    assert len(events("join.prep")) == 3 * preps_a_table     # A: prepped anew
+    for h in (ha, hb):
+        node._close_preps(h)
+        node._close_preps(h)       # closing twice is a no-op
+        h.close()
+    assert not node._preps
+
+
+def test_a_build_table_back_from_a_spill_replaces_its_entry(traced):
+    """The hit test is the table's identity: the same handle handing out
+    another table is a miss, and the entry's old prep is closed."""
+    from spark_rapids_tpu.exec.joins import TpuShuffledHashJoinExec
+    session, events = traced
+    session()
+    node = TpuShuffledHashJoinExec(
+        Source(probe()), Source(duplicate_build()), ["pk"], ["bk"],
+        "left_semi", None, merge_keys=False, min_bucket=64)
+    handle, table = registered(duplicate_build())
+    node._get_prep_hash(table, handle)
+    node._get_prep_hash(table, handle)
+    (first,) = prep_handles(node, handle)
+    assert len(events("join.prep")) == 1
+    restored = Source(duplicate_build()).batch    # equal rows, another table
+    node._get_prep_hash(restored, handle)
+    (second,) = prep_handles(node, handle)
+    assert len(events("join.prep")) == 2
+    assert not live(first) and live(second) and len(node._preps) == 1
+    node._close_preps(handle)
+    handle.close()
+
+
+class Partitions(Source):
+    """``n`` partitions, each the one batch."""
+
+    def __init__(self, table, n):
+        super().__init__(table)
+        self.num_partitions = n
+
+
+@pytest.mark.parametrize("how,preps", [("left_semi", 1), ("inner", 2)])
+def test_a_broadcast_join_preps_its_build_once_for_all_partitions(
+        traced, how, preps):
+    from spark_rapids_tpu.exec.joins import TpuBroadcastHashJoinExec
+    session, events = traced
+    session()
+    node = TpuBroadcastHashJoinExec(
+        Partitions(probe(), PROBE_BATCHES), Source(duplicate_build()),
+        ["pk"], ["bk"], how, None, merge_keys=False, min_bucket=64)
+    for pidx in range(PROBE_BATCHES):
+        assert sum(int(t.num_rows) for t in node.execute_columnar(pidx)) > 0
+    assert len(events("join.build")) == 1
+    assert len(events("join.prep")) == preps
+    # the broadcast lives as long as the node, and its preps with it
+    assert list(node._preps) == [id(node._bc_handle)]
+    node.release_spill_handles()
+
+
+def test_preps_of_two_build_tables_do_not_wait_on_each_other(
+        traced, monkeypatch):
+    """Both threads must be inside ``resolve_scalars`` at once: each waits
+    there for the other. Under one lock a node, held across the read, the
+    second never gets that far and the barrier breaks."""
+    import threading
+    from spark_rapids_tpu.exec import joins
+    session, events = traced
+    session()
+    node = joins.TpuShuffledHashJoinExec(
+        Source(probe()), Source(duplicate_build()), ["pk"], ["bk"],
+        "left_semi", None, merge_keys=False, min_bucket=64)
+    builds = [registered(duplicate_build()), registered(other_build())]
+    # warm: the prep program is compiled before the threads meet
+    node._get_prep_hash(builds[0][1], builds[0][0])
+    node._close_preps(builds[0][0])
+    barrier = threading.Barrier(2, timeout=30)
+    real = joins.resolve_scalars
+
+    def meeting(*values):
+        barrier.wait()
+        return real(*values)
+    monkeypatch.setattr(joins, "resolve_scalars", meeting)
+    results = {}
+
+    def prep(i):
+        handle, table = builds[i]
+        try:
+            results[i] = node._get_prep_hash(table, handle)[2]
+        except BaseException as e:      # the assertion below shows it
+            results[i] = e
+    threads = [threading.Thread(target=prep, args=(i,)) for i in range(2)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=60)
+        assert not t.is_alive()
+    assert results == {0: False, 1: False}, results
+    assert not barrier.broken
+    assert len(node._preps) == 2
+    for handle, _ in builds:
+        node._close_preps(handle)
+        handle.close()
+
+
+def test_prep_entries_under_many_threads(traced):
+    """More threads than cores, a short switch interval: every thread preps
+    its own build table, hits it, closes it, again and again. A lost entry
+    shows as a second prep of a table that is still open, a leaked one as
+    an entry left behind."""
+    import sys
+    import threading
+    from spark_rapids_tpu.exec.joins import TpuShuffledHashJoinExec
+    session, events = traced
+    session()
+    node = TpuShuffledHashJoinExec(
+        Source(probe()), Source(duplicate_build()), ["pk"], ["bk"],
+        "left_semi", None, merge_keys=False, min_bucket=64)
+    workers, rounds = 16, 4
+    builds = [registered(duplicate_build() if i % 2 else other_build())
+              for i in range(workers)]
+    failures = []
+
+    def work(i):
+        handle, table = builds[i]
+        try:
+            for _ in range(rounds):
+                first = node._get_prep_hash(table, handle)
+                again = node._get_prep_hash(table, handle)
+                assert first[0] is again[0]     # the second is a hit
+                node._close_preps(handle)
+        except BaseException as e:
+            failures.append(e)
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=work, args=(i,))
+                   for i in range(workers)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+            assert not t.is_alive()
+    finally:
+        sys.setswitchinterval(was)
+    assert not failures, failures
+    assert len(events("join.prep")) == workers * rounds
+    assert not node._preps
+    for handle, _ in builds:
+        handle.close()
 
 
 # ---- the slot table itself ---------------------------------------------------

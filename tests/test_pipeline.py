@@ -502,3 +502,183 @@ def test_pipeline_conf_snapshot():
     finally:
         sess.close()
         TpuSession({"spark.rapids.tpu.pipeline.enabled": True}).close()
+
+
+# ---------------------------------------------------------------------------
+# OrderedFanIn: many producers, one ordered consumer (the mesh exchange's
+# map side). Nothing here is timed by sleeping: producers meet at barriers
+# and events, and a test ends by joining the worker threads.
+# ---------------------------------------------------------------------------
+FAN_STAGE = "unit:fan"
+
+
+@pytest.fixture
+def pipelining(monkeypatch):
+    """Set the process-wide switch for one test: ``pipelining(False)``."""
+    def switch(enabled: bool):
+        monkeypatch.setitem(P._SETTINGS, "enabled", enabled)
+    switch(True)
+    return switch
+
+
+def _fan_threads():
+    return [t for t in threading.enumerate()
+            if t.name == f"tpu-prefetch:{FAN_STAGE}"]
+
+
+def _join_fan_threads():
+    for t in _fan_threads():
+        t.join(timeout=20)
+        assert not t.is_alive(), t.name
+
+
+def test_fan_in_starts_every_producer_before_the_first_item(pipelining):
+    """Each producer waits for all the others before it yields: a drain
+    that starts them one after another (``prefetched`` made in a loop
+    starts at its first ``next()``) breaks the barrier."""
+    n, depth = 4, 2
+    baseline = P.active_workers()
+    barrier = threading.Barrier(n, timeout=20)
+    produced = [0] * n
+
+    def maker(p):
+        def make():
+            barrier.wait()
+            for i in range(100):
+                produced[p] += 1
+                yield (p, i)
+        return make
+
+    fan = P.OrderedFanIn([maker(p) for p in range(n)], stage=FAN_STAGE,
+                         depth=depth)
+    assert fan.producers == n
+    assert not _fan_threads()           # nothing runs before the first next()
+    assert next(fan) == (0, 0)
+    assert len(_fan_threads()) == n
+    assert not barrier.broken
+    # the bound: a producer is at most its queue and the item in its hand
+    # ahead of the consumer
+    assert produced[0] <= 1 + depth + 1
+    assert all(c <= depth + 1 for c in produced[1:]), produced
+    fan.close()
+    _join_fan_threads()
+    assert P.active_workers() == baseline
+
+
+@pytest.mark.parametrize("finish", ["last-first", "first-first", "middle-out"])
+def test_fan_in_yields_in_producer_order_whoever_finishes_first(
+        pipelining, finish):
+    n, items = 4, 2
+    order = {"last-first": [3, 2, 1, 0], "first-first": [0, 1, 2, 3],
+             "middle-out": [2, 1, 3, 0]}[finish]
+    finished = [threading.Event() for _ in range(n)]
+    finish_log = []
+
+    def maker(p):
+        # a producer runs once the one before it in ``order`` has produced
+        # everything it has (the queue holds all of it, so it can)
+        before = order[order.index(p) - 1] if order.index(p) else None
+
+        def make():
+            if before is not None:
+                assert finished[before].wait(timeout=20)
+            for i in range(items):
+                yield (p, i)
+            finish_log.append(p)
+            finished[p].set()
+        return make
+
+    fan = P.OrderedFanIn([maker(p) for p in range(n)], stage=FAN_STAGE,
+                         depth=items)
+    got = list(fan)
+    assert got == [(p, i) for p in range(n) for i in range(items)]
+    assert finish_log == order
+    _join_fan_threads()
+
+
+def test_fan_in_failure_reraises_with_context_and_cancels_the_rest(
+        pipelining):
+    n = 4
+    baseline = P.active_workers()
+    closed = [False] * n
+
+    def maker(p):
+        def make():
+            try:
+                if p == 1:
+                    yield (p, 0)
+                    raise _Injected("partition 1 blew up")
+                i = 0
+                while p > 1 or i < 2:   # partitions 2 and 3 never end
+                    yield (p, i)
+                    i += 1
+            finally:
+                closed[p] = True
+        return make
+
+    fan = P.OrderedFanIn([maker(p) for p in range(n)], stage=FAN_STAGE,
+                         depth=1)
+    assert [next(fan) for _ in range(3)] == [(0, 0), (0, 1), (1, 0)]
+    with pytest.raises(_Injected, match="partition 1 blew up") as ei:
+        next(fan)
+    assert FAN_STAGE in getattr(ei.value, "pipeline_context", ())
+    _join_fan_threads()     # the endless producers were cancelled
+    assert closed == [True] * n
+    assert P.active_workers() == baseline
+    with pytest.raises(StopIteration):
+        next(fan)
+
+
+def test_fan_in_closed_early_cancels_every_producer(pipelining):
+    n = 3
+    baseline = P.active_workers()
+    snap_before = len(P.pipeline_snapshot()["queues"])
+    closed = [False] * n
+
+    def maker(p):
+        def make():
+            try:
+                i = 0
+                while True:
+                    yield (p, i)
+                    i += 1
+            finally:
+                closed[p] = True
+        return make
+
+    fan = P.OrderedFanIn([maker(p) for p in range(n)], stage=FAN_STAGE,
+                         depth=2)
+    assert next(fan) == (0, 0)
+    assert len(P.pipeline_snapshot()["queues"]) == snap_before + n
+    fan.close()
+    _join_fan_threads()
+    assert closed == [True] * n
+    assert P.active_workers() == baseline
+    assert len(P.pipeline_snapshot()["queues"]) == snap_before
+
+
+@pytest.mark.parametrize("enabled,makers,producers,threads", [
+    (False, 3, 1, 0),       # pipelining off: the serial chain, no worker
+    (True, 1, 1, 1),        # one input partition: ``prefetched``
+    (True, 0, 0, 0),
+], ids=["disabled", "one-partition", "no-partition"])
+def test_fan_in_serial_paths(pipelining, enabled, makers, producers,
+                             threads):
+    pipelining(enabled)
+    started = P.pipeline_stats()["workers_started"]
+    calling = threading.get_ident()
+    ran_on = []
+
+    def maker(p):
+        def make():
+            ran_on.append(threading.get_ident())
+            yield from ((p, i) for i in range(3))
+        return make
+
+    fan = P.OrderedFanIn([maker(p) for p in range(makers)], stage=FAN_STAGE)
+    assert fan.producers == producers
+    assert list(fan) == [(p, i) for p in range(makers) for i in range(3)]
+    assert P.pipeline_stats()["workers_started"] - started == threads
+    if not enabled:
+        assert ran_on == [calling] * makers
+    _join_fan_threads()
