@@ -30,6 +30,7 @@ import numpy as np
 from ..columnar import dtypes as dt
 from ..columnar.device import DeviceColumn, DeviceTable
 from ..conf import register_conf
+from ..expr.hashing import float_word_bits
 from ..plan.physical import AggSpec, PhysicalPlan
 from ..plan.schema import Field, Schema
 from ..utils import metrics as M
@@ -275,20 +276,10 @@ _BIG32 = np.int32(2**31 - 1)
 
 def _word_bits_u32(w: jax.Array) -> jax.Array:
     """Equality word -> u32 hash contribution: equal values give equal
-    bits. A float64 word goes by the bits of its float32 rounding and of
-    the float32 rounding of what that leaves (both functions of the value
-    alone): the TPU holds a float64 as a pair of float32 and its compiler
-    has no bitcast of one to 64 integer bits (a float64 group-by key —
-    TPC-H Q18's ``o_totalprice`` — failed to compile there)."""
+    bits (a float word by ``expr/hashing.py`` ``float_word_bits``, which
+    the hash partitioner shares)."""
     if jnp.issubdtype(w.dtype, jnp.floating):
-        hi = w.astype(jnp.float32)
-        u = jax.lax.bitcast_convert_type(hi, jnp.uint32)
-        if w.dtype == jnp.float32:
-            return u
-        lo = jnp.where(jnp.isfinite(hi), w - hi.astype(w.dtype),
-                       jnp.zeros_like(w)).astype(jnp.float32)
-        return u ^ (jax.lax.bitcast_convert_type(lo, jnp.uint32)
-                    * jnp.uint32(0x9E3779B1))
+        return float_word_bits(jnp, w)
     if w.dtype == jnp.bool_:
         return w.astype(jnp.uint32)
     u = w.astype(jnp.uint64)
@@ -703,7 +694,8 @@ class TpuHashAggregateExec(TpuExec):
                        for (_, op, _, out_dt) in self._columns_ops())
 
     def book_branch(self, num_groups: int, rows: int,
-                    trips: Optional[Sequence[int]] = None) -> None:
+                    trips: Optional[Sequence[int]] = None,
+                    on=None) -> None:
         """Span ``agg.dense`` / ``agg.scatter`` (``rows`` = the batch's
         capacity, ``groups``): the branch of ``grouped`` that reduced a
         batch of ``num_groups`` groups. Booked where the host already
@@ -715,7 +707,8 @@ class TpuHashAggregateExec(TpuExec):
         bucket-resolve loops, and those of them that ran over the whole
         batch) where the aggregate ran as a program of its own, which
         returns them; a fused stage returns its table alone. A batch
-        whose count the host never reads books neither."""
+        whose count the host never reads books neither. ``on`` (the batch)
+        gives the span its ``device``, as ``sync`` has it."""
         if not self.key_names:
             return
         dense = self._dense_ok() and num_groups <= FEW_GROUPS
@@ -723,7 +716,7 @@ class TpuHashAggregateExec(TpuExec):
         if trips is not None and not dense:
             args["rounds"], args["full_rounds"] = trips
         with get_tracer().span("agg.dense" if dense else "agg.scatter",
-                               "agg", **args):
+                               "agg", on=on, **args):
             pass
 
     def shrink_booked(self, fn, out: DeviceTable, rows: int
@@ -739,7 +732,7 @@ class TpuHashAggregateExec(TpuExec):
         if not self.key_names or out.capacity <= resolve_min_bucket(None):
             return shrink_to_fit(out), None
         n, *trips = resolve_scalars(out.num_rows, *fn.trips)
-        self.book_branch(n, rows, trips)
+        self.book_branch(n, rows, trips, on=out.row_mask)
         return shrink_to_fit(out, num_rows=n), n
 
     def host_batch_fn(self):
@@ -1156,7 +1149,8 @@ class TpuHashAggregateExec(TpuExec):
                     # compiles for one or two capacities, not per sum.
                     # span agg.merge: one step of the cascade, by the
                     # capacity it ran at and the state it leaves
-                    with get_tracer().span("agg.merge", "agg") as span:
+                    with get_tracer().span("agg.merge", "agg",
+                                           on=out.row_mask) as span:
                         with pending as prev:
                             both = concat_device_tables([prev, out])
                         span.note(rows=both.capacity)

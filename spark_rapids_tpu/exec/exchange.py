@@ -326,6 +326,10 @@ class TpuShuffleExchangeExec(TpuExec):
                     max_cnt = int(counts.max()) if active.any() else 1
                     quota = min(per_shard,
                                 bucket_rows(max_cnt, self.min_bucket))
+                    # what the all-to-all carries, padding included: 1 -
+                    # rows / slots is its padding share
+                    count.note(rows=int(counts.sum()), quota=quota,
+                               slots=n * n * quota)
 
                 # where the rows leave the device that produced them
                 with tracer.span("exchange.shard", "exchange",
@@ -507,7 +511,7 @@ class TpuLocalExchangeExec(TpuExec):
             for b, n in zip(batches, ns):
                 n = int(n)
                 if fused_agg is not None:
-                    fused_agg.book_branch(n, b.capacity)
+                    fused_agg.book_branch(n, b.capacity, on=b.row_mask)
                 if not n:
                     continue
                 with self.metrics.timed(M.OP_TIME):

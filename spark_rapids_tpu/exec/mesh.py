@@ -217,7 +217,7 @@ class TpuMeshStageExec(TpuExec):
                 rows = int(cnt)
                 if rows == 0 and in_rows[i] == 0:
                     continue
-                final_agg.book_branch(rows, t.capacity)
+                final_agg.book_branch(rows, t.capacity, on=t.row_mask)
                 out = shrink_to_fit(t, num_rows=rows)
                 per_part[i].append(out)
                 self.account_batch(rows)

@@ -75,6 +75,40 @@ def _normalize_float(xp, vals):
     return xp.where(vals != vals, xp.full_like(vals, float("nan")), vals)
 
 
+def float_word_bits(xp, w):
+    """Float word -> u32 hash contribution, the same function under numpy
+    and ``jax.numpy``: equal values give equal bits. A float64 goes by the
+    bits of its float32 rounding and of the float32 rounding of what that
+    leaves (both functions of the value alone): the TPU holds a float64 as
+    a pair of float32 and its compiler has no bitcast of one to 64 integer
+    bits (a float64 group-by key — TPC-H Q18's ``o_totalprice`` — failed to
+    compile there, and so did its hash exchange on the mesh). Callers under
+    numpy silence the overflow of a cast past float32's range."""
+    hi = w.astype(xp.float32)
+    u = hi.view(xp.uint32)
+    if w.dtype == xp.float32:
+        return u
+    lo = xp.where(xp.isfinite(hi), w - hi.astype(w.dtype),
+                  xp.zeros_like(w)).astype(xp.float32)
+    return u ^ (lo.view(xp.uint32) * xp.uint32(0x9E3779B1))
+
+
+def float_key_bits(xp, v):
+    """A float key's u32 by the group-by's equality (``exec/aggregate.py``
+    ``_key_code_words``): -0.0 hashes as +0.0 and every NaN alike, so a
+    hash partitioner sends all rows of one group to one partition.
+
+    Magnitudes whose split would hold a float32 subnormal hash as 0 (below
+    2^-126 for a float32 key, 2^-73 for a float64 one, whose remainder
+    keeps at most 29 bits): XLA flushes subnormals in a conversion, numpy
+    does not, and host and device must place a key alike. Equal keys still
+    hash alike; tiny distinct ones share a partition."""
+    tiny = 2.0 ** -126 if v.dtype == xp.float32 else 2.0 ** -73
+    nan = xp.isnan(v)
+    v = xp.where(xp.logical_or(nan, xp.abs(v) < tiny), xp.zeros_like(v), v)
+    return xp.where(nan, xp.uint32(0x7FC00000), float_word_bits(xp, v))
+
+
 def _view_u64(xp, x):
     if xp is np:
         return x.view(np.uint64)

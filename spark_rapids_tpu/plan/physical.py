@@ -26,6 +26,7 @@ from ..columnar.host import HostColumn, HostTable
 from ..expr.aggregates import AggregateFunction
 from ..expr.base import EvalContext, Expression
 from ..expr.functions import SortOrder
+from ..expr.hashing import float_key_bits
 from .schema import Field, Schema
 
 __all__ = [
@@ -567,8 +568,8 @@ def _murmur_fmix(vals: np.ndarray) -> np.ndarray:
     if vals.dtype == np.bool_:
         x = vals.astype(np.uint32)
     elif vals.dtype.kind == "f":
-        x = vals.astype(np.float64).view(np.uint64)
-        x = (x & np.uint64(0xFFFFFFFF)).astype(np.uint32) ^ (x >> np.uint64(32)).astype(np.uint32)
+        with np.errstate(over="ignore", invalid="ignore"):
+            x = float_key_bits(np, vals)
     else:
         x64 = vals.astype(np.int64).view(np.uint64)
         x = (x64 & np.uint64(0xFFFFFFFF)).astype(np.uint32) ^ (x64 >> np.uint64(32)).astype(np.uint32)

@@ -28,6 +28,7 @@ import numpy as np
 from ..columnar.device import DeviceTable, stable_counting_order
 from ..columnar.host import HostTable
 from ..conf import RapidsConf, SHUFFLE_COMPRESSION_CODEC, register_conf
+from ..expr.hashing import float_key_bits
 from ..memory.stores import SpillCorruptionError
 from ..utils import faults, movement
 from ..utils.tracing import get_tracer
@@ -172,9 +173,7 @@ def _column_key_hash(col) -> jax.Array:
     elif v.dtype == jnp.bool_:
         k = v.astype(jnp.uint32)
     elif jnp.issubdtype(v.dtype, jnp.floating):
-        bits = v.astype(jnp.float64).view(jnp.uint64)
-        k = (bits & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32) \
-            ^ (bits >> jnp.uint64(32)).astype(jnp.uint32)
+        k = float_key_bits(jnp, v)
     else:
         bits = v.astype(jnp.int64).view(jnp.uint64)
         k = (bits & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32) \

@@ -250,13 +250,15 @@ STRUCTURAL_SPANS = frozenset({"query", "task", "stage", "wait.pipeline",
 #: span arguments that are summed per phase beside ``calls`` / ``self_s`` /
 #: ``bytes`` (a boolean counts 0 / 1): row and group counts, the trip counts
 #: of the hash loops, scalars a ``sync`` read, what ``stage.stats`` walked,
-#: the producers an ``exchange.map`` started together.
+#: the producers an ``exchange.map`` started together, the slot quota and
+#: the slots of an ``exchange.count``.
 #: ``to_dict`` emits them flat where non-zero, so a reader that takes
 #: ``phases[name].get(field, 0)`` reads ``field="rounds"`` as it reads
 #: ``"calls"``
 COUNTED_ARGS = frozenset({"rows", "rows_out", "groups", "rounds",
                           "full_rounds", "parts", "scalars", "unique",
-                          "shards", "handles", "producers"})
+                          "shards", "handles", "producers", "quota",
+                          "slots"})
 
 #: queries whose phase totals ``Tracer.recent_queries`` remembers
 RECENT_QUERIES = 256

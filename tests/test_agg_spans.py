@@ -271,3 +271,27 @@ def test_rounds_count_the_trips_of_the_resolve_loop():
     groups, rounds, full_rounds = trips(np.arange(300) * 4)
     assert groups == 300 and rounds >= 2 and 1 <= full_rounds <= rounds
     assert trips([]) == (0, 0, 0)
+
+
+def test_the_aggregate_spans_name_their_device_on_a_mesh(traced):
+    """On a four-device mesh each partition's final aggregate runs on the
+    device the all-to-all sent its keys to: ``agg.scatter`` / ``agg.dense``
+    (and ``agg.merge``, a step of one partition's cascade) say which, as
+    ``sync`` does, so ``tools.trace gaps`` can put the aggregate's seconds
+    on each chip."""
+    from spark_rapids_tpu.parallel.mesh import data_parallel_mesh
+    session, events = traced
+    # AQE off: it would coalesce four small partitions into one
+    sess = session(**{"spark.rapids.tpu.shuffle.partitions": 4,
+                      "spark.rapids.tpu.aqe.enabled": False})
+    sess.attach_mesh(data_parallel_mesh(4))
+    rng = np.random.default_rng(9)
+    t = pa.table({"k": rng.integers(0, 5000, 20000),
+                  "v": rng.uniform(0, 1, 20000)})
+    got = sess.create_dataframe(t, num_partitions=2).group_by("k").agg(
+        fsum(col("v")).alias("s")).collect()
+    assert got.num_rows == len(set(t.column("k").to_pylist()))
+    aggs = events("agg.")
+    assert aggs and all("device" in e.args for e in aggs)
+    devices = {e.args["device"] for e in aggs}
+    assert devices <= {0, 1, 2, 3} and len(devices) == 4, devices

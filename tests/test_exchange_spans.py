@@ -388,3 +388,46 @@ def test_the_map_side_feeds_the_exchange_what_the_serial_drain_feeds_it(
     for name in ("dispatch", "sync", "d2h", "join.prep", "join.probe.expand",
                  "exchange.count", "exchange.shard", "exchange.split"):
         assert phases[name]["calls"] == serial[name]["calls"], name
+
+
+def test_exchange_count_notes_the_rows_and_slots_of_the_all_to_all(
+        monkeypatch):
+    """``exchange.count`` a chunk: ``rows`` its live rows, ``quota`` the
+    slots a source-destination pair handed to the all-to-all, ``slots`` = n
+    x n x quota, what the collective carries padding included; the phase
+    totals hold their sums a query (1 - rows / slots: the padding share)."""
+    from spark_rapids_tpu.shuffle import ici
+    handed = []
+    real = ici.ici_all_to_all_exchange
+
+    def seen(table, keys, mesh, axis="dp", quota=None, **kw):
+        handed.append((int(np.asarray(table.row_mask).sum()), quota))
+        return real(table, keys, mesh, axis, quota=quota, **kw)
+    monkeypatch.setattr(ici, "ici_all_to_all_exchange", seen)
+    tracer = get_tracer()
+    was = tracer.enabled
+    tracer.enabled = True
+    tracer.clear()
+    sess = session(**{"spark.rapids.tpu.mesh.stageExecution.enabled": False})
+    try:
+        t = table(seed=7, rows=900)
+        got = group_by(sess, t).collect().to_pandas()
+        phases = sess.last_query_phases()["phases"]
+        counts = [e.args for e in tracer.events()
+                  if e.name == "exchange.count"]
+    finally:
+        tracer.enabled = was
+        tracer.clear()
+        sess.close()
+    assert len(got) == len(set(t.column("k").to_pylist()))
+    assert len(counts) == len(handed) >= 1
+    for args, (live, quota) in zip(counts, handed):
+        assert (args["rows"], args["quota"], args["slots"]) \
+            == (live, quota, N * N * quota)
+        assert args["rows"] <= args["slots"]
+    for field, i in (("rows", 0), ("quota", 1)):
+        assert phases["exchange.count"][field] == sum(h[i] for h in handed)
+    assert phases["exchange.count"]["slots"] \
+        == N * N * sum(q for _, q in handed)
+    # the partial states cross, one a key a map partition
+    assert 0 < sum(h[0] for h in handed) <= 3 * 40

@@ -119,13 +119,154 @@ def test_manager_write_read_roundtrip():
     assert all(len(parts) == 1 for parts in seen_keys.values())
 
 
-def test_device_partitioner_matches_host():
+def _float_keys(dtype, n=4096, seed=3):
+    """Cent values up to 560,000.00 (``o_totalprice``'s domain), random bit
+    patterns, and the edge values: subnormals, +-inf, -0.0 / +0.0 and NaNs
+    with other payloads; a few nulls."""
+    rng = np.random.default_rng(seed)
+    width = np.dtype(dtype).itemsize * 8
+    bits = rng.integers(0, 2**width - 1, n // 2, dtype=f"u{width // 8}")
+    edge = np.array([0.0, -0.0, np.inf, -np.inf, 5e-324, -1e-310, 1e-45,
+                     1.4e-45, 3e-39, 1e-35, 1e-22, 1e38, 3.5e38, 1e300])
+    nans = np.array([0x7FF8000000000000, 0xFFF8000000000001,
+                     0x7FF0000000000123], np.uint64).view(np.float64)
+    with np.errstate(over="ignore", invalid="ignore"):
+        vals = np.concatenate([
+            rng.integers(0, 56_000_000, n // 2) / 100.0, edge, nans
+        ]).astype(dtype)
+    vals = np.concatenate([vals, bits.view(dtype)])
+    if dtype == np.float32:     # NaNs of other float32 payloads
+        vals = np.concatenate([vals, np.array(
+            [0x7FC00000, 0xFFC00001, 0x7F800123], np.uint32).view(
+                np.float32)])
+    valid = np.ones(len(vals), bool)
+    valid[rng.integers(0, len(vals), 16)] = False
+    return pa.array(vals, mask=~valid)
+
+
+def _keyed_table(dtype):
+    f = _float_keys(dtype)
+    n = len(f)
+    rng = np.random.default_rng(4)
+    return HostTable.from_arrow(pa.table({
+        "f": f, "k": pa.array(rng.integers(-2**40, 2**40, n)),
+        "d": pa.array(rng.integers(8000, 10600, n).astype(np.int32),
+                      pa.int32()).cast(pa.date32())}))
+
+
+@pytest.mark.parametrize("case,keys", [
+    ("int", ["k"]),
+    ("float64", ["f"]), ("float32", ["f"]),
+    ("float64+int+date", ["d", "f", "k"]),
+    ("float32+int+date", ["k", "f", "d"]),
+])
+def test_device_partitioner_matches_host(case, keys):
+    """Fixed-width keys land on the same partition in both engines, bit for
+    bit, float keys too (their hash goes through float32 words the TPU can
+    bitcast: ``expr/hashing.py`` ``float_key_bits``)."""
     from spark_rapids_tpu.plan.physical import murmur_hash_columns
-    t = _host_table(128, seed=2)
+    if case == "int":
+        t = _host_table(128, seed=2)
+    else:
+        t = _keyed_table(np.float32 if "32" in case else np.float64)
     dt_ = DeviceTable.from_host(t, min_bucket=8)
-    dev = np.asarray(device_partition_ids(dt_, ["k"], 8))[:128]
-    host = (murmur_hash_columns(t, ["k"]) % np.uint32(8)).astype(np.int32)
-    np.testing.assert_array_equal(dev, host)
+    for n in (4, 8):
+        dev = np.asarray(device_partition_ids(dt_, keys, n))[:t.num_rows]
+        host = (murmur_hash_columns(t, keys) % np.uint32(n)).astype(
+            np.int32)
+        np.testing.assert_array_equal(dev, host)
+
+
+@pytest.mark.parametrize("dtype", [np.float64, np.float32])
+@pytest.mark.parametrize("engine", ["device", "host"])
+def test_equal_float_keys_share_a_partition(dtype, engine):
+    """By the group-by's equality: -0.0 with +0.0, every NaN with every
+    other whatever its sign and payload."""
+    from spark_rapids_tpu.plan.physical import murmur_hash_columns
+    nans = np.array([0x7FF8000000000000, 0xFFF8000000000001,
+                     0x7FF0000000000123], np.uint64).view(np.float64)
+    with np.errstate(invalid="ignore"):
+        vals = np.concatenate([[0.0, -0.0], nans]).astype(dtype)
+    t = HostTable.from_arrow(pa.table({"f": vals}))
+    for n in (2, 4, 8, 64):
+        if engine == "device":
+            got = np.asarray(device_partition_ids(
+                DeviceTable.from_host(t, min_bucket=8), ["f"], n))[:5]
+        else:
+            got = murmur_hash_columns(t, ["f"]) % np.uint32(n)
+        assert got[0] == got[1] and got[2] == got[3] == got[4], (n, got)
+
+
+#: partition ids the parent of PR 38 gave integer keys: the change touched
+#: the float branch only, so an integer-keyed exchange (every exchange of
+#: ``sf1-mesh4.q3``) moves the same rows to the same devices as before
+_INT_KEYS = [0, 1, -1, 7, 2**40 + 3, 1_500_000, 5_999_997, -2**63,
+             2**63 - 1, 123456789]
+_INT32_KEYS = [8035, 10591, 0, -1, 9000, 9131, 8400, 10000, 2**31 - 1,
+               -2**31]
+
+
+@pytest.mark.parametrize("keys,n,want", [
+    (["k"], 4, [2, 1, 2, 2, 2, 1, 1, 2, 2, 0]),
+    (["k"], 8, [6, 5, 6, 2, 2, 1, 5, 6, 6, 4]),
+    (["d"], 4, [2, 1, 2, 2, 2, 0, 3, 0, 2, 2]),
+    (["d"], 8, [2, 5, 6, 6, 2, 0, 7, 0, 6, 6]),
+    (["k", "d"], 4, [2, 2, 2, 2, 2, 3, 0, 0, 2, 0]),
+    (["k", "d"], 8, [6, 6, 2, 6, 2, 7, 4, 4, 2, 0]),
+])
+def test_integer_keys_keep_the_parents_placement(keys, n, want):
+    from spark_rapids_tpu.plan.physical import murmur_hash_columns
+    t = HostTable.from_arrow(pa.table({
+        "k": pa.array(_INT_KEYS, pa.int64()),
+        "d": pa.array(_INT32_KEYS, pa.int32())}))
+    dev = device_partition_ids(DeviceTable.from_host(t, min_bucket=8),
+                               keys, n)
+    assert np.asarray(dev)[:len(want)].tolist() == want
+    assert (murmur_hash_columns(t, keys) % np.uint32(n)).tolist() == want
+
+
+@pytest.fixture(scope="module")
+def float_key_frame():
+    """A float key holding both zeros and NaNs of several payloads, with
+    other keys between them."""
+    nans = np.array([0x7FF8000000000000, 0xFFF8000000000001,
+                     0x7FF0000000000123], np.uint64).view(np.float64)
+    rng = np.random.default_rng(5)
+    vals = np.concatenate([[0.0, -0.0] * 20, np.tile(nans, 10),
+                           rng.integers(1, 50, 60) / 4.0])
+    rng.shuffle(vals)
+    return pa.table({"f": vals, "v": np.ones(len(vals))})
+
+
+@pytest.mark.parametrize("mesh", [False, True], ids=["one-device", "mesh4"])
+def test_a_mesh_group_by_gives_one_group_for_both_zeros_and_all_nans(
+        float_key_frame, mesh):
+    """On the mesh the partial states are exchanged by the key's hash: a
+    group split over two devices would come out twice from the final
+    aggregate."""
+    from spark_rapids_tpu.expr.functions import col, sum as fsum
+    from spark_rapids_tpu.session import TpuSession
+    sess = TpuSession({"spark.rapids.tpu.shuffle.partitions": 4,
+                       "spark.rapids.sql.test.enabled": True})
+    try:
+        if mesh:
+            from spark_rapids_tpu.parallel.mesh import data_parallel_mesh
+            sess.attach_mesh(data_parallel_mesh(4))
+        df = sess.create_dataframe(float_key_frame, num_partitions=2)
+        got = df.group_by("f").agg(fsum(col("v")).alias("n")) \
+            .collect().to_pandas()
+        phases = sess.last_query_phases()["phases"]
+    finally:
+        sess.close()
+    assert ("exchange.count" in phases) == mesh    # the ICI exchange
+    zero = got[got.f == 0.0]
+    nan = got[got.f.isna()]
+    assert len(zero) == 1 and zero.n.iloc[0] == 40
+    assert len(nan) == 1 and nan.n.iloc[0] == 30
+    assert got.n.sum() == len(float_key_frame)
+    assert len(got) == 2 + len(set(
+        float_key_frame.column("f").to_numpy()[
+            ~np.isnan(float_key_frame.column("f").to_numpy())]) - {0.0})
 
 
 def test_heartbeats():

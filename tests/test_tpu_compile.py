@@ -15,6 +15,8 @@ The topology is described inside a module-scoped fixture, so every xdist
 worker collects the same tests and only the worker that runs this file loads
 libtpu. Keep every such test in THIS file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -295,3 +297,45 @@ def test_ici_exchange_on_four_chips(topo, sess, rng):
     per_device = compiled.memory_analysis()
     assert per_device.argument_size_in_bytes \
         < sum(x.nbytes for x in jax.tree_util.tree_leaves(table)) // 2
+
+
+#: a float64 bitcast to 64 integer bits: the TPU's X64 rewriter has none
+_F64_TO_INT64 = re.compile(r"= [su]64\[[^\]]*\]\S* bitcast-convert\(f64")
+
+
+@pytest.mark.parametrize("program", ["partition_ids", "all_to_all"])
+def test_q18s_final_keys_hash_exchange_on_four_chips(topo, rng, program):
+    """TPC-H Q18's last group-by on the mesh exchanges its partial states
+    by five keys: the string ``c_name``, two int64 keys, the int32 date and
+    the float64 ``o_totalprice``. The exchange hashed a float64 key through
+    a 64-bit bitcast until PR 38 (UNIMPLEMENTED on the chip)."""
+    from spark_rapids_tpu.columnar.device import DeviceTable
+    from spark_rapids_tpu.columnar.host import HostTable
+    from spark_rapids_tpu.exec.exchange import _pid_program
+    from spark_rapids_tpu.shuffle.ici import exchange_program
+    n = 4 * ROWS
+    t = pa.table({
+        "c_name": [f"Customer#{i:09d}" for i in rng.integers(1, 150_000, n)],
+        "c_custkey": rng.integers(1, 150_000, n),
+        "o_orderkey": rng.integers(1, 6_000_000, n),
+        "o_orderdate": pa.array(rng.integers(8_000, 10_600, n).astype(
+            np.int32)).cast(pa.date32()),
+        "o_totalprice": rng.integers(85_000, 56_000_000, n) / 100.0,
+        "sum_qty": rng.integers(1, 51, n).astype(np.float64)})
+    table = DeviceTable.from_host(HostTable.from_arrow(t), n)
+    keys = ["c_name", "c_custkey", "o_orderkey", "o_orderdate",
+            "o_totalprice"]
+    mesh = Mesh(np.array(topo.devices).reshape(-1), ("dp",))
+    if program == "partition_ids":
+        fn = jax.jit(_pid_program(keys, 4))
+        args = _shapes((table,), SingleDeviceSharding(topo.devices[0]))
+    else:
+        fn = exchange_program(table.columns, table.names, keys, mesh, "dp",
+                              quota=ROWS // 2)
+        args = _shapes((table.columns, table.row_mask),
+                       NamedSharding(mesh, P("dp")))
+    lowered = fn.lower(*args)
+    assert not _F64_TO_INT64.search(lowered.as_text(dialect="hlo"))
+    compiled = lowered.compile()
+    if program == "all_to_all":
+        assert "all-to-all" in compiled.as_text()

@@ -274,6 +274,11 @@ def test_under_a_profiler_session_the_spans_are_in_the_xplane(
 
 
 # -- which device: sync / d2h / dispatch ---------------------------------------
+#: the spans given the arrays they wait for or work on (``span(on=...)``)
+ON_A_DEVICE = ("sync", "d2h", "dispatch", "agg.dense", "agg.scatter",
+               "agg.merge")
+
+
 def test_the_device_is_worked_out_only_while_someone_records_it(
         sess, lineitem_dir, monkeypatch, tmp_path):
     tracer = get_tracer()
@@ -284,7 +289,7 @@ def test_the_device_is_worked_out_only_while_someone_records_it(
     monkeypatch.setattr(tracing, "device_of",
                         lambda on: (asked.append(on), real(on))[1])
     phases = run_query(sess, lineitem_dir, "q1")["phases"]
-    spans = sum(phases[n]["calls"] for n in ("sync", "d2h", "dispatch"))
+    spans = sum(phases[n]["calls"] for n in ON_A_DEVICE if n in phases)
     # profiler off, ring off: nobody looks at what a span is ``on``
     assert asked == [] and spans > 10
     df = tpch.QUERIES["q1"]({"lineitem": sess.read_parquet(lineitem_dir)})
@@ -295,7 +300,7 @@ def test_the_device_is_worked_out_only_while_someone_records_it(
         tracer.enabled = False
         events = tracer.events()
         tracer.clear()
-    said = [e for e in events if e.name in ("sync", "d2h", "dispatch")]
+    said = [e for e in events if e.name in ON_A_DEVICE]
     assert len(said) == len(asked) == spans
     # one CPU device holds every array of the query; a program whose
     # arguments hold no array says -1, as one over a mesh would
@@ -318,7 +323,7 @@ def test_the_device_is_worked_out_only_while_someone_records_it(
     stats = [dict(e.stats) for plane in profile.planes
              if plane.name == "/host:CPU" for line in plane.lines
              for e in line.events
-             if e.name in ("srt.sync", "srt.d2h", "srt.dispatch")]
+             if e.name[len("srt."):] in ON_A_DEVICE]
     assert len(stats) == spans and all("device" in st for st in stats)
 
 
