@@ -37,7 +37,8 @@ from ..utils import metrics as M
 from ..utils.tracing import get_tracer
 from .base import TpuExec
 
-__all__ = ["TpuHashAggregateExec", "fused_grouped_aggregate"]
+__all__ = ["TpuHashAggregateExec", "fused_grouped_aggregate",
+           "passed_through", "SKIP_SHARE"]
 
 _BIG = np.int64(2**62)
 
@@ -272,6 +273,26 @@ _COLLECT_OPS = frozenset(
 #: chunks and one step, 10 x 2^20 (PERF.md section 6, PR 34)
 _CHUNK_ROWS = 1 << 22
 _BIG32 = np.int32(2**31 - 1)
+
+#: a grouped partial aggregate is skipped for the rest of a partition when
+#: the first batch it reduced kept more than 1/``SKIP_SHARE`` of that
+#: batch's live rows as groups (``skips``; Spark's skipPartialAggregate).
+#: The partial costs a whole grouped aggregate at its input's capacity, and
+#: what it buys is a smaller state downstream, where every program (the
+#: exchange's compaction and count, the final aggregate's chunks and merge
+#: steps) costs by the power-of-two bucket of the live rows. Keeping more
+#: than half of the rows leaves the state's bucket at least half the
+#: input's, and at a full batch the same one: TPC-H Q18's ``l_orderkey``
+#: keeps 754 k of 1,048,576 rows, and its partial, 3.0 s a query on one
+#: v5e, bought no smaller chunk in the final aggregate (PERF.md sections 5
+#: and 6, PR 38 and PR 39). Derived, not tuned: a knob would stand for a
+#: property of the data, which the first batch shows.
+SKIP_SHARE = 2
+#: update ops whose partial state of ONE row is a row-wise projection of
+#: the row (``passthrough_fn``): the value, its square or the row's
+#: contribution, valid where the row contributes
+_PASSTHROUGH_OPS = frozenset(
+    {"sum", "sumsq", "count", "min", "max", "first", "last"})
 
 
 def _word_bits_u32(w: jax.Array) -> jax.Array:
@@ -693,6 +714,71 @@ class TpuHashAggregateExec(TpuExec):
         return not any(op in _COLLECT_OPS or dt.is_d128(out_dt)
                        for (_, op, _, out_dt) in self._columns_ops())
 
+    def can_pass_through(self) -> bool:
+        """Whether this grouped partial may pass batches through as
+        one-row states (``passthrough_fn``): static, from the plan, like
+        ``_dense_ok``. Every op's state must be a row-wise projection of a
+        fixed-width value: no collect, no decimal128 state (its overflow
+        flag), no string or nested ``min`` / ``max``."""
+        return self.mode == "partial" and bool(self.key_names) and all(
+            op in _PASSTHROUGH_OPS and not dt.is_d128(out_dt)
+            and not isinstance(out_dt, (dt.StringType, dt.BinaryType,
+                                        dt.ArrayType, dt.StructType,
+                                        dt.MapType))
+            for (_, op, _, out_dt) in self._columns_ops())
+
+    @staticmethod
+    def skips(groups: int, rows: int) -> bool:
+        """The rule: a first batch of ``rows`` live rows reduced to
+        ``groups`` groups says the partial does not reduce (``SKIP_SHARE``)."""
+        return groups * SKIP_SHARE > rows
+
+    def passthrough_fn(self) -> Callable[[DeviceTable], DeviceTable]:
+        """Every row its own partial state, at the input's capacity: keys
+        as they are, ``sum`` / ``sumsq`` the value (squared) in the state's
+        type, ``count`` the row's contribution, ``min`` / ``max`` /
+        ``first`` / ``last`` the value, each valid where the row
+        contributes; ``row_mask`` and ``num_rows`` the input's. The merge
+        ops of the final aggregate read these rows as they read the states
+        of the partial (NaN order included)."""
+        cols_ops = self._columns_ops()
+        key_names = self.key_names
+        out_names = tuple(self.schema.names)
+
+        def run(table: DeviceTable) -> DeviceTable:
+            mask = table.row_mask
+            out_cols = [table.column(k) for k in key_names]
+            for in_col, op, _, out_dt in cols_ops:
+                col = table.column(in_col)
+                contrib = mask if col.all_valid \
+                    else jnp.logical_and(col.validity, mask)
+                out_dtype = jnp.dtype(
+                    np.bool_ if isinstance(out_dt, dt.BooleanType)
+                    else out_dt.np_dtype())
+                if op == "count":
+                    out_cols.append(DeviceColumn(
+                        contrib.astype(out_dtype), mask, out_dt, None))
+                    continue
+                x = col.data.astype(out_dtype)
+                if op in ("sum", "sumsq"):
+                    x = jnp.where(contrib, x * x if op == "sumsq" else x,
+                                  jnp.zeros_like(x))
+                out_cols.append(DeviceColumn(x, contrib, out_dt, None))
+            return DeviceTable(tuple(out_cols), mask, table.num_rows,
+                               out_names)
+        return run
+
+    def book_skip(self, table: DeviceTable) -> DeviceTable:
+        """Span ``agg.skip`` (``rows`` = the capacity) for a batch that
+        passed through, booked where the skip was decided, with no sync:
+        the batch is marked so that no consumer books a branch of
+        ``grouped`` for it (``passed_through``)."""
+        table._tpu_passed_through = True
+        with get_tracer().span("agg.skip", "agg", on=table.row_mask,
+                               rows=table.capacity):
+            pass
+        return table
+
     def book_branch(self, num_groups: int, rows: int,
                     trips: Optional[Sequence[int]] = None,
                     on=None) -> None:
@@ -719,21 +805,26 @@ class TpuHashAggregateExec(TpuExec):
                                "agg", on=on, **args):
             pass
 
-    def shrink_booked(self, fn, out: DeviceTable, rows: int
-                      ) -> Tuple[DeviceTable, Optional[int]]:
+    def shrink_booked(self, fn, out: DeviceTable, rows: int,
+                      live_in=None
+                      ) -> Tuple[DeviceTable, Optional[int], bool]:
         """``shrink_to_fit`` of a batch ``fn`` (a ``_canon_fn`` of this
         node) aggregated from ``rows`` rows of capacity, its branch
         booked: the resolve loops' trip counts ride in the transfer that
-        reads the group count for the shrink. -> (the shrunk batch, its
-        group count or None where none is read: no keys, or a batch
-        already at the minimum bucket)."""
+        reads the group count for the shrink, and so does ``live_in``,
+        the input's live row count, where given. -> (the shrunk batch,
+        its group count or None where none is read: no keys, or a batch
+        already at the minimum bucket; whether ``skips`` says the rest
+        of the partition passes through, False without ``live_in``)."""
         from ..columnar.device import (resolve_min_bucket, resolve_scalars,
                                        shrink_to_fit)
         if not self.key_names or out.capacity <= resolve_min_bucket(None):
-            return shrink_to_fit(out), None
-        n, *trips = resolve_scalars(out.num_rows, *fn.trips)
+            return shrink_to_fit(out), None, False
+        extra = () if live_in is None else (live_in,)
+        n, *trips = resolve_scalars(out.num_rows, *extra, *fn.trips)
+        skip = live_in is not None and self.skips(n, trips.pop(0))
         self.book_branch(n, rows, trips, on=out.row_mask)
-        return shrink_to_fit(out, num_rows=n), n
+        return shrink_to_fit(out, num_rows=n), n, skip
 
     def host_batch_fn(self):
         # host-engine partial aggregation over one downloaded batch — the
@@ -1075,6 +1166,17 @@ class TpuHashAggregateExec(TpuExec):
         fn.trips = None
         return fn
 
+    def _canon_passthrough_fn(self) -> Callable[[DeviceTable], DeviceTable]:
+        """``passthrough_fn`` as a program of its own, schema-erased as
+        ``_canon_fn`` is: the batches an unfused partial passes through."""
+        from ..utils.compile_cache import cached_jit
+        canon, ckey = self._canon_exec()
+        out_names = tuple(self.schema.names)
+        base = cached_jit(ckey + "|pass", canon.passthrough_fn,
+                          name="agg_passthrough")
+        # srtpu: retry-ok(run only by _passed_through, under with_retry_split) srtpu: degrade-ok(run only by _passed_through, under quarantine_on_failure as the chunks are)
+        return lambda batch: base(batch.canonical()).with_names(out_names)
+
     def execute_columnar(self, pidx: int) -> Iterator[DeviceTable]:
         from ..columnar.device import concat_device_tables
         from ..memory.catalog import SpillPriorities, get_catalog
@@ -1100,6 +1202,11 @@ class TpuHashAggregateExec(TpuExec):
         # mergeable states. A final-mode aggregate emits finished values
         # (e.g. avg = sum/count), which no merge pass can recombine.
         splitter = split_device_rows if self.mode == "partial" else None
+        # a grouped partial decides from its first chunk whether the rest
+        # of the partition passes through (``skips``), as a fused one does
+        # from its first batch (exec/wholestage.py)
+        deciding = self.can_pass_through()
+        child_batches = iter(self.child_device_batches(pidx))
 
         def chunked_inputs():
             """Stage child batches and aggregate one CONCAT per
@@ -1111,7 +1218,7 @@ class TpuHashAggregateExec(TpuExec):
             below."""
             staged: List[DeviceTable] = []
             cap = 0
-            for b in self.child_device_batches(pidx):
+            for b in child_batches:
                 staged.append(b)
                 cap += b.capacity
                 if cap >= _CHUNK_ROWS:
@@ -1132,10 +1239,20 @@ class TpuHashAggregateExec(TpuExec):
                         self.metrics.timed(M.AGG_TIME):
                     # shrink to the group bucket: the running state must
                     # not scale with input capacity (out-of-core bound)
-                    out, _ = self.shrink_booked(fn, with_retry_split(
+                    out, _, skip = self.shrink_booked(fn, with_retry_split(
                         fn, batch, splitter=splitter, combiner=agg_combine,
                         scope="partial-agg", context=self.node_desc()),
-                        batch.capacity)
+                        batch.capacity,
+                        batch.num_rows if deciding else None)
+                deciding = False
+                if skip:
+                    # the first chunk's state, then every batch left of
+                    # the partition as one-row states: the downstream
+                    # merge reduces them as it reduces partial states
+                    self.account_batch()
+                    yield out
+                    yield from self._passed_through(child_batches)
+                    return
                 if pending is None:
                     pending = catalog.register(
                         out, SpillPriorities.ACTIVE_ON_DECK)
@@ -1159,7 +1276,7 @@ class TpuHashAggregateExec(TpuExec):
                         # spill-only retry: the concat'd pair is already
                         # at the group bucket — there is nothing useful
                         # to halve
-                        state, groups = merged.shrink_booked(
+                        state, groups, _ = merged.shrink_booked(
                             merge_fn, with_retry(
                                 merge_fn, both, scope="agg-merge",
                                 context=self.node_desc()), both.capacity)
@@ -1179,6 +1296,22 @@ class TpuHashAggregateExec(TpuExec):
         finally:
             if pending is not None:
                 pending.close()
+
+    def _passed_through(self, batches) -> Iterator[DeviceTable]:
+        """The batches left of a partition once the skip is decided, each
+        through the pass-through program under the partial's OOM ladder
+        (its halves concatenate back into the same rows)."""
+        from ..memory.retry import split_device_rows, with_retry_split
+        from .fallback import quarantine_on_failure
+        pass_fn = self._canon_passthrough_fn()
+        for b in batches:
+            with quarantine_on_failure(self), \
+                    self.metrics.timed(M.AGG_TIME):
+                out = with_retry_split(pass_fn, b, splitter=split_device_rows,
+                                       scope="partial-agg",
+                                       context=self.node_desc())
+            self.account_batch()
+            yield self.book_skip(out)
 
     def _merged_exec(self) -> "TpuHashAggregateExec":
         """Exec that re-aggregates concatenated partial outputs."""
@@ -1220,6 +1353,12 @@ def fused_grouped_aggregate(node) -> "Optional[TpuHashAggregateExec]":
     if isinstance(top, TpuHashAggregateExec) and top.key_names:
         return top
     return None
+
+
+def passed_through(table: DeviceTable) -> bool:
+    """Whether a grouped partial passed ``table`` through as one-row states
+    (``TpuHashAggregateExec.book_skip``): no branch of ``grouped`` ran."""
+    return getattr(table, "_tpu_passed_through", False)
 
 
 def _empty_device_table(schema: Schema, cap: int) -> DeviceTable:

@@ -485,9 +485,10 @@ class TpuLocalExchangeExec(TpuExec):
         from ..parallel.pipeline import parallel_map
         catalog = get_catalog()
         from ..columnar.device import resolve_scalars, shrink_to_fit
-        from .aggregate import fused_grouped_aggregate
+        from .aggregate import fused_grouped_aggregate, passed_through
         # a fused partial aggregate's batches arrive unshrunk: their row
         # count, read below, is the group count its branch was picked by
+        # (a batch it passed through ran no branch: it booked agg.skip)
         fused_agg = fused_grouped_aggregate(self.child)
         # node context is thread-local; drain() runs on pool workers, so
         # capture the query identity here (the materializing thread holds
@@ -510,7 +511,7 @@ class TpuLocalExchangeExec(TpuExec):
             ns = resolve_scalars(*[b.num_rows for b in batches])
             for b, n in zip(batches, ns):
                 n = int(n)
-                if fused_agg is not None:
+                if fused_agg is not None and not passed_through(b):
                     fused_agg.book_branch(n, b.capacity, on=b.row_mask)
                 if not n:
                     continue

@@ -123,58 +123,100 @@ class TpuWholeStageExec(TpuExec):
             return table
         return run
 
+    def passthrough_fn(self):
+        """The chain with its grouped partial aggregate passing every row
+        through as its own state (``TpuHashAggregateExec.passthrough_fn``),
+        or None where the aggregate cannot (``can_pass_through``)."""
+        from .aggregate import fused_grouped_aggregate
+        agg = fused_grouped_aggregate(self)
+        if agg is None or not agg.can_pass_through():
+            return None
+        below, top = _scoped_chain(self.chain[:-1]), agg.passthrough_fn()
+
+        def run(table: DeviceTable) -> DeviceTable:
+            table = below(table)
+            with jax.named_scope("agg_passthrough"):
+                return top(table)
+        return run
+
     def execute_columnar(self, pidx: int) -> Iterator[DeviceTable]:
+        from ..columnar.device import resolve_scalars
         from ..memory.retry import split_device_rows, with_retry_split
         from ..parallel.pipeline import maybe_prefetched, stage_name
         from ..utils.compile_cache import cached_jit
+        from .aggregate import fused_grouped_aggregate
+        from .fallback import with_host_fallback
         from .transitions import take_exclusive
         chain = self.chain
-
-        def build():
-            return _scoped_chain(chain)
-
         sig = self.plan_signature()
-        fused = cached_jit(sig, build, name="stage")
-        donating = cached_jit(sig + "|donate", build, name="stage",
-                              donate_argnums=(0,)) \
-            if self.donate_inputs else None
+
+        def runner(key, build):
+            """One program of the stage under the degradation boundary:
+            the OOM ladder escalates INSIDE (spill -> retry -> split); when
+            it terminates — or the failure is a classified non-retryable
+            XLA error — the boundary re-runs the batch through the
+            composed host chain instead of failing the query
+            (exec/fallback.py). The chain is row-wise, so halves of the
+            input concat back into the same output; split halves lose the
+            exclusive flag and dispatch through the non-donating entry."""
+            plain = cached_jit(key, build, name="stage")
+            donating = cached_jit(key + "|donate", build, name="stage",
+                                  donate_argnums=(0,)) \
+                if self.donate_inputs else None
+
+            def dispatch(b: DeviceTable) -> DeviceTable:
+                if donating is not None and take_exclusive(b):
+                    # nbytes BEFORE the call: donated buffers may be dead
+                    # the moment dispatch returns
+                    self.metrics.add(M.DONATED_BYTES, b.nbytes())
+                    return donating(b)
+                return plain(b)
+            return with_host_fallback(
+                self,
+                lambda b: with_retry_split(dispatch, b,
+                                           splitter=split_device_rows,
+                                           scope="wholestage",
+                                           context=self.node_name()),
+                self.host_batch_fn())
+
+        run = runner(sig, lambda: _scoped_chain(chain))
+        # a chain that ends in a grouped partial aggregate decides from a
+        # partition's first batch whether the partial reduces: where its
+        # groups are more than 1/SKIP_SHARE of the batch's live rows, the
+        # rest of the partition runs the pass-through program (every row
+        # its own state). The counts are read in one sync, once the next
+        # batch is in hand (a partition of one batch reads nothing)
+        agg = fused_grouped_aggregate(self)
+        deciding = agg is not None and agg.can_pass_through()
+        passing = runner(sig + "|pass", self.passthrough_fn) \
+            if deciding else None
         # stage boundary: the source (typically the upload transition)
         # produces the NEXT batch on a prefetch worker while XLA runs the
         # current one (parallel/pipeline.py)
-        source = maybe_prefetched(
+        source = iter(maybe_prefetched(
             lambda: self.source.execute_columnar(pidx),
             stage=f"source:{stage_name(self.source)}",
-            registry=self.metrics)
-        def dispatch(b: DeviceTable) -> DeviceTable:
-            if donating is not None and take_exclusive(b):
-                # nbytes BEFORE the call: donated buffers may be dead
-                # the moment dispatch returns
-                self.metrics.add(M.DONATED_BYTES, b.nbytes())
-                return donating(b)
-            return fused(b)
-
-        # degradation boundary: the OOM ladder escalates INSIDE (spill →
-        # retry → split); when it terminates — or the failure is a
-        # classified non-retryable XLA error — the boundary re-runs the
-        # batch through the composed host chain instead of failing the
-        # query (exec/fallback.py)
-        from .fallback import with_host_fallback
-        run = with_host_fallback(
-            self,
-            lambda b: with_retry_split(dispatch, b,
-                                       splitter=split_device_rows,
-                                       scope="wholestage",
-                                       context=self.node_name()),
-            self.host_batch_fn())
-        for batch in source:
+            registry=self.metrics))
+        batch = next(source, None)
+        if deciding and batch is not None:
+            # not donated: its row count is read after the program
+            take_exclusive(batch)
+        while batch is not None:
             with self.metrics.timed(M.OP_TIME):
-                # full OOM escalation ladder (memory/retry.py): the chain
-                # is row-wise, so halves of the input concat back into the
-                # same output. Split halves lose the exclusive flag and
-                # dispatch through the non-donating entry.
                 out = run(batch)
+            if run is passing:
+                agg.book_skip(out)
+            following = next(source, None)
+            if deciding:
+                deciding = False
+                if following is not None:
+                    groups, rows = resolve_scalars(out.num_rows,
+                                                   batch.num_rows)
+                    if agg.skips(groups, rows):
+                        run = passing
             self.metrics.add(M.NUM_OUTPUT_BATCHES, 1)
             yield out
+            batch = following
 
 
 def fuse_stages(plan, conf=None):

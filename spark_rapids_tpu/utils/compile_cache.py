@@ -74,6 +74,7 @@ PROGRAM_NAMES: Dict[str, str] = {
     "agg_grouped": "hash group-by aggregate, one batch (exec/aggregate.py)",
     "agg_ungrouped": "aggregate without keys, one batch (exec/aggregate.py)",
     "agg_sizes": "collect_list/set width probe (exec/aggregate.py)",
+    "agg_passthrough": "a skipped partial aggregate: every row its own state (exec/aggregate.py)",
     "compact": "DeviceTable.compact (columnar/device.py)",
     "compact_shrink": "shrink_to_fit: compact into the row count's bucket (columnar/device.py)",
     "concat": "concat_device_tables (columnar/device.py)",

@@ -6,7 +6,9 @@ that ran over the whole batch, where the aggregate ran as a program of its
 own), ``agg.merge`` a concat-and-merge step of the final aggregate's
 cascade (``rows`` = the concat's capacity, ``groups`` = the state it
 leaves), ``join.probe.pk`` with ``rows_out`` beside ``rows`` — and no
-program and no blocking read that the code without them did not make."""
+program and no blocking read that the code without them did not make;
+``agg.skip`` a batch a grouped partial passed through, whose decision is
+the one read a partition that PR 39 adds."""
 import numpy as np
 import pyarrow as pa
 import pytest
@@ -218,6 +220,62 @@ def test_the_phase_totals_hold_the_sums_of_the_spans_arguments(traced):
     assert phases["join.build"]["rows"] == sum(
         e.args["rows"] for e in events("join.build"))
     assert "rounds" not in phases["dispatch"]
+
+
+# ---- a partial that does not reduce passes its batches through --------------
+#: the plans above with ``lineitem`` read in 250-row batches, four a
+#: partition: (programs, syncs) read from commit 1920b46 (PR 38)
+BATCHED_PARENT_CROSSINGS = {"q1": (29, 6), "q18": (56, 24)}
+
+
+def run_batched(session, name):
+    from spark_rapids_tpu.io.memory import InMemorySource
+    from spark_rapids_tpu.plan.logical import LogicalScan
+    from spark_rapids_tpu.session import DataFrame
+    make, parts = PLANS[name]
+    tables = make()
+    sess = session()
+    frames = {n: DataFrame(sess, LogicalScan(InMemorySource(
+        t, parts, batch_rows=250 if n == "lineitem" else 1 << 20)))
+        for n, t in tables.items()}
+    return tables, parts, QUERIES[name](frames).collect().to_pandas()
+
+
+def test_q18s_partial_passes_through_every_batch_after_a_partitions_first(
+        traced):
+    """``big``'s partial keeps ~180 groups of a 250-row batch: each of the
+    three partitions aggregates its first batch, reads its groups and rows
+    in one sync, and passes its other three through (``agg.skip``, no
+    branch booked); the final aggregate merges them to pandas' answer.
+    One sync a partition more than the parent; two programs fewer: the
+    third partition's last two batches, which the parent's partial reduced
+    to 40 groups, no longer shrink to the 64-row bucket."""
+    session, events = traced
+    tables, parts, got = run_batched(session, "q18")
+    want = q18_by_pandas(tables)
+    assert list(got.o_orderkey) == list(want.o_orderkey)
+    np.testing.assert_array_equal(got.sum_qty, want.sum_qty)
+    skips = events("agg.skip")
+    assert len(skips) == 3 * parts
+    assert all(e.args["rows"] == 256 for e in skips)
+    firsts = [e for e in events("agg.scatter") if "rounds" not in e.args
+              and e.args["rows"] == 256]
+    assert len(firsts) == parts and all(
+        2 * e.args["groups"] > 250 for e in firsts)
+    programs, syncs = BATCHED_PARENT_CROSSINGS["q18"]
+    assert crossings(events) == (programs - 2, syncs + parts)
+
+
+def test_q1s_partial_keeps_reducing_and_its_programs(traced):
+    """Six groups a 250-row batch: no batch passes through, every one is
+    ``agg.dense``, the parent's programs run, and the only new crossing is
+    the read of each partition's first counts."""
+    session, events = traced
+    _, parts, got = run_batched(session, "q1")
+    assert len(got) > 0 and not events("agg.skip")
+    assert len(events("agg.dense")) == 4 * parts + 1
+    programs, syncs = BATCHED_PARENT_CROSSINGS["q1"]
+    assert crossings(events) == (programs, syncs + parts)
 
 
 # ---- a state that outgrows one batch's bucket --------------------------------

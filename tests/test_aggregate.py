@@ -1,5 +1,6 @@
 """Aggregation differential tests (reference: HashAggregatesSuite +
 hash_aggregate_test.py)."""
+import numpy as np
 import pyarrow as pa
 import pytest
 
@@ -572,3 +573,129 @@ def test_hash_grouping_against_numpy(case):
     assert int(num_groups) == is_rep.sum() == len(first)
     np.testing.assert_array_equal(np.asarray(gid)[active], want_gid[active])
     assert trips(int(rounds), int(full_rounds)), (rounds, full_rounds)
+
+
+# ---- a partial that does not reduce passes its batches through --------------
+def _skip_run(table, query, batch_rows=128, parts=2, **conf):
+    """(answer, ``agg.skip`` spans, ``agg.dense`` + ``agg.scatter`` spans)
+    of ``query`` over ``table`` in ``parts`` partitions of ``batch_rows``-row
+    batches, each batch one the partial decides on."""
+    from spark_rapids_tpu.io.memory import InMemorySource
+    from spark_rapids_tpu.plan.logical import LogicalScan
+    from spark_rapids_tpu.session import DataFrame, TpuSession
+    from spark_rapids_tpu.utils.tracing import get_tracer
+    tracer = get_tracer()
+    was = tracer.enabled
+    tracer.enabled = True
+    tracer.clear()
+    sess = TpuSession({"spark.rapids.tpu.batchRowsMinBucket": 64,
+                       "spark.rapids.sql.test.enabled": True, **conf})
+    try:
+        df = DataFrame(sess, LogicalScan(
+            InMemorySource(table, parts, batch_rows=batch_rows)))
+        got = query(df).collect(device=True)
+        names = [e.name for e in tracer.events()]
+    finally:
+        tracer.enabled = was
+        tracer.clear()
+        sess.close()
+    return (got, names.count("agg.skip"),
+            names.count("agg.dense") + names.count("agg.scatter"))
+
+
+def _wide_table(rng, n=1024, keys=600):
+    """``n`` rows of ~``keys`` int64 keys: a 128-row batch holds ~115
+    groups, so the partial keeps nine rows of ten and is skipped."""
+    from harness import data_gen
+    t = data_gen(rng, n, {"i": ("int64", -1000, 1000), "f": "float64"})
+    return t.append_column("k", pa.array(rng.integers(0, keys, n)))
+
+
+SKIP_OPS = {
+    "sum": lambda: fsum(col("i")),
+    "sumsq": lambda: var_pop(col("i")),     # sum, sumsq and count states
+    "count": lambda: count(col("f")),
+    "count_star": lambda: count_star(),
+    "min_nan_null": lambda: fmin(col("f")),
+    "max_nan_null": lambda: fmax(col("f")),
+    "avg": lambda: avg(col("f")),
+    "first": lambda: first(col("i")),
+    "last": lambda: last(col("i")),
+}
+
+
+@pytest.mark.parametrize("op", sorted(SKIP_OPS))
+def test_a_skipped_partial_gives_the_kept_partials_answer(op, rng,
+                                                          monkeypatch):
+    """Two partitions of four batches: each partition's first batch is
+    aggregated and shows ~115 groups in 128 rows, so its three others pass
+    through as one-row states, and the final aggregate merges them to the
+    answer of the plan whose partial reduces every batch (``SKIP_SHARE``
+    0: no batch ever skips). Integers are exact, floats a few ulps off at
+    most (the sums add in another order)."""
+    from harness import assert_tables_equal
+    from spark_rapids_tpu.exec import aggregate
+    t = _wide_table(rng)
+
+    def query(df):
+        return df.group_by("k").agg(SKIP_OPS[op]().alias("a"))
+    got, skipped, reduced = _skip_run(t, query)
+    assert skipped == 6, (skipped, reduced)
+    monkeypatch.setattr(aggregate, "SKIP_SHARE", 0)
+    want, kept, _ = _skip_run(t, query)
+    assert kept == 0
+    assert got.num_rows == len(set(t.column("k").to_pylist()))
+    assert_tables_equal(got, want, rel_tol=1e-12)
+
+
+def test_a_float_key_of_both_zeros_groups_as_one_when_skipped(rng):
+    """-0.0 and +0.0 in every batch, among ~115 other keys a batch: the
+    rows pass through with their keys as they are, and the final
+    aggregate's normalised grouping still makes them one group."""
+    n = 1024
+    k = rng.integers(1, 600, n).astype(np.float64)
+    k[::16] = 0.0
+    k[8::16] = -0.0
+    i = rng.integers(0, 50, n)
+    got, skipped, _ = _skip_run(
+        pa.table({"k": k, "i": i}), lambda df: df.group_by("k").agg(
+            fsum(col("i")).alias("s"), count_star().alias("n")))
+    assert skipped == 6
+    got = got.to_pandas().sort_values("k")
+    want = pd_groupby_sum_count(k, i)
+    np.testing.assert_array_equal(got.k, want.index)
+    np.testing.assert_array_equal(got.s, want.s)
+    np.testing.assert_array_equal(got.n, want.n)
+    assert (got.k == 0).sum() == 1 and int(got.n[got.k == 0].iloc[0]) == n // 8
+
+
+def pd_groupby_sum_count(k, i):
+    """pandas' group-by, where -0.0 and +0.0 are one key."""
+    import pandas as pd
+    return pd.DataFrame({"k": k + 0.0, "i": i}).groupby("k").i.agg(
+        s="sum", n="count")
+
+
+def test_a_batch_of_four_groups_keeps_the_partial(rng):
+    """Q1's shape: four groups a 128-row batch, every batch reduced."""
+    t = pa.table({"k": rng.integers(0, 4, 1024),
+                  "v": rng.uniform(0, 1, 1024)})
+    got, skipped, reduced = _skip_run(
+        t, lambda df: df.group_by("k").agg(fsum(col("v")).alias("s")))
+    assert got.num_rows == 4 and skipped == 0 and reduced == 9
+
+
+def test_an_aggregate_with_a_collect_list_never_skips(rng):
+    """``collect_list``'s state is a list, no row-wise projection: the
+    plan's partial never passes through, whatever its groups."""
+    from spark_rapids_tpu.expr.functions import collect_list
+    t = _wide_table(rng)
+    got, skipped, _ = _skip_run(t, lambda df: df.group_by("k").agg(
+        fsum(col("i")).alias("s"), collect_list(col("i")).alias("l")))
+    assert skipped == 0
+    assert got.num_rows == len(set(t.column("k").to_pylist()))
+    lens = {r["k"]: len(r["l"]) for r in got.to_pylist()}
+    i = t.column("i").to_pylist()
+    for key, n in lens.items():
+        assert n == sum(1 for kk, v in zip(t.column("k").to_pylist(), i)
+                        if kk == key and v is not None)

@@ -158,6 +158,32 @@ def test_grouped_aggregate_by_a_float64_key(topo, one_chip, sess, rng):
     _compile(final.batch_fn(with_rounds=True), (partial(batch),), one_chip)
 
 
+def test_q18s_subquery_stage_passing_its_rows_through(topo, one_chip, sess,
+                                                     rng):
+    """The pass-through ``srt_stage`` of Q18's ``big`` (``lineitem`` by
+    ``l_orderkey``, ``sum(l_quantity)``): the chain below the partial and
+    every row its own state, in both entries a TPU session dispatches, and
+    the final aggregate over what it leaves."""
+    from spark_rapids_tpu.exec.aggregate import TpuHashAggregateExec
+    from spark_rapids_tpu.exec.wholestage import TpuWholeStageExec
+    from spark_rapids_tpu.expr.functions import col, sum
+    df = sess.create_dataframe(_table(rng, ROWS - 7))
+    q = df.group_by("k").agg(sum(col("v")).alias("sum_qty"))
+    plan = sess._physical(q.logical, device=True)
+    final = _find(plan, TpuHashAggregateExec)
+    stage = _find(plan, TpuWholeStageExec)
+    assert final is not None and final.mode == "final" and stage is not None
+    batch = next(stage.source.execute_columnar(0))
+    passing = stage.passthrough_fn()
+    assert passing is not None
+    text = _compile(passing, (batch,), one_chip).as_text()
+    _compile(passing, (batch,), one_chip, donate_argnums=(0,))
+    _compile(final.batch_fn(with_rounds=True), (passing(batch),), one_chip)
+    # no grouping in it: no loop, no branch, no scatter
+    assert " while(" not in text and " conditional(" not in text
+    assert " scatter(" not in text
+
+
 def test_pk_hash_join(topo, one_chip, sess, rng):
     """FK->PK join on the sort-free slot table: build prep and fused probe
     (exec/joins.py pk_hash_join_fn)."""
