@@ -1186,28 +1186,39 @@ class TpuShuffledHashJoinExec(TpuExec):
             name="join_pk")
         return fused, (b_order, sv, nvalid)
 
+    def _expand_total(self, probe: DeviceTable, counts) -> dict:
+        """The output's slot total of a probe batch or window, read in one
+        batched-funnel transfer (the decision boundary that sizes the
+        expand), as ``{"rows_out": total}``. An outer join without a
+        condition gives each live probe row with no match one null-extended
+        slot; their count rides in the same transfer as ``unmatched``."""
+        live = jnp.sum(jnp.where(probe.row_mask, counts, 0))
+        if not (self.how in ("left", "full") and self.condition is None):
+            (total,) = resolve_scalars(live)
+            return {"rows_out": int(total)}
+        unmatched = jnp.sum(jnp.logical_and(probe.row_mask, counts == 0))
+        total, unmatched = resolve_scalars(live + unmatched, unmatched)
+        return {"rows_out": int(total), "unmatched": int(unmatched)}
+
     def _probe_expand(self, build: DeviceTable, probe: DeviceTable,
                       counts_fn, seen_box) -> Iterator[DeviceTable]:
         """Counts, the ``total`` sync that sizes the output, and the expand
         of one probe batch or window: span ``join.probe.expand``, closed
-        before anything is yielded. An oversized gather goes on in probe
-        row windows, each a span of its own."""
-        outer_slots = self.how in ("left", "full") and self.condition is None
+        before anything is yielded, with ``rows_out`` = the rows the expand
+        emits (and ``unmatched`` for an outer join). An oversized gather
+        goes on in probe row windows, each a span of its own that books
+        them instead."""
         with get_tracer().span("join.probe.expand", "join",
-                               rows=probe.capacity):
+                               rows=probe.capacity) as span:
             b_order, starts, counts, matched = counts_fn(build, probe)
             if matched is not None:
                 seen_box[0] = jnp.logical_or(seen_box[0], matched)
-            # output capacity is data-dependent: one batched-funnel
-            # transfer resolves the slot total (the decision boundary)
-            (total,) = resolve_scalars(
-                jnp.sum(jnp.where(
-                    probe.row_mask,
-                    jnp.maximum(counts, 1) if outer_slots else counts, 0)))
-            total = int(total)
+            out_rows = self._expand_total(probe, counts)
+            total = out_rows["rows_out"]
             max_out = self._max_out_rows()
             outs = None
             if total <= max_out:
+                span.note(**out_rows)
                 out_cap = bucket_rows(max(total, 1), self.min_bucket)
                 outs = self._expand_one(build, probe, b_order, starts,
                                         counts, out_cap, seen_box)
@@ -1274,17 +1285,16 @@ class TpuShuffledHashJoinExec(TpuExec):
             start += wsize
             outs = None
             with get_tracer().span("join.probe.expand", "join",
-                                   rows=window.capacity):
+                                   rows=window.capacity) as span:
                 b_order, starts, counts, _ = counts_fn(build, window)
-                (wtotal,) = resolve_scalars(jnp.sum(jnp.where(
-                    window.row_mask,
-                    jnp.maximum(counts, 1) if outer_slots else counts, 0)))
-                wtotal = int(wtotal)
+                out_rows = self._expand_total(window, counts)
+                wtotal = out_rows["rows_out"]
                 if wtotal == 0 and not outer_slots \
                         and self.condition is None \
                         and self.how not in ("left_semi", "left_anti"):
                     continue
                 if wtotal <= 2 * max_out or wsize <= self.min_bucket:
+                    span.note(**out_rows)
                     out_cap = bucket_rows(max(wtotal, 1), self.min_bucket)
                     outs = self._expand_one(build, window, b_order, starts,
                                             counts, out_cap, seen_box)

@@ -20,6 +20,7 @@ Match semantics follow Java ``Matcher.find()`` (unanchored unless ^/$).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import List, Optional, Set, Tuple
 
 import numpy as np
@@ -457,48 +458,89 @@ class DeviceNfa:
         return ends
 
     def matches(self, ctx, col):
-        """col: device EvalCol (string). Returns (n,) bool of find() matches."""
+        """col: device EvalCol (string). Returns (n,) bool of find() matches.
+        Its ops sit under ``jax.named_scope("like_nfa")`` in the program
+        that evaluates it (a fused stage's, for a filter)."""
+        import jax
+        with jax.named_scope("like_nfa"):
+            return self._scan_matches(ctx, col)
+
+    def _factored(self):
+        """The transition table as two small tables. Every byte class that
+        enters state t comes from the same source states (t's
+        predecessors), so ``masks[c, t]`` is either 0 or that set, and one
+        step is ``follow(active) & label(byte)``: ``follow`` ORs together
+        the successors of the active states, ``label`` is the set of states
+        whose byte set holds the byte. -> (successors: (S,) uint32, one
+        (states, inclusive byte ranges) a distinct byte set)."""
+        masks = self.masks
+        S = masks.shape[1]
+        preds = np.bitwise_or.reduce(masks, axis=0)
+        succ = np.array([sum(1 << t for t in range(S)
+                             if (int(preds[t]) >> s) & 1) for s in range(S)],
+                        dtype=np.uint32)
+        member = masks[self.class_of_byte] != 0              # (256, S)
+        sets = {}
+        for t in np.flatnonzero(member.any(axis=0)):
+            held = member[:, t]
+            sets.setdefault(held.tobytes(), [held, 0])[1] |= 1 << int(t)
+        groups = []
+        for held, states in sets.values():
+            edge = np.flatnonzero(np.diff(np.concatenate(
+                ([0], held.astype(np.int8), [0]))))
+            groups.append((states, list(zip(edge[::2].tolist(),
+                                            (edge[1::2] - 1).tolist()))))
+        return succ, groups
+
+    def _scan_matches(self, ctx, col):
+        """One ``lax.scan`` step a byte column over the transposed (w, n)
+        byte matrix, each row's state a uint32 bitmask: the byte's label
+        set is computed for the whole matrix up front by range compares
+        (no gather), and a step is S masked ORs over (n,) vectors."""
         xp = ctx.xp
         from jax import lax
         v, lengths = col.values, col.lengths
         n, w = v.shape
-        cls = xp.asarray(self.class_of_byte)[v.astype(xp.int32)]   # (n, w)
-        masks = xp.asarray(self.masks)                             # (c, S)
-        S = self.masks.shape[1]
-        bit = (xp.uint32(1) << xp.arange(S, dtype=xp.uint32))      # (S,)
+        succ, groups = self._factored()
         start = xp.uint32(self.start_bits)
         accept = xp.uint32(self.accept_bits)
-        pos_in = xp.arange(w, dtype=xp.int32)
+        vt = v.T                                                   # (w, n)
+        labels = xp.zeros((w, n), dtype=xp.uint32)
+        for states, ranges in groups:
+            held = True if ranges == [(0, 255)] else functools.reduce(
+                xp.logical_or, [vt == lo if lo == hi
+                                else xp.logical_and(vt >= lo, vt <= hi)
+                                for lo, hi in ranges])
+            labels = labels | xp.where(held, xp.uint32(states), xp.uint32(0))
+        follows = [(xp.uint32(1 << s), xp.uint32(int(to)))
+                   for s, to in enumerate(succ) if to]
+        pos_in = xp.arange(w, dtype=xp.int32)[:, None]
 
         # per-character stepping: continuation bytes leave the state untouched
-        lead_in = xp.logical_and((v & 0xC0) != 0x80,
-                                 pos_in[None, :] < lengths[:, None])
+        lead_in = xp.logical_and((vt & 0xC0) != 0x80,
+                                 pos_in < lengths[None, :])
         # position of the final character's lead byte (for $ anchoring)
-        any_lead = xp.any(lead_in, axis=1)
-        last_lead = w - 1 - xp.argmax(lead_in[:, ::-1], axis=1)
+        any_lead = xp.any(lead_in, axis=0)
+        last_lead = w - 1 - xp.argmax(lead_in[::-1, :], axis=0)
         is_last_char = xp.logical_and(
-            lead_in, pos_in[None, :] == last_lead[:, None])
-        is_last_char = xp.logical_and(is_last_char, any_lead[:, None])
+            lead_in, pos_in == last_lead[None, :])
+        is_last_char = xp.logical_and(is_last_char, any_lead[None, :])
 
-        def step(carry, j):
+        def step(carry, x):
             active, matched = carry
-            c_j = cls[:, j]                                  # (n,)
-            m = masks[c_j]                                   # (n, S)
-            hits = (active[:, None] & m) != 0                # (n, S)
-            nxt = (hits.astype(xp.uint32) * bit[None, :]).sum(axis=1,
-                                                              dtype=xp.uint32)
+            label, inside, last = x
+            follow = xp.zeros_like(active)
+            for bit, to in follows:
+                follow = follow | xp.where((active & bit) != 0, to,
+                                           xp.uint32(0))
+            nxt = follow & label
             if not self.anchored_start:
                 nxt = nxt | start                 # restart a match anywhere
-            inside = lead_in[:, j]
             active = xp.where(inside, nxt, active)
             done = (active & accept) != 0
-            if self.anchored_end:
-                # match must consume through the final character
-                matched = xp.where(is_last_char[:, j],
-                                   xp.logical_or(matched, done), matched)
-            else:
-                matched = xp.where(inside, xp.logical_or(matched, done),
-                                   matched)
+            # anchored: the match must consume through the final character
+            at = last if self.anchored_end else inside
+            matched = xp.where(at, xp.logical_or(matched, done), matched)
             return (active, matched), None
 
         empty_match = xp.full((n,), self.nullable, dtype=bool)
@@ -508,7 +550,8 @@ class DeviceNfa:
                             xp.full((n,), self.nullable and not self.anchored_end,
                                     dtype=bool))
         init = (xp.full((n,), self.start_bits, dtype=xp.uint32), matched0)
-        (active, matched), _ = lax.scan(step, init, pos_in)
+        (active, matched), _ = lax.scan(step, init,
+                                        (labels, lead_in, is_last_char))
         if self.anchored_end:
             matched = xp.logical_or(
                 matched, xp.logical_and(lengths == 0,

@@ -963,17 +963,21 @@ class Like(Expression):
             return EvalCol(vals, c.validity, dt.BOOLEAN)
         xp = ctx.xp
         if kind is not None:
+            import jax
             op, needle = kind
             nb = needle.encode()
-            if op == "contains":
-                vals = _device_find(ctx, c, nb) >= 0
-            elif op == "prefix":
-                vals = _device_startswith(ctx, c, nb)
-            elif op == "suffix":
-                vals = _device_endswith(ctx, c, nb)
-            else:  # equals
-                vals = xp.logical_and(_device_startswith(ctx, c, nb),
-                                      c.lengths == len(nb))
+            # the search kernels' ops under one name in the program (the
+            # general pattern's are under the NFA's ``like_nfa``)
+            with jax.named_scope("like_search"):
+                if op == "contains":
+                    vals = _device_find(ctx, c, nb) >= 0
+                elif op == "prefix":
+                    vals = _device_startswith(ctx, c, nb)
+                elif op == "suffix":
+                    vals = _device_endswith(ctx, c, nb)
+                else:  # equals
+                    vals = xp.logical_and(_device_startswith(ctx, c, nb),
+                                          c.lengths == len(nb))
             return EvalCol(vals, c.validity, dt.BOOLEAN)
         # general pattern: device regex NFA
         from .regex import compile_device_nfa

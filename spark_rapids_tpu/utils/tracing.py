@@ -251,14 +251,15 @@ STRUCTURAL_SPANS = frozenset({"query", "task", "stage", "wait.pipeline",
 #: ``bytes`` (a boolean counts 0 / 1): row and group counts, the trip counts
 #: of the hash loops, scalars a ``sync`` read, what ``stage.stats`` walked,
 #: the producers an ``exchange.map`` started together, the slot quota and
-#: the slots of an ``exchange.count``.
+#: the slots of an ``exchange.count``, the probe rows an outer join
+#: emitted null-extended (``join.probe.expand``).
 #: ``to_dict`` emits them flat where non-zero, so a reader that takes
 #: ``phases[name].get(field, 0)`` reads ``field="rounds"`` as it reads
 #: ``"calls"``
 COUNTED_ARGS = frozenset({"rows", "rows_out", "groups", "rounds",
                           "full_rounds", "parts", "scalars", "unique",
                           "shards", "handles", "producers", "quota",
-                          "slots"})
+                          "slots", "unmatched"})
 
 #: queries whose phase totals ``Tracer.recent_queries`` remembers
 RECENT_QUERIES = 256

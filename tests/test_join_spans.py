@@ -696,3 +696,90 @@ def test_the_probe_walks_the_rows_still_open(traced, how, duplicates, shape):
     else:
         assert (span.args["rounds"], span.args["full_rounds"]) == trips
         assert len(events("sync")) == 2         # and the row count
+
+
+# ---- what leaves the expand: ``rows_out``, and ``unmatched`` of an outer join ---
+def numpy_counts():
+    """Build rows a probe row matches, in the probe's row order."""
+    held = duplicate_build().to_pandas().bk.value_counts()
+    return probe().to_pandas().pk.map(held).fillna(0).astype(int).to_numpy()
+
+
+def expand_node(how):
+    """The join node driven directly on one probe batch of 500 rows."""
+    from spark_rapids_tpu.exec.joins import TpuShuffledHashJoinExec
+    return TpuShuffledHashJoinExec(
+        Source(probe()), Source(duplicate_build()), ["pk"], ["bk"], how, None,
+        merge_keys=False, min_bucket=64)
+
+
+#: (blocking syncs + downloads, programs) of each drive, read from the code
+#: before ``rows_out`` and ``unmatched`` were booked (the parent of the change
+#: that added them, the same drives): the counts ride in the transfer that
+#: read the output's total, so neither moves
+EXPAND_CROSSINGS = {("left", False): (11, 9), ("inner", False): (11, 9),
+                    ("left", True): (20, 28), ("inner", True): (20, 28)}
+
+
+@pytest.mark.parametrize("windowed", [False, True],
+                         ids=["one-batch-a-partition", "windowed"])
+@pytest.mark.parametrize("how", ["left", "inner"])
+def test_the_expand_books_the_rows_it_emits(traced, monkeypatch, how,
+                                            windowed):
+    """``join.probe.expand`` books ``rows_out`` = the rows the expand
+    emitted (numpy's count: every match, and for a left join one
+    null-extended row a probe row with none) and, for the outer join alone,
+    ``unmatched`` = the live probe rows with no match. A probe over the
+    output budget goes in windows: each window books its own, the span that
+    only sized them books neither, so the sums hold either way."""
+    from spark_rapids_tpu.columnar.host import HostTable
+    from spark_rapids_tpu.exec.joins import TpuShuffledHashJoinExec
+    session, events = traced
+    counts = numpy_counts()
+    unmatched = int((counts == 0).sum())
+    emitted = int(counts.sum()) + (unmatched if how == "left" else 0)
+    assert 0 < unmatched < len(counts) and counts.max() == 15
+    if windowed:
+        monkeypatch.setattr(TpuShuffledHashJoinExec, "_max_out_rows",
+                            lambda self: 64)
+        got = pd.concat([HostTable.to_arrow(t.to_host()).to_pandas()
+                         for t in expand_node(how).execute_columnar(0)])
+    else:
+        got = join(session(), duplicate_build(), how)
+    assert len(got) == emitted
+    assert int(got.bk.isna().sum()) == (unmatched if how == "left" else 0)
+    spans = events("join.probe.expand")
+    booked = [e for e in spans if "rows_out" in e.args]
+    assert sum(e.args["rows_out"] for e in booked) == emitted
+    if how == "left":
+        assert all("unmatched" in e.args for e in booked)
+        assert sum(e.args["unmatched"] for e in booked) == unmatched
+    else:
+        assert not any("unmatched" in e.args for e in spans)
+    if windowed:
+        # the whole batch's span sized the windows and emitted nothing
+        assert len(spans) == len(booked) + 1 and len(booked) >= 5
+        assert spans[0].args["rows"] == 512
+        assert all(e.args["rows_out"] <= 2 * 64 or e.args["rows"] <= 64
+                   for e in booked)
+    else:
+        assert len(spans) == len(booked) == PROBE_BATCHES
+    assert crossings(events) == EXPAND_CROSSINGS[how, windowed]
+
+
+def test_phase_totals_sum_rows_out_and_unmatched(traced):
+    """The per-query phase totals carry both sums flat, as every counted
+    argument: what ``benchmark/readers/query_phases.py`` reads with
+    ``field "rows_out"`` or ``field "unmatched"``."""
+    from spark_rapids_tpu.utils.tracing import COUNTED_ARGS
+    session, _ = traced
+    sess = session()
+    b = sess.create_dataframe(duplicate_build(), num_partitions=2)
+    p = sess.create_dataframe(probe(), num_partitions=PROBE_BATCHES)
+    p.join(b, how="left", condition=col("pk") == col("bk")).collect()
+    phase = sess.last_query_phases()["phases"]["join.probe.expand"]
+    counts = numpy_counts()
+    assert "unmatched" in COUNTED_ARGS
+    assert phase["calls"] == PROBE_BATCHES
+    assert phase["unmatched"] == int((counts == 0).sum())
+    assert phase["rows_out"] == int(counts.sum() + (counts == 0).sum())

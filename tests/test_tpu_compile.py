@@ -365,3 +365,25 @@ def test_q18s_final_keys_hash_exchange_on_four_chips(topo, rng, program):
     compiled = lowered.compile()
     if program == "all_to_all":
         assert "all-to-all" in compiled.as_text()
+
+
+def test_like_nfa_at_a_full_batch(topo, one_chip):
+    """Q13's ``NOT LIKE '%special%requests%'`` NFA alone over a full
+    2^20-row batch of 128-byte strings, the bucket ``o_comment`` takes:
+    one loop, a step a byte column, and no gather anywhere (a gather of a
+    (n, states) mask row a step made it 67 times slower on the chip)."""
+    from spark_rapids_tpu.expr.regex import compile_device_nfa
+
+    class Ctx:
+        xp = jnp
+
+    class Col:
+        def __init__(self, values, lengths):
+            self.values, self.lengths = values, lengths
+
+    nfa = compile_device_nfa("^.*special.*requests.*$")
+    args = (jax.ShapeDtypeStruct((1 << 20, 128), jnp.uint8),
+            jax.ShapeDtypeStruct((1 << 20,), jnp.int32))
+    text = _compile(lambda v, n: nfa.matches(Ctx, Col(v, n)), args,
+                    one_chip).as_text()
+    assert text.count(" while(") == 1 and " gather(" not in text
