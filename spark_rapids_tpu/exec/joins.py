@@ -37,7 +37,7 @@ from ..columnar import dtypes as dt
 from ..columnar.device import (DeviceColumn, DeviceTable, bucket_rows,
                                resolve_min_bucket, resolve_scalars,
                                concat_device_tables, open_rows_by_rank,
-                               shrink_to_fit, slice_rows)
+                               prefix_sum, shrink_to_fit, slice_rows)
 from ..expr.base import EvalContext, Expression
 from ..plan.logical import _join_schema
 from ..plan.physical import PhysicalPlan
@@ -579,15 +579,27 @@ class _JoinKernels:
 
     def _slots(self, build, probe, b_order, starts, counts, out_cap, outer):
         """Common slot math: per-output-slot probe index, build index,
-        valid/matched flags."""
+        valid/matched flags. Each non-empty probe row owns the run of slots
+        from its offset: a 1 scattered at every run's start, prefix-summed
+        over the slots, is the owner's rank among the non-empty rows, and
+        ``open_rows_by_rank`` turns the rank into the row. O(out_cap), where
+        a ``searchsorted`` of the slots in the running counts gathers the
+        whole output once per level of a log2(probe rows) binary search.
+        Slots at or past ``total`` map to some row and are masked."""
         slot_counts = jnp.maximum(counts, 1) if outer else counts
         slot_counts = jnp.where(probe.row_mask, slot_counts, 0)
-        cum = jnp.cumsum(slot_counts)
+        cum = prefix_sum(slot_counts)
         total = cum[-1]
         offsets = cum - slot_counts
+        nonempty = slot_counts > 0
+        iota = jnp.arange(probe.capacity, dtype=jnp.int32)
+        rows, _ = open_rows_by_rank(nonempty, iota, probe.capacity)
+        # offsets of non-empty rows are distinct and below total <= out_cap
+        heads = jnp.where(nonempty, offsets, out_cap).astype(jnp.int32)
+        flags = jnp.zeros(out_cap, jnp.int32).at[heads].set(1, mode="drop")
+        rank = jnp.clip(prefix_sum(flags) - 1, 0, probe.capacity - 1)
+        pi = jnp.take(rows, rank)
         j = jnp.arange(out_cap, dtype=jnp.int64)
-        pi = jnp.searchsorted(cum, j, side="right")
-        pi = jnp.clip(pi, 0, probe.capacity - 1)
         k = j - jnp.take(offsets, pi)
         has_match = jnp.take(counts, pi) > 0
         b_sorted_pos = jnp.take(starts, pi) + k

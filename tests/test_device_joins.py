@@ -307,3 +307,184 @@ def test_join_strategy_differential(strategy):
             dev = sorted(map(str, q.collect(device=True).to_pylist()))
             cpu = sorted(map(str, q.collect(device=False).to_pylist()))
             assert dev == cpu, (strategy, how, build.num_rows)
+
+
+# -- the expand's slot map ----------------------------------------------------
+
+def _slot_reference(mask, counts, starts, b_order, out_cap, outer):
+    """The expand's slot map by a binary search of every slot in the running
+    slot counts: -> (probe row, build row, valid, build-matched, total)."""
+    slot_counts = np.where(mask, np.maximum(counts, 1) if outer else counts, 0)
+    cum = np.cumsum(slot_counts)
+    j = np.arange(out_cap)
+    pi = np.clip(np.searchsorted(cum, j, side="right"), 0, len(mask) - 1)
+    k = j - (cum - slot_counts)[pi]
+    bi = b_order[np.clip(starts[pi] + k, 0, len(b_order) - 1)]
+    valid = j < cum[-1]
+    return pi, bi, valid, valid & (counts[pi] > 0), int(cum[-1])
+
+
+def _slot_case(case, rng):
+    """-> (probe row mask, counts, build capacity, out_cap or None for the
+    total's size) of one probe batch shape."""
+    cap = 64
+    mask = np.arange(cap) < 50
+    counts = rng.integers(0, 5, cap)
+    out_cap = None
+    if case.startswith("masked"):
+        mask = rng.random(cap) < 0.6
+    elif case.startswith("zero-ends"):
+        counts = rng.integers(1, 5, cap)
+        counts[:5] = counts[20:27] = counts[45:] = 0
+        mask = np.ones(cap, bool)
+    elif case.startswith("all-empty"):
+        counts[:] = 0
+    elif case == "exact-fit":
+        counts = np.full(cap, 2)                     # total == out_cap
+        mask = np.ones(cap, bool)
+        out_cap = 2 * cap
+    elif case == "roomy":
+        out_cap = 1024                               # total < out_cap
+    elif case == "one-owner":
+        counts[:] = 0
+        counts[17] = 256
+        out_cap = 256
+    elif case == "q13-batch":
+        # a 2^17-row batch of 75,000 customers, a third with no orders,
+        # the rest 1-36 orders each, into a 2^20-slot output
+        cap = 1 << 17
+        mask = np.arange(cap) < 75_000
+        counts = np.where(rng.random(cap) < 1 / 3, 0,
+                          rng.integers(1, 37, cap))
+        out_cap = 1 << 20
+        return mask, counts, 1 << 21, out_cap
+    return mask, counts, 256, out_cap
+
+
+@pytest.mark.parametrize("case,outer", [
+    ("inner", False), ("outer", True), ("masked-inner", False),
+    ("masked-outer", True), ("zero-ends-inner", False),
+    ("zero-ends-outer", True), ("all-empty", False),
+    ("all-empty-outer-masked", True), ("exact-fit", False),
+    ("roomy", True), ("one-owner", False), ("q13-batch", True)])
+def test_expand_slot_map(case, outer):
+    """``_slots``' scatter-and-prefix-sum map of output slots to probe rows
+    against a binary search of the running counts: the probe and build row
+    and the matched flag of every valid slot, the valid slots and the
+    total."""
+    import types
+    import jax
+    import jax.numpy as jnp
+    from spark_rapids_tpu.exec.joins import _JoinKernels
+    rng = np.random.default_rng(41)
+    mask, counts, bcap, out_cap = _slot_case(case, rng)
+    if case == "all-empty-outer-masked":
+        mask[:] = False
+    counts = counts.astype(np.int64)
+    starts = rng.integers(0, bcap - counts.max(initial=0) + 1,
+                          len(counts)).astype(np.int64)
+    b_order = rng.permutation(bcap).astype(np.int32)
+    slot_counts = np.where(mask, np.maximum(counts, 1) if outer else counts, 0)
+    if out_cap is None:
+        out_cap = max(int(slot_counts.sum()), 1)
+    assert slot_counts.sum() <= out_cap
+
+    def slots(mask, b_order, starts, counts):
+        probe = types.SimpleNamespace(row_mask=mask, capacity=mask.shape[0])
+        build = types.SimpleNamespace(capacity=b_order.shape[0])
+        return _JoinKernels(None)._slots(build, probe, b_order, starts,
+                                         counts, out_cap, outer)
+    got = jax.jit(slots)(jnp.asarray(mask), jnp.asarray(b_order),
+                         jnp.asarray(starts), jnp.asarray(counts))
+    pi, bi, valid, matched, total = (np.asarray(x) for x in got)
+    rpi, rbi, rvalid, rmatched, rtotal = _slot_reference(
+        mask, counts, starts, b_order, out_cap, outer)
+    assert int(total) == rtotal
+    np.testing.assert_array_equal(valid, rvalid)
+    np.testing.assert_array_equal(pi[rvalid], rpi[rvalid])
+    np.testing.assert_array_equal(bi[rvalid], rbi[rvalid])
+    np.testing.assert_array_equal(matched[rvalid], rmatched[rvalid])
+
+
+def _expand_node(how, condition):
+    """A hash join of a probe (k, pv) against a build (rk, bv) on k = rk,
+    its children standing in by their schemas alone."""
+    import types
+    from spark_rapids_tpu.columnar import dtypes as dt
+    from spark_rapids_tpu.exec.joins import TpuShuffledHashJoinExec
+    from spark_rapids_tpu.plan.schema import Schema
+    left = types.SimpleNamespace(
+        schema=Schema.of(("k", dt.LONG), ("pv", dt.LONG)), num_partitions=1)
+    right = types.SimpleNamespace(
+        schema=Schema.of(("rk", dt.LONG), ("bv", dt.LONG)), num_partitions=1)
+    return TpuShuffledHashJoinExec(left, right, ["k"], ["rk"], how,
+                                   condition, False, min_bucket=8)
+
+
+@pytest.mark.parametrize("program,how", [
+    ("expand", "left"), ("expand", "inner"), ("expand", "full"),
+    ("cond", "left_semi"), ("cond", "left_anti"), ("cond", "left")])
+def test_expand_programs_follow_the_slot_map(program, how):
+    """Whole expand programs, with and without a residual condition, over a
+    duplicate-keyed build and a probe with masked rows and null payloads:
+    the rows they emit, in order, are those the binary-search slot map
+    gives."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.columnar.device import DeviceTable
+    from spark_rapids_tpu.columnar.host import HostTable
+    rng = np.random.default_rng(42)
+    pk, bk = rng.integers(0, 12, 40), rng.integers(0, 9, 30)
+    pv = rng.integers(0, 100, 40)
+    pv_null = rng.random(40) < 0.15
+    bv = rng.integers(0, 100, 30)
+    probe = DeviceTable.from_host(HostTable.from_arrow(pa.table({
+        "k": pa.array(pk), "pv": pa.array(pv, mask=pv_null)})), capacity=64)
+    live = np.arange(64) < 40
+    live[[3, 11, 12, 39]] = False
+    probe = probe.filter_mask(jnp.asarray(live))
+    build = DeviceTable.from_host(HostTable.from_arrow(pa.table({
+        "rk": pa.array(bk), "bv": pa.array(bv)})), capacity=32)
+    b_order = np.argsort(np.concatenate([bk, np.full(2, 99)]),
+                         kind="stable").astype(np.int32)
+    sk = bk[b_order[:30]]
+    pkeys = np.concatenate([pk, np.zeros(24, np.int64)])
+    starts = np.searchsorted(sk, pkeys, side="left").astype(np.int64)
+    counts = np.searchsorted(sk, pkeys, side="right") - starts
+    # dead rows still carry counts: the map itself must drop them
+    counts[40:] = 0
+    condition = (col("pv") < col("bv")).expr if program == "cond" else None
+    node = _expand_node(how, condition)
+    outer = program == "expand" and how in ("left", "full")
+    pslots = np.where(live, np.maximum(counts, 1) if outer else counts, 0)
+    out_cap = int(pslots.sum())
+    args = (build, probe, jnp.asarray(b_order), jnp.asarray(starts),
+            jnp.asarray(counts))
+    rpi, rbi, rvalid, rmatched, _ = _slot_reference(
+        live, counts, starts, b_order, out_cap, outer)
+    pv_l = [None if n else int(v) for v, n in zip(pv, pv_null)] + [None] * 24
+    bv_l = [int(v) for v in bv] + [None] * 2
+    pairs = [(int(pkeys[p]), pv_l[p]) + ((int(bk[b]), bv_l[b]) if m
+                                         else (None, None))
+             for p, b, m in zip(rpi[rvalid], rbi[rvalid], rmatched[rvalid])]
+
+    def rows(t):
+        return [tuple(r.values()) for r in t.to_host().to_arrow().to_pylist()]
+    if program == "expand":
+        out = node._kernels.expand_fn(out_cap, how)(*args)
+        assert rows(out) == pairs
+        return
+    passes = [p[1] is not None and p[1] < p[3] for p in pairs]
+    hit = set(int(p) for p, ok in zip(rpi[rvalid], passes) if ok)
+    res = node._kernels.expand_cond_fn(out_cap, how)(*args)
+    probe_rows = [(int(pkeys[i]), pv_l[i]) for i in range(64) if live[i]]
+    if how == "left_semi":
+        assert rows(res) == [r for i, r in zip(np.flatnonzero(live),
+                                               probe_rows) if i in hit]
+    elif how == "left_anti":
+        assert rows(res) == [r for i, r in zip(np.flatnonzero(live),
+                                               probe_rows) if i not in hit]
+    else:
+        kept, pad = res
+        assert rows(kept) == [p for p, ok in zip(pairs, passes) if ok]
+        assert rows(pad) == [r + (None, None) for i, r in zip(
+            np.flatnonzero(live), probe_rows) if i not in hit]

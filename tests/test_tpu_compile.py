@@ -268,6 +268,27 @@ def test_probe_walk_at_a_full_batch(topo, one_chip):
     assert text.count(" while(") == 2 and "reduce-window(" not in text
 
 
+def test_join_expand_slot_map_at_q13s_shapes(topo, one_chip):
+    """The expand's slot math at ``sf1.q13``'s shapes: a 2^17-row probe
+    batch against a 2^21-row build into 2^20 output slots. Seconds to
+    compile, and what it compiled holds no loop (a ``searchsorted`` of the
+    slots is a binary search of 18 rounds) and no windowed scan."""
+    import types
+    from spark_rapids_tpu.exec.joins import _JoinKernels
+
+    def slots(mask, b_order, starts, counts):
+        probe = types.SimpleNamespace(row_mask=mask, capacity=mask.shape[0])
+        build = types.SimpleNamespace(capacity=b_order.shape[0])
+        return _JoinKernels(None)._slots(build, probe, b_order, starts,
+                                         counts, 1 << 20, True)
+    args = (jax.ShapeDtypeStruct((1 << 17,), jnp.bool_),
+            jax.ShapeDtypeStruct((1 << 21,), jnp.int32),
+            jax.ShapeDtypeStruct((1 << 17,), jnp.int64),
+            jax.ShapeDtypeStruct((1 << 17,), jnp.int64))
+    text = _compile(slots, args, one_chip).as_text()
+    assert " while(" not in text and "reduce-window(" not in text
+
+
 @pytest.mark.parametrize("keys", [
     {"k": pa.array([7, 8], pa.int64())},                  # Q18's, Q3's
     {"rf": ["A", "N"], "ls": ["F", "O"]}], ids=["int64", "q1_strings"])
