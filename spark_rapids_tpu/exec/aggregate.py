@@ -41,6 +41,7 @@ __all__ = ["TpuHashAggregateExec", "fused_grouped_aggregate",
            "passed_through", "SKIP_SHARE"]
 
 _BIG = np.int64(2**62)
+_BIG32 = np.int32(2**31 - 1)
 
 
 def _minmax_identity(xp_dtype, for_min: bool):
@@ -161,10 +162,13 @@ def _keys_equal_prev(sv: jax.Array) -> jax.Array:
 #: with dense masked reductions instead of scatters into ``capacity``
 #: segments; the device picks the branch from the batch's own group count.
 #: The dense branch costs one streaming pass over the batch per live group,
-#: a scatter-add ~77 ms per buffer per 2^20 rows whatever the groups; fixed
-#: from the chip readings in PERF.md section 6 (PR 31), where the dense
-#: branch at its worst (this many groups, 2^20 rows, Q1's 11 buffers) takes
-#: under a quarter of the scatter branch's time.
+#: a 64-bit scatter 78-122 ms per buffer per 2^20 rows whatever the groups
+#: (an int32 one 7 ms: the scatter branch's counts and positions, PERF.md
+#: section 6, PR 43); fixed from the chip readings in PERF.md section 6
+#: (PR 31), where the dense branch at its worst (this many groups, 2^20
+#: rows, Q1's 11 buffers) takes under a quarter of the scatter branch's
+#: time, as the branch was then: all 19 of its reductions over Q1's batch
+#: scattered in 64 bits, where the 7 sums alone still do.
 FEW_GROUPS = 16
 
 
@@ -190,13 +194,36 @@ def _seg_max(x, gid, cap):
     return jax.ops.segment_max(x, gid, num_segments=cap)
 
 
+def _chosen_row(op: str, contrib: jax.Array, gid: jax.Array, cap: int,
+                pos: jax.Array, rows: int) -> jax.Array:
+    """Each segment's first (``first``) or last (``last``) contributing
+    row, as int32 indices into ``rows`` rows; a segment with none gives an
+    index in range whose value its validity hides."""
+    big = _BIG if cap == 1 else _BIG32
+    p = jnp.where(contrib, -pos if op == "last" else pos,
+                  jnp.full_like(pos, big))
+    best = _seg_min(p, gid, cap)
+    idx = -best if op == "last" else best
+    return jnp.clip(idx, 0, rows - 1).astype(jnp.int32)
+
+
 def _reduce_segment(op: str, vals: jax.Array, contrib: jax.Array,
                     gid: jax.Array, cap: int, pos: jax.Array,
                     out_dt: dt.DataType) -> Tuple[jax.Array, jax.Array]:
-    """Per-group reduction -> (values[cap], validity[cap])."""
+    """Per-group reduction -> (values[cap], validity[cap]).
+
+    Into ``cap > 1`` segments the row counts and the ``first`` / ``last``
+    positions are reduced in int32: both are integers below ``cap``, exact
+    in 32 bits, and a 32-bit scatter is the chip's native one where a
+    64-bit scatter lowers to a variadic scatter over pairs of 32-bit words
+    (see ``FEW_GROUPS`` for the costs). ``pos`` is then int32 too. The
+    values keep their own dtype: their sums, minima and maxima are the
+    aggregate's answer at the precision the plan asked for. Into one
+    segment every reduction is a plain reduce and counts in int64."""
     out_dtype = jnp.dtype(np.bool_ if isinstance(out_dt, dt.BooleanType)
                           else out_dt.np_dtype())
-    counts = _seg_sum(contrib.astype(jnp.int64), gid, cap)
+    counts = _seg_sum(contrib.astype(jnp.int64 if cap == 1 else jnp.int32),
+                      gid, cap)
     has = counts > 0
     if op == "count":
         return counts.astype(out_dtype), jnp.ones(cap, dtype=bool)
@@ -208,11 +235,7 @@ def _reduce_segment(op: str, vals: jax.Array, contrib: jax.Array,
                                          out_dt.precision)
             return out, jnp.logical_and(has, jnp.logical_not(over))
         if op in ("first", "last"):
-            p = jnp.where(contrib, -pos if op == "last" else pos,
-                          jnp.full_like(pos, _BIG))
-            best = _seg_min(p, gid, cap)
-            idx = -best if op == "last" else best
-            idx = jnp.clip(idx, 0, vals.shape[0] - 1).astype(jnp.int32)
+            idx = _chosen_row(op, contrib, gid, cap, pos, vals.shape[0])
             return jnp.take(vals, idx, axis=0), has
         raise TypeError(f"decimal128 aggregate op {op!r} is host-only")
     if op in ("sum", "sumsq"):
@@ -246,11 +269,7 @@ def _reduce_segment(op: str, vals: jax.Array, contrib: jax.Array,
                 out = jnp.where(nan_counts > 0, jnp.full_like(out, jnp.nan), out)
         return out.astype(out_dtype), has
     if op in ("first", "last"):
-        p = jnp.where(contrib, -pos if op == "last" else pos,
-                      jnp.full_like(pos, _BIG))
-        best = _seg_min(p, gid, cap)
-        idx = -best if op == "last" else best
-        idx = jnp.clip(idx, 0, vals.shape[0] - 1).astype(jnp.int32)
+        idx = _chosen_row(op, contrib, gid, cap, pos, vals.shape[0])
         return jnp.take(vals, idx, axis=0).astype(out_dtype), has
     if op == "any":
         x = jnp.where(contrib, vals, jnp.zeros_like(vals))
@@ -272,7 +291,6 @@ _COLLECT_OPS = frozenset(
 #: 24 x 2^20 rows of aggregate for 16.0 s a query on one v5e; at 2^22 two
 #: chunks and one step, 10 x 2^20 (PERF.md section 6, PR 34)
 _CHUNK_ROWS = 1 << 22
-_BIG32 = np.int32(2**31 - 1)
 
 #: a grouped partial aggregate is skipped for the rest of a partition when
 #: the first batch it reduced kept more than 1/``SKIP_SHARE`` of that
@@ -967,9 +985,12 @@ class TpuHashAggregateExec(TpuExec):
             def scatter():
                 """Every row scattered into its group's segment: flat in
                 the group count, ``cap`` segments and ``cap`` key rows."""
+                # positions in 32 bits, as ``_reduce_segment`` takes them
+                # into ``cap`` segments
+                pos32 = jnp.arange(cap, dtype=jnp.int32)
                 with jax.named_scope("groupby_key_gather"):
-                    rep_src = jnp.where(active_s, pos,
-                                        jnp.full_like(pos, _BIG))
+                    rep_src = jnp.where(active_s, pos32,
+                                        jnp.full_like(pos32, _BIG32))
                     keys = key_rows(_seg_min(rep_src, gid, cap))
                 states = []
                 with jax.named_scope("agg_scatter"):
@@ -979,7 +1000,7 @@ class TpuHashAggregateExec(TpuExec):
                             _collect_segment(op, sv, slen, contrib, gid, cap,
                                              list_width)
                             if op in _COLLECT_OPS else
-                            _reduce_segment(op, sv, contrib, gid, cap, pos,
+                            _reduce_segment(op, sv, contrib, gid, cap, pos32,
                                             out_dt))
                 return keys, states
 

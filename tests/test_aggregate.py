@@ -208,6 +208,129 @@ def test_grouped_branches_differential(branch, strategy, case, monkeypatch):
         assert phases["agg.dense" if took_dense else "agg.scatter"]["calls"]
 
 
+#: case -> (aggregates, whether the final merges the rows passed through
+#: as one-row states, so that it reduces many states a group)
+_SCATTER_CASES = {
+    "count": (lambda: [count(col("v")).alias("a"),
+                       count_star().alias("b")], False),
+    "merge_sum_of_counts": (lambda: [count(col("v")).alias("a"),
+                                     count_star().alias("b")], True),
+    "float64_sum_with_nulls": (lambda: [fsum(col("v")).alias("a"),
+                                        fsum(col("nul")).alias("b")], True),
+    "first_last_with_nulls": (lambda: [first(col("i")).alias("a"),
+                                       last(col("v")).alias("b")], True),
+    "min_max_with_nan": (lambda: [fmin(col("v")).alias("a"),
+                                  fmax(col("v")).alias("b")], True),
+}
+
+
+def _scatter_reference(case, k, v, i):
+    """-> (keys in first-appearance order, {output: (values, validity)}):
+    the case's aggregates group by group in numpy, rows in table order."""
+    keys = list(dict.fromkeys(k.tolist()))
+    vv, iv = ~v.mask, ~i.mask
+    out = {"a": [], "b": []}
+    for key in keys:
+        rows = np.flatnonzero(k == key)
+        vr, ir = v.data[rows][vv[rows]], i.data[rows][iv[rows]]
+        if case in ("count", "merge_sum_of_counts"):
+            got = [(np.int64(len(vr)), True), (np.int64(len(rows)), True)]
+        elif case == "float64_sum_with_nulls":
+            acc = np.zeros(1)
+            np.add.at(acc, np.zeros(len(vr), np.int64), vr)
+            got = [(acc[0], len(vr) > 0), (0.0, False)]
+        elif case == "first_last_with_nulls":
+            got = [(ir[0] if len(ir) else 0, len(ir) > 0),
+                   (vr[-1] if len(vr) else 0.0, len(vr) > 0)]
+        else:
+            # Spark's total order: NaN is the largest double
+            nan = np.isnan(vr)
+            lo = vr[~nan].min() if (~nan).any() else np.nan
+            hi = np.nan if nan.any() else vr.max() if len(vr) else 0.0
+            got = [(lo, len(vr) > 0), (hi, len(vr) > 0)]
+        for name, g in zip("ab", got):
+            out[name].append(g)
+    return keys, {n: (np.array([x for x, _ in g]), np.array([h for _, h in g]))
+                  for n, g in out.items()}
+
+
+@pytest.mark.parametrize("case", sorted(_SCATTER_CASES))
+@pytest.mark.parametrize("strategy", ["sort", "hash"])
+def test_scatter_branch_against_numpy(strategy, case, monkeypatch):
+    """The scatter branch (``FEW_GROUPS`` patched to 0) of a partial
+    aggregate and of the final one over its states, or over the rows
+    passed through as one-row states, against numpy group by group: the
+    same bits, the same validity, ``count`` as int64, and the groups in
+    the grouping's order (first appearance under the hash grouping, key
+    order under the sorted one). Row counts and positions are reduced in
+    32 bits into ``cap`` segments; the answer may not show it."""
+    import spark_rapids_tpu.exec.aggregate as A
+    from spark_rapids_tpu.exec.wholestage import TpuWholeStageExec
+    from spark_rapids_tpu.session import TpuSession
+    from spark_rapids_tpu.utils.compile_cache import clear_cache
+    aggs, passed = _SCATTER_CASES[case]
+    rng = np.random.default_rng([43, sum(map(ord, case))])
+    n = 700
+    k = rng.integers(-30, 30, n) * 7
+    k[rng.random(n) < 0.45] = 0      # a group of ~300 rows
+    v = np.ma.masked_array(rng.normal(size=n) * 1e3,
+                           mask=rng.random(n) < 0.2)
+    v.data[rng.random(n) < 0.1] = np.nan
+    v.data[k == 14] = np.nan                 # a group whose values are NaN
+    v.mask[k == 21] = True                   # and one whose are null
+    i = np.ma.masked_array(rng.integers(-1 << 40, 1 << 40, n),
+                           mask=rng.random(n) < 0.3)
+    t = pa.table({"k": k, "v": pa.array(v.data, mask=v.mask),
+                  "i": pa.array(i.data, mask=i.mask),
+                  "nul": pa.array([None] * n, type=pa.float64())})
+    monkeypatch.setattr(A, "FEW_GROUPS", 0)
+    clear_cache()        # programs are cached by plan, not by FEW_GROUPS
+    sess = TpuSession({"spark.rapids.tpu.batchRowsMinBucket": 1024,
+                       "spark.rapids.tpu.groupby.strategy": strategy,
+                       "spark.rapids.tpu.aqe.enabled": False})
+    try:
+        q = sess.create_dataframe(t).group_by("k").agg(*aggs())
+        plan = sess._physical(q.logical, device=True)
+
+        def find(p, cls):
+            if isinstance(p, cls):
+                return p
+            return next(filter(None, (find(c, cls) for c in p.children)),
+                        None)
+        stage = find(plan, TpuWholeStageExec)
+        final = find(plan, A.TpuHashAggregateExec)
+        assert final.mode == "final" and stage.chain[-1].mode == "partial"
+        batch = next(stage.source.execute_columnar(0))
+        states = (stage.passthrough_fn() if passed else stage.batch_fn())(
+            batch)
+        out = final.batch_fn()(states)
+        groups = int(out.num_rows)
+        # the columns: the key, then the aggregates in the query's order
+        got = {name: (np.asarray(c.data)[:groups],
+                      np.asarray(c.validity)[:groups])
+               for name, c in zip("kab", out.columns)}
+    finally:
+        sess.close()
+        clear_cache()
+    keys, want = _scatter_reference(case, k, v, i)
+    if strategy == "sort":
+        order = np.argsort(keys, kind="stable")
+        keys = [keys[j] for j in order]
+        want = {nm: (x[order], h[order]) for nm, (x, h) in want.items()}
+    assert groups == len(keys)
+    np.testing.assert_array_equal(got["k"][0], keys)
+    for name, (x, has) in want.items():
+        data, valid = got[name]
+        np.testing.assert_array_equal(valid, has, err_msg=name)
+        if case in ("count", "merge_sum_of_counts"):
+            assert data.dtype == np.int64, (name, data.dtype)
+        # the same bits where the value is valid
+        assert data[has].dtype.itemsize == 8
+        np.testing.assert_array_equal(
+            data[has].view(np.int64),
+            x[has].astype(data.dtype).view(np.int64), err_msg=name)
+
+
 # ---------------------------------------------------------------------------
 # what the grouped aggregate's program may hold (a jaxpr guard of the kind
 # tests/test_shrink_to_fit.py has): gathers and scatters cost by their index
@@ -421,6 +544,27 @@ def test_grouped_holds_one_cond_with_a_dense_and_a_scatter_branch(
     assert names.count("scatter-add") <= 18, names.count("scatter-add")
     assert [n for n in names if n.startswith("scatter")
             and n != "scatter-add"] == ["scatter-min"]
+
+    def updates(eqns, name):
+        """dtype names of the updates of a jaxpr's ``name`` equations"""
+        return [e.invars[2].aval.dtype.name for e in eqns
+                if e.primitive.name == name]
+
+    def outputs(eqns, name):
+        return [e.outvars[0].aval.dtype.name for e in eqns
+                if e.primitive.name == name]
+    # ... the representative row's scatter-min over int32 positions
+    assert updates(scatter, "scatter-min") == ["int32"]
+    # ... a 64-bit scatter-add for each of the 7 value buffers (the sums)
+    # and none else: every count scatters in int32
+    adds = updates(scatter, "scatter-add")
+    assert [d for d in adds if d != "int32"] == ["float64"] * 7, adds
+    assert "int32" in adds
+    # the dense branch reduces as at the parent: counts and the
+    # representative's position in int64
+    sums = outputs(dense, "reduce_sum")
+    assert sorted(set(sums)) == ["float64", "int64"], sums
+    assert outputs(dense, "reduce_min") == ["int64"]
     # outside the branches only the bucket-resolve loops scatter
     outside = [e.primitive.name for e in q1_shaped_jaxpr.eqns
                if e.primitive.name.startswith("scatter")]

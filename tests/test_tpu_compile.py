@@ -184,6 +184,39 @@ def test_q18s_subquery_stage_passing_its_rows_through(topo, one_chip, sess,
     assert " scatter(" not in text
 
 
+def _scatter_results(text):
+    """The element types of every scatter's result in compiled HLO text:
+    one entry a scatter, a tuple of them where it is variadic."""
+    return [tuple(re.findall(r"([a-z]+\d+)\[", m))
+            for m in re.findall(r"= (\(.*?\)|\S+) scatter\(", text)]
+
+
+def test_q18s_final_aggregate_scatters_one_value_buffer_in_64_bits(
+        topo, one_chip, sess, rng):
+    """Q18's final ``sum(l_quantity)`` by ``l_orderkey`` over one-row
+    states: the chip holds a 64-bit scatter as a variadic scatter over a
+    pair of 32-bit words. The grouping's scatter branch scatters the row
+    counts and the representative positions in int32 (the parent held
+    three such pairs: an int64 count, an int64 position and the value), so
+    the program's one variadic scatter is the float64 sum's (two float32);
+    the bucket-resolve loop's scatters are int32 as well."""
+    from spark_rapids_tpu.exec.aggregate import TpuHashAggregateExec
+    from spark_rapids_tpu.exec.wholestage import TpuWholeStageExec
+    from spark_rapids_tpu.expr.functions import col, sum
+    df = sess.create_dataframe(_table(rng, ROWS - 7))
+    q = df.group_by("k").agg(sum(col("v")).alias("sum_qty"))
+    plan = sess._physical(q.logical, device=True)
+    final = _find(plan, TpuHashAggregateExec)
+    stage = _find(plan, TpuWholeStageExec)
+    batch = next(stage.source.execute_columnar(0))
+    states = stage.passthrough_fn()(batch)
+    text = _compile(final.batch_fn(with_rounds=True), (states,),
+                    one_chip).as_text()
+    scatters = _scatter_results(text)
+    assert [s for s in scatters if len(s) > 1] == [("f32", "f32")], scatters
+    assert set(scatters) - {("f32", "f32")} == {("s32",)}, scatters
+
+
 def test_pk_hash_join(topo, one_chip, sess, rng):
     """FK->PK join on the sort-free slot table: build prep and fused probe
     (exec/joins.py pk_hash_join_fn)."""
